@@ -16,7 +16,8 @@ def top_level_task():
     xs, y = synthetic_dataset(cfg.batch_size * 2, [inp.shape[1:]], (1,),
                               num_classes=1000)
     model.fit(xs[0], y, epochs=cfg.epochs)
+    return model
 
 
 if __name__ == "__main__":
-    top_level_task()
+    model = top_level_task()

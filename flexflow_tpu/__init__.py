@@ -8,16 +8,6 @@ annotations, Legion DMA/GASNet become ICI/DCN collectives emitted by GSPMD,
 and the CUDA/cuDNN kernels become XLA HLO (+ Pallas for the hot paths).
 """
 
-import os as _os
-
-if _os.environ.get("FLEXFLOW_PLATFORM"):
-    # Force the jax backend through jax.config: embedded hosts (C API) and
-    # subprocess tests cannot rely on JAX_PLATFORMS alone because a
-    # pre-registered accelerator PJRT plugin may override the env var.
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", _os.environ["FLEXFLOW_PLATFORM"])
-
 from . import losses, metrics, obs
 from .analysis import (Diagnostic, DiagnosticReport, Severity,
                        VerificationError, verify)

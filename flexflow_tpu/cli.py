@@ -76,6 +76,15 @@ def main(argv=None) -> None:
         # flight-recorder post-mortem dumps (docs/observability.md)
         from .obs.flight import flight_main
         raise SystemExit(flight_main(argv[1:]))
+    run_script(argv)
+
+
+def run_script(argv) -> dict:
+    """``flexflow-tpu <script.py> [FlexFlow flags]``: parse the flags
+    into the process default FFConfig and execute the script as
+    ``__main__``.  Returns the script's module namespace, so an
+    embedding caller (chip_smoke.py) can inspect the model the script
+    trained; :func:`main` discards it."""
     script = None
     for a in argv:
         if a.endswith(".py"):
@@ -137,9 +146,13 @@ def main(argv=None) -> None:
     from flexflow_tpu.parallel import initialize_distributed
     initialize_distributed(
         num_processes=cfg.num_nodes if cfg.num_nodes > 1 else None)
+    # a training run keeps its compiles like the engines and harnesses
+    # do (flexflow_tpu/compile_cache.py: the one placement rule)
+    from .compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
     # the script sees the remaining argv like any __main__
     sys.argv = [script] + flags
-    runpy.run_path(script, run_name="__main__")
+    return runpy.run_path(script, run_name="__main__")
 
 
 def _lint_builders():

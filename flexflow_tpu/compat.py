@@ -1,27 +1,15 @@
-"""JAX API-drift compatibility shim (ROADMAP "JAX API-drift
-modernization").
+"""Host-memory placement that depends on the BACKEND, not the jax version.
 
-The repo targets two jax surfaces that moved underneath it:
+The ``pinned_host`` memory kind is not addressable everywhere (XLA:CPU
+exposes only ``unpinned_host``).  :func:`host_memory_kind` reports the
+host-side memory kind the running backend actually addresses
+(preferring ``pinned_host``), and :func:`with_host_memory` places a
+sharding there, returning None when the backend has no host memory
+space at all so callers keep device placement instead of crashing.
 
-* ``jax.shard_map`` — promoted to the top level in newer jax; on the
-  jaxlib this container ships it still lives at
-  ``jax.experimental.shard_map.shard_map`` with the OLD keyword names
-  (``check_rep`` instead of ``check_vma``, ``auto=`` naming the
-  NON-manual axes instead of ``axis_names=`` naming the manual ones).
-  :func:`shard_map` feature-detects once and adapts the call.
-* the ``pinned_host`` memory kind — not every jaxlib/backend exposes
-  it (this container's CPU backend has only ``unpinned_host``).
-  :func:`host_memory_kind` reports the host-side memory kind the
-  running backend actually addresses (preferring ``pinned_host``),
-  and :func:`with_host_memory` places a sharding there, returning
-  None when the backend has no host memory space at all so callers
-  can keep device placement instead of crashing.
-
-ONE module owns the feature detection: every consumer (ops/attention's
-ring, ops/conv's pallas-pool lift, parallel/pipeline, the host-placed
-parameter paths in model.py and ops/linear.py, and the tests that pin
-host placement) imports from here, so the next jax migration is a
-one-file change.
+Every consumer (the host-placed parameter paths in model.py and
+ops/linear.py, and the tests that pin host placement) imports from
+here.
 """
 
 from __future__ import annotations
@@ -30,96 +18,15 @@ import functools
 from typing import Optional
 
 
-def _resolve_shard_map():
-    """The callable + which keyword dialect it speaks.  Returns
-    ``(fn, modern)`` where ``modern`` means the top-level ``jax.
-    shard_map`` surface (``check_vma=``/``axis_names=``)."""
-    import jax
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, True
-    from jax.experimental.shard_map import shard_map as fn
-    return fn, False
-
-
-@functools.lru_cache(maxsize=1)
-def _shard_map_impl():
-    return _resolve_shard_map()
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True,
-              axis_names=None):
-    """Version-portable ``shard_map``.
-
-    ``axis_names`` (modern spelling) names the axes the body handles
-    MANUALLY; None means all of ``mesh``'s axes (the default on every
-    surface).  On the legacy experimental surface this translates to
-    ``auto = mesh_axes - axis_names`` and ``check_vma`` to
-    ``check_rep``."""
-    fn, modern = _shard_map_impl()
-    if modern:
-        kw = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = frozenset(axis_names)
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    kw = {"check_rep": check_vma}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-@functools.lru_cache(maxsize=1)
-def take_wraps_negative_ids() -> bool:
-    """Whether this jax's ``jnp.take`` (default fill mode) treats a
-    NEGATIVE index as python-style wraparound to the last row — the
-    legacy behavior, where the forward reads a real row and the VJP
-    routes the gradient there — rather than as out-of-bounds (NaN fill,
-    gradient dropped).  The sparse embedding-update scatter must mirror
-    whichever semantics the dense autodiff path has on the running jax
-    (model.py; tests/test_sparse_embedding.py pins sparse == dense)."""
-    try:
-        import jax
-        import jax.numpy as jnp
-
-        # ensure_compile_time_eval: the first call may happen inside a
-        # jit trace (the sparse-update branch is decided at trace
-        # time), where a bare op would return a tracer and bool() would
-        # raise — and the lru_cache would pin the wrong answer
-        with jax.ensure_compile_time_eval():
-            y = jnp.take(jnp.asarray([[1.0], [2.0]]), jnp.asarray([-1]),
-                         axis=0)
-            # wraparound reads the last row (2.0); modern jax NaN-fills
-            return bool((y == 2.0).all())
-    except Exception:
-        return False
-
-
-def shard_map_partial_auto_supported() -> bool:
-    """Whether this jax can compile a PARTIAL-auto shard_map (some mesh
-    axes manual, others left to GSPMD).  The legacy experimental
-    surface lowers ``axis_index``/ring collectives through instructions
-    the SPMD partitioner rejects (observed: ``PartitionId ... is not
-    supported for SPMD partitioning``, plus hard XLA aborts) when auto
-    axes are present — callers with an exact sequential fallback (the
-    pipeline) should take it instead of crashing the process."""
-    return _shard_map_impl()[1]
-
-
 @functools.lru_cache(maxsize=1)
 def host_memory_kind() -> Optional[str]:
     """The host-side memory kind this backend addresses: ``pinned_host``
     where available, else ``unpinned_host``, else None (no host memory
     space — callers keep device placement).  Cached: the answer is a
     property of the process's backend."""
-    try:
-        import jax
+    import jax
 
-        dev = jax.local_devices()[0]
-        kinds = {m.kind for m in dev.addressable_memories()}
-    except Exception:
-        return None
+    kinds = {m.kind for m in jax.local_devices()[0].addressable_memories()}
     for kind in ("pinned_host", "unpinned_host"):
         if kind in kinds:
             return kind
@@ -134,10 +41,7 @@ def with_host_memory(sharding):
     kind = host_memory_kind()
     if kind is None:
         return None
-    try:
-        return sharding.with_memory_kind(kind)
-    except Exception:
-        return None
+    return sharding.with_memory_kind(kind)
 
 
-__all__ = ["shard_map", "host_memory_kind", "with_host_memory"]
+__all__ = ["host_memory_kind", "with_host_memory"]

@@ -439,6 +439,15 @@ class FFModel:
         # FF104) through _run_verifier below, replacing the old ad-hoc
         # warning with the structured diagnostic path.
 
+        # exported BEFORE the mesh is built: search-and-export (-s) for
+        # a machine this process does not own must leave its file even
+        # though going on to train there is an error (below)
+        if cfg.export_strategy_file:
+            from .strategy.proto import save_strategy_file
+            save_strategy_file(cfg.export_strategy_file,
+                               {op.name: op.parallel_config
+                                for op in self.layers if op.parallel_config})
+
         # --- mesh construction ---
         if mesh is not None:
             self.mesh = mesh
@@ -447,11 +456,6 @@ class FFModel:
             if shape is None:
                 shape = self._infer_mesh_shape()
             self.mesh = MachineMesh(shape)
-        if cfg.export_strategy_file:
-            from .strategy.proto import save_strategy_file
-            save_strategy_file(cfg.export_strategy_file,
-                               {op.name: op.parallel_config
-                                for op in self.layers if op.parallel_config})
 
         # --- label tensor (reference model.cc:1001-1006) ---
         if self.label_tensor is None:
@@ -554,12 +558,12 @@ class FFModel:
         ndev = (self.config.num_devices if self.config.workers_per_node
                 else len(jax.devices()))
         if ndev > len(jax.devices()):
-            from .fflogger import get_logger
-            get_logger("mesh").warning(
+            raise ValueError(
                 f"-ll:tpu/--nodes request {ndev} devices but only "
-                f"{len(jax.devices())} are visible; training on "
-                f"{len(jax.devices())}")
-        ndev = min(ndev, len(jax.devices()))
+                f"{len(jax.devices())} are visible "
+                f"(platform {jax.devices()[0].platform}); a run asked "
+                f"to train on {ndev} chips does not quietly train on "
+                f"fewer")
         lcm = {"n": 1, "c": 1, "h": 1, "w": 1, "s": 1}
         mx = dict(lcm)
         any_cfg = False
@@ -879,21 +883,14 @@ class FFModel:
                     trainable.pop(_ROWS + op_name)
                     idx = batch[pos].astype(jnp.int32).reshape(-1)
                     # negative ids must follow the DENSE path's take-VJP
-                    # on the running jax (sparse == dense is the pin,
-                    # tests/test_sparse_embedding.py): modern jax drops
-                    # them — push them out of range so mode="drop"
-                    # drops too; legacy jax wraps them to the last row —
-                    # .at[] wraps numpy-style already, so leave them
+                    # (sparse == dense is the pin,
+                    # tests/test_sparse_embedding.py): jnp.take wraps
+                    # them python-style and its VJP routes the gradient
+                    # to that row, while scatter modes treat negatives
+                    # as out of bounds — wrap explicitly so the -1
+                    # row's gradient lands where the dense path put it
                     nrows = params[tname].shape[0]
-                    from .compat import take_wraps_negative_ids
-                    if take_wraps_negative_ids():
-                        # scatter modes treat negatives as OOB even
-                        # where take wraps them — wrap explicitly so
-                        # the -1 row's gradient lands where the dense
-                        # path put it
-                        idx = jnp.where(idx < 0, idx + nrows, idx)
-                    else:
-                        idx = jnp.where(idx < 0, nrows, idx)
+                    idx = jnp.where(idx < 0, idx + nrows, idx)
                     g2 = g.reshape(idx.shape[0], -1)
                     # scatter-add == plain-SGD exactly: untouched rows
                     # have zero gradient, duplicate ids accumulate.

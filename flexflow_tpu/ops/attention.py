@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from ..compat import shard_map
 from ..initializers import GlorotUniform, ZeroInitializer
 from ..op import Op, OpContext, OpType
 from .common import cast_compute
@@ -78,20 +77,42 @@ def _tuned_block_sizes(sq: int, sk: int):
         block_k_major_dq=bkv, block_k_dq=bkv, block_q_dq=bq)
 
 
-def _flash_attention(q, k, v, causal: bool, scale: float):
+def _flash_attention(q, k, v, causal: bool, scale: float, mesh=None):
     """Pallas TPU flash attention (jax.experimental.pallas.ops.tpu):
     blockwise online softmax on-chip — the VMEM-resident fused kernel the
     pallas_guide prescribes for the attention hot op.  Layout adapters:
-    ours is (n,s,h,d), the kernel wants (n,h,s,d)."""
+    ours is (n,s,h,d), the kernel wants (n,h,s,d).
+
+    On a distributed ``mesh`` the kernel runs per shard under shard_map:
+    GSPMD treats a pallas_call as an opaque custom call and would
+    all-gather its operands, so every chip would run the whole batch.
+    Attention is independent per sample and per head, so the batch
+    shards over ``n`` and the heads over ``c`` halo-free (a dim the
+    axis does not divide stays whole); other mesh axes see replicas."""
     from jax.experimental.pallas.ops.tpu.flash_attention import \
         flash_attention as _fa
 
-    qt = jnp.transpose(q, (0, 2, 1, 3))
-    kt = jnp.transpose(k, (0, 2, 1, 3))
-    vt = jnp.transpose(v, (0, 2, 1, 3))
-    out = _fa(qt, kt, vt, causal=causal, sm_scale=scale,
-              block_sizes=_tuned_block_sizes(q.shape[1], k.shape[1]))
-    return jnp.transpose(out, (0, 2, 1, 3))
+    blocks = _tuned_block_sizes(q.shape[1], k.shape[1])
+
+    def kern(q, k, v):
+        qt = jnp.transpose(q, (0, 2, 1, 3))
+        kt = jnp.transpose(k, (0, 2, 1, 3))
+        vt = jnp.transpose(v, (0, 2, 1, 3))
+        out = _fa(qt, kt, vt, causal=causal, sm_scale=scale,
+                  block_sizes=blocks)
+        return jnp.transpose(out, (0, 2, 1, 3))
+
+    if mesh is None or not mesh.is_distributed:
+        return kern(q, k, v)
+
+    def axes(axis, size):
+        return (mesh.subaxes(axis) or None
+                if size % mesh.axis_size(axis) == 0 else None)
+
+    spec = PartitionSpec(axes("n", q.shape[0]), None,
+                         axes("c", q.shape[2]), None)
+    return jax.shard_map(kern, mesh=mesh.mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 def _decode_attention(q, k_cache, v_cache, pos, scale: float):
@@ -268,12 +289,12 @@ def ring_attention(q, k, v, mesh, causal: bool, scale: float,
                  dropout_rate=dropout_rate if rng is not None else 0.0)
     if rng is None:
         wrapped = lambda q, k, v: fn(q, k, v, None)  # noqa: E731
-        return shard_map(wrapped, mesh.mesh,
-                         in_specs=(spec, spec, spec), out_specs=spec,
-                         check_vma=False)(q, k, v)
-    return shard_map(fn, mesh.mesh,
-                     in_specs=(spec, spec, spec, PartitionSpec()),
-                     out_specs=spec, check_vma=False)(q, k, v, rng)
+        return jax.shard_map(wrapped, mesh=mesh.mesh,
+                             in_specs=(spec, spec, spec), out_specs=spec,
+                             check_vma=False)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh.mesh,
+                         in_specs=(spec, spec, spec, PartitionSpec()),
+                         out_specs=spec, check_vma=False)(q, k, v, rng)
 
 
 class MultiHeadAttention(Op):
@@ -372,7 +393,7 @@ class MultiHeadAttention(Op):
                                   self.dropout if ctx.training else 0.0, rng)
         elif _use_flash(q, k, ctx.flash_attention, rng is not None,
                         training=ctx.training):
-            attn = _flash_attention(q, k, v, self.causal, scale)
+            attn = _flash_attention(q, k, v, self.causal, scale, ctx.mesh)
         else:
             attn = _dense_attention(q, k, v, self.causal, scale,
                                     self.dropout if ctx.training else 0.0,
@@ -401,7 +422,7 @@ class MultiHeadAttention(Op):
         q, k, v = self._qkv(params, xq, xq, xq, ctx)
         scale = 1.0 / math.sqrt(self.head_dim)
         if _use_flash(q, k, ctx.flash_attention, False, training=False):
-            attn = _flash_attention(q, k, v, self.causal, scale)
+            attn = _flash_attention(q, k, v, self.causal, scale, ctx.mesh)
         else:
             attn = _dense_attention(q, k, v, self.causal, scale, 0.0, None)
         return [self._out_proj(params, attn, n, sq, ctx)], k, v

@@ -72,8 +72,8 @@ def _fast_max_pool(x, kernel, stride, padding, spatial):
     (2, 3) for NCHW) of a 4-D array.  Forward is an elementwise max
     over the k*k strided window slices — XLA fuses the max tree into
     one pass, where generic ``reduce_window`` measured 3-6x the
-    bandwidth roofline on chip (stem pool fwd 1.2 ms vs ~0.2,
-    artifacts/r5/bottleneck_inc.log)."""
+    bandwidth roofline on chip (stem pool fwd 1.2 ms vs ~0.2 —
+    attested, record removed in PR 21, to be re-measured)."""
     (kh, kw), (sh, sw), (ph, pw) = kernel, stride, padding
     dh, dw = spatial
     h, w = x.shape[dh], x.shape[dw]
@@ -132,8 +132,8 @@ _fast_max_pool.defvjp(_fast_max_pool_fwd, _fast_max_pool_bwd)
 def _use_fast_pool() -> bool:
     # Built-in default OFF: on the one real device kind measured so far
     # (TPU v5 lite) the equality-mask VJP lost 6.5x to SelectAndScatter
-    # (artifacts/r5/microbench.log), so unmeasured kinds keep XLA's
-    # lowering until decide_fast_kernels.py measures a win there.
+    # (attested, record removed in PR 21), so unmeasured kinds keep
+    # XLA's lowering until a chip run measures a win there (ROADMAP S4).
     return flag_enabled("FF_FAST_POOL", "fast_pool", default=False)
 
 
@@ -252,7 +252,7 @@ _conv_fast_dgrad.defvjp(_conv_fast_dgrad_fwd, _conv_fast_dgrad_bwd)
 
 def _use_fast_dgrad() -> bool:
     # Built-in default OFF — measured 2.6x slower than XLA's dilated
-    # dgrad on TPU v5 lite (artifacts/r5/microbench.log); see
+    # dgrad on TPU v5 lite (attested, record removed in PR 21); see
     # _use_fast_pool for the tuning story.
     return flag_enabled("FF_FAST_DGRAD", "fast_dgrad", default=False)
 
@@ -417,23 +417,7 @@ class Pool2D(Op):
             padding = ((0, 0), (0, 0), (ph, ph), (pw, pw))
         if self.pool_type == "max":
             y = None
-            from .pallas_pool import (pallas_max_pool_nhwc, supported,
-                                      use_pallas_pool)
-
-            if (ctx.conv_layout == "nhwc" and use_pallas_pool()
-                    and supported(x.shape, x.dtype, self.kernel,
-                                  self.stride, self.padding)):
-                # Single-pass Pallas tile kernel for BOTH directions —
-                # see pallas_pool.py for the SelectAndScatter story;
-                # distributed meshes get the shard_map lift (None when
-                # the split can't be expressed halo-free)
-                if ctx.mesh is None or not ctx.mesh.is_distributed:
-                    y = pallas_max_pool_nhwc(x, self.kernel, self.stride,
-                                             self.padding)
-                else:
-                    y = self._pallas_pool_sharded(x, ctx.mesh)
-            if y is None and _use_fast_pool() \
-                    and jnp.issubdtype(x.dtype, jnp.floating):
+            if _use_fast_pool() and jnp.issubdtype(x.dtype, jnp.floating):
                 y = _fast_max_pool(x, self.kernel, self.stride,
                                    self.padding, spatial)
             if y is None:
@@ -450,51 +434,6 @@ class Pool2D(Op):
             y = jnp.transpose(y, (0, 3, 1, 2))
         return [y]
 
-    @staticmethod
-    def _splits_spatial(dims) -> bool:
-        """Do these (n, c, h, w) split degrees touch the h/w dims — the
-        one case the halo-free shard_map lift cannot express?  Shared by
-        the runtime route (resolved strategy) and the analytic cost
-        model (candidate degrees)."""
-        return dims is not None and len(dims) >= 4 \
-            and (dims[2] > 1 or dims[3] > 1)
-
-    def _spatially_split(self) -> bool:
-        pc = self.parallel_config
-        return pc is not None and self._splits_spatial(pc.dims)
-
-    def _pallas_pool_sharded(self, x, mesh):
-        """shard_map-lifted Pallas pool for distributed meshes.  GSPMD
-        treats a bare pallas_call as an opaque custom call and would
-        all-gather the operand (verified on the 8-dev mesh), so the
-        kernel must run per-shard under manual sharding.  Pooling is
-        independent per sample, so the batch (n) mesh axes shard
-        halo-free; the lift deliberately shards ONLY over n — pool
-        strategies never c-split activations (parallel_dims), and
-        unmentioned mesh axes are replicated, which matches the
-        activation's actual state under dp/tp.  An h/w-splitting
-        strategy on THIS op falls back to the XLA lowering (returns
-        None): the spec would have to all-gather real spatial shards.
-        ``x`` is NHWC here."""
-        from jax.sharding import PartitionSpec as _P
-
-        from ..compat import shard_map as _shard_map
-        from .pallas_pool import pallas_max_pool_nhwc
-
-        if self._spatially_split():
-            return None
-        n_axes = mesh.subaxes("n")
-        if not n_axes or x.shape[0] % mesh.axis_size("n"):
-            return None
-        spec = _P(n_axes, None, None, None)
-
-        def kern(v):  # positional call keeps custom_vjp nondiff args intact
-            return pallas_max_pool_nhwc(v, self.kernel, self.stride,
-                                        self.padding)
-
-        return _shard_map(kern, mesh.mesh, in_specs=(spec,),
-                          out_specs=spec, check_vma=False)(x)
-
     def parallel_dims(self):
         return (True, False, True, True)
 
@@ -506,21 +445,5 @@ class Pool2D(Op):
         # pool2x2 measurement (seed CalibrationTable,
         # search/calibration_seed.json pool2d row) put it at 1.9x its
         # bandwidth roofline; avg-pool backward is a plain dilated sum,
-        # on roofline.  The overhead is gone only when the Pallas tile
-        # kernel would actually run: tuned ON for this device kind,
-        # shape/window inside the kernel's support envelope (layout
-        # approximated as NHWC — the library's TPU auto for pool-heavy
-        # graphs), and the split under evaluation not spatial — an
-        # h/w-splitting strategy takes the XLA fallback at runtime
-        # (Pool2D._pallas_pool_sharded) and really pays the 1.9x.
-        if self.pool_type != "max":
-            return 1.0
-        if self._splits_spatial(part_degrees):
-            return 1.9
-        from .pallas_pool import supported, use_pallas_pool
-        if use_pallas_pool():
-            n, c, h, w = self.inputs[0].shape
-            if supported((n, h, w, c), self.inputs[0].dtype, self.kernel,
-                         self.stride, self.padding):
-                return 1.0
-        return 1.9
+        # on roofline
+        return 1.9 if self.pool_type == "max" else 1.0

@@ -89,8 +89,8 @@ class LayerNorm(Op):
         x = inputs[0]
         if self.w_scale is not None and self.w_bias is not None:
             # fused single-pass Pallas kernel (ops/pallas_norm.py):
-            # default OFF behind the same tuned-table/VMEM gate as
-            # pallas_pool; bit-parity with the stock path below is
+            # default OFF behind the tuned-table gate and a VMEM
+            # bound; bit-parity with the stock path below is
             # pinned in tests/test_pallas_norm.py
             from .pallas_norm import (fused_layernorm, supported,
                                       use_pallas_norm)

@@ -17,12 +17,14 @@ tests/test_pallas_norm.py.  The backward recomputes through the plain
 jnp reference under ``jax.vjp`` (the forward's win is bandwidth; the
 backward keeps autodiff-exact gradients).
 
-Gating — the same measure-then-enable pipeline as ``pallas_pool``:
-``FF_PALLAS_NORM`` env  >  tuned-table key ``pallas_norm`` (per device
-kind, written by scripts/decide_fast_kernels.py once
-``scripts/kernel_microbench.py`` measures a win)  >  built-in OFF.
-``supported()`` additionally bounds the per-tile VMEM working set
-(``FF_PALLAS_NORM_VMEM``) and requires a whole-row tiling.
+Gating: ``FF_PALLAS_NORM`` env  >  tuned-table key ``pallas_norm`` (per
+device kind, committed once ``scripts/kernel_microbench.py`` measures a
+win there — ROADMAP S4)  >  built-in OFF.  ``supported()`` additionally
+bounds the per-tile VMEM working set (``FF_PALLAS_NORM_VMEM``, under the
+16 MiB scoped-VMEM default Mosaic applies) and requires a whole-row
+tiling.  Compiled by Mosaic and compared with the reference on TPU v5
+lite at 16 384 x 768 bf16 rows by chip_smoke.py (PR 21); whether it is
+FASTER there is not measured.
 """
 
 from __future__ import annotations
@@ -45,12 +47,14 @@ _LIVE_FACTOR = 6
 
 def use_pallas_norm() -> bool:
     """Env > tuned table (device kind) > built-in OFF (enable per
-    device kind only after kernel_microbench measures a win there)."""
+    device kind only after a chip run measures a win there)."""
     return flag_enabled("FF_PALLAS_NORM", "pallas_norm", default=False)
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode is for the CPU (tests) only; on any other
+    platform the kernel is compiled by Mosaic or raises."""
+    return jax.default_backend() == "cpu"
 
 
 def _rows(shape) -> int:

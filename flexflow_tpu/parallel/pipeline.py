@@ -56,7 +56,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec
 
-from ..compat import shard_map, shard_map_partial_auto_supported
 from .mesh import MachineMesh
 
 
@@ -142,18 +141,8 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x, mesh: MachineMesh,
                 f"num_stages={total_stages}, got {virtual_stages}")
         S_eff = total_stages // virtual_stages  # required pipeline width
     S = mesh.axis_size("p")
-    # a partial-auto shard_map (p manual, other mesh axes live — n data
-    # sharding handled by GSPMD) only compiles on the modern surface;
-    # the legacy one (compat) rejects/aborts it, so take the SAME-MATH
-    # sequential fallback there — parity with the pipelined schedule is
-    # exact by construction (the p==1 path below), only the bubble
-    # overlap is lost on that jax version
-    legacy_partial = (
-        S > 1 and not shard_map_partial_auto_supported()
-        and any(mesh.mesh.shape[a] > 1 for a in mesh.mesh.axis_names
-                if a not in mesh.subaxes("p")))
-    if S <= 1 or legacy_partial:
-        # sequential fallback: same math in the schedule's traversal order
+    if S <= 1:
+        # no pipeline axis: same math in the schedule's traversal order
         order = traversal_order(total_stages,
                                 S_eff if schedule == "interleaved" else 1,
                                 schedule)
@@ -194,18 +183,13 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x, mesh: MachineMesh,
         fn = partial(_pipeline_local, stage_fn=sfn, S=S, M=M,
                      p_axes=p_axes)
     # rank identity rides in as a p-sharded operand instead of
-    # lax.axis_index: under the legacy partial-auto shard_map surface
-    # (compat) axis_index lowers to a PartitionId instruction XLA's
-    # SPMD partitioner rejects when auto axes are present; an explicit
-    # arange sharded over p gives every rank the same value portably
+    # lax.axis_index, which under a partial-auto shard_map lowers to a
+    # PartitionId instruction the SPMD partitioner has rejected when
+    # auto axes are present; the aux accumulator crosses the boundary
+    # as shape (1,) because out_specs need a dim to name
     rank_ids = jnp.arange(S, dtype=jnp.int32)
-    # the aux accumulator crosses the shard_map boundary as shape (1,),
-    # not a scalar: a 0-d value carried through the inner lax.scan
-    # breaks the LEGACY shard_map's autodiff (its partial-eval gives
-    # the scalar residual a dim-0 spec and raises _SpecError on the
-    # grad path — minimal repro pinned while migrating to compat)
-    y, aux = shard_map(
-        fn, mesh.mesh,
+    y, aux = jax.shard_map(
+        fn, mesh=mesh.mesh,
         in_specs=(pspec, x_spec, PartitionSpec(p_axes)),
         out_specs=(x_spec, PartitionSpec(None)), check_vma=False,
         axis_names=frozenset(p_axes))(stacked_params, x, rank_ids)

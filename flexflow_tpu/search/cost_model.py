@@ -59,15 +59,23 @@ DEFAULT_SPEC = V5P_SPEC
 def spec_for_device(device_kind: str | None = None) -> DeviceSpec:
     """Pick the DeviceSpec matching the attached chip (the reference bakes
     one GPU fabric model into simulator.cu:27-29; we auto-select per
-    generation).  Unknown kinds (e.g. the CPU test backend) fall back to
-    DEFAULT_SPEC so virtual-mesh tests stay deterministic."""
+    generation).  On a TPU an unknown kind raises: pricing a chip nobody
+    described as a v5p would steer the search by a wrong machine.  Off
+    the TPU (the CPU test mesh, the device-free lint/explain tools)
+    DEFAULT_SPEC stands in, so those stay deterministic.  An explicit
+    ``device_kind`` is looked up as a TPU kind."""
     if device_kind is None:
-        try:
-            import jax
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
+        import jax
+        dev = jax.devices()[0]
+        if dev.platform != "tpu":
             return DEFAULT_SPEC
-    return _KIND_TO_SPEC.get(device_kind, DEFAULT_SPEC)
+        device_kind = dev.device_kind
+    if device_kind not in _KIND_TO_SPEC:
+        raise ValueError(
+            f"no DeviceSpec for device_kind {device_kind!r} (known: "
+            f"{sorted(_KIND_TO_SPEC)}); add its published figures to "
+            f"search/cost_model.py before searching or linting on it")
+    return _KIND_TO_SPEC[device_kind]
 
 # ops whose arithmetic runs on the VPU, not the MXU
 _VPU_OPS = {

@@ -4,9 +4,10 @@ The reference selects conv algorithms by measuring each candidate on the
 real device and caching the winner (its cudnnFindConvolutionForwardAlgorithm
 sweep, src/ops/conv_2d.cu:864-922).  The TPU analogue: alternative
 XLA lowerings (custom max-pool VJP, phase-decomposed strided dgrad,
-channels-minor concat) are benchmarked on chip by
-``scripts/decide_fast_kernels.py``, which writes the winners to
-``tuned_defaults.json`` next to this module.  Resolution order for each
+channels-minor concat) are benchmarked on chip
+(``scripts/kernel_microbench.py``, one chip command — ROADMAP S4) and
+the winners committed in ``tuned_defaults.json`` next to this module.
+Resolution order for each
 flag: explicit env var  >  tuned file entry for this device kind  >
 built-in default.  The file is committed, so the tuning survives into
 every later run on the same device kind; on device kinds never measured
@@ -37,10 +38,7 @@ def _device_kind() -> str:
     # backend is already up (never on the import path)
     import jax
 
-    try:
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
+    return jax.devices()[0].device_kind
 
 
 def flag_enabled(env_var: str, tuned_key: str, default: bool = True) -> bool:

@@ -22,7 +22,7 @@
   migrated) must all be True, and the stall/goodput booleans must
   agree with the recorded per-arm rows.
 * ``artifacts/pallas_flags_*.json`` — the per-device-kind Pallas
-  decision artifacts ``scripts/decide_pallas_flags.sh`` emits: each
+  decision artifacts (one per device kind a microbench decided): each
   must carry the schema version, device kind, and an on/speedup/row
   triple per flag.  Zero committed decisions is fine (no chip window
   yet); a MALFORMED one is not.
@@ -43,7 +43,7 @@ PREFIX_BENCH = os.path.join(REPO, "artifacts", "gen_prefix_bench_r16.json")
 _ACCEPTANCE_KEYS = ("ttft_cache_win", "prefix_parity",
                     "chunked_stall_win", "throughput_comparable",
                     "hbm_high_water_ok", "reconciliation_ok")
-_PALLAS_FLAGS = ("pallas_pool", "pallas_norm")
+_PALLAS_FLAGS = ("pallas_norm",)
 
 
 def _fail(msg: str) -> int:

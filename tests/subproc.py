@@ -11,23 +11,20 @@ min-entry-size gates opened so CPU-backend compiles are cached too.
 import os
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-# CACHE_DIR_IS_DEFAULT: conftest only session-clears the cache when it
-# owns the path — a user-supplied FF_TEST_JAX_CACHE (possibly shared
-# with other projects) must never be rmtree'd
-CACHE_DIR_IS_DEFAULT = "FF_TEST_JAX_CACHE" not in os.environ
-CACHE_DIR = os.environ.get(
-    "FF_TEST_JAX_CACHE", os.path.join(REPO, ".jax_cache"))
+# CACHE_DIR_IS_OURS: conftest session-clears (and sets in code) only the
+# suite's own ``.jax_cache``; a JAX_COMPILATION_CACHE_DIR given from
+# outside is used as it is and never rmtree'd
+CACHE_DIR_IS_OURS = not os.environ.get("JAX_COMPILATION_CACHE_DIR")
+CACHE_DIR = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+             or os.path.join(REPO, ".jax_cache"))
 
 
 def cached_env(**overrides):
+    """Children run on the CPU and share the session's cache directory
+    through the environment (jax's default 1 s floor keeps the tiny
+    jits out of it)."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["FLEXFLOW_PLATFORM"] = "cpu"
     env["JAX_COMPILATION_CACHE_DIR"] = CACHE_DIR
-    # same 1s floor as conftest: children are the processes that DO get
-    # killed (example-corpus timeouts) — thousands of tiny-entry writes
-    # would maximize the odds of a truncated entry left mid-kill
-    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "1"
-    env["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "0"
     env.update(overrides)
     return env

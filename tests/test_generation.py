@@ -54,8 +54,10 @@ def _ctx():
 
 def test_attention_decode_matches_forward_every_prefix():
     """The correctness anchor: single-token decode against the KV cache
-    reproduces the causal forward's row at EVERY prefix length —
-    bit-identical on CPU (allclose elsewhere)."""
+    reproduces the causal forward's row at EVERY prefix length, to
+    float32 tolerance (ROADMAP D6: the contract is the mathematics, not
+    one XLA:CPU build's accumulation order — JAX 0.9.0 drifts 1 ulp
+    here)."""
     n, S, D, H = 2, 16, 32, 4
     rng = np.random.default_rng(0)
     x = rng.standard_normal((n, S, D)).astype(np.float32)
@@ -73,7 +75,6 @@ def test_attention_decode_matches_forward_every_prefix():
     khost, vhost = np.asarray(k), np.asarray(v)
     dec = jax.jit(lambda p, x1, kc, vc, pos: op.decode(p, x1, kc, vc,
                                                        pos, ctx))
-    exact = jax.default_backend() == "cpu"
     for t in range(S):
         kc = np.zeros_like(khost)
         vc = np.zeros_like(vhost)
@@ -83,10 +84,8 @@ def test_attention_decode_matches_forward_every_prefix():
                                jnp.asarray(vc),
                                jnp.full((n,), t, jnp.int32))
         got, want = np.asarray(out)[:, 0], np.asarray(full)[:, t]
-        if exact:
-            np.testing.assert_array_equal(got, want, err_msg=f"t={t}")
-        else:
-            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6,
+                                   err_msg=f"t={t}")
         # the decode wrote this position's K/V — exactly the forward's
         np.testing.assert_array_equal(np.asarray(kc2)[:, t],
                                       khost[:, t])

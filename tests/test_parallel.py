@@ -141,7 +141,9 @@ def test_output_spec_mesh_expressibility():
     # mixed degree < axis size maps onto a prime sub-axis subset
     spec = output_spec(t, ParallelConfig(dims=(2, 2),
                                          device_ids=tuple(range(4))), mesh)
-    assert tuple(spec) == (("n0",), "c")  # sub-axis subset of the n axis
+    # sub-axis subset of the n axis (PartitionSpec normalises the
+    # 1-tuple ("n0",) to the bare name)
+    assert tuple(spec) == ("n0", "c")
     # a non-divisor degree degrades to replication, RECORDED as an
     # aggregated verifier diagnostic (FF106) instead of one warning per
     # traced tensor (ISSUE 3)

@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from flexflow_tpu.ops.linear import Linear
-from flexflow_tpu.profiling import (_example_inputs, _fence, _init_params,
+from flexflow_tpu.profiling import (_example_inputs, _init_params,
                                     _nearest_rank, profile_op, quantiles,
                                     time_calls)
 from flexflow_tpu.tensor import Tensor
@@ -125,13 +125,6 @@ def test_profile_op_sub_shapes():
     r = profile_op(op, compute_dtype="float32", warmup=1, iters=2,
                    input_shapes=[(4, 16)])
     assert math.isfinite(r["fwd_ms"])
-
-
-def test_fence_forces_host_read():
-    # the slope timer's execution fence is a device->host element read:
-    # it must accept arbitrary pytrees and scalars
-    _fence(jnp.ones((2, 3)))
-    _fence({"a": jnp.zeros(()), "b": [jnp.ones((4,))]})
 
 
 def test_slope_mode_nan_survives_failed_backward():

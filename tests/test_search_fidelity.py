@@ -220,7 +220,6 @@ def test_calibrated_backward_overheads(monkeypatch):
     from flexflow_tpu.ops.conv import Pool2D
     from flexflow_tpu.search.cost_model import DEFAULT_SPEC, op_compute_time
 
-    monkeypatch.setenv("FF_PALLAS_POOL", "0")  # hermetic vs env/tuned table
     t = Tensor((8, 64, 28, 28), name="x")
     mx = Pool2D("mp", t, 2, 2, 2, 2, 0, 0, pool_type="max")
     av = Pool2D("ap", t, 2, 2, 2, 2, 0, 0, pool_type="avg")

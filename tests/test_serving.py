@@ -1182,39 +1182,6 @@ def test_engine_emits_serve_stats_events(capsys):
 
 
 # ----------------------------------------------------------------------
-# compile cache: FF_CACHE_DIR override + idempotence
-# ----------------------------------------------------------------------
-def test_compile_cache_enable_idempotent(monkeypatch):
-    import jax
-
-    from flexflow_tpu import compile_cache
-
-    current = jax.config.jax_compilation_cache_dir
-    assert current  # the test harness configured its session cache
-    compile_cache.enable()  # default call defers to the harness's dir
-    assert jax.config.jax_compilation_cache_dir == current
-    monkeypatch.setenv("FF_CACHE_DIR", current)
-    compile_cache.enable()  # explicit same-dir: no churn either
-    assert jax.config.jax_compilation_cache_dir == current
-
-
-def test_compile_cache_resolve_dir(monkeypatch):
-    from flexflow_tpu import compile_cache
-
-    monkeypatch.delenv("FF_CACHE_DIR", raising=False)
-    d, explicit = compile_cache._resolve_dir(None)
-    assert d == compile_cache.default_dir() and not explicit
-    d, explicit = compile_cache._resolve_dir("/tmp/somewhere")
-    assert d == "/tmp/somewhere" and explicit
-    monkeypatch.setenv("FF_CACHE_DIR", "/tmp/env-cache")
-    d, explicit = compile_cache._resolve_dir(None)
-    assert d == "/tmp/env-cache" and explicit
-    # an explicit argument outranks the env override
-    d, explicit = compile_cache._resolve_dir("/tmp/arg-cache")
-    assert d == "/tmp/arg-cache" and explicit
-
-
-# ----------------------------------------------------------------------
 # serve-bench smoke
 # ----------------------------------------------------------------------
 def test_serve_bench_smoke(tmp_path, capsys):

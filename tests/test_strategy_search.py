@@ -229,7 +229,9 @@ def test_spec_for_device_auto_select():
     assert spec_for_device("TPU v5 lite") is V5E_SPEC
     assert spec_for_device("TPU v5e") is V5E_SPEC
     assert spec_for_device("TPU v5p") is DEFAULT_SPEC
-    assert spec_for_device("cpu") is DEFAULT_SPEC
+    assert spec_for_device() is DEFAULT_SPEC  # the CPU test mesh
+    with pytest.raises(ValueError, match="no DeviceSpec"):
+        spec_for_device("TPU v9")
 
 
 def test_shared_sim_contradicting_kwargs_warn():

@@ -36,54 +36,3 @@ def test_default_when_table_absent(monkeypatch, tmp_path):
     assert tuned.flag_enabled("FF_FAST_POOL", "fast_pool") is True
     assert tuned.flag_enabled("FF_FAST_POOL", "fast_pool",
                               default=False) is False
-
-
-def test_decide_script_no_arms(tmp_path, monkeypatch):
-    """With no measured arm logs the decision script leaves defaults."""
-    import scripts.decide_fast_kernels as dk
-
-    monkeypatch.setattr(dk, "R", str(tmp_path))
-    monkeypatch.setattr(dk, "OUT", str(tmp_path / "out.json"))
-    assert dk.main() == 0
-    assert not (tmp_path / "out.json").exists()
-
-
-def test_decide_script_same_window_arms(tmp_path, monkeypatch):
-    """fast vs control arms in one window decide all three flags."""
-    import scripts.decide_fast_kernels as dk
-
-    row = '{"metric": "m", "ms_per_step": %s, "unit": "x"}\n'
-    (tmp_path / "incep_fast3.log").write_text(row % 99.0)
-    (tmp_path / "incep_ctrl2.log").write_text(row % 55.0)
-    monkeypatch.setattr(dk, "R", str(tmp_path))
-    monkeypatch.setattr(dk, "OUT", str(tmp_path / "out.json"))
-    assert dk.main() == 0
-    table = json.loads((tmp_path / "out.json").read_text())
-    kind = tuned._device_kind()
-    assert table["fast_pool"][kind] is False
-    assert table["fast_dgrad"][kind] is False
-    assert table["fast_concat"][kind] is False
-
-    # and the reverse outcome when fast wins, plus the 3-arm split:
-    (tmp_path / "incep_noconcat.log").write_text(row % 50.0)
-    (tmp_path / "incep_fast4.log").write_text(row % 47.0)
-    assert dk.main() == 0
-    table = json.loads((tmp_path / "out.json").read_text())
-    assert table["fast_pool"][kind] is True     # noconcat 50 < ctrl 55
-    assert table["fast_concat"][kind] is True   # fast 47 < noconcat 50
-
-
-def test_decide_script_concat_without_control(tmp_path, monkeypatch):
-    """fast vs noconcat alone decides fast_concat (ctrl2 arm missing)."""
-    import scripts.decide_fast_kernels as dk
-
-    row = '{"metric": "m", "ms_per_step": %s, "unit": "x"}\n'
-    (tmp_path / "incep_fast3.log").write_text(row % 47.0)
-    (tmp_path / "incep_noconcat.log").write_text(row % 50.0)
-    monkeypatch.setattr(dk, "R", str(tmp_path))
-    monkeypatch.setattr(dk, "OUT", str(tmp_path / "out.json"))
-    assert dk.main() == 0
-    table = json.loads((tmp_path / "out.json").read_text())
-    kind = tuned._device_kind()
-    assert table["fast_concat"][kind] is True
-    assert "fast_pool" not in table  # pool/dgrad stay undecided
