@@ -1,0 +1,44 @@
+"""Operations the post-norm transformer needs, counted from its shapes.
+
+The benchmark's own count: the program's ``op.flops()`` may change with the
+program, this may not.  A multiply-add is two operations.  Counted: the four
+attention projections, the two feed-forward products, the attention scores
+and values.  Not counted: embeddings, LayerNorm, GELU, softmax, the head
+(a 2-class head on one token is 3 kFLOP a sequence), and anything a kernel
+recomputes (flash attention recomputes the scores in its backward pass).
+"""
+
+from __future__ import annotations
+
+# the attention kernels' names in a v5e trace (looked at by hand, PR 23):
+# jvp_jit_flash_attention__ / flash_attention is the forward,
+# flash_mha_bwd_dkv_* and flash_mha_bwd_dq_* the backward passes
+FLASH_KERNELS = r"^(jvp_jit_flash_attention|flash_mha_|flash_attention)"
+
+
+def forward_flops_per_token(sz, seq):
+    """Forward operations for one token of a ``seq``-token sequence.  The
+    scores and values are counted in full for the encoder and, for a causal
+    model, at the half that the mask leaves."""
+    d, f, L = sz["d_model"], sz["d_ff"], sz["layers"]
+    proj = 2 * 4 * d * d
+    ffn = 2 * 2 * d * f
+    attn = 2 * 2 * seq * d
+    if sz["causal"]:
+        attn //= 2
+    return L * (proj + ffn + attn)
+
+
+def train_flops_per_token(sz, seq):
+    """Forward + backward = 3 x forward (each product has two gradient
+    products)."""
+    return 3 * forward_flops_per_token(sz, seq)
+
+
+def attention_train_flops(sz, batch, seq):
+    """Scores + values, forward and backward, for ``batch`` sequences in all
+    layers: two products forward, four backward (dV, dP, dQ, dK)."""
+    per_seq_layer = 2 * seq * seq * sz["d_model"]   # one product
+    if sz["causal"]:
+        per_seq_layer //= 2
+    return 6 * per_seq_layer * batch * sz["layers"]
