@@ -128,6 +128,8 @@ class FFModel:
         # on batch size, both reused across predict()/serving calls
         self._fwd_compiled: Dict[Any, Any] = {}
         self._exec_digest_cache: Optional[str] = None
+        # step_op_table()'s instruction -> graph-op tables, by batch shape
+        self._step_op_tables: Dict[Any, Dict[str, tuple]] = {}
         self._dummy_labels: Dict[int, np.ndarray] = {}
         # serving weight quantization (ISSUE 14): "" = full-precision
         # params; "int8" after quantize_weights() replaced the eligible
@@ -603,19 +605,26 @@ class FFModel:
         every ``cast_compute`` site follows the strategy without any op
         knowing about the axis.  With no overrides the installed value
         is the session dtype for every op — traced programs are
-        bit-identical to a build without the axis."""
+        bit-identical to a build without the axis.
+
+        Each op runs under ``jax.named_scope(op.name)``: metadata at
+        trace time and nothing at run time, so every instruction of the
+        compiled step names the graph op it came from
+        (``obs/device_ops.py`` reads a profiler trace by it)."""
         from .ops.common import resolve_op_dtype
         base_dtype = ctx.compute_dtype
         for op in ops:
             ctx.compute_dtype = resolve_op_dtype(op, base_dtype)
             in_vals = [values[t.uid] for t in op.inputs]
-            out_vals = op.forward(params, in_vals, ctx)
-            for t, v in zip(op.outputs, out_vals):
-                if constrain and op.parallel_config is not None:
-                    spec = output_spec(t, op.parallel_config, self.mesh)
-                    v = jax.lax.with_sharding_constraint(
-                        v, self.mesh.sharding(spec))
-                values[t.uid] = v
+            with jax.named_scope(op.name):
+                out_vals = op.forward(params, in_vals, ctx)
+                for t, v in zip(op.outputs, out_vals):
+                    if constrain and op.parallel_config is not None:
+                        spec = output_spec(t, op.parallel_config,
+                                           self.mesh)
+                        v = jax.lax.with_sharding_constraint(
+                            v, self.mesh.sharding(spec))
+                    values[t.uid] = v
         ctx.compute_dtype = base_dtype
 
     def _execute(self, params: Dict[str, jax.Array],
@@ -786,9 +795,11 @@ class FFModel:
                 # accumulation, where the k microbatch losses ADD — without
                 # the scale the (batch-size-free) aux terms would count k
                 # times in loss and gradients
-                loss = loss_fn(logits, labels) + aux * aux_scale
-                sums = metrics_mod.compute_batch_metrics(
-                    logits, labels, metric_names, loss_type)
+                with jax.named_scope("loss"):
+                    loss = loss_fn(logits, labels) + aux * aux_scale
+                with jax.named_scope("metrics"):
+                    sums = metrics_mod.compute_batch_metrics(
+                        logits, labels, metric_names, loss_type)
             else:
                 # masked padded-tail objective (pad_tail mode): the
                 # mean/sum over the VALID rows only.  ``base`` is this
@@ -797,14 +808,17 @@ class FFModel:
                 # so the k losses ADD for BOTH reductions and grads
                 # accumulate without a post-divide (see _step_core)
                 mb = logits.shape[0]
-                mask = ((jnp.arange(mb) + base) < nvalid).astype(jnp.float32)
-                total = jnp.sum(per_ex_fn(logits, labels) * mask)
-                denom = (jnp.maximum(nvalid, 1).astype(jnp.float32)
-                         if loss_reduction == "mean" else 1.0)
-                loss = total / denom + aux * aux_scale
-                sums = metrics_mod.compute_batch_metrics(
-                    logits, labels, metric_names, loss_type,
-                    nvalid=jnp.clip(nvalid - base, 0, mb))
+                with jax.named_scope("loss"):
+                    mask = ((jnp.arange(mb) + base)
+                            < nvalid).astype(jnp.float32)
+                    total = jnp.sum(per_ex_fn(logits, labels) * mask)
+                    denom = (jnp.maximum(nvalid, 1).astype(jnp.float32)
+                             if loss_reduction == "mean" else 1.0)
+                    loss = total / denom + aux * aux_scale
+                with jax.named_scope("metrics"):
+                    sums = metrics_mod.compute_batch_metrics(
+                        logits, labels, metric_names, loss_type,
+                        nvalid=jnp.clip(nvalid - base, 0, mb))
             return loss, (updates, preds, sums)
 
         grad_fn = jax.value_and_grad(loss_and_metrics, has_aux=True)
@@ -914,8 +928,9 @@ class FFModel:
                 grads = {k: (jax.device_put(g, dev_sh[k])
                              if k in host_sh else g)
                          for k, g in grads.items()}
-            new_trainable, new_opt_state = self.optimizer.update(
-                trainable, grads, opt_state)
+            with jax.named_scope("optimizer"):
+                new_trainable, new_opt_state = self.optimizer.update(
+                    trainable, grads, opt_state)
             # NOTE: updated host params leave the step in device memory; the
             # eager _repin_host() in train_batch/fit moves them back to
             # pinned_host (XLA's SPMD pass cannot yet shard an in-program
@@ -980,6 +995,7 @@ class FFModel:
         # lazily) AND the exec digest half of their cache key
         self._fwd_compiled = {}
         self._exec_digest_cache = None
+        self._step_op_tables = {}
         donate = (0, 1)
         self._train_step = jax.jit(train_step, donate_argnums=donate)
         self._train_window = jax.jit(window_step, donate_argnums=donate)
@@ -1762,6 +1778,29 @@ class FFModel:
                 self._train_window.lower(self._params, self._opt_state,
                                          window, self._step).compile()
 
+    def step_op_table(self, *arrays) -> Dict[str, tuple]:
+        """Which graph op each instruction of the compiled train step
+        belongs to, for ``arrays``' shapes: ``{instruction name: (owner,
+        "fwd" | "bwd" | None)}`` with the graph ops' names and
+        ``optimizer`` / ``loss`` / ``metrics`` as owners
+        (:func:`flexflow_tpu.obs.device_ops.table_from_hlo`).  Sum a
+        profiler trace's operations by it with
+        :func:`~flexflow_tpu.obs.device_ops.attribute`.  Lowers and
+        compiles the step (a persistent-cache hit where the run's own
+        compile wrote one) the FIRST time it is asked for a shape and
+        keeps the table; nothing else calls it, so a run that never asks
+        compiles nothing twice."""
+        from .obs.device_ops import STEP_OWNERS, table_from_hlo
+        batch = tuple(self._shard_batch(arrays))
+        key = tuple((a.shape, str(a.dtype)) for a in batch)
+        if key not in self._step_op_tables:
+            text = self._train_step.lower(
+                self._params, self._opt_state, batch,
+                self._step).compile().as_text()
+            self._step_op_tables[key] = table_from_hlo(
+                text, [op.name for op in self.layers] + list(STEP_OWNERS))
+        return self._step_op_tables[key]
+
     def _check_accum_divisible(self, n: int, what: str) -> None:
         """Every entry point that feeds the jitted step validates its
         batch here — the scan reshape inside would otherwise fail with
@@ -1856,9 +1895,13 @@ class FFModel:
         self._check_not_quantized("train_batch")
         if arrays:
             self._check_accum_divisible(len(arrays[0]), "batch of")
-        batch = tuple(self._shard_batch(arrays))
-        self._params, self._opt_state, loss, sums = self._train_step(
-            self._params, self._opt_state, batch, self._step)
+        # both stretches of host work lie on the profiler's clock under
+        # names of their own (no-ops while no profiler session is open)
+        with jax.profiler.TraceAnnotation("train_batch.h2d"):
+            batch = tuple(self._shard_batch(arrays))
+        with jax.profiler.TraceAnnotation("train_batch.dispatch"):
+            self._params, self._opt_state, loss, sums = self._train_step(
+                self._params, self._opt_state, batch, self._step)
         if self._host_shardings:
             self._repin_host()
         self._surface_runtime_fallbacks()

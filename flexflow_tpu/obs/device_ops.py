@@ -1,0 +1,81 @@
+"""Device time of the fused train step, read by graph op.
+
+XLA fuses the whole step into one program, but every instruction of the
+compiled module still carries the name stack it was traced under in its
+``metadata={op_name="..."}``, and ``FFModel`` runs each graph op, the loss,
+the metrics and the optimizer update under a ``jax.named_scope`` of its own.
+So a profiler trace of the REAL step can be summed by graph op, forward and
+backward apart — where ``flexflow_tpu/profiling.py`` times each op compiled
+in isolation.
+
+:func:`table_from_hlo` maps every instruction of a compiled module's text to
+its owner; :func:`attribute` sums a trace's ``[name, start, duration]``
+operations by that table.  What it cannot see (looked at on a v5e, PR 24): a
+fusion carries ONE instruction's metadata, so where XLA fuses the tail of one
+graph op into the head of the next the whole fusion goes to one of them; and
+an instruction XLA made itself from a parameter (a layout copy of a weight,
+a buffer's initial broadcast) names that parameter, not a scope, and is
+owned by nobody.
+"""
+
+from __future__ import annotations
+
+import re
+from typing import Dict, Iterable, Optional, Sequence, Tuple
+
+# the scopes FFModel's train step opens beside one per graph op
+STEP_OWNERS = ("loss", "metrics", "optimizer")
+
+Owner = Tuple[Optional[str], Optional[str]]
+
+# `%name = type opcode(...), ..., metadata={... op_name="a/b/c" ...}`, with
+# or without the leading ROOT; the first path of a `;`-joined op_name
+_INSTRUCTION = re.compile(
+    r'^\s*(?:ROOT\s+)?%(?P<name>[^\s=]+)\s*=.*?'
+    r'metadata=\{[^}]*?op_name="(?P<path>[^";]*)', re.M)
+# a transform's frame round a scope: `jvp(x)`, `transpose(jvp(x))`
+_FRAME = re.compile(r"^\w+\((.*)\)$")
+
+
+def _owner(path: str, owners) -> Owner:
+    """The first component of the name stack ``path`` that is one of
+    ``owners`` once its transform frames are taken off, and whether the
+    stack lies in the backward pass (``transpose(`` round a frame; the
+    primitive called ``transpose`` has no parenthesis) or the forward pass
+    of a differentiated function (``jvp(``)."""
+    for part in path.split("/"):
+        while part not in owners:
+            m = _FRAME.match(part)
+            if m is None:
+                break
+            part = m.group(1)
+        else:
+            phase = ("bwd" if "transpose(" in path
+                     else "fwd" if "jvp(" in path else None)
+            return part, phase
+    return None, None
+
+
+def table_from_hlo(text: str, owners: Iterable[str]) -> Dict[str, Owner]:
+    """``{instruction name: (owner | None, "fwd" | "bwd" | None)}`` for every
+    instruction of the compiled module ``text`` (``compiled.as_text()``) that
+    carries an ``op_name``.  The names are the ones the profiler's ``XLA
+    Ops`` line prints (``fusion.12``); a Pallas kernel's custom call is
+    named by XLA after the kernel (``flash_mha_bwd_dkv_...512.3``) and is
+    found the same way.  ``owners`` are the scope names to look for: the
+    graph ops' and :data:`STEP_OWNERS`.  Nothing is guessed: an instruction
+    whose name stack holds none of them is owned by ``None``."""
+    owners = frozenset(owners)
+    return {m.group("name"): _owner(m.group("path"), owners)
+            for m in _INSTRUCTION.finditer(text)}
+
+
+def attribute(ops: Sequence, table: Dict[str, Owner]) -> Dict[Owner, float]:
+    """Seconds of the traced operations ``[name, start_ns, duration_ns]``
+    summed by owner; an operation the table lacks is owned by
+    ``(None, None)``."""
+    out: Dict[Owner, float] = {}
+    for name, _, dur in ops:
+        key = table.get(name, (None, None))
+        out[key] = out.get(key, 0.0) + dur / 1e9
+    return out

@@ -5,7 +5,8 @@ draws a ``trace_id`` from the process :class:`Tracer`; the engines then
 record the request's lifecycle as completed spans: ``admission_wait``
 (blocked for admission), ``queue`` (submit → packed), the per-dispatch
 ``pack``/``dispatch``/``fetch``/``scatter`` quartet, generation's
-``prefill``/``decode_step``, ``fit()``'s per-window ``train_window``,
+``prefill``/``decode_step``, the generation engine's step-boundary
+phases (:meth:`Tracer.phase`), ``fit()``'s per-window ``train_window``,
 and exactly ONE terminal ``request`` span per logical request whose
 ``phase`` arg names its outcome (:data:`TERMINAL_PHASES`) — which is
 what lets a trace file reconcile EXACTLY against the ServingMetrics
@@ -42,6 +43,8 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 from . import lockwatch
 
 RAW_SCHEMA = "ff-trace-v1"
@@ -70,6 +73,32 @@ def phase_of(exc: BaseException) -> str:
     if isinstance(exc, OverloadError):
         return "rejected"
     return "error"
+
+
+class _Phase:
+    """One phase of host work in both sinks (:meth:`Tracer.phase`)."""
+
+    __slots__ = ("_tracer", "_name", "_clock", "_traced", "_kw", "_ann",
+                 "_t0")
+
+    def __init__(self, tracer, name, clock, traced, step_num, kw):
+        self._tracer, self._name, self._clock = tracer, name, clock
+        self._traced, self._kw = traced, kw
+        self._ann = (TraceAnnotation(name) if step_num is None
+                     else StepTraceAnnotation(name, step_num=step_num))
+
+    def __enter__(self):
+        if self._traced:
+            self._t0 = self._clock()
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        if self._traced:
+            self._tracer.span(self._name, None, self._t0, self._clock(),
+                              **self._kw)
+        return False
 
 
 class Tracer:
@@ -174,6 +203,19 @@ class Tracer:
             except Exception:  # noqa: BLE001 — a broken diagnostic
                 pass           # sink must never fail the serving path
 
+    def phase(self, name: str, clock: Callable[[], float], traced: bool,
+              step_num: Optional[int] = None, **kw) -> _Phase:
+        """A phase of host work, recorded ONCE into both sinks: as a
+        ``jax.profiler.TraceAnnotation`` named ``name`` (it lies on the
+        profiler's clock beside the device's operations and costs about
+        a microsecond while no profiler session is open, so it needs no
+        switch; with ``step_num`` a ``StepTraceAnnotation``) and, when
+        ``traced``, as a dispatch-scope span on ``clock`` with ``kw``
+        (``cat``, ``tid``, args) handed to :meth:`span`.  ``traced`` is
+        the caller's ONE read of ``active`` for its step boundary —
+        with it False no clock is read and nothing is recorded."""
+        return _Phase(self, name, clock, traced, step_num, kw)
+
     # ---- export --------------------------------------------------------
     def snapshot(self) -> Dict:
         """The raw ``ff-trace-v1`` payload: bounded span list + enough
@@ -182,9 +224,15 @@ class Tracer:
             spans = list(self._spans)
             dropped = self._dropped
             rate = self.sample_rate
+        # ONE clock anchor, the two reads back to back: it puts spans
+        # taken on ``time.monotonic`` (the engines' default clock) on the
+        # wall clock, where a profiler session's start is known too
+        anchor = {"monotonic_ns": time.monotonic_ns(),
+                  "unix_ns": time.time_ns()}
         return {"schema": RAW_SCHEMA, "pid": os.getpid(),
                 "sample_rate": rate, "dropped": dropped,
-                "created_unix": round(time.time(), 3), "spans": spans}
+                "created_unix": round(time.time(), 3),
+                "clock_anchor": anchor, "spans": spans}
 
     def save(self, path: str) -> Dict:
         """Write the raw snapshot to ``path`` (atomic) and return it."""

@@ -1,12 +1,14 @@
 """Per-op profiling (the ``--profiling`` flag — reference cudaEvent timing
 inside every forward/backward task, conv_2d.cu:446-471, linear.cu:379-406).
 
-XLA fuses the whole step into one program, so per-op numbers cannot be read
-off the fused execution; like the reference's simulator measure mode
+XLA fuses the whole step into one program, so a host clock cannot time one
+op of the fused execution; like the reference's simulator measure mode
 (``measure_compute_time``, simulator.cc:235-273), each op is compiled and
 timed IN ISOLATION on the real device, fwd and fwd+bwd, then reported as a
 table.  ``FFModel.fit`` prints it once up front when ``config.profiling``
-is set.
+is set.  For numbers read off the fused step itself (a profiler trace's
+device operations summed by the graph op whose scope they were traced
+under) see ``obs/device_ops.py`` and ``FFModel.step_op_table``.
 """
 
 from __future__ import annotations

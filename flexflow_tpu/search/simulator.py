@@ -314,6 +314,18 @@ class Simulator:
             return {n: u for n in raw}
         return {n: v / total for n, v in raw.items()}
 
+    def op_times(self, layers: List[Op], strategies
+                 ) -> Dict[str, Tuple[float, float]]:
+        """``{op name: (forward s, backward s)}`` as ``_op_plan`` prices
+        each op under ``strategies`` — the per-op compute the search
+        adds up, for scoring the cost model op by op against a traced
+        step (``obs/device_ops.py``)."""
+        out = {}
+        for op in layers:
+            _, _, ft, bt, _ = self._op_plan(op, strategies)
+            out[op.name] = (ft, bt)
+        return out
+
     def peak_memory_bytes(self, layers: List[Op],
                           strategies: Dict[str, ParallelConfig],
                           mesh_shape: Optional[Dict[str, int]] = None,

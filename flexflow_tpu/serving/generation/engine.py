@@ -77,6 +77,13 @@ from .sampling import SamplingParams
 _END = object()  # token-stream sentinel
 
 
+def _program_name(fn) -> str:
+    """The name a profiler trace prints for the jitted ``fn``'s program
+    (``jit_decode`` for ``decode``), so a span's reader finds the
+    program's device time without knowing the decoder."""
+    return "jit_" + getattr(fn, "__name__", "")
+
+
 def _resolve(fut: Future, out) -> bool:
     """Complete a stream future with a result or exception, from EITHER
     lifecycle state: pending (failure paths fire before the engine
@@ -229,7 +236,7 @@ class _Slot:
 
     __slots__ = ("stream", "prompt", "pages", "draft_pages",
                  "hit_tokens", "next_pos", "chunks", "last_token",
-                 "length", "generated", "prefilling", "t_join")
+                 "length", "generated", "prefilling", "t_join", "t_exec")
 
     def __init__(self, stream: GenerationStream, prompt: np.ndarray,
                  hit_pages: List[int], page_size: int, t_join: float):
@@ -247,6 +254,9 @@ class _Slot:
         self.generated = 0
         self.prefilling = True
         self.t_join = t_join
+        # dispatch instant of the slot's FIRST prefill chunk — read only
+        # while tracing (it splits `prefill` into wait and work)
+        self.t_exec: Optional[float] = None
 
 
 class GenerationMetrics(ServingMetrics):
@@ -576,6 +586,13 @@ class GenerationEngine:
         self.migrate_import_ms: List[float] = []
         self._caches = None
         self._n_steps = 0
+        # step boundaries begun, and the ONE read of `tracer.active`
+        # each boundary makes for all its phases (_begin_boundary)
+        self._boundary = 0
+        self._traced = False
+        # the open `generate.turn` phase: from the end of a decode's
+        # deliver to the next boundary's begin (_open_turn)
+        self._turn = None
         self._chunks_total = 0
         self._hit_tokens = 0
         self._prompt_tokens = 0
@@ -935,14 +952,11 @@ class GenerationEngine:
         loop (a poisoned step fails the active streams, the engine
         keeps serving)."""
         t0 = self.clock()
-        self._batcher.reap_expired()
-        adopted = self._join_adopted()
-        self._admit()
-        progressed = self._prefill_step() or adopted
-        self._grow_active_pages()
+        adopted, progressed = self._begin_boundary()
         if not any(s is not None and not s.prefilling
                    for s in self._slots_state):
-            return max(0.0, self.clock() - t0) if progressed else None
+            return (max(0.0, self.clock() - t0)
+                    if progressed or adopted else None)
         self._fire_slow_decode()
         try:
             self._step_active()
@@ -950,6 +964,7 @@ class GenerationEngine:
             # as _decode_loop: the step's failure is the streams', not
             # the fleet dispatcher's
             self._recover_from_dispatch_error(e, "gen_decode_error")
+        self._close_turn()   # what follows is the fleet's time
         return max(0.0, self.clock() - t0)
 
     @property
@@ -1113,41 +1128,86 @@ class GenerationEngine:
         free slots, advance prefill by AT MOST one chunk (the
         decode-stall cap), then advance every active stream by one
         token with ONE dispatch + ONE fetch (RL010)."""
-        while True:
-            if self._abort.is_set():
-                self._abort_active()
-                return
+        try:
+            while True:
+                if self._abort.is_set():
+                    self._abort_active()
+                    return
+                _, progressed = self._begin_boundary()
+                if any(s is not None and not s.prefilling
+                       for s in self._slots_state):
+                    self._fire_slow_decode()
+                    try:
+                        self._step_active()
+                    except BaseException as e:  # noqa: BLE001 — one
+                        # poisoned step must fail the ACTIVE streams, not
+                        # kill the dispatcher; queued prompts still served
+                        self._recover_from_dispatch_error(e,
+                                                          "gen_decode_error")
+                    continue
+                if progressed or any(s is not None
+                                     for s in self._slots_state):
+                    continue  # prefill still in flight: keep chunking
+                with self._phase("generate.idle"):
+                    reqs = self._batcher.next_batch(timeout=0.05)
+                if reqs:
+                    for r in reqs:
+                        self._assign(r)
+                    continue
+                if (self._closing.is_set()
+                        and self._batcher.queue_depth == 0):
+                    return
+        finally:
+            self._close_turn()
+
+    def _phase(self, name: str, step_num: Optional[int] = None, **args):
+        """One phase of this step boundary in both sinks
+        (:meth:`~flexflow_tpu.obs.trace.Tracer.phase`): the profiler's
+        annotation always, the engine-clock span when the boundary is
+        traced.  Profiler-side names start ``generate`` / ``gen-prefill``
+        so a trace reduction that keeps the engine's annotations keeps
+        the phases too; ``step`` is the boundary's number, the
+        identifier the phases of one boundary share."""
+        return self._tracer.phase(
+            name, self.clock, self._traced, step_num, cat="engine",
+            tid=self.name or "generate", step=self._boundary, **args)
+
+    def _open_turn(self) -> None:
+        """After a decode's deliver: open the phase that lasts until the
+        next boundary begins (or the fleet's ``dispatch_pending``
+        returns).  The loop only turns round in it, yet on the chip the
+        engine's thread stood there 2-3 ms at most boundaries of a
+        128-client run (PERF.md, PR 24), so it has a name."""
+        self._turn = self._phase("generate.turn")
+        self._turn.__enter__()
+
+    def _close_turn(self) -> None:
+        turn, self._turn = self._turn, None
+        if turn is not None:
+            turn.__exit__(None, None, None)
+
+    def _begin_boundary(self):
+        """The host work every step boundary starts with, for the owned
+        decode loop and the fleet's ``dispatch_pending`` alike: expire,
+        adopt, admit, advance prefill by at most one chunk, grow the
+        active slots' pages.  Returns ``(adopted, progressed)``."""
+        self._close_turn()
+        self._boundary += 1
+        # ONE lock-free tracing check per step boundary, handed down to
+        # every phase (hot-path contract, docs/observability.md)
+        self._traced = self._tracer.active
+        with self._phase("generate.admit"):
             # expire queued deadlines at EVERY step boundary — with all
             # slots busy, _admit() never polls, and a deadline must
             # fail AT the deadline (PR 8's contract), not when a slot
             # happens to free
             self._batcher.reap_expired()
-            self._join_adopted()
+            adopted = self._join_adopted()
             self._admit()
-            progressed = self._prefill_step()
+        progressed = self._prefill_step()
+        with self._phase("generate.grow_pages"):
             self._grow_active_pages()
-            if any(s is not None and not s.prefilling
-                   for s in self._slots_state):
-                self._fire_slow_decode()
-                try:
-                    self._step_active()
-                except BaseException as e:  # noqa: BLE001 — one
-                    # poisoned step must fail the ACTIVE streams, not
-                    # kill the dispatcher; queued prompts still served
-                    self._recover_from_dispatch_error(e,
-                                                      "gen_decode_error")
-                continue
-            if progressed or any(s is not None
-                                 for s in self._slots_state):
-                continue  # prefill still in flight: keep chunking
-            reqs = self._batcher.next_batch(timeout=0.05)
-            if reqs:
-                for r in reqs:
-                    self._assign(r)
-                continue
-            if (self._closing.is_set()
-                    and self._batcher.queue_depth == 0):
-                return
+        return adopted, progressed
 
     def _admit(self) -> None:
         """Join queued prompts into free slots at the step boundary —
@@ -1229,27 +1289,29 @@ class GenerationEngine:
         remaining = int(prompt.size) - start
         chunk = (remaining if self.prefill_chunk <= 0
                  else min(self.prefill_chunk, remaining))
-        if not self._ensure_pages(slot, st, start + chunk):
-            self._prefill_q.popleft()
-            self._fail_slot(slot, st, KVCacheExhausted(
-                f"no KV page free for prefill at position {start} "
-                f"(pool {self.num_pages} pages, "
-                f"{self._pool.pages_in_use} in use, prefix cache "
-                f"fully referenced)"), "shed")
-            return True
-        bucket = self._decoder.prefill_bucket(chunk)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :chunk] = prompt[start:start + chunk]
-        fn = self._decoder.prefill_fn(bucket)
+        with self._phase("gen-prefill.prepare"):
+            if not self._ensure_pages(slot, st, start + chunk):
+                self._prefill_q.popleft()
+                self._fail_slot(slot, st, KVCacheExhausted(
+                    f"no KV page free for prefill at position {start} "
+                    f"(pool {self.num_pages} pages, "
+                    f"{self._pool.pages_in_use} in use, prefix cache "
+                    f"fully referenced)"), "shed")
+                return True
+            bucket = self._decoder.prefill_bucket(chunk)
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :chunk] = prompt[start:start + chunk]
+            fn = self._decoder.prefill_fn(bucket)
+            row = self._table[slot].copy()
         final = start + chunk >= int(prompt.size)
         tok = 0
+        if self._traced and st.t_exec is None:
+            st.t_exec = self.clock()
         try:
-            with jax.profiler.StepTraceAnnotation(
-                    "gen-prefill", step_num=self._n_steps):
+            with self._phase("gen-prefill", step_num=self._n_steps):
                 first, self._caches = fn(
-                    self._params, self._caches, tokens,
-                    self._table[slot].copy(), np.int32(slot),
-                    np.int32(start), np.int32(chunk))
+                    self._params, self._caches, tokens, row,
+                    np.int32(slot), np.int32(start), np.int32(chunk))
                 if final:
                     # one fetch per JOIN (not per chunk): the stream's
                     # first token comes out of the last chunk itself
@@ -1271,6 +1333,16 @@ class GenerationEngine:
             return True  # next chunk at a later step boundary
         self._prefill_q.popleft()
         now = self.clock()
+        with self._phase("gen-prefill.deliver"):
+            return self._deliver_first_token(slot, st, tok, now, bucket, fn)
+
+    def _deliver_first_token(self, slot: int, st: _Slot, tok: int,
+                             now: float, bucket: int, fn) -> bool:
+        """The join's hand-over at ``now``, the instant its first token
+        reached the host: activate the slot, emit the token, promote the
+        prompt's pages, record the request's road here, then migrate,
+        mirror into the draft or retire as the stream asks."""
+        prompt = st.prompt
         st.prefilling = False
         st.length = int(prompt.size)
         st.last_token = tok
@@ -1285,15 +1357,27 @@ class GenerationEngine:
             # prefix re-touches its nodes' LRU stamps)
             full = max(0, (int(prompt.size) - 1) // self.page_size)
             self._prefix.insert(prompt, st.pages[:full])
-        if self._tracer.active and stream.trace is not None:
+        if self._traced and stream.trace is not None:
             tname = self.name or "generate"
+            args = dict(slot=slot, phase="target",
+                        prompt_len=int(prompt.size),
+                        prefix_hit_tokens=st.hit_tokens,
+                        prefill_chunks=st.chunks)
             self._tracer.span("queue", stream.trace, stream.t_submit,
                               st.t_join, tid=tname, slot=slot)
             self._tracer.span("prefill", stream.trace, st.t_join, now,
-                              tid=tname, slot=slot, phase="target",
-                              prompt_len=int(prompt.size),
-                              prefix_hit_tokens=st.hit_tokens,
-                              prefill_chunks=st.chunks)
+                              tid=tname, **args)
+            if st.t_exec is not None:
+                # `prefill` split at the dispatch of the slot's first
+                # chunk: queue + prefill_wait + prefill_exec tile
+                # submit() to the first token exactly
+                self._tracer.span("prefill_wait", stream.trace,
+                                  st.t_join, st.t_exec, tid=tname,
+                                  slot=slot)
+                self._tracer.span("prefill_exec", stream.trace,
+                                  st.t_exec, now, tid=tname,
+                                  step=self._boundary, bucket=bucket,
+                                  program=_program_name(fn), **args)
         if stream.handoff is not None and not (
                 st.generated >= stream.max_new
                 or (self.eos_id is not None and tok == self.eos_id)):
@@ -1615,61 +1699,66 @@ class GenerationEngine:
         host-computed — inactive and PREFILLING slots ride the pool's
         OOB sentinel so their dummy writes drop instead of corrupting
         a (possibly shared) page."""
-        tokens = np.zeros((self.slots,), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        wp = np.full((self.slots,), self._pool.no_page, np.int32)
-        wr = np.zeros((self.slots,), np.int32)
-        nactive = 0
-        for i, s in enumerate(self._slots_state):
-            if s is not None and not s.prefilling:
-                tokens[i] = s.last_token
-                pos[i] = s.length
-                wp[i] = self._table[i, s.length // self.page_size]
-                wr[i] = s.length % self.page_size
-                nactive += 1
-        sampled = self._batch_sampling()
-        # ONE lock-free tracing check per decode step (hot-path
-        # contract, docs/observability.md)
-        traced = self._tracer.active
+        with self._phase("generate.prepare"):
+            tokens = np.zeros((self.slots,), np.int32)
+            pos = np.zeros((self.slots,), np.int32)
+            wp = np.full((self.slots,), self._pool.no_page, np.int32)
+            wr = np.zeros((self.slots,), np.int32)
+            nactive = 0
+            for i, s in enumerate(self._slots_state):
+                if s is not None and not s.prefilling:
+                    tokens[i] = s.last_token
+                    pos[i] = s.length
+                    wp[i] = self._table[i, s.length // self.page_size]
+                    wr[i] = s.length % self.page_size
+                    nactive += 1
+            sampled = self._batch_sampling()
+        traced = self._traced   # the boundary's one read of the gate
         t0 = self.clock()
         with jax.profiler.StepTraceAnnotation("generate",
                                               step_num=self._n_steps):
             if sampled:
                 temp, top_k, top_p, seeds = self._sampling_arrays()
                 fn = self._decoder.decode_sampled_fn()
-                nxt, self._caches = fn(
-                    self._params, self._caches, tokens, pos,
-                    self._table.copy(), wp, wr, temp, top_k, top_p,
-                    seeds)
+                with self._phase("generate.dispatch"):
+                    nxt, self._caches = fn(
+                        self._params, self._caches, tokens, pos,
+                        self._table.copy(), wp, wr, temp, top_k, top_p,
+                        seeds)
             else:
                 fn = self._decoder.decode_fn()
-                nxt, self._caches = fn(self._params, self._caches,
-                                       tokens, pos, self._table.copy(),
-                                       wp, wr)
+                with self._phase("generate.dispatch"):
+                    nxt, self._caches = fn(
+                        self._params, self._caches, tokens, pos,
+                        self._table.copy(), wp, wr)
             # THE one host sync per decode step for the whole batch —
             # per-stream tokens are scattered from it below (RL010)
-            host = np.asarray(jax.device_get(nxt))
+            with self._phase("generate.fetch"):
+                host = np.asarray(jax.device_get(nxt))
         now = self.clock()
         self._n_steps += 1
-        for i, s in enumerate(self._slots_state):
-            if s is None or s.prefilling:
-                continue
-            tok = int(host[i])
-            s.length += 1
-            s.generated += 1
-            s.last_token = tok
-            s.stream._emit(tok)
-            self._retire(i, s, now)
-        if traced:
-            self._tracer.span("decode_step", None, t0, now,
-                              tid=self.name or "generate",
-                              step=self._n_steps - 1, active=nactive,
-                              phase="decode")
-        self.metrics.record_decode_step(nactive, now - t0)
-        self._fire_cancel_at_token(now)
-        if self.stats_every and self._n_steps % self.stats_every == 0:
-            self.metrics.emit(extra={"slots": self.slots,
-                                     "active": nactive})
+        with self._phase("generate.deliver"):
+            for i, s in enumerate(self._slots_state):
+                if s is None or s.prefilling:
+                    continue
+                tok = int(host[i])
+                s.length += 1
+                s.generated += 1
+                s.last_token = tok
+                s.stream._emit(tok)
+                self._retire(i, s, now)
+            if traced:
+                self._tracer.span("decode_step", None, t0, now,
+                                  tid=self.name or "generate",
+                                  step=self._n_steps - 1, active=nactive,
+                                  phase="decode",
+                                  program=_program_name(fn))
+            self.metrics.record_decode_step(nactive, now - t0)
+            self._fire_cancel_at_token(now)
+            if self.stats_every and self._n_steps % self.stats_every == 0:
+                self.metrics.emit(extra={"slots": self.slots,
+                                         "active": nactive})
+        self._open_turn()
 
     # ---- speculative round ---------------------------------------------
     def _spec_decode_once(self) -> None:
@@ -1693,49 +1782,51 @@ class GenerationEngine:
         # past max_seq ride the sentinel (their writes drop, and the
         # prompt+max_new<=max_seq budget retires the stream before any
         # such row could be emitted)
-        for i, s in enumerate(self._slots_state):
-            if s is None or s.prefilling:
-                continue
-            upto = min(s.length + g, self.max_seq)
-            if not self._ensure_pages(i, s, upto):
-                self._fail_slot(i, s, KVCacheExhausted(
-                    f"no KV page free for a γ={g} verify window at "
-                    f"position {s.length} (pool {self.num_pages} "
-                    f"pages, {self._pool.pages_in_use} in use)"),
-                    "shed")
-                continue
-            if not self._ensure_draft_pages(i, s, upto):
-                self._fail_slot(i, s, KVCacheExhausted(
-                    f"no DRAFT KV page free at position {s.length} "
-                    f"(draft pool {self.num_pages} pages, "
-                    f"{self._draft_pool.pages_in_use} in use)"), "shed")
+        with self._phase("generate.grow_pages"):
+            for i, s in enumerate(self._slots_state):
+                if s is None or s.prefilling:
+                    continue
+                upto = min(s.length + g, self.max_seq)
+                if not self._ensure_pages(i, s, upto):
+                    self._fail_slot(i, s, KVCacheExhausted(
+                        f"no KV page free for a γ={g} verify window at "
+                        f"position {s.length} (pool {self.num_pages} "
+                        f"pages, {self._pool.pages_in_use} in use)"),
+                        "shed")
+                    continue
+                if not self._ensure_draft_pages(i, s, upto):
+                    self._fail_slot(i, s, KVCacheExhausted(
+                        f"no DRAFT KV page free at position {s.length} "
+                        f"(draft pool {self.num_pages} pages, "
+                        f"{self._draft_pool.pages_in_use} in use)"), "shed")
         active = [(i, s) for i, s in enumerate(self._slots_state)
                   if s is not None and not s.prefilling]
         if not active:
             return
         nactive = len(active)
-        tokens = np.zeros((self.slots,), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        vwp = np.full((self.slots, g), self._pool.no_page, np.int32)
-        vwr = np.zeros((self.slots, g), np.int32)
-        dwp = np.full((g, self.slots), self._draft_pool.no_page,
-                      np.int32)
-        dwr = np.zeros((g, self.slots), np.int32)
-        for i, s in active:
-            tokens[i] = s.last_token
-            pos[i] = s.length
-            for t in range(g):
-                p = s.length + t
-                if p >= self.max_seq:
-                    break  # sentinel stays: the write drops
-                vwp[i, t] = self._table[i, p // self.page_size]
-                vwr[i, t] = p % self.page_size
-                dwp[t, i] = self._draft_table[i, p // self.page_size]
-                dwr[t, i] = p % self.page_size
-        sampled = self._batch_sampling()
-        if sampled:
-            temp, top_k, top_p, seeds = self._sampling_arrays()
-        traced = self._tracer.active
+        with self._phase("generate.prepare"):
+            tokens = np.zeros((self.slots,), np.int32)
+            pos = np.zeros((self.slots,), np.int32)
+            vwp = np.full((self.slots, g), self._pool.no_page, np.int32)
+            vwr = np.zeros((self.slots, g), np.int32)
+            dwp = np.full((g, self.slots), self._draft_pool.no_page,
+                          np.int32)
+            dwr = np.zeros((g, self.slots), np.int32)
+            for i, s in active:
+                tokens[i] = s.last_token
+                pos[i] = s.length
+                for t in range(g):
+                    p = s.length + t
+                    if p >= self.max_seq:
+                        break  # sentinel stays: the write drops
+                    vwp[i, t] = self._table[i, p // self.page_size]
+                    vwr[i, t] = p % self.page_size
+                    dwp[t, i] = self._draft_table[i, p // self.page_size]
+                    dwr[t, i] = p % self.page_size
+            sampled = self._batch_sampling()
+            if sampled:
+                temp, top_k, top_p, seeds = self._sampling_arrays()
+        traced = self._traced   # the boundary's one read of the gate
         t0 = self.clock()
         try:
             self._fire_spec_draft_fail()
@@ -1764,66 +1855,72 @@ class GenerationEngine:
             self._tracer.span("decode_step", None, t0, t1,
                               tid=self.name or "generate",
                               step=self._n_steps, phase="draft",
-                              gamma=g, active=nactive)
+                              gamma=g, active=nactive,
+                              program=_program_name(dfn))
         # verify failures propagate to the caller's containment: the
         # donated target caches are poisoned, so _recover_from_
         # dispatch_error must fail the streams and rebuild everything
         vfn = self._decoder.verify_fn(g, sampled=sampled)
         with jax.profiler.StepTraceAnnotation(
                 "generate", step_num=self._n_steps):
-            if sampled:
-                (n_acc, out), self._caches = vfn(
-                    self._params, self._caches, tokens, d, q,
-                    pos, self._table.copy(), vwp, vwr, temp, top_k,
-                    top_p, seeds)
-            else:
-                (n_acc, out), self._caches = vfn(
-                    self._params, self._caches, tokens, d, pos,
-                    self._table.copy(), vwp, vwr)
+            with self._phase("generate.dispatch"):
+                if sampled:
+                    (n_acc, out), self._caches = vfn(
+                        self._params, self._caches, tokens, d, q,
+                        pos, self._table.copy(), vwp, vwr, temp, top_k,
+                        top_p, seeds)
+                else:
+                    (n_acc, out), self._caches = vfn(
+                        self._params, self._caches, tokens, d, pos,
+                        self._table.copy(), vwp, vwr)
             # THE one host sync per round for the whole batch (RL010):
             # accept counts + the emit-ready token rows together
-            n_host, out_host = jax.device_get((n_acc, out))
+            with self._phase("generate.fetch"):
+                n_host, out_host = jax.device_get((n_acc, out))
         n_host = np.asarray(n_host)
         out_host = np.asarray(out_host)
         now = self.clock()
         self._n_steps += 1
-        emitted = proposed = accepted = 0
-        for i, s in active:
-            n = int(n_host[i])
-            proposed += g
-            accepted += n
-            # rows < n are the accepted proposals; row n (when < γ) is
-            # the verifier's correction — emit in order, stopping
-            # EXACTLY where the sequential engine stops (EOS /
-            # max_new can land mid-window)
-            for t in range(min(n + 1, g)):
-                tok = int(out_host[i, t])
-                s.length += 1
-                s.generated += 1
-                s.last_token = tok
-                s.stream._emit(tok)
-                emitted += 1
-                if s.generated >= s.stream.max_new or (
-                        self.eos_id is not None
-                        and tok == self.eos_id):
-                    break
-            self._trim_slot_pages(i, s)
-            self._retire(i, s, now)
-        if traced:
-            self._tracer.span("decode_step", None, t1, now,
-                              tid=self.name or "generate",
-                              step=self._n_steps - 1, phase="verify",
-                              gamma=g, active=nactive,
-                              proposed=proposed, accepted=accepted)
-        self.metrics.record_spec_round(proposed, accepted)
-        # TPOT percentiles become per-ROUND walls here (documented in
-        # GenerationMetrics.snapshot); tokens_per_s stays comparable
-        self.metrics.record_decode_step(emitted, now - t0)
-        self._spec_account(g, proposed, accepted, now - t0)
-        self._fire_cancel_at_token(now)
-        if self.stats_every and self._n_steps % self.stats_every == 0:
-            self.metrics.emit(extra={"slots": self.slots,
-                                     "active": nactive})
+        with self._phase("generate.deliver"):
+            emitted = proposed = accepted = 0
+            for i, s in active:
+                n = int(n_host[i])
+                proposed += g
+                accepted += n
+                # rows < n are the accepted proposals; row n (when < γ) is
+                # the verifier's correction — emit in order, stopping
+                # EXACTLY where the sequential engine stops (EOS /
+                # max_new can land mid-window)
+                for t in range(min(n + 1, g)):
+                    tok = int(out_host[i, t])
+                    s.length += 1
+                    s.generated += 1
+                    s.last_token = tok
+                    s.stream._emit(tok)
+                    emitted += 1
+                    if s.generated >= s.stream.max_new or (
+                            self.eos_id is not None
+                            and tok == self.eos_id):
+                        break
+                self._trim_slot_pages(i, s)
+                self._retire(i, s, now)
+            if traced:
+                self._tracer.span("decode_step", None, t1, now,
+                                  tid=self.name or "generate",
+                                  step=self._n_steps - 1, phase="verify",
+                                  gamma=g, active=nactive,
+                                  proposed=proposed, accepted=accepted,
+                                  program=_program_name(vfn))
+            self.metrics.record_spec_round(proposed, accepted)
+            # TPOT percentiles become per-ROUND walls here (documented in
+            # GenerationMetrics.snapshot); tokens_per_s stays comparable
+            self.metrics.record_decode_step(emitted, now - t0)
+            self._spec_account(g, proposed, accepted, now - t0)
+            self._fire_cancel_at_token(now)
+            if self.stats_every and self._n_steps % self.stats_every == 0:
+                self.metrics.emit(extra={"slots": self.slots,
+                                         "active": nactive})
+        self._open_turn()
 
     def _ensure_draft_pages(self, slot: int, st: _Slot,
                             upto_pos: int) -> bool:
