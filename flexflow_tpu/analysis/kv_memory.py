@@ -3,10 +3,25 @@ runtime and the static tools (ISSUE 11 satellite; ISSUE 15 tentpole).
 
 Since ISSUE 15 the decode state is a **paged block pool**, not a dense
 ``(slots, max_seq, ...)`` preallocation: each attention op holds a K and
-a V pool of ``(num_pages, page_size, heads, head_dim)`` (heads sharded
-over the tensor-parallel ``c`` mesh axis; pages are interchangeable, so
-the page dim is replicated — any slot may hold any page) and a per-slot
-page table of gather indices maps logical positions onto pages.  HBM
+a V pool and a per-slot page table of gather indices maps logical
+positions onto pages.  The pools are stored **lane-dense** (ISSUE 25):
+``(num_pages, page_size, heads * head_dim)``, one row per token with
+its heads side by side — the folded dim sharded over the
+tensor-parallel ``c`` mesh axis where ``c`` divides the heads (each
+shard then holds ``heads / c`` whole heads, contiguous); pages are
+interchangeable, so the page dim is replicated — any slot may hold any
+page.  Why folded: on the TPU an array's two minor dims are tiled
+``(8, 128)``, and ``(heads, head_dim)`` = 12 x 64 fills no tile, so the
+row scatter, the page gather and the attention einsums each wanted a
+different layout of the old ``(num_pages, page_size, heads, head_dim)``
+pool and XLA converted the WHOLE pool between them — eight pool-sized
+transposing copies a layer in decode, six in prefill, 84 % of the
+device's time in the serve cell (PERF.md, PR 25).  With
+``heads * head_dim`` a multiple of 128 the folded rows are the tiles:
+the scatter updates the donated pool in place, the gather reads it,
+and only the GATHERED view is unfolded to ``(.., heads, head_dim)``
+for the einsums.  The bytes are the same in the same order, so the
+accounting below did not change.  HBM
 therefore scales with *pages*, and shared-prefix reuse (the prefix trie
 in ``serving/generation/pages.py``) makes pages-in-use scale with LIVE
 tokens rather than ``slots x max_seq``.  LSTM ops keep their f32
@@ -132,10 +147,14 @@ def kv_cache_layout(layers: List[Op],
         if op.op_type == OpType.ATTENTION and hasattr(op, "num_heads"):
             h, hd = op.num_heads, op.head_dim
             c_entry = "c" if (c > 1 and h % c == 0) else None
-            shape = (pool, page_size, h, hd)
-            # pages replicated over 'n' (interchangeable across slots),
-            # heads sharded over 'c' like the projections feeding them
-            entries = (None, None, c_entry, None)
+            # lane-dense rows: heads x head_dim folded into ONE minor
+            # dim, so no consumer wants the pool in another layout
+            # (module docstring)
+            shape = (pool, page_size, h * hd)
+            # pages replicated over 'n' (interchangeable across slots);
+            # the folded dim sharded over 'c' like the projections
+            # feeding it — h % c == 0 keeps heads whole per shard
+            entries = (None, None, c_entry)
             out[op.name] = {
                 "kind": "kv",
                 "shapes": {"k": shape, "v": shape},

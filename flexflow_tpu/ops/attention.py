@@ -428,6 +428,29 @@ class MultiHeadAttention(Op):
         return [self._out_proj(params, attn, n, sq, ctx)], k, v
 
     # ---- paged KV cache (docs/serving.md "Paged KV & prefix caching") --
+    def _fold_rows(self, kv):
+        """``(..., h, hd)`` K or V rows -> the pool's stored form
+        ``(..., h * hd)``: each token's heads side by side in ONE
+        lane-dense minor dim (``analysis/kv_memory.py`` says why: with
+        ``(h, hd)`` minor no (8, 128) tile is filled, and XLA's TPU
+        compiler transposed the whole pool for the scatter, back for
+        the donated output, again for the gather and once more for the
+        einsums — eight pool-sized copies a layer).  The same bytes in
+        the same order, so nothing a CPU parity pin reads changes."""
+        return kv.reshape(kv.shape[:-2] + (self.embed_dim,))
+
+    def _gather_pages(self, pool, table):
+        """Each row of ``table`` (n, pages_per_slot) gathered out of
+        the folded ``pool`` back into position order, and THAT view —
+        never the pool — unfolded to the ``(n, L, h, hd)`` the
+        attention einsums read.  ``mode="clip"``: sentinel table
+        entries are OOB by design, and ``jnp.take``'s default "fill"
+        would gather NaN, which the exact-zero mask multiplies to NaN,
+        not zero."""
+        rows = jnp.take(pool, table, axis=0, mode="clip")
+        return rows.reshape(table.shape[0], -1, self.num_heads,
+                            self.head_dim)
+
     def forward_paged(self, params, x, k_pool, v_pool, table_row, start,
                       length, ctx: OpContext):
         """One prefill CHUNK against the paged KV cache: project the
@@ -438,8 +461,9 @@ class MultiHeadAttention(Op):
         chunk itself, causally masked on GLOBAL positions.
 
         ``x``: (1, B, d) chunk hidden states at positions ``start ..
-        start+B-1``; ``k_pool``/``v_pool``: (num_pages, page, h, hd)
-        pools; ``table_row``: (pages_per_slot,) int32 page ids (the
+        start+B-1``; ``k_pool``/``v_pool``: (num_pages, page, h * hd)
+        pools — the LANE-DENSE stored form (see :meth:`_fold_rows`);
+        ``table_row``: (pages_per_slot,) int32 page ids (the
         pool's ``no_page`` sentinel marks unallocated entries — reads
         of them are masked, writes to them dropped); ``length``: valid
         rows in the chunk (pad rows' writes are dropped via the OOB
@@ -462,13 +486,12 @@ class MultiHeadAttention(Op):
         wp = jnp.take(table_row, qpos // page, mode="clip")
         wp = jnp.where(jnp.arange(B) < length, wp, no_page)
         wr = qpos % page
-        k_pool = k_pool.at[wp, wr].set(k[0], mode="drop")
-        v_pool = v_pool.at[wp, wr].set(v[0], mode="drop")
-        h, hd = self.num_heads, self.head_dim
-        kg = jnp.take(k_pool, table_row, axis=0,
-                      mode="clip").reshape(1, -1, h, hd)
-        vg = jnp.take(v_pool, table_row, axis=0,
-                      mode="clip").reshape(1, -1, h, hd)
+        k_pool = k_pool.at[wp, wr].set(self._fold_rows(k[0]),
+                                       mode="drop")
+        v_pool = v_pool.at[wp, wr].set(self._fold_rows(v[0]),
+                                       mode="drop")
+        kg = self._gather_pages(k_pool, table_row[None])
+        vg = self._gather_pages(v_pool, table_row[None])
         attn = _paged_chunk_attention(q, kg, vg, qpos,
                                       1.0 / math.sqrt(self.head_dim))
         return ([self._out_proj(params, attn, n, B, ctx)],
@@ -483,7 +506,11 @@ class MultiHeadAttention(Op):
         writes must drop rather than corrupt a shared page), gather
         each slot's page table back into position order and attend.
 
-        ``x``: (slots, 1, d); ``table``: (slots, pages_per_slot) int32;
+        ``x``: (slots, 1, d); ``k_pool``/``v_pool``: the folded
+        (num_pages, page, h * hd) pools (:meth:`_fold_rows`), updated
+        in place under donation — no compiled decode program copies
+        them (``GraphDecoder.pool_copies`` counts); ``table``: (slots,
+        pages_per_slot) int32;
         ``pos``: (slots,) int32 current position.  The gathered view is
         ``pages_per_slot * page`` wide; positions beyond ``pos`` are
         masked to exact zeros, so the step is bit-identical on CPU to
@@ -492,17 +519,12 @@ class MultiHeadAttention(Op):
         n = x.shape[0]
         xq = cast_compute(x, ctx)
         q, k, v = self._qkv(params, xq, xq, xq, ctx)
-        k_pool = k_pool.at[write_pages, write_rows].set(k[:, 0],
-                                                       mode="drop")
-        v_pool = v_pool.at[write_pages, write_rows].set(v[:, 0],
-                                                       mode="drop")
-        h, hd = self.num_heads, self.head_dim
-        # mode="clip": sentinel table entries are OOB by design (the
-        # default "fill" would gather NaN that poisons the masked sum)
-        kg = jnp.take(k_pool, table, axis=0,
-                      mode="clip").reshape(n, -1, h, hd)
-        vg = jnp.take(v_pool, table, axis=0,
-                      mode="clip").reshape(n, -1, h, hd)
+        k_pool = k_pool.at[write_pages, write_rows].set(
+            self._fold_rows(k[:, 0]), mode="drop")
+        v_pool = v_pool.at[write_pages, write_rows].set(
+            self._fold_rows(v[:, 0]), mode="drop")
+        kg = self._gather_pages(k_pool, table)
+        vg = self._gather_pages(v_pool, table)
         attn = _decode_attention(q, kg, vg, pos,
                                  1.0 / math.sqrt(self.head_dim))
         return ([self._out_proj(params, attn, n, 1, ctx)],
@@ -518,7 +540,9 @@ class MultiHeadAttention(Op):
         window row over it, causally masked on GLOBAL positions.
 
         ``x``: (slots, W, d) hidden states at positions ``pos[i] ..
-        pos[i]+W-1``; ``table``: (slots, pages_per_slot) int32;
+        pos[i]+W-1``; ``k_pool``/``v_pool``: the folded (num_pages,
+        page, h * hd) pools (:meth:`_fold_rows`); ``table``: (slots,
+        pages_per_slot) int32;
         ``pos``: (slots,) int32 first window position.  The chunked-
         prefill generalization of :meth:`decode_paged` — same
         :meth:`_qkv`/:meth:`_out_proj`, same gather, with
@@ -531,15 +555,12 @@ class MultiHeadAttention(Op):
         n, w, _ = x.shape
         xq = cast_compute(x, ctx)
         q, k, v = self._qkv(params, xq, xq, xq, ctx)
-        k_pool = k_pool.at[write_pages, write_rows].set(k, mode="drop")
-        v_pool = v_pool.at[write_pages, write_rows].set(v, mode="drop")
-        h, hd = self.num_heads, self.head_dim
-        # mode="clip": sentinel table entries are OOB by design (the
-        # default "fill" would gather NaN that poisons the masked sum)
-        kg = jnp.take(k_pool, table, axis=0,
-                      mode="clip").reshape(n, -1, h, hd)
-        vg = jnp.take(v_pool, table, axis=0,
-                      mode="clip").reshape(n, -1, h, hd)
+        k_pool = k_pool.at[write_pages, write_rows].set(
+            self._fold_rows(k), mode="drop")
+        v_pool = v_pool.at[write_pages, write_rows].set(
+            self._fold_rows(v), mode="drop")
+        kg = self._gather_pages(k_pool, table)
+        vg = self._gather_pages(v_pool, table)
         qpos = pos[:, None] + jnp.arange(w)[None, :]
         attn = _verify_window_attention(q, kg, vg, qpos,
                                         1.0 / math.sqrt(self.head_dim))

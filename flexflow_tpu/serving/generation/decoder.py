@@ -32,7 +32,13 @@ Pool geometry and sharding come from
 FF108/FF121/FF130 memory gates integrate, so what lint predicts is
 what this decoder allocates (the arrays themselves come from
 ``pages.alloc_pool_arrays``, the one allocation site RL013 pins).
-Heads shard over the tensor-parallel ``c`` mesh axis; the page dim is
+The K/V pools are stored lane-dense, ``(num_pages, page_size, heads *
+head_dim)``: the attention ops fold new rows before the scatter and
+unfold only the gathered view, so no program compiled here copies a
+pool (:meth:`GraphDecoder.pool_copies` reads the compiled text and
+says so; under ``(.., heads, head_dim)`` the TPU compile of a decode
+layer held eight pool-sized copies).  The folded dim shards over the
+tensor-parallel ``c`` mesh axis by whole heads; the page dim is
 replicated (pages are interchangeable across slots).
 
 Supported graphs: one (n, s) int token input; position-wise ops
@@ -45,6 +51,7 @@ silently produce wrong tokens for an unsupported graph.
 
 from __future__ import annotations
 
+import re
 from typing import Dict, List, Tuple
 
 import jax
@@ -65,6 +72,61 @@ from .pages import alloc_pool_arrays
 _POINTWISE_TYPES = (OpType.LINEAR, OpType.LAYERNORM, OpType.RMSNORM,
                     OpType.ELEMENT_UNARY, OpType.ELEMENT_BINARY,
                     OpType.SOFTMAX, OpType.DROPOUT)
+
+
+# one computation of a compiled module's text: `%name (params) -> type {`
+# (or `ENTRY %name ...`) up to the closing brace on a line of its own
+_COMPUTATION = re.compile(
+    r"^(?:ENTRY\s+)?%(?P<name>[^\s(]+)\s*\(.*?\{\n(?P<body>.*?)^\}",
+    re.M | re.S)
+# `%copy.3 = bf16[4096,16,768]{...} copy(...)`; an asynchronous copy's
+# `copy-start` yields a tuple whose first shape is the copied array's
+_COPY = re.compile(r"^\s*(?P<root>ROOT\s+)?%\S+\s*=\s*\(?\s*(?P<dtype>\w+)"
+                   r"\[(?P<dims>[\d,]*)\][^=]*?\scopy(?:-start)?\(", re.M)
+_FUSION_CALL = re.compile(r"\sfusion\(.*?calls=%(?P<callee>[^\s,)]+)")
+# bytes per element by XLA's primitive type names
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+              "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+              "f64": 8}
+
+
+def count_copies(hlo_text: str, elements) -> Dict[str, int]:
+    """``{"count", "bytes"}`` of the ``copy`` operations in a compiled
+    module's text (``compiled.as_text()``) whose result holds one of
+    the element counts in ``elements`` — the reading behind
+    :meth:`GraphDecoder.pool_copies`.  By element count and not by
+    shape, because a transposing copy keeps the elements and changes
+    everything else.
+
+    Counted is a copy that is a device operation of its own and writes
+    its whole result to memory: a ``copy`` (or ``copy-start``)
+    instruction of an unfused computation (the entry, a loop's body),
+    or the ROOT of a fusion that an unfused computation calls.  A copy
+    nested deeper — a fusion INSIDE a convolution's fusion — is how
+    that convolution reads its operand in the order it wants, tile by
+    tile, and passes over no memory of its own."""
+    elements = frozenset(int(e) for e in elements)
+    bodies = {m.group("name"): m.group("body")
+              for m in _COMPUTATION.finditer(hlo_text)}
+    calls = {name: {c.group("callee") for c in _FUSION_CALL.finditer(body)}
+             for name, body in bodies.items()}
+    fused = set().union(*calls.values())
+    top_fused = set().union(*(callees for name, callees in calls.items()
+                              if name not in fused))
+    count = nbytes = 0
+    for name, body in bodies.items():
+        if name in fused and name not in top_fused:
+            continue
+        for m in _COPY.finditer(body):
+            if name in fused and not m.group("root"):
+                continue
+            vol = 1
+            for d in m.group("dims").split(","):
+                vol *= int(d) if d else 1
+            if vol in elements:
+                count += 1
+                nbytes += vol * _HLO_BYTES.get(m.group("dtype"), 1)
+    return {"count": count, "bytes": nbytes}
 
 
 def prefill_buckets(max_seq: int) -> Tuple[int, ...]:
@@ -159,6 +221,7 @@ class GraphDecoder:
                 f"(models.build_transformer_lm / build_lstm_lm), not a "
                 f"classifier")
         self._final_uid = final.uid
+        self._vocab = int(final.shape[-1])
         for op in model.layers:
             if isinstance(op, MultiHeadAttention):
                 if not (op._self_attn and op.causal):
@@ -531,6 +594,99 @@ class GraphDecoder:
         fn = jax.jit(draft_s if sampled else draft, donate_argnums=(1,))
         self._draft_fns[key] = fn
         return fn
+
+    # ---- what the compiler made of the pool (ISSUE 25) ------------------
+    def _program_specs(self, device=None):
+        """``(key, jitted fn, abstract arguments)`` of every program
+        this decoder has built, with the shapes and dtypes the engine
+        calls it with (its warm-up's and its dispatches' are the same:
+        ``engine._warmup``), so that lowering them again asks the
+        compilation cache for the executable that serves.  ``device``
+        places every argument on one (possibly only DESCRIBED) device
+        instead of the model's."""
+        from jax.sharding import PartitionSpec, SingleDeviceSharding
+
+        mesh = self.model.mesh
+        one = None if device is None else SingleDeviceSharding(device)
+        if one is not None and mesh is not None and mesh.is_distributed:
+            raise ValueError("pool_copies(device=...) describes ONE "
+                             "device; this model's mesh is distributed")
+
+        def spec(shape, dtype, sharding=None):
+            return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
+                                        sharding=one or sharding)
+
+        params = {k: spec(v.shape, v.dtype, getattr(v, "sharding", None))
+                  for k, v in self.model._params.items()}
+        compute = self.model.config.compute_dtype
+        caches = {}
+        for name, ent in self.layout.items():
+            dt = compute if ent["dtype"] == "compute" else jnp.float32
+            caches[name] = {
+                leaf: spec(shape, dt, mesh.sharding(PartitionSpec(
+                    *ent["entries"][leaf]))
+                    if mesh is not None and mesh.is_distributed else None)
+                for leaf, shape in ent["shapes"].items()}
+
+        def i32(*shape):
+            return spec(shape, jnp.int32)
+
+        def f32(*shape):
+            return spec(shape, jnp.float32)
+
+        s, pps = self.slots, self.pages_per_slot
+        strategy = (f32(s), i32(s), f32(s), i32(s))  # temp,top_k,top_p,seeds
+        step = (i32(s), i32(s), i32(s, pps), i32(s), i32(s))
+        out = []
+        for b, fn in sorted(self._prefill_fns.items()):
+            out.append((f"jit_prefill.{b}", fn,
+                        (i32(1, b), i32(pps), i32(), i32(), i32())))
+        if self._decode_fn is not None:
+            out.append(("jit_decode", self._decode_fn, step))
+        if self._decode_sampled_fn is not None:
+            out.append(("jit_decode_s", self._decode_sampled_fn,
+                        step + strategy))
+        for (w, sampled), fn in sorted(self._verify_fns.items()):
+            window = (i32(s), i32(s, pps), i32(s, w), i32(s, w))
+            if sampled:
+                args = (i32(s), i32(s, w), f32(s, w, self._vocab)) \
+                    + window + strategy
+            else:
+                args = (i32(s), i32(s, w)) + window
+            out.append((f"jit_verify{'_s' if sampled else ''}.{w}", fn,
+                        args))
+        for (g, sampled), fn in sorted(self._draft_fns.items()):
+            args = (i32(s), i32(s), i32(s, pps), i32(g, s), i32(g, s))
+            out.append((f"jit_draft{'_s' if sampled else ''}.{g}", fn,
+                        args + (strategy if sampled else ())))
+        return [(key, fn, (params, caches) + args)
+                for key, fn, args in out]
+
+    def pool_copies(self, device=None) -> Dict[str, Dict[str, int]]:
+        """What says from inside the program that the pools are not
+        copied: ``{program: {"count", "bytes"}}`` — for every serving
+        program this decoder has built (``jit_decode``, ``jit_prefill.
+        <bucket>``, ``jit_verify.<W>``, ``jit_draft.<γ>``, the ``_s``
+        sampled variants), the ``copy`` instructions of its COMPILED
+        text whose element count is a K/V pool leaf's
+        (:func:`count_copies`).  The stored form is chosen so that each
+        reads 0 (``analysis/kv_memory.py``); under the old ``(pages,
+        page, heads, head_dim)`` form the TPU compile of one decode
+        layer held eight such copies, 100 MB each in the serve cell.
+
+        Computed ON DEMAND, never by ``start()``: each program is
+        lowered again with the shapes the engine calls it with and
+        compiled, which the persistent compilation cache answers where
+        the serving executable came from it or went to it.  ``device``
+        compiles for one given device instead — a TPU that
+        ``jax.experimental.topologies`` only describes will do, which
+        is how a test without a chip reads the TPU's compiler."""
+        leaves = {int(np.prod(shape))
+                  for ent in self.layout.values() if ent["kind"] == "kv"
+                  for shape in ent["shapes"].values()}
+        return {key: count_copies(
+                    fn.lower(*args).compile().as_text(), leaves)
+                for key, fn, args in self._program_specs(device)}
 
     # ---- shared-instance registry --------------------------------------
     @classmethod

@@ -696,6 +696,8 @@ class GenerationEngine:
         self._accept_ewma: Optional[float] = None
         self._spec_seen_proposed = 0
         self._spec_costs: Dict[int, float] = {}  # per-γ round-wall EWMA
+        # pool_copies()'s last answer; None until somebody asks
+        self._pool_copies: Optional[Dict[str, Dict[str, int]]] = None
         self.metrics.spec_stats_fn = self._spec_stats
         self._gen_faults: List[Dict] = []
         # lifecycle (same single-use contract as ServingEngine)
@@ -1112,15 +1114,38 @@ class GenerationEngine:
             "prefill_chunks": self._chunks_total,
         }
 
+    def pool_copies(self) -> Dict[str, Dict[str, int]]:
+        """``{program: {"count", "bytes"}}``: the pool-sized ``copy``
+        operations in the compiled text of every serving program this
+        engine's decoders have built — the target's under their
+        program names, the draft's under ``draft/`` — as
+        :meth:`GraphDecoder.pool_copies` counts them.  0 everywhere
+        says the stored form holds: no program pays for what the pool
+        weighs.  ON DEMAND only (it lowers and compiles each program
+        again; the compilation cache answers), so ``start()`` and the
+        serving loop never pay for it; once asked, :meth:`stats`
+        carries the answer under ``pool_copies``."""
+        out = dict(self._decoder.pool_copies(device=self.device))
+        if self._draft_decoder is not None:
+            out.update(
+                ("draft/" + key, val) for key, val in
+                self._draft_decoder.pool_copies(
+                    device=self.device).items())
+        self._pool_copies = out
+        return out
+
     def stats(self) -> Dict:
         active = sum(1 for s in self._slots_state if s is not None)
-        return {**self.metrics.snapshot(), "slots": self.slots,
-                "active_slots": active, "max_seq": self.max_seq,
-                "kv_cache_bytes": self.kv_cache_bytes,
-                "prefill_chunk": self.prefill_chunk,
-                "admission": self.admission,
-                "max_queue_requests": self.max_queue_requests,
-                "peak_queue_requests": self._batcher.peak_rows}
+        out = {**self.metrics.snapshot(), "slots": self.slots,
+               "active_slots": active, "max_seq": self.max_seq,
+               "kv_cache_bytes": self.kv_cache_bytes,
+               "prefill_chunk": self.prefill_chunk,
+               "admission": self.admission,
+               "max_queue_requests": self.max_queue_requests,
+               "peak_queue_requests": self._batcher.peak_rows}
+        if self._pool_copies is not None:
+            out["pool_copies"] = self._pool_copies
+        return out
 
     # ---- dispatcher thread ---------------------------------------------
     def _decode_loop(self) -> None:

@@ -8,7 +8,13 @@ evicts cached prefix pages under pressure; the trie maps token-id
 chains (one node per FULL page of tokens) to pooled pages so a submit
 whose prompt extends a cached prefix skips recomputing the shared
 pages.  The device side only ever sees page ids as gather/scatter
-indices (ops/attention.py ``prefill_paged``/``decode_paged``).
+indices (ops/attention.py ``forward_paged``/``decode_paged``/
+``verify_paged``) into pools stored LANE-DENSE: ``(num_pages,
+page_size, heads * head_dim)``, a token's heads side by side in one
+minor dim, so that no compiled serving program holds a copy the size of
+the pool (``analysis/kv_memory.py`` says why; ``GraphDecoder.
+pool_copies`` counts).  Everything below that touches a pool leaf asks
+only that it be page-major (``shape[0] == num_pages``).
 
 Sharing is all-or-nothing per page, and a shared page is immutable by
 construction: a lookup only ever matches COMPLETE pages strictly
@@ -255,9 +261,10 @@ class PrefixCache:
 def alloc_pool_arrays(layout: Dict[str, Dict], mesh, compute_dtype):
     """Materialize the ``analysis.kv_memory.kv_cache_layout`` on
     device: attention K/V page pools and LSTM state pairs, placed under
-    the layout's PartitionSpec entries.  THE one KV allocation site
-    (repo_lint RL013) — byte-for-byte what :func:`kv_page_plan`
-    accounts, pinned in tests/test_generation.py."""
+    the layout's PartitionSpec entries (K/V leaves in the layout's
+    lane-dense ``(num_pages, page_size, heads * head_dim)`` form).
+    THE one KV allocation site (repo_lint RL013) — byte-for-byte what
+    :func:`kv_page_plan` accounts, pinned in tests/test_generation.py."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec

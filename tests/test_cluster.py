@@ -490,10 +490,14 @@ class TestFF132Gate:
 # ----------------------------------------------------------------------
 # pages: the fixed-shape export/import round trip migration rides on
 # ----------------------------------------------------------------------
-def test_export_import_pages_padded_roundtrip():
+@pytest.mark.parametrize("heads", [3, 2 * 64])
+def test_export_import_pages_padded_roundtrip(heads):
+    """Page-major is all migration asks of a leaf: a narrow minor dim
+    and the pool's lane-dense folded one (heads x head_dim = 128) ride
+    the same gather and scatter."""
     import jax.numpy as jnp
 
-    num_pages, psize, heads = 6, 4, 3
+    num_pages, psize = 6, 4
     src = {"attn0": {
         "k": jnp.arange(num_pages * psize * heads,
                         dtype=jnp.float32).reshape(num_pages, psize,

@@ -153,13 +153,14 @@ def test_position_embedding_decode_matches_forward():
 # ---------------------------------------------------------------------
 # engine-level: GenerationEngine == replicated predict-style decode
 # ---------------------------------------------------------------------
-def _build_lm(seed=0, mesh_shape=None, slots=2):
+def _build_lm(seed=0, mesh_shape=None, slots=2, num_layers=2, d_model=32,
+              d_ff=64, compute_dtype="float32"):
     from flexflow_tpu.models import build_transformer_lm
-    cfg = ff.FFConfig(batch_size=4, compute_dtype="float32", seed=seed)
+    cfg = ff.FFConfig(batch_size=4, compute_dtype=compute_dtype, seed=seed)
     cfg.serve_gen_slots = slots
-    model = build_transformer_lm(cfg, num_layers=2, d_model=32,
-                                 num_heads=2, d_ff=64, seq_len=SEQ,
-                                 vocab_size=VOCAB)[0]
+    model = build_transformer_lm(cfg, num_layers=num_layers,
+                                 d_model=d_model, num_heads=2, d_ff=d_ff,
+                                 seq_len=SEQ, vocab_size=VOCAB)[0]
     model.compile(ff.SGDOptimizer(lr=0.01),
                   mesh=MachineMesh(mesh_shape or {"n": 1}))
     model.init_layers(seed=seed)
@@ -181,6 +182,13 @@ def reference_decode(model, prompt, max_new, max_seq=SEQ):
 @pytest.fixture(scope="module")
 def lm():
     return _build_lm()
+
+
+@pytest.fixture(scope="module")
+def lm_lane_dense():
+    """One layer whose folded K/V rows fill whole lanes: 2 heads x 64 =
+    128; d_ff 192 so that no weight has a pool's element count."""
+    return _build_lm(slots=4, num_layers=1, d_model=128, d_ff=192)
 
 
 @pytest.fixture(scope="module")
@@ -396,6 +404,12 @@ def test_sharded_engine_matches_replicated_reference(tmp_path, lm,
         outs = [list(int(t) for t in
                      eng.submit(p, max_new_tokens=6).result(timeout=180))
                 for p in prompts[:4]]
+        k_pool = eng._caches["attention_0"]["k"]
+        # the folded dim shards over c: 2 heads x 16 -> ONE whole head
+        # (16 contiguous lanes) a shard; pages and rows stay whole
+        assert k_pool.shape == (eng.num_pages, eng.page_size, 32)
+        assert {sh.data.shape for sh in k_pool.addressable_shards} \
+            == {(eng.num_pages, eng.page_size, 16)}
     refs = [reference_decode(lm, p, 6) for p in prompts[:4]]
     assert outs == refs
     # sharded pool accounting: heads over c (x2); the page dim is
@@ -730,17 +744,32 @@ def test_kv_pages_exhausted_sheds_only_one_stream(lm):
     assert eng._pool.pages_in_use == 0
 
 
-def test_kv_page_plan_matches_real_pool(lm):
+@pytest.mark.parametrize("which,folded,kv_bytes", [
+    ("lm", 32, 4), ("lm_lane_dense", 128, 4)])
+def test_kv_page_plan_matches_real_pool(request, which, folded, kv_bytes):
     """Byte-for-byte, per leaf: the kv_memory page plan == the pool
     arrays the decoder actually allocates (the FF108/FF121/FF130
-    scalar is total_bytes of this same plan)."""
-    from flexflow_tpu.analysis.kv_memory import kv_page_plan
+    scalar is total_bytes of this same plan), and every K/V leaf is
+    stored lane-dense — (pages, page, heads * head_dim), page-major."""
+    from flexflow_tpu.analysis.kv_memory import (kv_cache_layout,
+                                                 kv_page_plan)
+    lm = request.getfixturevalue(which)
     eng = GenerationEngine(lm, slots=2)
     dec = eng._decoder
     caches = dec.init_cache()
+    layout = kv_cache_layout(lm.layers, {"n": 1}, 2, SEQ,
+                             page_size=dec.page_size,
+                             num_pages=dec.num_pages)
+    assert layout == dec.layout
+    for name, ent in layout.items():
+        for leaf, shape in ent["shapes"].items():
+            assert shape == (dec.num_pages, dec.page_size, folded)
+            assert caches[name][leaf].shape == shape
+            assert len(ent["entries"][leaf]) == len(shape)
     real = sum(int(leaf.nbytes) for sub in caches.values()
                for leaf in sub.values())
-    plan = kv_page_plan(lm.layers, {"n": 1}, 2, SEQ, kv_dtype_bytes=4,
+    plan = kv_page_plan(lm.layers, {"n": 1}, 2, SEQ,
+                        kv_dtype_bytes=kv_bytes,
                         page_size=dec.page_size,
                         num_pages=dec.num_pages)
     assert real == plan["total_bytes"] == eng.kv_cache_bytes
@@ -750,6 +779,31 @@ def test_kv_page_plan_matches_real_pool(lm):
     # and the engine's high-water accounting uses the same page_bytes
     assert plan["page_bytes"] * plan["num_pages"] == plan["pool_bytes"]
     eng.stop()
+
+
+@pytest.mark.parametrize("heads,c,entry", [
+    (2, 1, None), (2, 2, "c"), (12, 4, "c"), (3, 2, None), (12, 8, None)])
+def test_kv_layout_shards_the_folded_dim_by_whole_heads(heads, c, entry):
+    """The folded K/V dim carries the tensor-parallel entry exactly
+    where c divides the HEADS (a shard then holds heads / c whole
+    heads, contiguous) — never where it merely divides heads x
+    head_dim — and the per-device bytes of the plan follow."""
+    from flexflow_tpu.analysis.kv_memory import (kv_cache_layout,
+                                                 kv_page_plan)
+    x = Tensor((4, SEQ, heads * 16), "float32")
+    op = MultiHeadAttention("attn", x, x, x, heads * 16, heads,
+                            causal=True)
+    mesh = {"n": 1, "c": c}
+    ent = kv_cache_layout([op], mesh, 4, SEQ)["attn"]
+    pages = 4 * (SEQ // 16)
+    assert ent["shapes"] == {"k": (pages, 16, heads * 16),
+                             "v": (pages, 16, heads * 16)}
+    assert ent["entries"]["k"] == ent["entries"]["v"] \
+        == (None, None, entry)
+    whole = kv_page_plan([op], {"n": 1}, 4, SEQ)["total_bytes"]
+    assert whole == 2 * pages * 16 * heads * 16 * 2
+    assert kv_page_plan([op], mesh, 4, SEQ)["total_bytes"] \
+        == whole / (c if entry else 1)
 
 
 def test_gen_stats_carry_pool_fields(lm, prompts):
@@ -1174,6 +1228,167 @@ def test_spec_config_validation(lm, draft_lm):
         SamplingParams(temperature=-1.0)
     with pytest.raises(ValueError, match="top_p"):
         SamplingParams(top_p=0.0)
+
+
+# ---------------------------------------------------------------------
+# the stored form of the pool: no serving program copies it (ISSUE 25)
+# ---------------------------------------------------------------------
+# a pool of 12 pages on 4 slots x 2 pages: the view a decode gathers
+# (8 pages) is not pool-sized, so only a copy of the POOL has a pool
+# leaf's element count
+_POOL_PAGES = 12
+_COMPILE_LIMIT_S = 240
+
+
+def _built_decoder(model, num_pages=_POOL_PAGES):
+    dec = GraphDecoder.for_model(model, 4, SEQ, num_pages=num_pages)
+    dec.decode_fn()
+    dec.prefill_fn(16)
+    dec.verify_fn(4)
+    dec.draft_fn(4)
+    return dec
+
+
+def _within(seconds, fn):
+    """``fn()`` on a thread of its own, failed (not waited for) past
+    its time limit: a compile that hangs must not hold the suite."""
+    import threading
+    box = {}
+
+    def run():
+        try:
+            box["out"] = fn()
+        except BaseException as e:  # noqa: BLE001 — re-raised below
+            box["err"] = e
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(seconds)
+    if t.is_alive():
+        pytest.fail(f"still compiling after {seconds} s")
+    if "err" in box:
+        raise box["err"]
+    return box["out"]
+
+
+def test_count_copies_counts_copies_that_pass_over_memory():
+    """The reading behind pool_copies(): by element count; a copy of an
+    unfused computation or the ROOT of a fusion one calls counts, a
+    copy nested inside another fusion (how a convolution reads its
+    operand) does not, a copy of another size does not."""
+    from flexflow_tpu.serving.generation.decoder import count_copies
+    text = """HloModule jit_decode
+
+%nested (p: bf16[8,16,128]) -> bf16[8,16,128] {
+  %p = bf16[8,16,128]{2,1,0} parameter(0)
+  ROOT %copy.1 = bf16[8,16,128]{0,2,1} copy(%p)
+}
+
+%conv (a: bf16[8,16,128]) -> f32[8,16] {
+  %a = bf16[8,16,128]{2,1,0} parameter(0)
+  %fusion.9 = bf16[8,16,128]{0,2,1} fusion(%a), kind=kLoop, calls=%nested
+  ROOT %r = f32[8,16]{1,0} convolution(%fusion.9, %fusion.9)
+}
+
+%transposer (q: bf16[8,16,128]) -> bf16[8,16,2,64] {
+  %q = bf16[8,16,128]{2,1,0} parameter(0)
+  %copy.5 = bf16[8,16,128]{2,1,0} copy(%q)
+  ROOT %copy.2 = bf16[8,16,2,64]{3,1,2,0:T(8,128)(2,1)} copy(%copy.5)
+}
+
+ENTRY %main (k: bf16[8,16,128], w: f32[128,96]) -> f32[8,16] {
+  %k = bf16[8,16,128]{2,1,0} parameter(0)
+  %w = f32[128,96]{1,0} parameter(1)
+  %copy.3 = bf16[8,16,128]{1,2,0} copy(%k), metadata={op_name="x"}
+  %copy.4 = f32[128,96]{0,1} copy(%w)
+  %copy-start.1 = (bf16[8,16,128]{2,1,0}, bf16[8,16,128]{2,1,0}, u32[]) copy-start(%k)
+  %fusion.1 = bf16[8,16,2,64]{3,1,2,0} fusion(%k), kind=kLoop, calls=%transposer
+  ROOT %fusion.2 = f32[8,16]{1,0} fusion(%copy.3), kind=kOutput, calls=%conv
+}
+"""
+    # copy.3, copy-start.1 and the ROOT of %transposer; not copy.1
+    # (nested), copy.5 (inside a fusion, not its root), copy.4 (a weight)
+    assert count_copies(text, [8 * 16 * 128]) \
+        == {"count": 3, "bytes": 3 * 8 * 16 * 128 * 2}
+    assert count_copies(text, [128 * 96]) == {"count": 1,
+                                              "bytes": 128 * 96 * 4}
+    assert count_copies(text, [7]) == {"count": 0, "bytes": 0}
+
+
+def test_no_serving_program_copies_the_pool_cpu(lm_lane_dense):
+    """decode, a prefill bucket, verify and the draft scan, compiled
+    for the CPU: no copy the size of a pool leaf — the scatter updates
+    the donated pool in place and the gather reads it where it lies."""
+    dec = _built_decoder(lm_lane_dense)
+    leaf = dec.layout["attention_0"]["shapes"]["k"]
+    assert leaf == (_POOL_PAGES, 16, 128)
+    got = _within(_COMPILE_LIMIT_S, dec.pool_copies)
+    assert set(got) == {"jit_decode", "jit_prefill.16", "jit_verify.4",
+                        "jit_draft.4"}
+    assert all(v == {"count": 0, "bytes": 0} for v in got.values()), got
+
+
+@pytest.fixture(scope="module")
+def v5e_device():
+    """One chip of a v5e that is only DESCRIBED: the TPU's compiler is
+    installed here and compiles for it with no chip attached.  Made
+    inside a fixture, never while a module is imported (one process at
+    a time may load the TPU's library)."""
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps it away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topo.devices[0]
+
+
+def test_no_serving_program_copies_the_pool_tpu(v5e_device):
+    """The same four programs in bf16, as served, compiled by the TPU's
+    compiler, where the (pages, page, heads, head_dim) form read 6
+    pool-sized copies a layer in each (8 in a decode whose gathered
+    view is pool-sized too; 2 x 64 minor fills no (8, 128) tile, so
+    scatter, gather and einsum each wanted the pool another way): the
+    folded form reads none.  The pool is sized like a deployment's
+    (8192 pages, 33.5 MB a leaf: only lowered here, never allocated) —
+    one of a few pages the compiler moves whole into fast memory and
+    back, which is a copy, and counted."""
+    from jax.experimental.compilation_cache import compilation_cache
+    dec = _built_decoder(
+        _build_lm(slots=4, num_layers=1, d_model=128, d_ff=192,
+                  compute_dtype="bfloat16"), num_pages=8192)
+    # an executable compiled for a described chip can be written to
+    # the persistent cache but not read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        got = _within(_COMPILE_LIMIT_S,
+                      lambda: dec.pool_copies(device=v5e_device))
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert set(got) == {"jit_decode", "jit_prefill.16", "jit_verify.4",
+                        "jit_draft.4"}
+    assert all(v == {"count": 0, "bytes": 0} for v in got.values()), got
+
+
+def test_engine_stats_carry_pool_copies_once_asked(lm, draft_lm, prompts):
+    """pool_copies is ON DEMAND: absent from stats() until asked, then
+    the target's programs by name and the draft's under draft/."""
+    eng = GenerationEngine(lm, slots=2, max_new_tokens=3,
+                           draft_model=draft_lm, spec_gamma=2,
+                           num_pages=6)
+    with eng:
+        eng.submit(prompts[0]).result(timeout=120)
+        assert "pool_copies" not in eng.stats()
+        got = eng.pool_copies()
+        assert eng.stats()["pool_copies"] == got
+    assert {"jit_decode", "jit_verify.2", "draft/jit_draft.2"} <= set(got)
+    assert {f"jit_prefill.{b}" for b in eng._decoder.buckets} <= set(got)
+    assert all(v["count"] == 0 for v in got.values()), got
 
 
 # slow: the sweep runs 4 arms x {greedy, sampled} x 2 (warm + measure)
