@@ -252,13 +252,16 @@ def leg_train_bert(sz, on_tpu, stats):
                      for op in model.layers)
         calls = _flash_operand_batches(_step_text(model, first))
         # jit shares one lowered function among the equal-shaped layers:
-        # three sites = the kernel's forward, dK/dV and dQ passes
-        assert len(calls) >= 3 and set(calls) == {batch}, (
-            f"expected the Mosaic flash kernel (fwd, dkv, dq) in the "
+        # two sites = the owned kernel's forward and its fused backward
+        # (ops/flash_kernel.py; at s = 512 the keys fit one block)
+        assert len(calls) >= 2 and set(calls) == {batch}, (
+            f"expected the Mosaic flash kernel (fwd, fused bwd) in the "
             f"lowered step, found tpu_custom_call batches {calls}")
+        assert model.attention_kernels()["owned"] == n_attn, \
+            model.attention_kernels()
         print(f"bert: lowered train step holds {len(calls)} "
-              f"tpu_custom_call (Mosaic) sites — flash fwd, dK/dV, dQ — "
-              f"shared by {n_attn} attention ops")
+              f"tpu_custom_call (Mosaic) sites — flash fwd, fused bwd — "
+              f"shared by {n_attn} attention ops, all on the owned kernel")
 
     # steady state + memorisation: 8 more steps on ONE repeated batch
     times, rep = [], []

@@ -1801,6 +1801,21 @@ class FFModel:
                 text, [op.name for op in self.layers] + list(STEP_OWNERS))
         return self._step_op_tables[key]
 
+    def attention_kernels(self, training: bool = True) -> Dict[str, int]:
+        """How many attention ops lowered to which core the last time
+        the training step (``training=False``: a forward-only program)
+        was traced: ``{"owned", "library", "dense", "ring"}`` — the
+        repo's Pallas flash kernel, jax's library kernel behind layout
+        transposes, the einsum chain, ring attention
+        (``MultiHeadAttention._attend`` notes its choice at trace time).
+        All zeros before the first step is traced."""
+        tally = dict.fromkeys(("owned", "library", "dense", "ring"), 0)
+        for op in self.layers:
+            core = getattr(op, "kernel_cores", {}).get(training)
+            if core is not None:
+                tally[core] += 1
+        return tally
+
     def _check_accum_divisible(self, n: int, what: str) -> None:
         """Every entry point that feeds the jitted step validates its
         batch here — the scan reshape inside would otherwise fail with

@@ -35,6 +35,11 @@ _INSTRUCTION = re.compile(
     r'metadata=\{[^}]*?op_name="(?P<path>[^";]*)', re.M)
 # a transform's frame round a scope: `jvp(x)`, `transpose(jvp(x))`
 _FRAME = re.compile(r"^\w+\((.*)\)$")
+# `%name = f32[32,12,512,128]{layout} broadcast(`: dtype, dims and opcode
+_RESULT = re.compile(
+    r'^\s*(?:ROOT\s+)?%(?P<name>[^\s=]+)\s*=\s*(?P<dtype>\w+)'
+    r'\[(?P<dims>[\d,]*)\]\S*\s+(?P<opcode>[\w-]+)\(.*?'
+    r'metadata=\{[^}]*?op_name="(?P<path>[^";]*)', re.M)
 
 
 def _owner(path: str, owners) -> Owner:
@@ -78,4 +83,27 @@ def attribute(ops: Sequence, table: Dict[str, Owner]) -> Dict[Owner, float]:
     for name, _, dur in ops:
         key = table.get(name, (None, None))
         out[key] = out.get(key, 0.0) + dur / 1e9
+    return out
+
+
+def attention_wrapper_ops(text: str, owners: Iterable[str]) -> list:
+    """The instructions of a compiled step's ``text`` that only prepare
+    operands for a flash-attention kernel, under one of the scopes
+    ``owners`` (the attention ops' names): a ``broadcast`` to a rank-4 f32
+    array at least 128 wide (the library kernel's backward widens its row
+    statistics ``l``, ``m``, ``di`` to ``(n, h, s, 128)`` and ``di`` to
+    ``(n, h, s, s)`` in HBM) and a ``copy`` of a rank-4 array (the
+    ``(n,s,h,d) <-> (n,h,s,d)`` layout changes round that kernel).  The
+    repo's own kernel (``ops/flash_kernel.py``) needs neither, so a step
+    that runs it reads ``[]``; names as the profiler prints them."""
+    owners = frozenset(owners)
+    out = []
+    for m in _RESULT.finditer(text):
+        dims = [int(d) for d in m.group("dims").split(",") if d]
+        if len(dims) != 4 or _owner(m.group("path"), owners)[0] is None:
+            continue
+        if m.group("opcode") == "copy" or (
+                m.group("opcode") == "broadcast"
+                and m.group("dtype") == "f32" and dims[-1] >= 128):
+            out.append(m.group("name"))
     return out

@@ -36,19 +36,23 @@ NEG_INF = -1e30  # finite mask value: keeps online-softmax exp() NaN-free
 
 def _use_flash(q, k, ctx_flag, training_dropout: bool,
                training: bool = True) -> bool:
-    """Kernel selection.  ``ctx_flag`` None = auto: flash at s >= 512
+    """Flash or dense.  ``ctx_flag`` None = auto: flash at s >= 512
     when training, s >= 1024 forward-only.  Two measured v5e crossovers
-    feed the split threshold (BASELINE.md "Flash attention"):
-    forward-only, dense wins at s=512 (1.17x) and flash at s >= 1024
-    (2.7-2.8x) — so inference keeps 1024.  The round-5 TRAINING A/B
-    (bench.py --flash on|off, BERT-base s=512) flipped the s=512
-    verdict for the full step: the dense path's O(s^2) f32 score matrix
-    in backward costs more than flash's forward handicap (107.25 ms vs
-    109.09 ms per step, 43.9% vs 43.2% MFU), so training uses 512.
-    Flash is the only option at s >= 8192 where the dense score matrix
-    exceeds HBM.  The kernel requires TPU, 128-aligned seq lens,
-    lane-block head_dim, and no attention-prob dropout (it never
-    materializes probabilities)."""
+    feed the split threshold (BASELINE.md "Flash attention"), BOTH taken
+    with jax's library kernel behind its layout transposes, before the
+    repo had a kernel of its own (ROADMAP S5 re-derives them against
+    :mod:`flash_kernel`): forward-only, dense wins at s=512 (1.17x) and
+    flash at s >= 1024 (2.7-2.8x) — so inference keeps 1024.  The
+    round-5 TRAINING A/B (bench.py --flash on|off, BERT-base s=512)
+    flipped the s=512 verdict for the full step: the dense path's
+    O(s^2) f32 score matrix in backward costs more than the library
+    kernel's forward handicap (107.25 ms vs 109.09 ms per step, 43.9% vs
+    43.2% MFU), so training uses 512 (with the owned kernel the same A/B
+    reads 65.51 ms vs 108.56 ms — BASELINE.md).  Flash is the only option at
+    s >= 8192 where the dense score matrix exceeds HBM.  Either kernel
+    requires TPU, 128-aligned seq lens, lane-block head_dim, and no
+    attention-prob dropout (it never materializes probabilities);
+    :func:`_flash_core` then says WHICH kernel."""
     if training_dropout or jax.default_backend() != "tpu":
         return False
     sq, sk, d = q.shape[1], k.shape[1], q.shape[3]
@@ -61,9 +65,11 @@ def _use_flash(q, k, ctx_flag, training_dropout: bool,
 
 
 def _tuned_block_sizes(sq: int, sk: int):
-    """v5e-tuned kernel blocks (scripts/tune_flash_attention.py): q 512 /
-    kv 1024 is within 4% of best at every measured s >= 1024.  Falls back
-    to kernel defaults when the tuned blocks don't divide the seq lens."""
+    """Blocks of jax's LIBRARY kernel only (the owned kernel chooses its
+    own, in :mod:`flash_kernel`), v5e-tuned
+    (scripts/tune_flash_attention.py): q 512 / kv 1024 is within 4% of
+    best at every measured s >= 1024.  Falls back to kernel defaults
+    when the tuned blocks don't divide the seq lens."""
     from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
 
     bq = 512 if sq % 512 == 0 else None
@@ -77,11 +83,44 @@ def _tuned_block_sizes(sq: int, sk: int):
         block_k_major_dq=bkv, block_k_dq=bkv, block_q_dq=bq)
 
 
+def _shard_axes(q, mesh):
+    """The mesh axes the batch and the heads of ``q`` (n, s, h, d) split
+    over when the flash kernel runs per shard (``None`` = the dim stays
+    whole: one chip, or an axis that does not divide it), and the heads a
+    shard then holds."""
+    if mesh is None or not mesh.is_distributed:
+        return None, None, q.shape[2]
+
+    def axes(axis, size):
+        return (mesh.subaxes(axis) or None
+                if size % mesh.axis_size(axis) == 0 else None)
+
+    c = axes("c", q.shape[2])
+    return (axes("n", q.shape[0]), c,
+            q.shape[2] // (mesh.axis_size("c") if c else 1))
+
+
+def _flash_core(q, k, mesh=None) -> str:
+    """Which flash kernel the operands get, from what a shard sees:
+    ``"owned"`` (:mod:`flash_kernel`: head size 64 with an even number of
+    local heads, or a lane multiple) or ``"library"`` (jax's kernel behind
+    layout transposes, e.g. three local heads when 12 split four ways)."""
+    from . import flash_kernel
+
+    heads = _shard_axes(q, mesh)[2]
+    return ("owned" if flash_kernel.supported(heads, q.shape[3], q.shape[1],
+                                              k.shape[1]) else "library")
+
+
 def _flash_attention(q, k, v, causal: bool, scale: float, mesh=None):
-    """Pallas TPU flash attention (jax.experimental.pallas.ops.tpu):
-    blockwise online softmax on-chip — the VMEM-resident fused kernel the
-    pallas_guide prescribes for the attention hot op.  Layout adapters:
-    ours is (n,s,h,d), the kernel wants (n,h,s,d).
+    """Pallas TPU flash attention: blockwise online softmax on-chip — the
+    VMEM-resident fused kernel the pallas_guide prescribes for the
+    attention hot op.  :func:`_flash_core` picks the kernel.  The OWNED
+    one reads ``(n, s, h * d)``, the projections' own layout: the fold
+    here cancels against ``_qkv``'s unfold (and ``_out_proj`` folds
+    first thing), so no layout op is left round it.  The LIBRARY one
+    (jax.experimental.pallas.ops.tpu) wants (n,h,s,d) and gets it through
+    four transposes.
 
     On a distributed ``mesh`` the kernel runs per shard under shard_map:
     GSPMD treats a pallas_call as an opaque custom call and would
@@ -89,30 +128,35 @@ def _flash_attention(q, k, v, causal: bool, scale: float, mesh=None):
     Attention is independent per sample and per head, so the batch
     shards over ``n`` and the heads over ``c`` halo-free (a dim the
     axis does not divide stays whole); other mesh axes see replicas."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import \
-        flash_attention as _fa
+    n_axes, c_axes, heads = _shard_axes(q, mesh)
+    if _flash_core(q, k, mesh) == "owned":
+        from .flash_kernel import flash_attention as _own
 
-    blocks = _tuned_block_sizes(q.shape[1], k.shape[1])
+        def kern(q, k, v):
+            return _own(q, k, v, heads, causal, scale)
 
-    def kern(q, k, v):
-        qt = jnp.transpose(q, (0, 2, 1, 3))
-        kt = jnp.transpose(k, (0, 2, 1, 3))
-        vt = jnp.transpose(v, (0, 2, 1, 3))
-        out = _fa(qt, kt, vt, causal=causal, sm_scale=scale,
-                  block_sizes=blocks)
-        return jnp.transpose(out, (0, 2, 1, 3))
+        args = [x.reshape(x.shape[:2] + (-1,)) for x in (q, k, v)]
+        spec = PartitionSpec(n_axes, None, c_axes)
+    else:
+        from jax.experimental.pallas.ops.tpu.flash_attention import \
+            flash_attention as _fa
 
-    if mesh is None or not mesh.is_distributed:
-        return kern(q, k, v)
+        blocks = _tuned_block_sizes(q.shape[1], k.shape[1])
 
-    def axes(axis, size):
-        return (mesh.subaxes(axis) or None
-                if size % mesh.axis_size(axis) == 0 else None)
+        def kern(q, k, v):
+            qt = jnp.transpose(q, (0, 2, 1, 3))
+            kt = jnp.transpose(k, (0, 2, 1, 3))
+            vt = jnp.transpose(v, (0, 2, 1, 3))
+            out = _fa(qt, kt, vt, causal=causal, sm_scale=scale,
+                      block_sizes=blocks)
+            return jnp.transpose(out, (0, 2, 1, 3))
 
-    spec = PartitionSpec(axes("n", q.shape[0]), None,
-                         axes("c", q.shape[2]), None)
-    return jax.shard_map(kern, mesh=mesh.mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+        args = [q, k, v]
+        spec = PartitionSpec(n_axes, None, c_axes, None)
+    if mesh is not None and mesh.is_distributed:
+        kern = jax.shard_map(kern, mesh=mesh.mesh, in_specs=(spec,) * 3,
+                             out_specs=spec, check_vma=False)
+    return kern(*args).reshape(q.shape)
 
 
 def _decode_attention(q, k_cache, v_cache, pos, scale: float):
@@ -327,6 +371,8 @@ class MultiHeadAttention(Op):
         self.head_dim = embed_dim // num_heads
         self.dropout, self.causal, self.use_bias = float(dropout), causal, use_bias
         self._self_attn = len(inputs) == 1
+        # {training: core} as last traced (see _attend)
+        self.kernel_cores = {}
         n, sq, dq = query.shape
         self._add_output((n, sq, embed_dim), query.dtype)
         init = kernel_initializer or GlorotUniform()
@@ -384,21 +430,36 @@ class MultiHeadAttention(Op):
         xv = xq if self._self_attn else cast_compute(inputs[2], ctx)
         n, sq, _ = xq.shape
         q, k, v = self._qkv(params, xq, xk, xv, ctx)
-        scale = 1.0 / math.sqrt(self.head_dim)
         rng = None
         if ctx.training and self.dropout > 0.0 and ctx.rng is not None:
             rng = jax.random.fold_in(ctx.rng, self.outputs[0].uid)
-        if self._wants_ring(ctx):
+        attn = self._attend(q, k, v, ctx, rng, ctx.training,
+                            self._wants_ring(ctx))
+        return [self._out_proj(params, attn, n, sq, ctx)]
+
+    def _attend(self, q, k, v, ctx: OpContext, rng=None,
+                training: bool = False, ring: bool = False):
+        """The full-sequence attention core, chosen from what the
+        operands look like, and noted at trace time in
+        ``self.kernel_cores[training]`` (``"ring"``, ``"owned"``,
+        ``"library"`` or ``"dense"``) for
+        :meth:`FFModel.attention_kernels`."""
+        scale = 1.0 / math.sqrt(self.head_dim)
+        dropout = self.dropout if training else 0.0
+        if ring:
+            core = "ring"
             attn = ring_attention(q, k, v, ctx.mesh, self.causal, scale,
-                                  self.dropout if ctx.training else 0.0, rng)
+                                  dropout, rng)
         elif _use_flash(q, k, ctx.flash_attention, rng is not None,
-                        training=ctx.training):
+                        training=training):
+            core = _flash_core(q, k, ctx.mesh)
             attn = _flash_attention(q, k, v, self.causal, scale, ctx.mesh)
         else:
-            attn = _dense_attention(q, k, v, self.causal, scale,
-                                    self.dropout if ctx.training else 0.0,
+            core = "dense"
+            attn = _dense_attention(q, k, v, self.causal, scale, dropout,
                                     rng)
-        return [self._out_proj(params, attn, n, sq, ctx)]
+        self.kernel_cores[training] = core
+        return attn
 
     # ---- autoregressive decode (docs/serving.md "Token generation") ----
     def kv_cache_shape(self, slots: int, max_seq: int):
@@ -420,11 +481,7 @@ class MultiHeadAttention(Op):
         xq = cast_compute(inputs[0], ctx)
         n, sq, _ = xq.shape
         q, k, v = self._qkv(params, xq, xq, xq, ctx)
-        scale = 1.0 / math.sqrt(self.head_dim)
-        if _use_flash(q, k, ctx.flash_attention, False, training=False):
-            attn = _flash_attention(q, k, v, self.causal, scale, ctx.mesh)
-        else:
-            attn = _dense_attention(q, k, v, self.causal, scale, 0.0, None)
+        attn = self._attend(q, k, v, ctx)
         return [self._out_proj(params, attn, n, sq, ctx)], k, v
 
     # ---- paged KV cache (docs/serving.md "Paged KV & prefix caching") --

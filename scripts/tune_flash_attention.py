@@ -1,9 +1,11 @@
 #!/usr/bin/env python
 """Flash-attention tune-or-retire study (VERDICT round-2 ask #9).
 
-Benchmarks the Pallas TPU flash kernel against XLA's fused dense attention
-across sequence lengths and kernel block sizes on the attached chip; the
-decision (ship which path at which lengths) is recorded in README.md.
+Benchmarks jax's library Pallas TPU flash kernel (forward, in the (n,h,s,d)
+layout it wants) against XLA's fused dense attention across sequence lengths
+and kernel block sizes on the attached chip, with the repo's own kernel
+(ops/flash_kernel.py, in the projections' (n,s,h*d) layout) as one more row;
+the decision (ship which path at which lengths) is recorded in README.md.
 
 Usage (chip must be free):  python scripts/tune_flash_attention.py
 """
@@ -96,6 +98,16 @@ def main():
             results.append(("flash_default", t_def))
         except Exception:
             pass
+        # the repo's own kernel (ops/flash_kernel.py) on the same values
+        # in ITS layout, (n, s, h * d); its blocks are chosen in its module
+        from flexflow_tpu.ops.flash_kernel import flash_attention as owned
+
+        def fold(x):
+            return jnp.transpose(x, (0, 2, 1, 3)).reshape(n, s, h * d)
+
+        results.append(("owned_kernel", bench(
+            lambda q, k, v: owned(q, k, v, h, False, scale),
+            fold(q), fold(k), fold(v))))
         best = min((t for _, t in results if np.isfinite(t)))
         print(f"s={s}:", flush=True)
         for name, t in sorted(results, key=lambda r: r[1]):

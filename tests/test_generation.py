@@ -13,6 +13,8 @@ batching, streaming, cancellation, admission reuse, KV-cache memory
 accounting and the FF_FAULT generation kinds.
 """
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -1249,6 +1251,21 @@ def _built_decoder(model, num_pages=_POOL_PAGES):
     return dec
 
 
+@contextlib.contextmanager
+def _no_compilation_cache():
+    """An executable compiled for a described chip can be written to the
+    persistent cache but not read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 def _within(seconds, fn):
     """``fn()`` on a thread of its own, failed (not waited for) past
     its time limit: a compile that hangs must not hold the suite."""
@@ -1355,24 +1372,74 @@ def test_no_serving_program_copies_the_pool_tpu(v5e_device):
     (8192 pages, 33.5 MB a leaf: only lowered here, never allocated) —
     one of a few pages the compiler moves whole into fast memory and
     back, which is a copy, and counted."""
-    from jax.experimental.compilation_cache import compilation_cache
     dec = _built_decoder(
         _build_lm(slots=4, num_layers=1, d_model=128, d_ff=192,
                   compute_dtype="bfloat16"), num_pages=8192)
-    # an executable compiled for a described chip can be written to
-    # the persistent cache but not read back without one
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _no_compilation_cache():
         got = _within(_COMPILE_LIMIT_S,
                       lambda: dec.pool_copies(device=v5e_device))
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
     assert set(got) == {"jit_decode", "jit_prefill.16", "jit_verify.4",
                         "jit_draft.4"}
     assert all(v == {"count": 0, "bytes": 0} for v in got.values()), got
+
+
+@pytest.mark.parametrize("kernel,clean", [("owned", True),
+                                          ("library", False)])
+def test_train_step_holds_no_flash_wrapper_tpu(v5e_device, monkeypatch,
+                                               kernel, clean):
+    """The TRAIN step of a two-layer encoder (head size 64, s = 512, as
+    the benchmark's train cell) compiled by the TPU's compiler: with the
+    repo's own flash kernel no instruction under an attention scope
+    widens a row statistic to (n, h, s, >= 128) f32 or copies a
+    (n, s, h, d) array into another layout; with jax's library kernel
+    (the same shape, the owned kernel refused) both are there, so the
+    reader reads.  Here and not in tests/test_transformer.py: every
+    described-chip compile of the suite lives in this one file, whose
+    worker alone loads the TPU's library."""
+    from flexflow_tpu.models.transformer import build_transformer
+    from flexflow_tpu.obs.device_ops import attention_wrapper_ops
+    from flexflow_tpu.ops import attention as attn_mod, flash_kernel
+    from jax.sharding import SingleDeviceSharding
+
+    cfg = ff.FFConfig.parse_args(["-b", "4", "-ll:tpu", "1"])
+    cfg.compute_dtype = "bfloat16"
+    model, _, logits = build_transformer(
+        cfg, num_layers=2, d_model=256, num_heads=4, d_ff=512, seq_len=512,
+        vocab_size=128, num_classes=2)
+    model.compile(ff.AdamOptimizer(alpha=1e-4),
+                  ff.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
+                  [ff.METRICS_ACCURACY], final_tensor=logits)
+    model.init_layers(seed=0)
+    # steer the code that asks for the backend, as the chip would answer
+    monkeypatch.setattr(attn_mod.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(flash_kernel, "_interpret", lambda: False)
+    if kernel == "library":
+        monkeypatch.setattr(flash_kernel, "supported", lambda *a: False)
+    chip = SingleDeviceSharding(v5e_device)
+
+    def described(tree):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=chip), tree)
+
+    batch = (jax.ShapeDtypeStruct((4, 512), jnp.int32, sharding=chip),
+             jax.ShapeDtypeStruct((4, 1), jnp.int32, sharding=chip))
+    def compiled_text():
+        # on this thread: the suite's float32 matmul default is nothing
+        # jax's kernel lowers
+        with jax.default_matmul_precision("default"):
+            return model._train_step.lower(
+                described(model._params), described(model._opt_state),
+                batch, model._step).compile().as_text()
+
+    with _no_compilation_cache():
+        text = _within(_COMPILE_LIMIT_S, compiled_text)
+    assert model.attention_kernels()[kernel] == 2
+    attention = [op.name for op in model.layers
+                 if isinstance(op, MultiHeadAttention)]
+    found = attention_wrapper_ops(text, attention)
+    assert (found == []) if clean else len(found) >= 8, found
+    assert ("flash_mha_bwd_fused" in text) == clean
+    assert "flash_attention_fwd" in text or not clean
 
 
 def test_engine_stats_carry_pool_copies_once_asked(lm, draft_lm, prompts):
