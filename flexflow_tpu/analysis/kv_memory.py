@@ -65,7 +65,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
-from ..op import Op, OpType
+from ..op import Op
 
 # the LSTM decode carry stays f32 across timesteps (ops/rnn.py keeps
 # cell state in f32 for stability) regardless of the compute dtype
@@ -132,47 +132,21 @@ def kv_cache_layout(layers: List[Op],
                     num_pages: int = 0) -> Dict[str, Dict]:
     """Per-op decode-state geometry: ``{op_name: {"kind":
     "kv"|"state", "shapes": {leaf: shape}, "entries": {leaf:
-    PartitionSpec entries}, "dtype": "compute"|"f32"}}``.  THE one
-    place the pool layout is decided — the generation decoder allocates
-    exactly this (through ``serving/generation/pages.py``, the only
-    module allowed to allocate it — repo_lint RL013), and
+    PartitionSpec entries}, "dtype": "compute"|"f32"}}``, each entry
+    what that op DECLARES (``Op.serve_state`` — a layer's serving form
+    lives in the layer; an op that keeps nothing has no entry).  The one
+    place the declarations are gathered — the generation decoder
+    allocates exactly this (through ``serving/generation/pages.py``, the
+    only module allowed to allocate it — repo_lint RL013), and
     :func:`kv_page_plan` integrates exactly this."""
     _check_page_args(page_size, num_pages)
     page_size = int(page_size) or DEFAULT_PAGE_SIZE
     pool = int(num_pages) or default_num_pages(slots, max_seq, page_size)
-    n_deg = slot_shard_degree(slots, mesh_sizes)
-    c = _axis(mesh_sizes, "c")
     out: Dict[str, Dict] = {}
     for op in layers:
-        if op.op_type == OpType.ATTENTION and hasattr(op, "num_heads"):
-            h, hd = op.num_heads, op.head_dim
-            c_entry = "c" if (c > 1 and h % c == 0) else None
-            # lane-dense rows: heads x head_dim folded into ONE minor
-            # dim, so no consumer wants the pool in another layout
-            # (module docstring)
-            shape = (pool, page_size, h * hd)
-            # pages replicated over 'n' (interchangeable across slots);
-            # the folded dim sharded over 'c' like the projections
-            # feeding it — h % c == 0 keeps heads whole per shard
-            entries = (None, None, c_entry)
-            out[op.name] = {
-                "kind": "kv",
-                "shapes": {"k": shape, "v": shape},
-                "entries": {"k": entries, "v": entries},
-                "dtype": "compute",
-            }
-        elif op.op_type == OpType.LSTM and hasattr(op, "hidden_size"):
-            hsz = op.hidden_size
-            c_entry = "c" if (c > 1 and hsz % c == 0) else None
-            n_entry = "n" if n_deg > 1 else None
-            shape = (int(slots), hsz)
-            entries = (n_entry, c_entry)
-            out[op.name] = {
-                "kind": "state",
-                "shapes": {"h": shape, "c": shape},
-                "entries": {"h": entries, "c": entries},
-                "dtype": "f32",
-            }
+        entry = op.serve_state(int(slots), pool, page_size, mesh_sizes)
+        if entry is not None:
+            out[op.name] = entry
     return out
 
 

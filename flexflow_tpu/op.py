@@ -137,10 +137,45 @@ class OpContext:
     embedding_rows: Optional[Dict[str, jax.Array]] = None
 
 
+@dataclasses.dataclass(frozen=True)
+class ServeStep:
+    """Where one serving step stands — what :meth:`Op.serve_step` is told
+    beside its inputs.  ``kind`` is one of
+
+    * ``"chunk"``: prompt positions ``start .. start+B-1`` of ONE slot
+      (inputs ``(1, B, ..)``, the first ``length`` rows real); ``table``
+      is that slot's page-table row ``(pages_per_slot,)``, and the rows'
+      write indices are computed in the program from it;
+    * ``"token"``: one position of EVERY slot (inputs ``(slots, 1, ..)``)
+      at ``pos`` (slots,);
+    * ``"window"``: ``W`` positions of every slot (inputs ``(slots, W,
+      ..)``) at ``pos[i] .. pos[i]+W-1``.
+
+    For the last two ``table`` is ``(slots, pages_per_slot)`` and
+    ``write_pages`` / ``write_rows`` (``(slots,)`` or ``(slots, W)``)
+    arrive from the host, the pool's ``no_page`` sentinel dropping the
+    write of a slot that is not decoding.  The arrays are the ones the
+    engine already computes; a field another kind uses is ``None``."""
+
+    kind: str
+    table: jax.Array
+    start: Optional[jax.Array] = None
+    length: Optional[jax.Array] = None
+    slot: Optional[jax.Array] = None
+    pos: Optional[jax.Array] = None
+    write_pages: Optional[jax.Array] = None
+    write_rows: Optional[jax.Array] = None
+
+
 class Op:
-    """Base operator.  Subclasses set ``op_type`` and implement ``forward``."""
+    """Base operator.  Subclasses set ``op_type`` and implement ``forward``;
+    a layer that keeps something between tokens also writes the serving
+    contract (``serve_state``, ``serve_check``, ``serve_step``)."""
 
     op_type: OpType = OpType.INPUT
+    # acts on each sequence position alone: ``forward`` on one position,
+    # a window or a prompt chunk IS the serving step (``serve_check``)
+    position_wise: bool = False
 
     def __init__(self, name: str, inputs: Sequence[Tensor]):
         self.name = name
@@ -171,6 +206,40 @@ class Op:
     def forward(self, params: Dict[str, jax.Array], inputs: List[jax.Array],
                 ctx: OpContext) -> List[jax.Array]:
         raise NotImplementedError
+
+    # --- serving (docs/serving.md "A layer that serves") ----------------
+    def serve_state(self, slots: int, num_pages: int, page_size: int,
+                    mesh_sizes: Optional[Dict[str, int]]
+                    ) -> Optional[Dict]:
+        """What this op keeps between tokens, or ``None`` (the default):
+        ``{"kind": "kv"|"state", "shapes": {leaf: shape}, "entries":
+        {leaf: PartitionSpec entries}, "dtype": "compute"|"f32"}``.
+        ``"kv"`` leaves are page-major, ``(num_pages, page_size, ..)``:
+        they page, share prefixes, roll back and migrate; a ``"state"``
+        leaf is a fixed per-slot array, and a graph holding one prefills
+        whole prompts only.  ``analysis/kv_memory.kv_cache_layout``
+        collects these; the engine allocates, the static gates charge and
+        ``serve_step`` receives exactly what is declared here."""
+        return None
+
+    def serve_check(self, max_seq: int) -> None:
+        """Raise ``ValueError`` naming this op if it cannot generate
+        streams of up to ``max_seq`` positions."""
+        if not self.position_wise and \
+                type(self).serve_step is Op.serve_step:
+            raise ValueError(
+                f"{self.name} ({self.op_type.value}) has no "
+                f"single-position decode path; generation supports "
+                f"causal attention, LSTM, embeddings and "
+                f"position-wise ops")
+
+    def serve_step(self, params: Dict[str, jax.Array],
+                   inputs: List[jax.Array], state, where: ServeStep,
+                   ctx: OpContext):
+        """One serving step: ``(outputs, state)`` with ``state`` the
+        declared leaves as updated (the programs donate them).  Default:
+        ``forward`` on whatever positions ``inputs`` hold."""
+        return self.forward(params, inputs, ctx), state
 
     # --- SOAP legality & cost model -------------------------------------
     def parallel_dims(self) -> Tuple[bool, ...]:

@@ -399,10 +399,10 @@ class MultiHeadAttention(Op):
 
     def _qkv(self, params, xq, xk, xv, ctx):
         """The q/k/v projections — ONE implementation shared by
-        forward, the prefill path (:meth:`forward_kv`) and the
-        single-token decode (:meth:`decode`), so the cached K/V a
-        decode step attends over carry exactly the bits the
-        full-sequence forward would recompute."""
+        forward and every kind of serving step (:meth:`serve_step`: a
+        prompt chunk, a decode position, a verify window), so the
+        cached K/V a decode step attends over carry exactly the bits
+        the full-sequence forward would recompute."""
         n = xq.shape[0]
         h, hd = self.num_heads, self.head_dim
 
@@ -461,29 +461,6 @@ class MultiHeadAttention(Op):
         self.kernel_cores[training] = core
         return attn
 
-    # ---- autoregressive decode (docs/serving.md "Token generation") ----
-    def kv_cache_shape(self, slots: int, max_seq: int):
-        """Per-slot KV-cache geometry: k and v each
-        ``(slots, max_seq, num_heads, head_dim)`` — the head dim is the
-        tensor-parallel one (sharded over the ``c`` mesh axis, matching
-        the head-sharded projections that produce it)."""
-        return (int(slots), int(max_seq), self.num_heads, self.head_dim)
-
-    def forward_kv(self, params, inputs, ctx: OpContext):
-        """The prefill half of the decode path: the exact forward
-        computation, returning the per-position K/V ``(n, s, h, hd)``
-        alongside the output so the caller can seed a decode cache.
-        Self-attention + causal only (the autoregressive contract);
-        never the ring path — prefill runs on the serving mesh where
-        the sequence axis is unsplit."""
-        assert self._self_attn and self.causal, \
-            f"{self.name}: decode/prefill needs causal self-attention"
-        xq = cast_compute(inputs[0], ctx)
-        n, sq, _ = xq.shape
-        q, k, v = self._qkv(params, xq, xq, xq, ctx)
-        attn = self._attend(q, k, v, ctx)
-        return [self._out_proj(params, attn, n, sq, ctx)], k, v
-
     # ---- paged KV cache (docs/serving.md "Paged KV & prefix caching") --
     def _fold_rows(self, kv):
         """``(..., h, hd)`` K or V rows -> the pool's stored form
@@ -508,144 +485,101 @@ class MultiHeadAttention(Op):
         return rows.reshape(table.shape[0], -1, self.num_heads,
                             self.head_dim)
 
-    def forward_paged(self, params, x, k_pool, v_pool, table_row, start,
-                      length, ctx: OpContext):
-        """One prefill CHUNK against the paged KV cache: project the
-        chunk's Q/K/V, scatter its K/V rows into the slot's pages (the
-        page table as scatter indices), then attend each chunk query
-        over the whole gathered table — history pages written by
-        earlier chunks (or borrowed from the prefix cache) plus the
-        chunk itself, causally masked on GLOBAL positions.
+    def serve_state(self, slots, num_pages, page_size, mesh_sizes):
+        """A K and a V pool, ``(num_pages, page_size, heads * head_dim)``:
+        lane-dense rows (:meth:`_fold_rows`), so that no consumer wants
+        the pool in another layout.  Pages are replicated over ``n``
+        (interchangeable across slots); the folded dim is sharded over
+        ``c`` like the projections feeding it, where ``c`` divides the
+        heads (each shard then holds whole heads)."""
+        c = (mesh_sizes or {}).get("c", 1)
+        c_entry = "c" if (c > 1 and self.num_heads % c == 0) else None
+        shape = (num_pages, page_size, self.num_heads * self.head_dim)
+        entries = (None, None, c_entry)
+        return {"kind": "kv",
+                "shapes": {"k": shape, "v": shape},
+                "entries": {"k": entries, "v": entries},
+                "dtype": "compute"}
 
-        ``x``: (1, B, d) chunk hidden states at positions ``start ..
-        start+B-1``; ``k_pool``/``v_pool``: (num_pages, page, h * hd)
-        pools — the LANE-DENSE stored form (see :meth:`_fold_rows`);
-        ``table_row``: (pages_per_slot,) int32 page ids (the
-        pool's ``no_page`` sentinel marks unallocated entries — reads
-        of them are masked, writes to them dropped); ``length``: valid
-        rows in the chunk (pad rows' writes are dropped via the OOB
-        sentinel and their outputs are garbage the caller ignores).
-        Functional like :meth:`decode` — the jitted chunk program
-        donates the pools.  Shares :meth:`_qkv`/:meth:`_out_proj` with
-        forward, so chunked prefill == the monolithic forward row for
-        row (the ISSUE 15 parity anchor)."""
-        assert self._self_attn and self.causal, \
-            f"{self.name}: paged prefill needs causal self-attention"
-        xq = cast_compute(x, ctx)
-        n, B, _ = xq.shape
+    def serve_check(self, max_seq):
+        if not (self._self_attn and self.causal):
+            raise ValueError(
+                f"{self.name}: generation needs causal "
+                f"self-attention (cross-attention/bidirectional "
+                f"blocks cannot decode autoregressively)")
+
+    def serve_step(self, params, inputs, state, where, ctx: OpContext):
+        """One step against the paged KV cache, whatever its kind:
+        project the positions' Q/K/V, scatter their K/V rows into the
+        pages at ``(write page, write row)``, gather each slot's page
+        table back into position order and attend over THAT — history
+        written by earlier steps (or borrowed from the prefix cache) plus
+        the rows just written, causally masked on GLOBAL positions.
+
+        ``state``: ``{"k", "v"}``, the folded ``(num_pages, page, h *
+        hd)`` pools, updated in place under donation — no compiled
+        serving program copies them (``GraphDecoder.pool_copies``
+        counts).  Table entries and write pages at the pool's ``no_page``
+        sentinel are OOB by design: such reads are masked, such writes
+        dropped (pad rows of a chunk, slots that are not decoding — a
+        write through a stale entry could corrupt a SHARED prefix page).
+        The kinds differ in how the write indices arrive and in the core:
+
+        * ``"chunk"``: indices computed here from the slot's table row,
+          ``start`` and ``length``; :func:`_paged_chunk_attention`, so
+          chunked prefill == the monolithic forward row for row (the
+          ISSUE 15 parity anchor), pad rows' outputs being garbage the
+          caller ignores;
+        * ``"token"``: host-computed indices; :func:`_decode_attention`
+          fed the gathered cache, bit-identical on CPU to the dense
+          forward's row at ``pos``;
+        * ``"window"``: host-computed ``(slots, W)`` indices;
+          :func:`_verify_window_attention`, each window row bit-identical
+          on CPU to the sequential token step at that position (the
+          greedy-speculation parity pin).  Rejected rows need no cleanup:
+          they stay masked until a later round overwrites them.
+
+        Shares :meth:`_qkv`/:meth:`_out_proj` with forward."""
+        xq = cast_compute(inputs[0], ctx)
+        n, w, _ = xq.shape
         q, k, v = self._qkv(params, xq, xq, xq, ctx)
-        page = k_pool.shape[1]
-        no_page = k_pool.shape[0]
-        qpos = start + jnp.arange(B)
-        # mode="clip" everywhere: the sentinel id is OOB by design, and
-        # jnp.take's default "fill" mode would gather NaN — which the
-        # exact-zero mask multiplies to NaN, not zero
-        wp = jnp.take(table_row, qpos // page, mode="clip")
-        wp = jnp.where(jnp.arange(B) < length, wp, no_page)
-        wr = qpos % page
-        k_pool = k_pool.at[wp, wr].set(self._fold_rows(k[0]),
-                                       mode="drop")
-        v_pool = v_pool.at[wp, wr].set(self._fold_rows(v[0]),
-                                       mode="drop")
-        kg = self._gather_pages(k_pool, table_row[None])
-        vg = self._gather_pages(v_pool, table_row[None])
-        attn = _paged_chunk_attention(q, kg, vg, qpos,
-                                      1.0 / math.sqrt(self.head_dim))
-        return ([self._out_proj(params, attn, n, B, ctx)],
-                k_pool, v_pool)
+        k_pool, v_pool = state["k"], state["v"]
+        chunk, token = where.kind == "chunk", where.kind == "token"
+        if chunk:
+            page = k_pool.shape[1]
+            no_page = k_pool.shape[0]
+            qpos = where.start + jnp.arange(w)
+            # mode="clip" everywhere: the sentinel id is OOB by design, and
+            # jnp.take's default "fill" mode would gather NaN — which the
+            # exact-zero mask multiplies to NaN, not zero
+            wp = jnp.take(where.table, qpos // page, mode="clip")
+            wp = jnp.where(jnp.arange(w) < where.length, wp, no_page)
+            wr = qpos % page
+        else:
+            wp, wr = where.write_pages, where.write_rows
 
-    def decode_paged(self, params, x, k_pool, v_pool, table, pos,
-                     write_pages, write_rows, ctx: OpContext):
-        """One decode step against the paged KV cache: project the
-        current token per slot, scatter its K/V into
-        ``(write_pages[i], write_rows[i])`` (the engine computes these
-        host-side — ``no_page`` for inactive/prefilling slots, whose
-        writes must drop rather than corrupt a shared page), gather
-        each slot's page table back into position order and attend.
+        def rows(kv):
+            # the new rows as the write indices address them, folded
+            return self._fold_rows(kv[0] if chunk else
+                                   kv[:, 0] if token else kv)
 
-        ``x``: (slots, 1, d); ``k_pool``/``v_pool``: the folded
-        (num_pages, page, h * hd) pools (:meth:`_fold_rows`), updated
-        in place under donation — no compiled decode program copies
-        them (``GraphDecoder.pool_copies`` counts); ``table``: (slots,
-        pages_per_slot) int32;
-        ``pos``: (slots,) int32 current position.  The gathered view is
-        ``pages_per_slot * page`` wide; positions beyond ``pos`` are
-        masked to exact zeros, so the step is bit-identical on CPU to
-        the dense full-sequence forward's row at ``pos`` (the same
-        :func:`_decode_attention` kernel, fed a gathered cache)."""
-        n = x.shape[0]
-        xq = cast_compute(x, ctx)
-        q, k, v = self._qkv(params, xq, xq, xq, ctx)
-        k_pool = k_pool.at[write_pages, write_rows].set(
-            self._fold_rows(k[:, 0]), mode="drop")
-        v_pool = v_pool.at[write_pages, write_rows].set(
-            self._fold_rows(v[:, 0]), mode="drop")
-        kg = self._gather_pages(k_pool, table)
-        vg = self._gather_pages(v_pool, table)
-        attn = _decode_attention(q, kg, vg, pos,
-                                 1.0 / math.sqrt(self.head_dim))
-        return ([self._out_proj(params, attn, n, 1, ctx)],
-                k_pool, v_pool)
+        def view(pool):
+            return self._gather_pages(
+                pool, where.table[None] if chunk else where.table)
 
-    def verify_paged(self, params, x, k_pool, v_pool, table, pos,
-                     write_pages, write_rows, ctx: OpContext):
-        """Speculative-verify step against the paged KV cache: project
-        a W-token window per slot, scatter its K/V rows into each
-        slot's pages at ``(write_pages[i, t], write_rows[i, t])``
-        (host-computed; the pool's ``no_page`` sentinel drops inactive
-        slots' writes), gather each slot's page table and attend every
-        window row over it, causally masked on GLOBAL positions.
-
-        ``x``: (slots, W, d) hidden states at positions ``pos[i] ..
-        pos[i]+W-1``; ``k_pool``/``v_pool``: the folded (num_pages,
-        page, h * hd) pools (:meth:`_fold_rows`); ``table``: (slots,
-        pages_per_slot) int32;
-        ``pos``: (slots,) int32 first window position.  The chunked-
-        prefill generalization of :meth:`decode_paged` — same
-        :meth:`_qkv`/:meth:`_out_proj`, same gather, with
-        :func:`_verify_window_attention` (a slot-batched
-        :func:`_paged_chunk_attention`) as the kernel, so each window
-        row is bit-identical on CPU to the sequential decode step at
-        that position (the greedy-speculation parity pin).  Rejected
-        rows need no cleanup: they stay masked until a later round
-        overwrites them."""
-        n, w, _ = x.shape
-        xq = cast_compute(x, ctx)
-        q, k, v = self._qkv(params, xq, xq, xq, ctx)
-        k_pool = k_pool.at[write_pages, write_rows].set(
-            self._fold_rows(k), mode="drop")
-        v_pool = v_pool.at[write_pages, write_rows].set(
-            self._fold_rows(v), mode="drop")
-        kg = self._gather_pages(k_pool, table)
-        vg = self._gather_pages(v_pool, table)
-        qpos = pos[:, None] + jnp.arange(w)[None, :]
-        attn = _verify_window_attention(q, kg, vg, qpos,
-                                        1.0 / math.sqrt(self.head_dim))
+        k_pool = k_pool.at[wp, wr].set(rows(k), mode="drop")
+        v_pool = v_pool.at[wp, wr].set(rows(v), mode="drop")
+        kg, vg = view(k_pool), view(v_pool)
+        scale = 1.0 / math.sqrt(self.head_dim)
+        if chunk:
+            attn = _paged_chunk_attention(q, kg, vg, qpos, scale)
+        elif token:
+            attn = _decode_attention(q, kg, vg, where.pos, scale)
+        else:
+            qpos = where.pos[:, None] + jnp.arange(w)[None, :]
+            attn = _verify_window_attention(q, kg, vg, qpos, scale)
         return ([self._out_proj(params, attn, n, w, ctx)],
-                k_pool, v_pool)
-
-    def decode(self, params, x, k_cache, v_cache, pos, ctx: OpContext):
-        """One decode step: project the current token, write its K/V
-        into the per-slot cache at ``pos``, attend over the cache.
-
-        ``x``: (slots, 1, d) hidden states; ``k_cache``/``v_cache``:
-        (slots, max_seq, h, hd); ``pos``: (slots,) int32 position of
-        the current token.  Returns ``([out], k_cache, v_cache)`` with
-        the updated caches — functional, so the jitted decode step can
-        donate the cache buffers and update them in place."""
-        n = x.shape[0]
-        xq = cast_compute(x, ctx)
-        q, k, v = self._qkv(params, xq, xq, xq, ctx)
-
-        def write(cache, upd, p):
-            return jax.lax.dynamic_update_slice(cache, upd, (p, 0, 0))
-
-        k_cache = jax.vmap(write)(k_cache, k, pos)
-        v_cache = jax.vmap(write)(v_cache, v, pos)
-        attn = _decode_attention(q, k_cache, v_cache, pos,
-                                 1.0 / math.sqrt(self.head_dim))
-        return ([self._out_proj(params, attn, n, 1, ctx)],
-                k_cache, v_cache)
+                {"k": k_pool, "v": v_pool})
 
     def parallel_dims(self):
         # (n, s, c): sample DP, sequence SP (ring), channel TP (heads)
@@ -723,34 +657,32 @@ class PositionEmbedding(Op):
         table = params[self.w_table.name][: x.shape[1]]
         return [x + cast_compute(table, ctx)[None]]
 
-    def decode(self, params, x, pos, ctx: OpContext):
-        """Single-position lookup for the decode path: ``x`` (slots, 1,
-        d) plus the table row at each slot's current position ``pos``
-        (slots,) — elementwise identical to forward's broadcast add at
-        that position."""
-        rows = jnp.take(params[self.w_table.name], pos, axis=0)
-        return [x + cast_compute(rows, ctx)[:, None, :]]
+    def serve_check(self, max_seq):
+        if self.max_len < max_seq:
+            raise ValueError(
+                f"{self.name}: position table holds {self.max_len} "
+                f"positions < max_seq {max_seq}")
 
-    def decode_window(self, params, x, pos, ctx: OpContext):
-        """W-position lookup for the speculative-verify path: ``x``
-        (slots, W, d) holds each slot's window at GLOBAL positions
-        ``pos[i] .. pos[i]+W-1`` — gathers those table rows per slot.
-        Row for row the same values :meth:`decode` adds one position at
-        a time."""
-        qpos = pos[:, None] + jnp.arange(x.shape[1])[None, :]
-        rows = jnp.take(params[self.w_table.name], qpos, axis=0)
-        return [x + cast_compute(rows, ctx)]
-
-    def forward_at(self, params, x, start, ctx: OpContext):
-        """Offset lookup for chunked prefill: ``x`` (1, B, d) holds
-        GLOBAL positions ``start .. start+B-1`` — gathers those table
-        rows (pad rows past the table clip; their outputs are chunk
-        padding the caller ignores).  Row for row the same values
-        ``forward``'s leading-slice broadcast adds, so a chunk at
-        offset 0 covering the whole prompt IS the forward."""
-        pos = start + jnp.arange(x.shape[1])
-        rows = jnp.take(params[self.w_table.name], pos, axis=0)
-        return [x + cast_compute(rows, ctx)[None]]
+    def serve_step(self, params, inputs, state, where, ctx: OpContext):
+        """The table rows at the step's GLOBAL positions, gathered, where
+        ``forward`` slices the table's head: a prompt chunk at ``start ..
+        start+B-1`` (pad rows past the table clip; their outputs are
+        chunk padding the caller ignores), one position ``pos`` per slot,
+        or a window ``pos[i] .. pos[i]+W-1`` per slot.  Row for row the
+        values ``forward``'s broadcast adds, so a chunk at offset 0
+        covering the whole prompt IS the forward."""
+        x = inputs[0]
+        table = params[self.w_table.name]
+        if where.kind == "chunk":
+            pos = where.start + jnp.arange(x.shape[1])
+            rows = cast_compute(jnp.take(table, pos, axis=0), ctx)[None]
+        elif where.kind == "token":
+            rows = cast_compute(jnp.take(table, where.pos, axis=0),
+                                ctx)[:, None, :]
+        else:
+            qpos = where.pos[:, None] + jnp.arange(x.shape[1])[None, :]
+            rows = cast_compute(jnp.take(table, qpos, axis=0), ctx)
+        return [x + rows], state
 
     def parallel_dims(self):
         return (True, True, False)

@@ -46,6 +46,7 @@ _BINARY = {
 
 class ElementUnary(Op):
     op_type = OpType.ELEMENT_UNARY
+    position_wise = True
 
     def __init__(self, name, input_tensor, fn: str, scalar=None):
         super().__init__(name, [input_tensor])
@@ -77,6 +78,7 @@ class ElementUnary(Op):
 
 class ElementBinary(Op):
     op_type = OpType.ELEMENT_BINARY
+    position_wise = True
 
     def __init__(self, name, in1, in2, fn: str):
         super().__init__(name, [in1, in2])

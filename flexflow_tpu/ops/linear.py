@@ -60,6 +60,7 @@ def _host_gather(table, idx, mesh):
 
 class Linear(Op):
     op_type = OpType.LINEAR
+    position_wise = True
 
     def __init__(self, name, input_tensor, out_dim, activation=None,
                  use_bias=True, kernel_initializer=None, bias_initializer=None):
@@ -127,6 +128,7 @@ class Linear(Op):
 
 class Embedding(Op):
     op_type = OpType.EMBEDDING
+    position_wise = True
 
     def __init__(self, name, input_tensor, num_entries, out_dim,
                  aggr="sum", kernel_initializer=None):
@@ -143,6 +145,13 @@ class Embedding(Op):
         self.w_table = self._add_weight(
             (num_entries, out_dim), kernel_initializer or GlorotUniform(),
             "table", sharded_dim=1)
+
+    def serve_check(self, max_seq):
+        if self.aggr != "none":
+            raise ValueError(
+                f"{self.name}: only sequence-mode (aggr='none') "
+                f"embeddings decode; bag aggregation collapses "
+                f"the sequence dim")
 
     def forward(self, params, inputs, ctx: OpContext):
         idx = inputs[0].astype(jnp.int32)

@@ -73,6 +73,7 @@ class BatchNorm(Op):
 
 class LayerNorm(Op):
     op_type = OpType.LAYERNORM
+    position_wise = True
 
     def __init__(self, name, input_tensor, eps=1e-5, use_scale=True,
                  use_bias=True):
@@ -123,6 +124,7 @@ class LayerNorm(Op):
 
 class RMSNorm(Op):
     op_type = OpType.RMSNORM
+    position_wise = True
 
     def __init__(self, name, input_tensor, eps=1e-6):
         super().__init__(name, [input_tensor])

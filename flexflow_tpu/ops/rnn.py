@@ -61,8 +61,8 @@ class LSTM(Op):
 
     def _weights(self, params, ctx):
         """The (wx, wh_t, bias) triple in the dtypes every execution
-        path shares — forward, the prefill (:meth:`forward_states`) and
-        the one-timestep decode (:meth:`decode`) must run the SAME gate
+        path shares — forward and both kinds of :meth:`serve_step` (a
+        whole prompt, one timestep) must run the SAME gate
         arithmetic or the decode parity contract breaks."""
         wx = cast_compute(params[self.w_x.name], ctx)
         # recurrent weights in the compute dtype: the per-step h @ Wh matmul
@@ -113,65 +113,90 @@ class LSTM(Op):
         seq = cast_compute(jnp.transpose(hs, (1, 0, 2)), ctx)
         return [seq, cast_compute(h_n, ctx), cast_compute(c_n, ctx)]
 
-    # ---- autoregressive decode (docs/serving.md "Token generation") ----
-    def forward_states(self, params, inputs, ctx: OpContext):
-        """The prefill half of the decode path: forward() that also
-        returns the PER-STEP f32 (h, c) state sequences, each
-        (n, s, H) — the caller gathers the state at each slot's prompt
-        boundary to seed :meth:`decode`.  Same :meth:`_cell` math as
-        forward, so the seeded decode continues the exact trajectory."""
+    # ---- serving (docs/serving.md "Token generation") -------------------
+    def serve_state(self, slots, num_pages, page_size, mesh_sizes):
+        """The f32 ``(h, c)`` carry, ``(slots, hidden)`` each — the state
+        IS the cache, and positional carry cannot page.  Slots shard
+        over ``n`` by the decode batch's rule, the hidden dim over ``c``
+        where it divides."""
+        from ..analysis.kv_memory import slot_shard_degree
+
+        c = (mesh_sizes or {}).get("c", 1)
+        entries = ("n" if slot_shard_degree(slots, mesh_sizes) > 1 else None,
+                   "c" if (c > 1 and self.hidden_size % c == 0) else None)
+        shape = (int(slots), self.hidden_size)
+        return {"kind": "state",
+                "shapes": {"h": shape, "c": shape},
+                "entries": {"h": entries, "c": entries},
+                "dtype": "f32"}
+
+    def serve_check(self, max_seq):
+        if self._has_state:
+            raise ValueError(
+                f"{self.name}: LSTM with an external initial_state "
+                f"is not decodable (seed states are a prefill "
+                f"product, not a graph input)")
+
+    def serve_step(self, params, inputs, state, where, ctx: OpContext):
+        """A whole prompt or one timestep of every slot, on the carried
+        f32 ``{"h", "c"}`` of ``(slots, H)``; the same :meth:`_cell` math
+        as forward either way, so decode continues the exact trajectory.
+
+        ``"chunk"``: forward() from the zero state over the slot's WHOLE
+        prompt (a chunk at an offset would need the carry of the one
+        before it as a program input: a graph with a ``"state"`` leaf
+        prefills whole prompts only, and ``start`` is 0), keeping the
+        per-step states, of which the one at ``length - 1`` is written
+        to row ``slot`` of the carry.
+
+        ``"token"``: one timestep from the carry, ``inputs[0]`` (slots,
+        1, d).  The cell runs inside a LENGTH-2 ``lax.scan`` whose
+        second step consumes zeros and is discarded.  Not decoration:
+        XLA unrolls a trip-count-1 loop and re-fuses the cell's sigmoid
+        chain with different vectorization than the full forward's
+        while-loop body (measured ~1 ulp drift on CPU — ``sigmoid(a) +
+        sigmoid(b)`` in one fusion is compilation-context-dependent),
+        while a trip count >= 2 keeps the loop and compiles the
+        IDENTICAL body, so decode matches the full-sequence forward
+        bit-for-bit (tests/test_generation.py pins it).  The wasted
+        second cell is noise next to the decode step's projections."""
+        if where.kind == "window":
+            raise ValueError(f"{self.name}: a recurrent carry cannot "
+                             f"roll back to an accept point")
         x = cast_compute(inputs[0], ctx)
-        n = x.shape[0]
         wx, wh_t, b = self._weights(params, ctx)
         xg = jnp.einsum("nsd,gd->nsg", x, wx,
                         preferred_element_type=jnp.float32)
-        h0, c0 = self._initial_carry(inputs, n)
 
         def step(carry, xg_t):
             h, c = self._cell(xg_t, carry[0], carry[1], wh_t, b)
             return (h, c), (h, c)
 
-        (h_n, c_n), (hs, cs) = jax.lax.scan(step, (h0, c0),
-                                            jnp.transpose(xg, (1, 0, 2)))
-        seq = cast_compute(jnp.transpose(hs, (1, 0, 2)), ctx)
-        outs = [seq, cast_compute(h_n, ctx), cast_compute(c_n, ctx)]
-        return (outs, jnp.transpose(hs, (1, 0, 2)),
-                jnp.transpose(cs, (1, 0, 2)))
-
-    def decode(self, params, x, h, c, ctx: OpContext):
-        """One-timestep decode from the carried f32 state: ``x``
-        (slots, 1, d) current-token input, ``h``/``c`` (slots, H).
-        Returns ``([seq, h_n, c_n], h, c)`` with the new f32 carry —
-        the RNN analogue of attention's KV-cache decode (the state IS
-        the cache).
-
-        The cell runs inside a LENGTH-2 ``lax.scan`` whose second step
-        consumes zeros and is discarded.  Not decoration: XLA unrolls a
-        trip-count-1 loop and re-fuses the cell's sigmoid chain with
-        different vectorization than the full forward's while-loop body
-        (measured ~1 ulp drift on CPU — ``sigmoid(a) + sigmoid(b)`` in
-        one fusion is compilation-context-dependent), while a trip
-        count >= 2 keeps the loop and compiles the IDENTICAL body, so
-        decode matches the full-sequence forward bit-for-bit
-        (tests/test_generation.py pins it).  The wasted second cell is
-        noise next to the decode step's projections."""
-        x = cast_compute(x, ctx)
-        wx, wh_t, b = self._weights(params, ctx)
-        xg = jnp.einsum("nsd,gd->nsg", x, wx,
-                        preferred_element_type=jnp.float32)   # (n,1,4H)
+        if where.kind == "chunk":
+            h0, c0 = self._initial_carry(inputs, x.shape[0])
+            (h_n, c_n), (hs, cs) = jax.lax.scan(
+                step, (h0, c0), jnp.transpose(xg, (1, 0, 2)))
+            seq = cast_compute(jnp.transpose(hs, (1, 0, 2)), ctx)
+            outs = [seq, cast_compute(h_n, ctx), cast_compute(c_n, ctx)]
+            hs, cs = (jnp.transpose(hs, (1, 0, 2)),
+                      jnp.transpose(cs, (1, 0, 2)))           # (1,s,H)
+            h_sel = jax.lax.dynamic_index_in_dim(
+                hs, where.length - 1, axis=1, keepdims=False)
+            c_sel = jax.lax.dynamic_index_in_dim(
+                cs, where.length - 1, axis=1, keepdims=False)
+            return outs, {
+                "h": jax.lax.dynamic_update_slice(
+                    state["h"], h_sel, (where.slot, 0)),
+                "c": jax.lax.dynamic_update_slice(
+                    state["c"], c_sel, (where.slot, 0))}
         xg2 = jnp.concatenate([jnp.transpose(xg, (1, 0, 2)),
                                jnp.zeros_like(
                                    jnp.transpose(xg, (1, 0, 2)))], 0)
-
-        def step(carry, xg_t):
-            h2, c2 = self._cell(xg_t, carry[0], carry[1], wh_t, b)
-            return (h2, c2), (h2, c2)
-
-        _, (hs, cs) = jax.lax.scan(step, (h, c), xg2)
+        _, (hs, cs) = jax.lax.scan(step, (state["h"], state["c"]), xg2)
         h2, c2 = hs[0], cs[0]
         seq = cast_compute(h2, ctx)[:, None, :]
         return ([seq, cast_compute(h2, ctx), cast_compute(c2, ctx)],
-                h2, c2)
+                {"h": h2, "c": c2})
 
     def parallel_dims(self):
         # (n, s, c): DP over samples, TP over the hidden/gate dim; the

@@ -148,6 +148,7 @@ class Dropout(Op):
     TPU-native: threefry key split per trace; identity in inference mode."""
 
     op_type = OpType.DROPOUT
+    position_wise = True
 
     def __init__(self, name, input_tensor, rate, seed=0):
         super().__init__(name, [input_tensor])
@@ -174,6 +175,7 @@ class Softmax(Op):
     """Reference softmax.cu (cudnnSoftmaxForward ACCURATE, sample-parallel)."""
 
     op_type = OpType.SOFTMAX
+    position_wise = True
 
     def __init__(self, name, input_tensor, axis=-1):
         super().__init__(name, [input_tensor])

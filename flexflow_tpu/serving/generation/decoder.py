@@ -8,13 +8,13 @@ derives both halves from the layer list itself:
 
 * **prefill chunk** — the forward over a ``(1, bucket)`` padded chunk
   of prompt positions ``start .. start+length-1``, through each op's
-  own forward arithmetic: position-wise ops run unchanged, attention
-  uses :meth:`~flexflow_tpu.ops.attention.MultiHeadAttention.
-  forward_paged` (scatter the chunk's K/V into the slot's pages, attend
-  over the gathered page table — history written by earlier chunks or
-  borrowed from the prefix cache, plus the chunk itself, causally
-  masked on global positions), the LSTM ``forward_states`` (whole-
-  prompt chunks only — cell state cannot page).  One jitted program per
+  own serving step (``Op.serve_step``, kind ``"chunk"``): position-wise
+  ops run their forward unchanged, attention scatters the chunk's K/V
+  into the slot's pages and attends over the gathered page table —
+  history written by earlier chunks or borrowed from the prefix cache,
+  plus the chunk itself, causally masked on global positions — and the
+  LSTM scans the whole prompt (whole-prompt chunks only — cell state
+  cannot page).  One jitted program per
   power-of-two chunk bucket; a single chunk covering the whole prompt
   IS the monolithic prefill, so ``serve_prefill_chunk=0`` reproduces
   the pre-paging behavior program-for-program.
@@ -41,12 +41,18 @@ layer held eight pool-sized copies).  The folded dim shards over the
 tensor-parallel ``c`` mesh axis by whole heads; the page dim is
 replicated (pages are interchangeable across slots).
 
-Supported graphs: one (n, s) int token input; position-wise ops
-(dense/norms/elementwise/softmax/dropout/embedding), causal
-self-attention, stateless-init LSTM, learned position embeddings.
-Anything else (convs, splits, cross-attention, MoE, pipelines) fails
-validation loudly at construction — a generation engine must never
-silently produce wrong tokens for an unsupported graph.
+Every program is ONE walk of the layer list (:meth:`GraphDecoder.
+_walk`) that hands each op its inputs, its declared state and where
+the step stands (``op.ServeStep``); what a layer keeps between tokens,
+whether it can generate at all and how it advances are the op's own
+(``Op.serve_state`` / ``serve_check`` / ``serve_step``), so this module
+names no op class.  Supported graphs: one (n, s) int token input;
+position-wise ops (dense/norms/elementwise/softmax/dropout/embedding),
+causal self-attention, stateless-init LSTM, learned position
+embeddings, and whatever else writes the contract.  Anything else
+(convs, splits, cross-attention, MoE, pipelines) fails validation
+loudly at construction — a generation engine must never silently
+produce wrong tokens for an unsupported graph.
 """
 
 from __future__ import annotations
@@ -60,18 +66,9 @@ import numpy as np
 
 from ...analysis.kv_memory import (DEFAULT_PAGE_SIZE, default_num_pages,
                                    kv_cache_layout, pages_per_slot)
-from ...op import OpContext, OpType
-from ...ops.attention import MultiHeadAttention, PositionEmbedding
-from ...ops.linear import Embedding
-from ...ops.rnn import LSTM
+from ...op import OpContext, ServeStep
 from . import sampling
 from .pages import alloc_pool_arrays
-
-# ops that act position-wise over the sequence dim: running them on a
-# (slots, 1, d) activation IS the decode step (validated per-op below)
-_POINTWISE_TYPES = (OpType.LINEAR, OpType.LAYERNORM, OpType.RMSNORM,
-                    OpType.ELEMENT_UNARY, OpType.ELEMENT_BINARY,
-                    OpType.SOFTMAX, OpType.DROPOUT)
 
 
 # one computation of a compiled module's text: `%name (params) -> type {`
@@ -184,14 +181,14 @@ class GraphDecoder:
                                       self.slots, self.max_seq,
                                       page_size=self.page_size,
                                       num_pages=self.num_pages)
-        self.has_attention = any(isinstance(op, MultiHeadAttention)
-                                 for op in model.layers)
-        self.has_state = any(isinstance(op, LSTM) for op in model.layers)
-        # cell state cannot page: an LSTM chunk at offset k would need
-        # the carry from chunk k-1 as a program input the stateless
-        # forward_states does not take — whole-prompt chunks only, and
-        # no prefix reuse (the engine enforces both)
-        self.supports_chunking = not self.has_state
+        kinds = {ent["kind"] for ent in self.layout.values()}
+        # a fixed per-slot "state" leaf cannot page: a chunk at offset k
+        # would need the carry from chunk k-1 as a program input —
+        # whole-prompt chunks only (the engine enforces it)
+        self.supports_chunking = "state" not in kinds
+        # every leaf page-major: what prefix reuse, speculation's
+        # rollback and KV migration each need of the graph's state
+        self.pageable = kinds == {"kv"}
         self._prefill_fns: Dict[int, object] = {}
         self._decode_fn = None
         self._decode_sampled_fn = None
@@ -223,35 +220,7 @@ class GraphDecoder:
         self._final_uid = final.uid
         self._vocab = int(final.shape[-1])
         for op in model.layers:
-            if isinstance(op, MultiHeadAttention):
-                if not (op._self_attn and op.causal):
-                    raise ValueError(
-                        f"{op.name}: generation needs causal "
-                        f"self-attention (cross-attention/bidirectional "
-                        f"blocks cannot decode autoregressively)")
-            elif isinstance(op, PositionEmbedding):
-                if op.max_len < self.max_seq:
-                    raise ValueError(
-                        f"{op.name}: position table holds {op.max_len} "
-                        f"positions < max_seq {self.max_seq}")
-            elif isinstance(op, LSTM):
-                if op._has_state:
-                    raise ValueError(
-                        f"{op.name}: LSTM with an external initial_state "
-                        f"is not decodable (seed states are a prefill "
-                        f"product, not a graph input)")
-            elif isinstance(op, Embedding):
-                if op.aggr != "none":
-                    raise ValueError(
-                        f"{op.name}: only sequence-mode (aggr='none') "
-                        f"embeddings decode; bag aggregation collapses "
-                        f"the sequence dim")
-            elif op.op_type not in _POINTWISE_TYPES:
-                raise ValueError(
-                    f"{op.name} ({op.op_type.value}) has no "
-                    f"single-position decode path; generation supports "
-                    f"causal attention, LSTM, embeddings and "
-                    f"position-wise ops")
+            op.serve_check(self.max_seq)
 
     # ---- shared context ------------------------------------------------
     def _ctx(self) -> OpContext:
@@ -297,42 +266,11 @@ class GraphDecoder:
             return fn
         if bucket not in self.buckets:
             raise ValueError(f"unknown prefill bucket {bucket}")
-        layers = self.model.layers
 
         def prefill(params, caches, tokens, table_row, slot, start,
                     length):
-            ctx = self._ctx()
-            values: Dict[int, jax.Array] = {self._input_uid: tokens}
-            new = {name: dict(sub) for name, sub in caches.items()}
-            for op in layers:
-                ins = [values[t.uid] for t in op.inputs]
-                if isinstance(op, MultiHeadAttention):
-                    outs, kp, vp = op.forward_paged(
-                        params, ins[0], new[op.name]["k"],
-                        new[op.name]["v"], table_row, start, length, ctx)
-                    new[op.name] = {"k": kp, "v": vp}
-                elif isinstance(op, LSTM):
-                    # whole-prompt chunk only (supports_chunking False):
-                    # start == 0, so forward_states' zero-state scan is
-                    # exactly the monolithic prefill
-                    outs, hs, cs = op.forward_states(params, ins, ctx)
-                    h_sel = jax.lax.dynamic_index_in_dim(
-                        hs, length - 1, axis=1, keepdims=False)
-                    c_sel = jax.lax.dynamic_index_in_dim(
-                        cs, length - 1, axis=1, keepdims=False)
-                    new[op.name] = {
-                        "h": jax.lax.dynamic_update_slice(
-                            new[op.name]["h"], h_sel, (slot, 0)),
-                        "c": jax.lax.dynamic_update_slice(
-                            new[op.name]["c"], c_sel, (slot, 0)),
-                    }
-                elif isinstance(op, PositionEmbedding):
-                    outs = op.forward_at(params, ins[0], start, ctx)
-                else:
-                    outs = op.forward(params, ins, ctx)
-                for t, val in zip(op.outputs, outs):
-                    values[t.uid] = val
-            logits = values[self._final_uid]
+            logits, new = self._walk(params, caches, tokens, ServeStep(
+                "chunk", table_row, start=start, length=length, slot=slot))
             last = jax.lax.dynamic_index_in_dim(
                 logits, length - 1, axis=1, keepdims=False)[0]
             nxt = jnp.argmax(last).astype(jnp.int32)
@@ -400,68 +338,53 @@ class GraphDecoder:
         self._decode_sampled_fn = jax.jit(decode_s, donate_argnums=(1,))
         return self._decode_sampled_fn
 
-    # ---- speculative decoding (docs/serving.md "Speculative
-    # decoding & sampling") ----------------------------------------------
-    def _walk_decode(self, params, caches, tokens, pos, table,
-                     write_pages, write_rows):
-        """The shared single-position layer walk: returns the (slots,
-        V) logits + updated caches (the body of :meth:`decode_fn`,
-        factored so the sampled decode and the draft scan run the
-        IDENTICAL arithmetic)."""
+    # ---- the graph walk -------------------------------------------------
+    def _walk(self, params, caches, x, where: ServeStep):
+        """THE graph walk, which every program runs: ``x`` — a (1,
+        bucket) prompt chunk, (slots, 1) current tokens or a (slots, W)
+        window, as ``where`` says — through every op's ``serve_step``
+        with the op's own leaves of ``caches``.  Returns the final
+        tensor's value (.., V) and the caches as the ops left them: the
+        same structure and leaf names, since the programs donate them
+        and hand them on."""
         ctx = self._ctx()
-        x = tokens[:, None]                              # (slots, 1)
         values: Dict[int, jax.Array] = {self._input_uid: x}
         new: Dict[str, Dict[str, jax.Array]] = {}
         for op in self.model.layers:
             ins = [values[t.uid] for t in op.inputs]
-            if isinstance(op, MultiHeadAttention):
-                outs, kp, vp = op.decode_paged(
-                    params, ins[0], caches[op.name]["k"],
-                    caches[op.name]["v"], table, pos,
-                    write_pages, write_rows, ctx)
-                new[op.name] = {"k": kp, "v": vp}
-            elif isinstance(op, LSTM):
-                outs, h2, c2 = op.decode(
-                    params, ins[0], caches[op.name]["h"],
-                    caches[op.name]["c"], ctx)
-                new[op.name] = {"h": h2, "c": c2}
-            elif isinstance(op, PositionEmbedding):
-                outs = op.decode(params, ins[0], pos, ctx)
-            else:
-                outs = op.forward(params, ins, ctx)
+            outs, state = op.serve_step(params, ins, caches.get(op.name),
+                                        where, ctx)
+            if state is not None:
+                new[op.name] = state
             for t, val in zip(op.outputs, outs):
                 values[t.uid] = val
-        return values[self._final_uid][:, 0], new        # (slots, V)
+        return values[self._final_uid], new
 
+    def _walk_decode(self, params, caches, tokens, pos, table,
+                     write_pages, write_rows):
+        """One position of every slot: the (slots, V) logits + updated
+        caches (the body of :meth:`decode_fn`, shared so that the
+        sampled decode and the draft scan run the IDENTICAL
+        arithmetic)."""
+        logits, new = self._walk(
+            params, caches, tokens[:, None],
+            ServeStep("token", table, pos=pos, write_pages=write_pages,
+                      write_rows=write_rows))
+        return logits[:, 0], new
+
+    # ---- speculative decoding (docs/serving.md "Speculative
+    # decoding & sampling") ----------------------------------------------
     def _walk_window(self, params, caches, window, pos, table,
                      write_pages, write_rows):
-        """The W-position verify walk: ``window`` (slots, W) int32
-        tokens at global positions ``pos[i] .. pos[i]+W-1`` per slot,
-        through every op's window path — attention via
-        :meth:`~flexflow_tpu.ops.attention.MultiHeadAttention.
-        verify_paged` (the slot-batched chunked-prefill kernel),
-        position embeddings via ``decode_window``, position-wise ops
-        unchanged.  Returns the (slots, W, V) logits + updated caches.
-        Speculation requires ``supports_chunking`` (no LSTM): a cell
-        state cannot roll back to an accept point."""
-        ctx = self._ctx()
-        values: Dict[int, jax.Array] = {self._input_uid: window}
-        new: Dict[str, Dict[str, jax.Array]] = {}
-        for op in self.model.layers:
-            ins = [values[t.uid] for t in op.inputs]
-            if isinstance(op, MultiHeadAttention):
-                outs, kp, vp = op.verify_paged(
-                    params, ins[0], caches[op.name]["k"],
-                    caches[op.name]["v"], table, pos,
-                    write_pages, write_rows, ctx)
-                new[op.name] = {"k": kp, "v": vp}
-            elif isinstance(op, PositionEmbedding):
-                outs = op.decode_window(params, ins[0], pos, ctx)
-            else:
-                outs = op.forward(params, ins, ctx)
-            for t, val in zip(op.outputs, outs):
-                values[t.uid] = val
-        return values[self._final_uid], new              # (slots, W, V)
+        """The W-position verify step: ``window`` (slots, W) int32
+        tokens at global positions ``pos[i] .. pos[i]+W-1`` per slot.
+        Returns the (slots, W, V) logits + updated caches.  Speculation
+        requires ``pageable`` state: a carry cannot roll back to an
+        accept point."""
+        return self._walk(
+            params, caches, window,
+            ServeStep("window", table, pos=pos, write_pages=write_pages,
+                      write_rows=write_rows))
 
     def verify_fn(self, width: int, sampled: bool = False):
         """The jitted speculative-VERIFY program for one window width

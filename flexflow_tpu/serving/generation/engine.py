@@ -562,8 +562,7 @@ class GenerationEngine:
               else prefix_cache)
         self.prefix_cache_enabled = (
             str(pc).lower() not in ("off", "0", "false", "no")
-            and self._decoder.has_attention
-            and self._decoder.supports_chunking)
+            and self._decoder.pageable)
         # dispatcher-thread-only state (single writer, no lock)
         self._slots_state: List[Optional[_Slot]] = [None] * self.slots
         self._pool = KVPagePool(self.num_pages, self.page_size)
@@ -641,8 +640,7 @@ class GenerationEngine:
             if gmax < max(g, 2):
                 raise ValueError(f"spec_gamma_max {gmax} < gamma "
                                  f"{max(g, 2)}")
-            if not (self._decoder.has_attention
-                    and self._decoder.supports_chunking):
+            if not self._decoder.pageable:
                 raise ValueError(
                     "speculative decoding needs a chunkable causal-"
                     "attention graph (LSTM state cannot roll back to "
@@ -1429,8 +1427,7 @@ class GenerationEngine:
         stream = st.stream
         t0 = self.clock()
         try:
-            if not (self._decoder.has_attention
-                    and self._decoder.supports_chunking):
+            if not self._decoder.pageable:
                 raise RuntimeError(
                     "graph state is not pageable (no paged attention): "
                     "KV migration needs a chunkable attention graph")

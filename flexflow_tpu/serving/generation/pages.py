@@ -8,8 +8,8 @@ evicts cached prefix pages under pressure; the trie maps token-id
 chains (one node per FULL page of tokens) to pooled pages so a submit
 whose prompt extends a cached prefix skips recomputing the shared
 pages.  The device side only ever sees page ids as gather/scatter
-indices (ops/attention.py ``forward_paged``/``decode_paged``/
-``verify_paged``) into pools stored LANE-DENSE: ``(num_pages,
+indices (``MultiHeadAttention.serve_step``, ops/attention.py) into
+pools stored LANE-DENSE: ``(num_pages,
 page_size, heads * head_dim)``, a token's heads side by side in one
 minor dim, so that no compiled serving program holds a copy the size of
 the pool (``analysis/kv_memory.py`` says why; ``GraphDecoder.

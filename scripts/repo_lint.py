@@ -125,6 +125,17 @@ by tier-1 ``tests/test_static_checks.py``).  Rules:
   (``time.time``/``time.monotonic``/``os.urandom``/``os.getpid``) —
   a key that differs between two identical runs.  The rare
   deliberate site carries an ``RL014-ok:`` comment.
+* **RL015 — the generation stack knows no layer kind** (ISSUE 29): what
+  a layer keeps between tokens, whether it can generate and how it
+  advances are the op's own (``Op.serve_state`` / ``serve_check`` /
+  ``serve_step``), so no module under
+  ``flexflow_tpu/serving/generation/`` nor
+  ``flexflow_tpu/analysis/kv_memory.py`` imports anything from
+  ``flexflow_tpu.ops`` or names ``OpType`` — an ``isinstance`` arm or
+  an op-type comparison there is the ladder a new layer kind would
+  have to climb again.  (``serving/quantize.py`` rewrites ``Linear``
+  weights and rightly knows ``Linear``; it is outside the rule.)  A
+  deliberate site carries an ``RL015-ok:`` comment.
 
 Exit 0 when clean, 1 with ``file:line: RLxxx message`` findings on
 stdout.  No third-party deps — must run on a bare CPython.
@@ -373,6 +384,10 @@ class _Visitor(ast.NodeVisitor):
             and relpath not in _RL012_EXEMPT)
         self.in_generation = relpath.startswith(
             "flexflow_tpu/serving/generation/")
+        # RL015: where a layer kind must not be known
+        self.in_layer_blind_scope = (
+            self.in_generation
+            or relpath == "flexflow_tpu/analysis/kv_memory.py")
         self.in_clock_scope = (self.in_serving
                                and relpath not in _RL008_EXEMPT)
         # RL009 engages where the concurrency-heavy classes live: the
@@ -500,6 +515,43 @@ class _Visitor(ast.NodeVisitor):
                   "site must pass a declared literal (obs/events.py), "
                   "or carry an 'RL011-ok: <literals>' comment when the "
                   "name is a validated parameter")
+
+    def _layer_kind(self, node: ast.AST, what: str) -> None:
+        """RL015: a reference to an op class or an op type where the
+        code serves every layer through the contract on ``Op``."""
+        line = (self.lines[node.lineno - 1]
+                if 0 < node.lineno <= len(self.lines) else "")
+        if "RL015-ok" not in line:
+            self._add(node, "RL015",
+                      f"{what} in {self.relpath} — the generation stack "
+                      f"asks the op (Op.serve_state / serve_check / "
+                      f"serve_step) and names no layer kind; put the "
+                      f"decision behind the op, or annotate "
+                      f"'RL015-ok: why' if this site is legitimate")
+
+    def visit_ImportFrom(self, node: ast.ImportFrom) -> None:
+        if self.in_layer_blind_scope:
+            mod = node.module or ""
+            if mod == "ops" or mod.startswith(("ops.", "flexflow_tpu.ops")) \
+                    or any(a.name == "ops" for a in node.names):
+                self._layer_kind(node, "import from flexflow_tpu.ops")
+            elif any(a.name == "OpType" for a in node.names):
+                self._layer_kind(node, "OpType imported")
+        self.generic_visit(node)
+
+    def visit_Import(self, node: ast.Import) -> None:
+        if self.in_layer_blind_scope and any(
+                a.name.startswith("flexflow_tpu.ops") for a in node.names):
+            self._layer_kind(node, "import of flexflow_tpu.ops")
+        self.generic_visit(node)
+
+    def visit_Attribute(self, node: ast.Attribute) -> None:
+        if self.in_layer_blind_scope and (
+                node.attr == "OpType"
+                or (isinstance(node.value, ast.Name)
+                    and node.value.id == "OpType")):
+            self._layer_kind(node, "an OpType named")
+        self.generic_visit(node)
 
     def visit_Constant(self, node: ast.Constant) -> None:
         v = node.value

@@ -40,9 +40,18 @@ def test_candle_uno_trains():
     assert losses[-1] < losses[0]
 
 
+# An example that asks for no devices runs on ONE.  Its script takes "all
+# visible chips" (``-ll:tpu`` 0), and a child of this suite would see the
+# eight virtual devices of tests/conftest.py: eight-way data parallelism
+# nothing in a one-chip example needs, whose every step is a rendezvous
+# of eight threads that, beside five other busy workers, ran into XLA's
+# 40 s limit (ROADMAP D15).  One device has no rendezvous to starve.
+_ONE_DEVICE = {"XLA_FLAGS": "--xla_force_host_platform_device_count=1"}
+
+
 def _run_example(script, *extra, env=None, timeout=600):
     from tests.subproc import cached_env
-    env = cached_env(**(env or {}))
+    env = cached_env(**{**_ONE_DEVICE, **(env or {})})
     out = subprocess.run(
         [sys.executable, "-m", "flexflow_tpu.cli", os.path.join(REPO, script),
          *extra],

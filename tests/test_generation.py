@@ -14,6 +14,7 @@ accounting and the FF_FAULT generation kinds.
 """
 
 import contextlib
+import re
 
 import numpy as np
 import pytest
@@ -24,7 +25,7 @@ import jax.numpy as jnp
 import flexflow_tpu as ff
 from flexflow_tpu import faults
 from flexflow_tpu.fflogger import capture_events
-from flexflow_tpu.op import OpContext
+from flexflow_tpu.op import Op, OpContext, OpType, ServeStep
 from flexflow_tpu.ops.attention import MultiHeadAttention, PositionEmbedding
 from flexflow_tpu.ops.rnn import LSTM
 from flexflow_tpu.parallel.mesh import MachineMesh
@@ -54,50 +55,93 @@ def _ctx():
     return OpContext(training=False, compute_dtype="float32", mesh=None)
 
 
+def _state(op, slots, num_pages=0, page_size=0):
+    """Zeroed f32 leaves of what ``op`` declares it keeps."""
+    ent = op.serve_state(slots, num_pages, page_size, None)
+    return {leaf: jnp.zeros(shape, jnp.float32)
+            for leaf, shape in ent["shapes"].items()}
+
+
+def _stepper(op, kind):
+    """``op.serve_step`` for one kind of step, jitted as the decoder's
+    programs jit it: ``run(params, x, state, **index arrays)``."""
+    ctx = _ctx()
+
+    def run(params, x, state, **where):
+        return op.serve_step(params, [x], state,
+                             ServeStep(kind, **where), ctx)
+
+    return jax.jit(run)
+
+
+def _chunk(table_row, slot, start, length):
+    """The index arrays of one slot's prompt chunk."""
+    return {"table": None if table_row is None else jnp.asarray(table_row),
+            "slot": jnp.int32(slot), "start": jnp.int32(start),
+            "length": jnp.int32(length)}
+
+
 def test_attention_decode_matches_forward_every_prefix():
-    """The correctness anchor: single-token decode against the KV cache
+    """The correctness anchor, on what serves: the paged step through
+    the serving contract — a scrambled page table, a folded pool, one
+    position of every slot per call, as ``jit_decode`` runs it —
     reproduces the causal forward's row at EVERY prefix length, to
     float32 tolerance (ROADMAP D6: the contract is the mathematics, not
     one XLA:CPU build's accumulation order — JAX 0.9.0 drifts 1 ulp
-    here)."""
-    n, S, D, H = 2, 16, 32, 4
+    here); and so does each row of a prompt chunk."""
+    n, S, D, H, page = 2, 16, 32, 4, 4
     rng = np.random.default_rng(0)
     x = rng.standard_normal((n, S, D)).astype(np.float32)
     t_in = Tensor((n, S, D), "float32", "x")
     op = MultiHeadAttention("attn", t_in, t_in, t_in, D, H, causal=True)
     params = _op_params(op, jax.random.PRNGKey(0))
     ctx = _ctx()
+    # 8 pages of 4 rows, no slot's pages in order or next to each other
+    table = np.array([[5, 2, 7, 0], [3, 6, 1, 4]], np.int32)
+    no_page = table.size
+    empty = _state(op, n, num_pages=no_page, page_size=page)
+    assert empty["k"].shape == (no_page, page, D)      # folded rows
 
     full = jax.jit(lambda p, x: op.forward(p, [x], ctx)[0])(params, x)
-    (pref_out,), k, v = jax.jit(
-        lambda p, x: op.forward_kv(p, [x], ctx))(params, x)
-    # prefill IS the forward (shared _qkv/_out_proj arithmetic)
-    np.testing.assert_array_equal(np.asarray(pref_out), np.asarray(full))
+    chunk, token = _stepper(op, "chunk"), _stepper(op, "token")
+    # prefill: one chunk a slot; its rows are the forward's rows
+    filled = empty
+    for i in range(n):
+        (out,), filled = chunk(params, x[i:i + 1], filled,
+                               **_chunk(table[i], i, 0, S))
+        np.testing.assert_allclose(np.asarray(out)[0],
+                                   np.asarray(full)[i],
+                                   rtol=1e-5, atol=1e-6)
+    khost = np.asarray(filled["k"])
 
-    khost, vhost = np.asarray(k), np.asarray(v)
-    dec = jax.jit(lambda p, x1, kc, vc, pos: op.decode(p, x1, kc, vc,
-                                                       pos, ctx))
+    state = empty
     for t in range(S):
-        kc = np.zeros_like(khost)
-        vc = np.zeros_like(vhost)
-        kc[:, :t] = khost[:, :t]
-        vc[:, :t] = vhost[:, :t]
-        (out,), kc2, vc2 = dec(params, x[:, t:t + 1], jnp.asarray(kc),
-                               jnp.asarray(vc),
-                               jnp.full((n,), t, jnp.int32))
+        where = {"table": jnp.asarray(table),
+                 "pos": jnp.full((n,), t, jnp.int32),
+                 "write_pages": jnp.asarray(table[:, t // page]),
+                 "write_rows": jnp.full((n,), t % page, jnp.int32)}
+        (out,), state = token(params, x[:, t:t + 1], state, **where)
         got, want = np.asarray(out)[:, 0], np.asarray(full)[:, t]
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6,
                                    err_msg=f"t={t}")
-        # the decode wrote this position's K/V — exactly the forward's
-        np.testing.assert_array_equal(np.asarray(kc2)[:, t],
-                                      khost[:, t])
+        # the step wrote this position's K row where the table says —
+        # the row the prefill wrote there
+        np.testing.assert_array_equal(
+            np.asarray(state["k"])[table[:, t // page], t % page],
+            khost[table[:, t // page], t % page])
+    # a slot that is not decoding writes nothing: the sentinel drops it
+    where["write_pages"] = jnp.full((n,), no_page, jnp.int32)
+    _, after = token(params, x[:, :1], state, **where)
+    for leaf in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(after[leaf]),
+                                      np.asarray(state[leaf]))
 
 
 def test_lstm_decode_matches_forward_every_prefix():
-    """The RNN cell's decode (state carry in a 2-step scan — see
+    """The RNN cell's token step (state carry in a 2-step scan — see
     ops/rnn.py for why the scan matters) matches the scanned forward
-    bit-for-bit, both step-by-step and seeded from mid-sequence prefill
-    states."""
+    bit-for-bit, both step-by-step and seeded at mid-sequence by a
+    prompt chunk, which writes its last real position's carry."""
     n, S, D, H = 2, 16, 24, 8
     rng = np.random.default_rng(1)
     x = rng.standard_normal((n, S, D)).astype(np.float32)
@@ -105,35 +149,35 @@ def test_lstm_decode_matches_forward_every_prefix():
     op = LSTM("lstm", t_in, H)
     params = _op_params(op, jax.random.PRNGKey(1))
     ctx = _ctx()
-
-    fseq, _, _ = jax.jit(lambda p, x: op.forward(p, [x], ctx))(params, x)
-    outs, hs, cs = jax.jit(
-        lambda p, x: op.forward_states(p, [x], ctx))(params, x)
-    np.testing.assert_array_equal(np.asarray(outs[0]), np.asarray(fseq))
-
-    dec = jax.jit(lambda p, x1, h, c: op.decode(p, x1, h, c, ctx))
     exact = jax.default_backend() == "cpu"
-    h = jnp.zeros((n, H), jnp.float32)
-    c = jnp.zeros((n, H), jnp.float32)
-    for t in range(S):
-        (o, _, _), h, c = dec(params, x[:, t:t + 1], h, c)
-        got, want = np.asarray(o)[:, 0], np.asarray(fseq)[:, t]
+
+    def same(got, want, **kw):
         if exact:
-            np.testing.assert_array_equal(got, want, err_msg=f"t={t}")
+            np.testing.assert_array_equal(got, want, **kw)
         else:
             np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
-    # seed the carry from the prefill's mid-sequence states
+
+    forward = jax.jit(lambda p, x: op.forward(p, [x], ctx)[0])
+    fseq = np.asarray(forward(params, x))
+    chunk, token = _stepper(op, "chunk"), _stepper(op, "token")
+    state = _state(op, n)
+    assert state["h"].shape == state["c"].shape == (n, H)
+    for t in range(S):
+        (o, _, _), state = token(params, x[:, t:t + 1], state, table=None)
+        same(np.asarray(o)[:, 0], fseq[:, t], err_msg=f"t={t}")
+    # a prompt chunk IS the forward of that one row, and leaves the
+    # carry from which the next position continues the trajectory
     for t0 in (5, 11):
-        (o, _, _), _, _ = dec(params, x[:, t0:t0 + 1],
-                              jnp.asarray(hs[:, t0 - 1]),
-                              jnp.asarray(cs[:, t0 - 1]))
-        if exact:
-            np.testing.assert_array_equal(np.asarray(o)[:, 0],
-                                          np.asarray(fseq)[:, t0])
-        else:
-            np.testing.assert_allclose(np.asarray(o)[:, 0],
-                                       np.asarray(fseq)[:, t0],
-                                       rtol=1e-5, atol=1e-6)
+        state = _state(op, n)
+        for i in range(n):
+            (o, _, _), state = chunk(params, x[i:i + 1], state,
+                                     **_chunk(None, i, 0, t0))
+            same(np.asarray(o)[0, :t0],
+                 np.asarray(forward(params, x[i:i + 1]))[0, :t0])
+        (o, _, _), _ = token(params, x[:, t0:t0 + 1], state, table=None)
+        same(np.asarray(o)[:, 0], fseq[:, t0])
+    with pytest.raises(ValueError, match="lstm.*roll back"):
+        op.serve_step(params, [x], state, ServeStep("window", None), ctx)
 
 
 def test_position_embedding_decode_matches_forward():
@@ -144,12 +188,24 @@ def test_position_embedding_decode_matches_forward():
     op = PositionEmbedding("pe", t_in)
     params = _op_params(op, jax.random.PRNGKey(2))
     ctx = _ctx()
-    full = jax.jit(lambda p, x: op.forward(p, [x], ctx)[0])(params, x)
-    dec = jax.jit(lambda p, x1, pos: op.decode(p, x1, pos, ctx)[0])
+    full = np.asarray(
+        jax.jit(lambda p, x: op.forward(p, [x], ctx)[0])(params, x))
+    token = _stepper(op, "token")
     for t in range(S):
-        out = dec(params, x[:, t:t + 1], jnp.full((n,), t, jnp.int32))
-        np.testing.assert_array_equal(np.asarray(out)[:, 0],
-                                      np.asarray(full)[:, t])
+        (out,), _ = token(params, x[:, t:t + 1], None, table=None,
+                          pos=jnp.full((n,), t, jnp.int32))
+        np.testing.assert_array_equal(np.asarray(out)[:, 0], full[:, t])
+    # a window of every slot and a prompt chunk at an offset read the
+    # same table rows
+    (out,), _ = _stepper(op, "window")(
+        params, x[:, 3:7], None, table=None,
+        pos=jnp.full((n,), 3, jnp.int32))
+    np.testing.assert_array_equal(np.asarray(out), full[:, 3:7])
+    (out,), state = _stepper(op, "chunk")(params, x[1:, 4:8], None,
+                                          **_chunk(None, 1, 4, 3))
+    np.testing.assert_array_equal(np.asarray(out), full[1:, 4:8])
+    assert state is None
+    assert op.serve_state(n, 8, 4, None) is None       # keeps nothing
 
 
 # ---------------------------------------------------------------------
@@ -369,6 +425,176 @@ def test_decoder_rejects_unsupported_graphs():
         GraphDecoder(clf, 2, 16)
     with pytest.raises(ValueError, match="slots"):
         GraphDecoder(clf, 1, 16)
+
+
+# ---------------------------------------------------------------------
+# the serving contract (ISSUE 29): a layer's serving form lives in the
+# layer — nothing under flexflow_tpu/ knows the ops defined here
+# ---------------------------------------------------------------------
+class PrefixMean(Op):
+    """``y[t] = mean(x[0..t])`` — a layer kind the package has never
+    heard of, with a fixed per-slot state (the running sum; the count is
+    the position).  Forward adds position by position in a scan, as the
+    token step does, so both make the same sums."""
+
+    op_type = OpType.ELEMENT_UNARY
+
+    def __init__(self, name, x):
+        super().__init__(name, [x])
+        self._add_output(x.shape, x.dtype)
+
+    @staticmethod
+    def _sums(x):
+        n, _, d = x.shape
+
+        def add(total, x_t):
+            total = total + x_t
+            return total, total
+
+        _, sums = jax.lax.scan(add, jnp.zeros((n, d), jnp.float32),
+                               jnp.transpose(x, (1, 0, 2)))
+        return jnp.transpose(sums, (1, 0, 2))
+
+    def forward(self, params, inputs, ctx):
+        x = inputs[0]
+        count = jnp.arange(1, x.shape[1] + 1, dtype=jnp.float32)
+        return [self._sums(x) / count[None, :, None]]
+
+    def serve_state(self, slots, num_pages, page_size, mesh_sizes):
+        return {"kind": "state",
+                "shapes": {"sum": (slots, self.inputs[0].shape[-1])},
+                "entries": {"sum": (None, None)}, "dtype": "f32"}
+
+    def serve_check(self, max_seq):
+        pass
+
+    def serve_step(self, params, inputs, state, where, ctx):
+        x = inputs[0]
+        if where.kind == "chunk":       # a whole prompt of one slot
+            sums = self._sums(x)
+            last = jax.lax.dynamic_index_in_dim(
+                sums, where.length - 1, axis=1, keepdims=False)
+            count = jnp.arange(1, x.shape[1] + 1, dtype=jnp.float32)
+            return ([sums / count[None, :, None]],
+                    {"sum": jax.lax.dynamic_update_slice(
+                        state["sum"], last, (where.slot, 0))})
+        total = state["sum"] + x[:, 0]
+        count = (where.pos + 1).astype(jnp.float32)
+        return [(total / count[:, None])[:, None]], {"sum": total}
+
+
+def test_engine_serves_a_layer_kind_defined_outside_the_package():
+    """What adding a layer kind costs: the op's own three members.  An
+    op defined HERE, keeping a fixed per-slot array, sits in a small LM
+    graph and GenerationEngine serves it token for token equal to the
+    full forward — whole-prompt prefill (its state cannot page), its
+    bytes in the plan the static gates charge — and no file under
+    flexflow_tpu/ names it."""
+    import pathlib
+    from flexflow_tpu.analysis.kv_memory import kv_page_plan
+    d = 24
+    cfg = ff.FFConfig(batch_size=4, compute_dtype="float32", seed=9)
+    model = ff.FFModel(cfg)
+    tokens = model.create_tensor((cfg.batch_size, SEQ), dtype="int32",
+                                 name="tokens")
+    t = model.embedding(tokens, VOCAB, d, aggr="none", name="tok_embedding")
+    t = model._register(PrefixMean("prefix_mean", t)).outputs[0]
+    t = model.dense(t, d, activation="relu", name="mix")
+    model.softmax(model.dense(t, VOCAB, name="vocab_projection"))
+    model.compile(ff.SGDOptimizer(lr=0.01), mesh=MachineMesh({"n": 1}))
+    model.init_layers(seed=9)
+    rng = np.random.default_rng(10)
+    prompts = [rng.integers(1, VOCAB, int(k)).astype(np.int32)
+               for k in (3, 7, 4, 5)]
+    with GenerationEngine(model, slots=2, max_new_tokens=6) as eng:
+        dec = eng._decoder
+        assert not dec.supports_chunking and not dec.pageable
+        assert not eng.prefix_cache_enabled and eng.prefill_chunk == 0
+        assert dec.layout == {"prefix_mean": {
+            "kind": "state", "shapes": {"sum": (2, d)},
+            "entries": {"sum": (None, None)}, "dtype": "f32"}}
+        outs = [list(int(t) for t in eng.submit(p).result(timeout=120))
+                for p in prompts]
+        assert eng.kv_cache_bytes == 2 * d * 4
+    assert outs == [reference_decode(model, p, 6) for p in prompts]
+    plan = kv_page_plan(model.layers, {"n": 1}, 2, SEQ, kv_dtype_bytes=4)
+    assert plan["state_bytes"] == 2 * d * 4 and plan["pool_bytes"] == 0
+    pkg = pathlib.Path(ff.__file__).parent
+    named = [str(f) for f in pkg.rglob("*.py")
+             if re.search("prefix_?mean", f.read_text(), re.I)]
+    assert named == []
+
+
+_KV = {"kind": "kv", "dtype": "compute"}
+_STATE = {"kind": "state", "dtype": "f32"}
+
+
+def _kv(pages, c):
+    shape, entries = (pages, 16, 32), (None, None, c)
+    return dict(_KV, shapes={"k": shape, "v": shape},
+                entries={"k": entries, "v": entries})
+
+
+def _hc(hidden, n, c):
+    shape, entries = (4, hidden), (n, c)
+    return dict(_STATE, shapes={"h": shape, "c": shape},
+                entries={"h": entries, "c": entries})
+
+
+@pytest.mark.parametrize("mesh", [None, {"n": 2, "c": 2}],
+                         ids=["no-mesh", "n2xc2"])
+@pytest.mark.parametrize("which", ["transformer_lm", "lstm_lm"])
+def test_kv_cache_layout_is_what_it_was_before_the_ops_declared_it(
+        which, mesh):
+    """The decision moved into the ops (ISSUE 29); its answer did not.
+    The layout of each LM written out as kv_memory.py used to build it:
+    4 slots x 32 positions in pages of 16, and 5 pages where the pool
+    is given."""
+    from flexflow_tpu.analysis.kv_memory import kv_cache_layout
+    from flexflow_tpu.models import build_lstm_lm, build_transformer_lm
+    cfg = ff.FFConfig(batch_size=4, compute_dtype="float32")
+    c = "c" if mesh else None
+    if which == "transformer_lm":
+        model = build_transformer_lm(cfg, num_layers=2, d_model=32,
+                                     num_heads=2, d_ff=64, seq_len=SEQ,
+                                     vocab_size=VOCAB)[0]
+        want = lambda pages: {"attention_0": _kv(pages, c),  # noqa: E731
+                              "attention_1": _kv(pages, c)}
+    else:
+        model = build_lstm_lm(cfg, vocab_size=VOCAB, embed_dim=24,
+                              hidden_dim=24, num_layers=2, seq_len=SEQ)[0]
+        n = "n" if mesh else None
+        want = lambda pages: {"lm_lstm_0": _hc(24, n, c),    # noqa: E731
+                              "lm_lstm_1": _hc(24, n, c)}
+    assert kv_cache_layout(model.layers, mesh, 4, SEQ) == want(8)
+    assert kv_cache_layout(model.layers, mesh, 4, SEQ, page_size=16,
+                           num_pages=5) == want(5)
+
+
+def test_decoder_refuses_an_op_that_cannot_step_by_its_name():
+    """Neither position-wise nor with a step of its own: refused by
+    GraphDecoder at construction, the op's name in the message — never
+    served through a forward that mixes positions."""
+    class Shift(Op):
+        op_type = OpType.RESHAPE
+
+        def __init__(self, name, x):
+            super().__init__(name, [x])
+            self._add_output(x.shape, x.dtype)
+
+        def forward(self, params, inputs, ctx):
+            return [jnp.roll(inputs[0], 1, axis=1)]
+
+    cfg = ff.FFConfig(batch_size=4, compute_dtype="float32")
+    model = ff.FFModel(cfg)
+    tokens = model.create_tensor((4, SEQ), dtype="int32", name="tokens")
+    t = model.embedding(tokens, VOCAB, 16, aggr="none")
+    t = model._register(Shift("shift_by_one", t)).outputs[0]
+    model.softmax(model.dense(t, VOCAB))
+    model.compile(ff.SGDOptimizer(lr=0.01), mesh=MachineMesh({"n": 1}))
+    with pytest.raises(ValueError, match=r"shift_by_one \(reshape\) has no "
+                                         r"single-position decode path"):
+        GraphDecoder(model, 2, SEQ)
 
 
 # ---------------------------------------------------------------------

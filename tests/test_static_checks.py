@@ -446,6 +446,31 @@ def test_static_checks_script_passes_on_repo():
      "import numpy as np\n\ndef f(n, s, d):\n"
      "    return np.zeros((n, s, d), np.float32)\n",
      None),
+    # RL015: the generation stack and the KV accounting serve every
+    # layer through the contract on Op and name no layer kind (ISSUE 29)
+    ("flexflow_tpu/serving/generation/zz_bad_op_class.py",
+     "from ...ops.rnn import LSTM\n\ndef f(op):\n"
+     "    return isinstance(op, LSTM)\n",
+     "RL015"),
+    ("flexflow_tpu/analysis/kv_memory.py",
+     "from ..op import Op, OpType\n\ndef f(op):\n"
+     "    return op.op_type == OpType.ATTENTION\n",
+     "RL015"),
+    ("flexflow_tpu/serving/generation/zz_bad_op_type.py",
+     "from ... import op as _op\n\ndef f(op):\n"
+     "    return op.op_type is _op.OpType.LSTM\n",
+     "RL015"),
+    # asking the op is the sanctioned spelling
+    ("flexflow_tpu/serving/generation/zz_ok_asks_the_op.py",
+     "from ...op import Op, ServeStep\n\ndef f(op: Op, where: ServeStep):\n"
+     "    return op.serve_state(2, 8, 16, None), op.position_wise\n",
+     None),
+    # quantize.py rewrites Linear weights and rightly knows Linear
+    ("flexflow_tpu/serving/quantize.py",
+     "from ..op import OpType\nfrom ..ops.linear import Linear\n\n"
+     "def f(op):\n"
+     "    return isinstance(op, Linear) or op.op_type == OpType.LINEAR\n",
+     None),
     # RL014: unseeded RNG in serving code breaks the per-(seed,
     # request) sampling-determinism contract (ISSUE 16)
     ("flexflow_tpu/serving/zz_bad_np_random.py",
