@@ -373,6 +373,9 @@ class MultiHeadAttention(Op):
         self._self_attn = len(inputs) == 1
         # {training: core} as last traced (see _attend)
         self.kernel_cores = {}
+        # "paged" or "gathered": the decode core serve_step("token") got,
+        # as last traced
+        self.decode_core = None
         n, sq, dq = query.shape
         self._add_output((n, sq, embed_dim), query.dtype)
         init = kernel_initializer or GlorotUniform()
@@ -530,9 +533,15 @@ class MultiHeadAttention(Op):
           chunked prefill == the monolithic forward row for row (the
           ISSUE 15 parity anchor), pad rows' outputs being garbage the
           caller ignores;
-        * ``"token"``: host-computed indices; :func:`_decode_attention`
-          fed the gathered cache, bit-identical on CPU to the dense
-          forward's row at ``pos``;
+        * ``"token"``: host-computed indices.  Where
+          :meth:`_decode_core` says ``"paged"`` (a TPU, one device, a
+          geometry :mod:`paged_decode_kernel` takes) nothing is gathered:
+          the kernel reads each decoding slot's live pages out of the
+          pools where they lie, heads on the folded dim.  Otherwise
+          :func:`_decode_attention` fed the gathered cache, bit-identical
+          on CPU to the dense forward's row at ``pos``.  Which one was
+          traced is noted in ``self.decode_core``
+          (``GraphDecoder.decode_attention`` sums it);
         * ``"window"``: host-computed ``(slots, W)`` indices;
           :func:`_verify_window_attention`, each window row bit-identical
           on CPU to the sequential token step at that position (the
@@ -569,17 +578,36 @@ class MultiHeadAttention(Op):
 
         k_pool = k_pool.at[wp, wr].set(rows(k), mode="drop")
         v_pool = v_pool.at[wp, wr].set(rows(v), mode="drop")
-        kg, vg = view(k_pool), view(v_pool)
         scale = 1.0 / math.sqrt(self.head_dim)
-        if chunk:
-            attn = _paged_chunk_attention(q, kg, vg, qpos, scale)
-        elif token:
-            attn = _decode_attention(q, kg, vg, where.pos, scale)
+        if token:
+            self.decode_core = self._decode_core(k_pool, ctx)
+        if token and self.decode_core == "paged":
+            from .paged_decode_kernel import paged_decode_attention
+            attn = paged_decode_attention(
+                self._fold_rows(q[:, 0]), k_pool, v_pool, where.table,
+                where.pos, wp, self.num_heads, scale)
         else:
-            qpos = where.pos[:, None] + jnp.arange(w)[None, :]
-            attn = _verify_window_attention(q, kg, vg, qpos, scale)
+            kg, vg = view(k_pool), view(v_pool)
+            if chunk:
+                attn = _paged_chunk_attention(q, kg, vg, qpos, scale)
+            elif token:
+                attn = _decode_attention(q, kg, vg, where.pos, scale)
+            else:
+                qpos = where.pos[:, None] + jnp.arange(w)[None, :]
+                attn = _verify_window_attention(q, kg, vg, qpos, scale)
         return ([self._out_proj(params, attn, n, w, ctx)],
                 {"k": k_pool, "v": v_pool})
+
+    def _decode_core(self, pool, ctx: OpContext) -> str:
+        """``"paged"`` where the token step can read the pool in place
+        (:mod:`paged_decode_kernel`, from what the code can see: backend,
+        pool dtype, head and page geometry, one device), else
+        ``"gathered"``."""
+        from . import paged_decode_kernel
+        distributed = ctx.mesh is not None and ctx.mesh.is_distributed
+        return ("paged" if paged_decode_kernel.supported(
+            jax.default_backend(), pool.dtype, self.num_heads,
+            self.head_dim, pool.shape[1], distributed) else "gathered")
 
     def parallel_dims(self):
         # (n, s, c): sample DP, sequence SP (ring), channel TP (heads)

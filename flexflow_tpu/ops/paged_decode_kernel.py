@@ -1,0 +1,248 @@
+"""The repo's own Pallas decode attention for TPU: one query position a
+slot against the PAGED KV pools, read where they lie.
+
+The pools are stored lane-dense, ``(num_pages, page, heads * head_dim)``
+(``MultiHeadAttention.serve_state``).  The gathered decode
+(``ops/attention._decode_attention`` behind ``_gather_pages``) writes
+every slot's WHOLE page table out as a view, unfolds the view to
+``(.., heads, head_dim)`` and reads that twice: five passes over
+``slots x max_seq`` positions a layer, whatever each slot's length is.
+This kernel leaves the pools in HBM and copies, for slot ``i``, only pages
+``table[i, 0 .. pos[i] // page]`` into VMEM, once; a slot whose write page
+is the pool's ``no_page`` sentinel is not decoding (that is how the engine
+says so), costs one empty grid step and reads zero.
+
+**One grid step a slot, not a page.**  A step's pages arrive in copy
+GROUPS of up to ``_GROUP_ROWS`` key rows, one asynchronous copy a page
+and pool, double-buffered: before a group is computed on, the next one —
+this slot's, or the first of the next slot that decodes — is put in
+flight, so the copies of slot ``i + 1`` run under the arithmetic of slot
+``i``.  Which buffer a slot starts in is carried from step to step in
+SMEM (the grid is sequential).
+
+**The heads are never unfolded.**  The query row ``(1, h * hd)`` becomes a
+block-diagonal ``(R, h * hd)`` matrix (row ``r`` keeps head ``r``'s lanes,
+``R`` the heads rounded up to 16 sublanes), so ``Q K^T`` over a chunk of
+``(T, h * hd)`` key rows is ``(R, T)`` scores, one row a head; the online
+softmax runs on those in f32 with the same finite ``NEG_INF`` mask on
+``kpos > pos`` as everywhere; ``P V`` is ``(R, h * hd)``, of which row
+``r`` is right in head ``r``'s lanes, and the diagonal blocks are taken
+once a slot.  The ``h``-fold extra multiply-adds are noise: a decode step
+is bound by the bytes of the pages.
+
+Rows of a buffer that no copy of this group wrote (the tail past the
+slot's last live page) hold what an earlier group left there, or the
+zeros the first step stores: finite, and masked to an exact 0.0 weight.
+Pages beyond ``pos`` are never read, so a stale table entry there — a
+page another stream owns by now — costs nothing and leaks nothing.
+
+The kernel's ``name=`` is ``paged_decode_attention`` in the device trace
+(not ``flash_..``: ``perfbench/flops``' ``FLASH_KERNELS`` matches on that
+prefix and the train cells' ``flash_share`` must not learn of it).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .common import dtype_itemsize
+from .flash_kernel import _NT, LANES, NEG_INF, _dot, _interpret
+
+_HEAD_ROWS = 16             # a bf16 sublane tile: heads round up to it
+_GROUP_ROWS = 512           # key rows of one copy group, at most
+_BUFFER_BYTES = 8 << 20     # the four group buffers (K, V; two each)
+_VMEM_LIMIT = 32 << 20
+
+
+def supported(backend: str, dtype, num_heads: int, head_dim: int,
+              page_size: int, distributed: bool) -> bool:
+    """What the in-place read needs (``dtype``: the pool's, an array's
+    ``.dtype``): a TPU; a pool dtype the MXU takes;
+    the folded row a whole number of 128-lane tiles that no head straddles
+    unevenly; a page that is whole sublane tiles of the dtype (a copy
+    lands on tile boundaries) and tiles a 128-row chunk or is tiled by
+    it; and ONE device — GSPMD would all-gather a sharded pool for an
+    opaque custom call (no cell serves across chips yet: ROADMAP W6)."""
+    if backend != "tpu" or distributed or dtype not in (jnp.bfloat16,
+                                                        jnp.float32):
+        return False
+    sublanes = 8 * (4 // dtype_itemsize(dtype))
+    return ((num_heads * head_dim) % LANES == 0
+            and (LANES % head_dim == 0 or head_dim % LANES == 0)
+            and page_size % sublanes == 0
+            and (LANES % page_size == 0 or page_size % LANES == 0))
+
+
+def _geometry(page: int, pages_per_slot: int, e: int, itemsize: int):
+    """``(chunk, group)``: key rows of one product (whole pages, 128 or
+    one larger page) and of one copy group (whole chunks: no more than a
+    slot can hold, than ``_GROUP_ROWS``, than the buffers' budget)."""
+    chunk = max(page, LANES)
+    whole = -(-pages_per_slot * page // chunk) * chunk
+    fits = _BUFFER_BYTES // (4 * e * itemsize) // chunk * chunk
+    return chunk, max(chunk, min(whole, _GROUP_ROWS // chunk * chunk, fits))
+
+
+def _kernel(table_ref, pos_ref, wp_ref, q_ref, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sems, turn, *, num_heads, scale, page,
+            pages_per_slot, chunk):
+    i, slots = pl.program_id(0), pl.num_programs(0)
+    no_page = k_hbm.shape[0]
+    group = k_buf.shape[1]
+    group_pages = group // page
+    e = q_ref.shape[2]
+    head_dim = e // num_heads
+    rows = -(-num_heads // _HEAD_ROWS) * _HEAD_ROWS
+
+    def decodes(s):
+        return wp_ref[s] != no_page
+
+    def live_pages(s):
+        return jnp.minimum(pos_ref[s] // page + 1, pages_per_slot)
+
+    def next_decoding(s):
+        """The first slot at or after ``s`` that decodes; ``slots`` if
+        none does."""
+        return jax.lax.while_loop(
+            lambda j: jnp.logical_and(
+                j < slots,
+                jnp.logical_not(decodes(jnp.minimum(j, slots - 1)))),
+            lambda j: j + 1, s)
+
+    def each_page(s, g, buf, do):
+        """``do`` on the two copies (K, V) of every live page of slot
+        ``s``'s group ``g``, into buffer ``buf``."""
+        first = g * group_pages
+        count = jnp.minimum(live_pages(s) - first, group_pages)
+
+        def body(p, carry):
+            # clipped like the gather's mode="clip": a live page is never
+            # the sentinel, and a copy must not leave the pool whatever
+            pid = jnp.minimum(table_ref[s * pages_per_slot + first + p],
+                              no_page - 1)
+            dst = pl.ds(pl.multiple_of(p * page, page), page)
+            do(pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, dst],
+                                     sems.at[0, buf]))
+            do(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, dst],
+                                     sems.at[1, buf]))
+            return carry
+
+        jax.lax.fori_loop(0, count, body, 0)
+
+    def start(s, g, buf):
+        each_page(s, g, buf, lambda copy: copy.start())
+
+    def wait(s, g, buf):
+        each_page(s, g, buf, lambda copy: copy.wait())
+
+    @pl.when(i == 0)
+    def _():
+        # finite tails for the first groups (see the module's docstring)
+        k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        turn[0] = 0
+        first = next_decoding(0)
+
+        @pl.when(first < slots)
+        def _():
+            start(first, 0, 0)
+
+    @pl.when(jnp.logical_not(decodes(i)))
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(decodes(i))
+    def _():
+        pos = pos_ref[i]
+        groups = pl.cdiv(live_pages(i), group_pages)
+        buf0 = turn[0]
+        after = next_decoding(i + 1)
+        head_of_lane = jax.lax.broadcasted_iota(
+            jnp.int32, (rows, e), 1) // head_dim
+        diagonal = head_of_lane == jax.lax.broadcasted_iota(
+            jnp.int32, (rows, e), 0)
+        # selected in f32: an i32-derived mask does not lay out as bf16's
+        q_heads = jnp.where(diagonal, q_ref[0].astype(jnp.float32),
+                            0.0).astype(q_ref.dtype)
+
+        def one_group(g, carry):
+            buf = (buf0 + g) % 2
+
+            @pl.when(g + 1 < groups)
+            def _():
+                start(i, g + 1, 1 - buf)
+
+            @pl.when(jnp.logical_and(g + 1 == groups, after < slots))
+            def _():
+                start(after, 0, 1 - buf)
+
+            wait(i, g, buf)
+            base = g * group
+            chunks = pl.cdiv(jnp.minimum(pos + 1 - base, group), chunk)
+
+            def one_chunk(c, carry):
+                m, l, acc = carry
+                at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+                k, v = k_buf[buf, at, :], v_buf[buf, at, :]
+                s = _dot(q_heads, k, _NT) * scale              # (rows, T)
+                kpos = base + c * chunk + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
+                s = jnp.where(kpos > pos, NEG_INF, s)
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m - m_new)
+                return (m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
+                        alpha * acc + _dot(p.astype(v.dtype), v))
+
+            return jax.lax.fori_loop(0, chunks, one_chunk, carry)
+
+        _, l, acc = jax.lax.fori_loop(
+            0, groups, one_group,
+            (jnp.full((rows, 1), NEG_INF, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32),
+             jnp.zeros((rows, e), jnp.float32)))
+        turn[0] = (buf0 + groups) % 2
+        o_ref[0] = jnp.sum(jnp.where(diagonal, acc / l, 0.0), axis=0,
+                           keepdims=True)
+
+
+# jitted so that the equal-shaped layers of a model share ONE traced and
+# lowered kernel (flash_kernel.py: tracing it per layer cost 3.5 s of set-up)
+@functools.partial(jax.jit, static_argnums=(6, 7))
+def paged_decode_attention(q, k_pool, v_pool, table, pos, write_pages,
+                           num_heads: int, scale: float):
+    """``q``: (slots, h * hd), each slot's current-token query, folded;
+    ``k_pool`` / ``v_pool``: (num_pages, page, h * hd), the new rows
+    already written; ``table``: (slots, pages_per_slot) int32; ``pos``:
+    (slots,) int32 position of the current token; ``write_pages``:
+    (slots,) int32, ``num_pages`` where a slot is not decoding ->
+    (slots, h * hd) f32, folded, zero for such a slot.  The caller checks
+    :func:`supported`."""
+    slots, e = q.shape
+    page, pages_per_slot = k_pool.shape[1], table.shape[1]
+    chunk, group = _geometry(page, pages_per_slot, e, k_pool.dtype.itemsize)
+    row = pl.BlockSpec((1, 1, e), lambda i, *_: (i, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    params = None if _interpret() else pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT)
+    out = pl.pallas_call(
+        functools.partial(_kernel, num_heads=num_heads, scale=scale,
+                          page=page, pages_per_slot=pages_per_slot,
+                          chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(slots,),
+            in_specs=[row, pool, pool], out_specs=row,
+            scratch_shapes=[pltpu.VMEM((2, group, e), k_pool.dtype),
+                            pltpu.VMEM((2, group, e), v_pool.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((slots, 1, e), jnp.float32),
+        compiler_params=params, interpret=_interpret(),
+        name="paged_decode_attention",
+    )(table.reshape(-1), pos, write_pages, q[:, None, :], k_pool, v_pool)
+    return out[:, 0, :]

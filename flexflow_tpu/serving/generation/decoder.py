@@ -22,10 +22,12 @@ derives both halves from the layer list itself:
   batch: embed the current token per slot, run every layer's
   single-position path, scatter K/V at each slot's
   ``(write_page, write_row)`` (host-computed; the pool's ``no_page``
-  sentinel drops inactive/prefilling slots' writes), gather each
-  slot's page table and attend, argmax the next token.  The cache
-  pytree is donated, so XLA updates the (potentially multi-GB) pools
-  in place.
+  sentinel drops inactive/prefilling slots' writes), attend over each
+  slot's pages — read in place by the paged decode kernel where the
+  attention op can use it, else gathered into a view first
+  (:meth:`GraphDecoder.decode_attention` says which) — argmax the next
+  token.  The cache pytree is donated, so XLA updates the (potentially
+  multi-GB) pools in place.
 
 Pool geometry and sharding come from
 :mod:`flexflow_tpu.analysis.kv_memory` — the SAME module the static
@@ -610,6 +612,19 @@ class GraphDecoder:
         return {key: count_copies(
                     fn.lower(*args).compile().as_text(), leaves)
                 for key, fn, args in self._program_specs(device)}
+
+    def decode_attention(self) -> Dict[str, int]:
+        """How many attention ops of the graph got which decode core when
+        a token step was last traced (``jit_decode``, ``jit_decode_s``,
+        the ``jit_draft.<γ>`` scan): ``{"paged", "gathered"}`` —
+        ``"paged"`` reads the pool in place
+        (:mod:`flexflow_tpu.ops.paged_decode_kernel`), ``"gathered"``
+        writes each slot's page table out as a view first.  Noted by the
+        op at trace time (``MultiHeadAttention.decode_core``), like
+        ``FFModel.attention_kernels()``; all zero before a token step is
+        traced."""
+        cores = [getattr(op, "decode_core", None) for op in self.model.layers]
+        return {core: cores.count(core) for core in ("paged", "gathered")}
 
     # ---- shared-instance registry --------------------------------------
     @classmethod

@@ -1140,7 +1140,8 @@ class GenerationEngine:
                "prefill_chunk": self.prefill_chunk,
                "admission": self.admission,
                "max_queue_requests": self.max_queue_requests,
-               "peak_queue_requests": self._batcher.peak_rows}
+               "peak_queue_requests": self._batcher.peak_rows,
+               "decode_attention": self._decoder.decode_attention()}
         if self._pool_copies is not None:
             out["pool_copies"] = self._pool_copies
         return out

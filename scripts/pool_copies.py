@@ -4,7 +4,9 @@ KV pool: builds the causal LM of one benchmark configuration with the
 slots of one traffic mix, warms the engine up as the benchmark does, and
 prints ``engine.pool_copies()`` — for every compiled serving program the
 ``copy`` operations whose element count is a pool leaf's (0 everywhere is
-the stored form holding; docs/observability.md "pool_copies").
+the stored form holding; docs/observability.md "pool_copies") — and
+``engine.stats()["decode_attention"]``, the decode core each attention op
+got (``paged`` reads the pool in place).
 
     python3 scripts/pool_copies.py                      # the gpt1 serve cell
     python3 scripts/pool_copies.py --draft-layers 2     # speculative: + verify, draft
@@ -58,6 +60,7 @@ def main(argv=None) -> int:
     with fflogger.silenced("serve"):
         with ff.GenerationEngine(build(), slots=slots, **spec) as engine:
             out["programs"] = engine.pool_copies()
+            out["decode_attention"] = engine.stats()["decode_attention"]
     print(json.dumps(out, indent=1))
     return int(any(v["count"] for v in out["programs"].values()))
 

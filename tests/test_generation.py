@@ -1668,6 +1668,168 @@ def test_train_step_holds_no_flash_wrapper_tpu(v5e_device, monkeypatch,
     assert "flash_attention_fwd" in text or not clean
 
 
+# ---------------------------------------------------------------------
+# decode attention reads the pages where they lie (ISSUE 30)
+# ---------------------------------------------------------------------
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                       ("bfloat16", 1e-2)])
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_paged_decode_kernel_matches_gathered_decode(head_dim, dtype, tol):
+    """The kernel (Pallas interpret mode on the CPU) against
+    ``_decode_attention`` on the gathered, unfolded view: slots at
+    position 0, a page's last row, a page's first row, inside the SECOND
+    copy group (640 positions a slot, 512 a group) and ``max_seq - 1``,
+    and one slot at the ``no_page`` sentinel, which reads zero.  Every
+    page beyond a slot's position — stale table entries, another
+    stream's pages by now — is NaN in the pools the kernel gets: read,
+    it would show.  Tolerance: f32 differs by the order of the online
+    softmax's sums (1e-5); bf16 by one rounding of an unnormalised
+    weight to 8 bits where the gathered path rounds the normalised one
+    (2 ** -8 a term on values of order 1: 1e-2)."""
+    from flexflow_tpu.ops.attention import _decode_attention
+    from flexflow_tpu.ops.paged_decode_kernel import paged_decode_attention
+
+    heads, page, pps = 2, 16, 40
+    e, max_seq = heads * head_dim, page * pps
+    pos = np.array([0, page - 1, page, 530, 77, max_seq - 1], np.int32)
+    slots, idle = len(pos), 4
+    rng = np.random.default_rng(head_dim)
+    num_pages = slots * pps + 3
+    table = rng.permutation(num_pages)[:slots * pps].reshape(
+        slots, pps).astype(np.int32)
+    write_pages = table[np.arange(slots), pos // page]
+    write_pages[idle] = num_pages
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), dtype)
+               for shape in ((slots, e), (num_pages, page, e),
+                             (num_pages, page, e)))
+
+    def view(pool):
+        return jnp.take(pool, table, axis=0).reshape(slots, max_seq,
+                                                     heads, head_dim)
+
+    scale = 1.0 / np.sqrt(head_dim)
+    want = _decode_attention(q.reshape(slots, 1, heads, head_dim), view(k),
+                             view(v), jnp.asarray(pos), scale)
+    stale = np.ones(num_pages, bool)
+    for i in range(slots):
+        if i != idle:
+            stale[table[i, :pos[i] // page + 1]] = False
+    poison = jnp.asarray(stale)[:, None, None]
+    got = paged_decode_attention(
+        q, jnp.where(poison, jnp.nan, k), jnp.where(poison, jnp.nan, v),
+        jnp.asarray(table), jnp.asarray(pos), jnp.asarray(write_pages),
+        heads, scale)
+    assert got.dtype == jnp.float32 and got.shape == (slots, e)
+    decoding = np.arange(slots) != idle
+    np.testing.assert_allclose(
+        np.asarray(got)[decoding],
+        np.asarray(want).reshape(slots, e)[decoding], rtol=tol, atol=tol)
+    assert np.all(np.asarray(got)[idle] == 0.0)
+
+
+@pytest.mark.parametrize("why,args", [
+    ("the serve cell", ("tpu", "bfloat16", 12, 64, 16, False)),
+    ("f32, one head a tile", ("tpu", "float32", 8, 128, 8, False)),
+    ("four heads a tile, a page of two chunks", ("tpu", "bfloat16", 8, 32,
+                                                  256, False)),
+    ("not a TPU", ("cpu", "bfloat16", 12, 64, 16, False)),
+    ("a sharded pool", ("tpu", "bfloat16", 12, 64, 16, True)),
+    ("a dtype the MXU does not take", ("tpu", "float16", 12, 64, 16, False)),
+    ("rows that fill no lane tile", ("tpu", "float32", 2, 16, 8, False)),
+    ("a head that straddles tiles", ("tpu", "float32", 4, 96, 8, False)),
+    ("a bf16 page of half a tile", ("tpu", "bfloat16", 12, 64, 8, False)),
+    ("a page that tiles no chunk", ("tpu", "float32", 12, 64, 24, False)),
+], ids=lambda x: x if isinstance(x, str) else "")
+def test_paged_decode_supported_reads_what_the_code_can_see(why, args):
+    from flexflow_tpu.ops.paged_decode_kernel import supported
+    backend, dtype, *rest = args
+    assert supported(backend, jnp.dtype(dtype), *rest) == (why in (
+        "the serve cell", "f32, one head a tile",
+        "four heads a tile, a page of two chunks")), why
+
+
+def _view_sized(text, elements):
+    """The instructions of a compiled module's text whose result holds
+    ``elements`` elements."""
+    found = []
+    for m in re.finditer(r"^\s*(?:ROOT\s+)?%\S+\s*=\s*\(?\s*\w+"
+                         r"\[(?P<dims>[\d,]*)\]", text, re.M):
+        if int(np.prod([int(d) for d in m.group("dims").split(",") if d]
+                       or [1])) == elements:
+            found.append(m.group(0).strip())
+    return found
+
+
+def test_decode_reads_the_pool_in_place_tpu(v5e_device, monkeypatch):
+    """``jit_decode`` and the ``jit_draft.4`` scan in bf16, compiled by
+    the TPU's compiler with the backend steered as the chip would answer:
+    each holds the ``paged_decode_attention`` kernel, NO instruction of
+    the gathered view's element count (slots x pages_per_slot x page x
+    heads x head_dim: the gather and its unfold are gone) and no
+    pool-sized copy; the prompt chunk and the verify window gather as
+    before (one slot's view, a window's: docs/serving.md says why).  5
+    slots and d_ff 192, so that no weight has the view's element count;
+    the same programs traced WITHOUT the steering hold the view, so the
+    reader reads.  The counter says which core was traced, on the decoder and
+    in an engine's ``stats()``."""
+    from flexflow_tpu.ops import attention as attn_mod
+
+    slots, num_pages = 5, 8192
+    model = _build_lm(slots=slots, num_layers=1, d_model=128, d_ff=192,
+                      compute_dtype="bfloat16")
+
+    def built(dec):
+        dec.decode_fn(), dec.prefill_fn(16), dec.verify_fn(4), dec.draft_fn(4)
+        return dec
+
+    def compiled_texts(dec):
+        with _no_compilation_cache():
+            return _within(_COMPILE_LIMIT_S, lambda: {
+                key: fn.lower(*args).compile().as_text()
+                for key, fn, args in dec._program_specs(v5e_device)})
+
+    # a decoder of its own: a program is traced once, as the backend
+    # answered then
+    control = built(GraphDecoder(model, slots, SEQ, num_pages=num_pages))
+    view = slots * control.pages_per_slot * control.page_size * 128
+    gathered = compiled_texts(control)
+    assert control.decode_attention() == {"paged": 0, "gathered": 1}
+    assert all(_view_sized(gathered[key], view)
+               for key in ("jit_decode", "jit_draft.4", "jit_verify.4"))
+    assert not any("paged_decode_attention" in t for t in gathered.values())
+
+    monkeypatch.setattr(attn_mod.jax, "default_backend", lambda: "tpu")
+    dec = built(GraphDecoder.for_model(model, slots, SEQ,
+                                       num_pages=num_pages))
+    paged = compiled_texts(dec)
+    assert dec.decode_attention() == {"paged": 1, "gathered": 0}
+    for key in ("jit_decode", "jit_draft.4"):
+        assert "paged_decode_attention" in paged[key], key
+        assert _view_sized(paged[key], view) == [], key
+    for key in ("jit_prefill.16", "jit_verify.4"):
+        assert "paged_decode_attention" not in paged[key], key
+    assert _view_sized(paged["jit_verify.4"], view)
+    with _no_compilation_cache():
+        got = _within(_COMPILE_LIMIT_S,
+                      lambda: dec.pool_copies(device=v5e_device))
+    assert set(got) == set(paged)
+    assert all(v == {"count": 0, "bytes": 0} for v in got.values()), got
+    # an engine on this geometry shares the decoder; never started here
+    eng = GenerationEngine(model, slots=slots, num_pages=num_pages)
+    assert eng.stats()["decode_attention"] == {"paged": 1, "gathered": 0}
+
+
+def test_gen_stats_carry_decode_attention(lm, prompts):
+    """On the CPU every attention op of the graph decodes over the
+    gathered view, and ``stats()`` says so once a token step is
+    traced."""
+    eng = GenerationEngine(lm, slots=2, max_new_tokens=3)
+    with eng:
+        eng.submit(prompts[0]).result(timeout=120)
+        assert eng.stats()["decode_attention"] == {"paged": 0,
+                                                   "gathered": 2}
+
+
 def test_engine_stats_carry_pool_copies_once_asked(lm, draft_lm, prompts):
     """pool_copies is ON DEMAND: absent from stats() until asked, then
     the target's programs by name and the draft's under draft/."""
