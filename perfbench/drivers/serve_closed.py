@@ -15,6 +15,13 @@ seconds untimed (set-up), then the window opens for exactly ``--seconds``:
   window, client-side time from ``submit()`` to the first token out of the
   stream iterator; a failed request counts as never answered.
 
+The same three numbers are printed for each of the window's sub-windows of
+about ``SUBWINDOW_S`` seconds (never fewer than two): whether runs differ
+inside themselves, which a longer window averages, or process from process,
+which it does not.  A traced run also counts, from the clients' records alone,
+the work the traced window asked of the model (``traced_work``), for the
+readers of the kernel's roofline share and of the step's share of the peak.
+
 When the window closes the clients stop submitting and the engine drains:
 every request submitted inside the window is ``attempted`` and has
 ``drain_s`` to end.  A request fails if it raises, delivers another number of
@@ -172,6 +179,50 @@ def measure(load, t0, t1):
             "failed": failed, "ttft": ttft, "finished": finished}
 
 
+SUBWINDOW_S = 10.0
+
+
+def window_numbers(m, seconds):
+    """The three end-to-end numbers of ``measure``'s records."""
+    nan = float("nan")
+    return {"serve_tokens_per_s": m["tokens"] / seconds,
+            "ttft_p95_ms": 1e3 * (quantile(m["ttft"], 0.95) or nan),
+            "itl_p95_ms": 1e3 * (quantile(m["gaps"], 0.95) or nan)}
+
+
+def subwindows(load, t0, t1):
+    """``[(from_s, to_s, numbers, ttft samples, gaps)]`` for the window cut
+    into equal parts of about ``SUBWINDOW_S`` seconds, two at the least."""
+    n = max(2, round((t1 - t0) / SUBWINDOW_S))
+    edges = [t0 + (t1 - t0) * i / n for i in range(n)] + [t1]
+    out = []
+    for a, b in zip(edges, edges[1:]):
+        m = measure(load, a, b)
+        out.append((a - t0, b - t0, window_numbers(m, b - a),
+                    len(m["ttft"]), len(m["gaps"])))
+    return out
+
+
+def traced_work(load, t0, t1):
+    """What the traffic asked of the model between ``t0`` and ``t1``, from
+    the clients' records alone.  A stream's token ``j >= 1`` comes out of a
+    decode step that attends over the prompt and the ``j`` tokens before it;
+    its token 0 comes out of the prompt's prefill."""
+    decode_tokens = live_positions = 0
+    prompt_lens = []
+    for r in load.requests:
+        for j, t in enumerate(r.t_tokens):
+            if not t0 <= t < t1:
+                continue
+            if j == 0:
+                prompt_lens.append(len(r.prompt))
+            else:
+                decode_tokens += 1
+                live_positions += len(r.prompt) + j
+    return {"decode_tokens": decode_tokens,
+            "live_positions": live_positions, "prompt_lens": prompt_lens}
+
+
 def sample_finished(finished, n, seed):
     """``n`` finished requests drawn from the seed, the longest among them."""
     if not finished:
@@ -232,8 +283,10 @@ def run(ctx, devs):
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             jax.profiler.start_trace(ctx.trace_dir, profiler_options=opts)
+            t_annotated = time.perf_counter()
             with jax.profiler.TraceAnnotation("pb.traced_window"):
                 time.sleep(float(tr["trace_s"]))
+            t_traced = (t_annotated, time.perf_counter())
             jax.profiler.stop_trace()
         time.sleep(max(0.0, t1 - time.perf_counter()))
         stuck = load.drain(float(tr["drain_s"]))
@@ -258,12 +311,14 @@ def run(ctx, devs):
             f"{1e3 * (median(m['gaps']) or 0):.2f} ms; closed loop late by "
             f"p50 {1e3 * median(late):.3f} ms max {1e3 * max(late):.3f} ms; "
             f"client threads still alive {stuck}")
-    nan = float("nan")
-    e2e = {"serve_tokens_per_s": m["tokens"] / ctx.seconds,
-           "itl_p95_ms": 1e3 * (quantile(m["gaps"], 0.95) or nan),
-           "ttft_p50_ms": 1e3 * (median(m["ttft"]) or nan),
-           "ttft_p95_ms": 1e3 * (quantile(m["ttft"], 0.95) or nan)}
+    for a, b, sub, n_ttft, n_gaps in subwindows(load, t0, t1):
+        ctx.say(f"sub-window {a:.2f}-{b:.2f} s: " + ", ".join(
+            f"{k} {v!r}" for k, v in sub.items())
+            + f" ({n_ttft} ttft samples, {n_gaps} gaps)")
+    e2e = window_numbers(m, ctx.seconds)
     ctx.counters["ttft_ms"] = [1e3 * t for t in m["ttft"]]
+    if ctx.trace:
+        ctx.counters["traced_work"] = traced_work(load, *t_traced)
 
     # ---- the comparison, outside the window and outside set-up ---------
     del engine, model

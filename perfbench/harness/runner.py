@@ -164,8 +164,7 @@ def run_cell(root, workload, seed, seconds, trace, t_start,
               "count": len(devs),
               "memory_peak_bytes": out["memory_peak_bytes"]}
     result = {"correct": decide(ctx, out["numbers"]),
-              "attempted": out["attempted"], "failed": out["failed"],
-              "compared": [list(n) for n in out["numbers"]]}
+              "attempted": out["attempted"], "failed": out["failed"]}
     if not ctx.trace:
         values = dict(out["end_to_end"], setup_s=setup_s)
         metrics = {}
@@ -201,7 +200,9 @@ def run_cell(root, workload, seed, seconds, trace, t_start,
             result["breakdown"] = {
                 "device_ops": xtrace.top_ops(trace_doc),
                 "idle_gaps": xtrace.idle_gaps(trace_doc, gaps)}
-    result.update(metrics=metrics, device=device)
+    # each number compared beside its limit comes last in the line
+    result.update(metrics=metrics, device=device,
+                  compared=[list(n) for n in out["numbers"]])
     return result
 
 
@@ -218,4 +219,8 @@ def main(argv, t_start, root=None):
                       args.trace, t_start)
     sys.stdout.flush()
     print(json.dumps(result), flush=True)
+    # and as the last lines of standard error
+    for name, value, limit in result["compared"]:
+        print(f"compare {name}: {value!r} (limit {limit!r})", file=sys.stderr)
+    sys.stderr.flush()
     return 0

@@ -5,6 +5,7 @@ reported tail is a value that was measured)."""
 from __future__ import annotations
 
 import math
+import statistics
 
 
 def quantile(values, q):
@@ -36,3 +37,23 @@ def union_ns(intervals):
     if cur_e is not None:
         busy += cur_e - cur_s
     return busy, gaps
+
+
+def spread_less_farthest(values):
+    """The spread the driver's check holds a bound against: the range of the
+    runs with the one farthest from their median left out, over the median
+    of all of them."""
+    vs = sorted(values)
+    if len(vs) < 3:
+        raise ValueError("a spread wants three runs or more")
+    mid = statistics.median(vs)
+    rest = vs[1:] if mid - vs[0] >= vs[-1] - mid else vs[:-1]
+    return (rest[-1] - rest[0]) / mid
+
+
+def spread_quartiles(values):
+    """The spread a bound is set from: the distance between the first and
+    the third quartile (``statistics.quantiles(values, n=4)``) over the
+    median."""
+    q1, _, q3 = statistics.quantiles(values, n=4)
+    return (q3 - q1) / statistics.median(values)

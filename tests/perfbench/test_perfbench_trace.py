@@ -89,12 +89,124 @@ def test_mfu_and_flash_roofline_divide_the_benchmarks_count_by_the_peak():
         100 * (2 * 50 * 2 / 2) / 1e12 / 400e-9)
 
 
+GPT1 = {"layers": 12, "d_model": 768, "d_ff": 3072, "vocab": 40478,
+        "causal": True}
+
+
+def _serve_flops():
+    cell = cells.load(pb_tiny.REPO, "gpt1.serve.closed-128")
+    fam = cell.module("families", cell.config["family"])
+    assert {k: fam.sizes(cell.config)[k] for k in GPT1} == GPT1
+    assert cell.config["run"]["compute_dtype"] == "bfloat16"
+    return cell, cell.module("flops", fam.FLOPS)
+
+
+def test_the_kv_bytes_and_the_serving_operations_counted_by_hand():
+    _, flops = _serve_flops()
+    # one position: K and V, 12 layers x 768 wide x 2 bytes = 36 864 bytes;
+    # the whole pool, 128 slots x 512 positions, is the cell's 2.4 GB
+    assert flops.decode_kv_bytes(GPT1, 1, 2) == 36_864
+    assert flops.decode_kv_bytes(GPT1, 128 * 512, 2) == 2_415_919_104
+    assert flops.decode_kv_bytes(GPT1, 100, 4) == 2 * 36_864 * 100
+    assert flops.ITEMSIZE["bfloat16"] == 2
+    # a decoded token: 12 x (8 d^2 + 4 d d_ff) in the layers, 2 d vocab in
+    # the head, and 4 d operations a layer for each position it attends over
+    dense, head = 12 * (8 * 768 ** 2 + 4 * 768 * 3072), 2 * 768 * 40478
+    assert (dense, head) == (169_869_312, 62_174_208)
+    assert flops.serve_flops(GPT1, 1, 0, []) == dense + head
+    assert flops.serve_flops(GPT1, 3, 500, []) == \
+        3 * (dense + head) + 12 * 4 * 768 * 500
+    # a prompt of 100: its tokens through the layers as a causal sequence
+    # (2 s d a token and layer for the scores and values), one head
+    assert flops.serve_flops(GPT1, 0, 0, [100]) == \
+        100 * (dense + 12 * 2 * 100 * 768) + head
+    assert flops.serve_flops(GPT1, 3, 500, [100, 100]) == \
+        flops.serve_flops(GPT1, 3, 500, []) \
+        + 2 * flops.serve_flops(GPT1, 0, 0, [100])
+
+
+# a traced serve window of 2 000 ns: two decode steps whose kernel ran
+# 3 x 100 ns inside the window (a fourth call began before it), over 50
+# live positions in all
+SERVE_TRACE = {
+    "devices": {0: {
+        "ops": [["paged_decode_attention.1", 900, 150],
+                ["paged_decode_attention.1", 1100, 100],
+                ["fusion.7", 1200, 300],
+                ["paged_decode_attention.2", 1500, 100],
+                ["paged_decode_attention.1", 2100, 100],
+                ["reshape.4", 2200, 50]],
+        "modules": [["jit_decode(5)", 1100, 600], ["jit_decode(5)", 2100, 200]]}},
+    "host": [["pb.traced_window", 1000, 2000]]}
+
+
+def _serve_obs(counters, peaks):
+    cell, flops = _serve_flops()
+    obs = _obs(SERVE_TRACE, dict(counters, chips=1), peaks=peaks, sizes=GPT1,
+               flops=flops)
+    obs.cell = cell
+    return obs
+
+
+def test_paged_decode_roofline_and_serve_mfu_on_a_hand_made_serve_trace():
+    work = {"decode_tokens": 4, "live_positions": 50, "prompt_lens": [100]}
+    peaks = {"bf16_flops": 1e15, "hbm_bytes_per_s": 1e13}
+    obs = _serve_obs({"traced_work": work}, peaks)
+    # 50 positions x 36 864 bytes over 1e13 bytes/s is 184.32 ns of the
+    # kernel's 300 ns inside the window
+    assert _reader("paged_decode_roofline")(obs) == pytest.approx(
+        100 * 50 * 36_864 / 1e13 / 300e-9)
+    dense, head = 169_869_312, 62_174_208
+    need = 4 * (dense + head) + 12 * 4 * 768 * 50 \
+        + 100 * (dense + 12 * 2 * 100 * 768) + head
+    assert _reader("serve_mfu")(obs) == pytest.approx(
+        100 * need / 2000e-9 / 1e15)
+    # nothing to read: no traced counts, no kernel in the trace, no decoded
+    # token, a device without the peak; never a 0
+    for name in ("paged_decode_roofline", "serve_mfu"):
+        assert _reader(name)(_serve_obs({}, peaks)) is None, name
+        assert _reader(name)(_serve_obs({"traced_work": work}, {})) is None
+    assert _reader("paged_decode_roofline")(_serve_obs(
+        {"traced_work": dict(work, live_positions=0)}, peaks)) is None
+    gone = _serve_obs({"traced_work": work}, peaks)
+    gone.trace = {"devices": {0: {"ops": [["fusion.7", 1200, 300]],
+                                  "modules": []}},
+                  "host": SERVE_TRACE["host"]}
+    assert _reader("paged_decode_roofline")(gone) is None
+    assert _reader("serve_mfu")(gone) > 0      # the step's share stays
+
+
+def test_the_two_serve_shares_at_the_cells_own_arithmetic():
+    """PERF.md's figures for the cell (PR 30): 0.62 GB of live pages a step,
+    1.66 ms of the kernel a step; 5 900 tokens a second.  The readers on a
+    trace of that shape read the shares worked out by hand, inside (0, 100]."""
+    positions = 96 * 175                   # 96 decoding slots at 175 live
+    steps, kernel_ns = 180, 1_660_000
+    ops = [["paged_decode_attention.3", 1000 + i * 16_000_000, kernel_ns]
+           for i in range(steps)]
+    doc = {"devices": {0: {"ops": ops, "modules": []}},
+           "host": [["pb.traced_window", 0, steps * 16_000_000]]}
+    work = {"decode_tokens": 96 * steps, "live_positions": positions * steps,
+            "prompt_lens": [110] * steps}
+    cell, flops = _serve_flops()
+    obs = _obs(doc, {"traced_work": work, "chips": 1},
+               peaks={"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+               sizes=GPT1, flops=flops)
+    obs.cell = cell
+    roofline = _reader("paged_decode_roofline")(obs)
+    assert roofline == pytest.approx(
+        100 * positions * 36_864 / 819e9 / 1.66e-3)
+    assert 40 < roofline < 50
+    mfu = _reader("serve_mfu")(obs)
+    assert 0.5 < mfu < 2.0
+
+
 def test_a_reader_that_finds_nothing_returns_nothing():
     obs = _obs({"devices": {0: {"ops": [], "modules": []}},
                 "host": [["pb.traced_window", 0, 10]]}, {"chips": 1})
     for name in ("step_ms", "mfu", "flash_share", "flash_roofline",
                  "sim_error", "search_s", "decode_step_ms", "prefill_ms",
-                 "slot_occupancy"):
+                 "slot_occupancy", "paged_decode_roofline", "serve_mfu"):
         assert _reader(name)(obs) is None, name
 
 
