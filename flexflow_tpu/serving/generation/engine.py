@@ -13,6 +13,18 @@ every step runs ONE decode dispatch + ONE token fetch for the whole
 batch (repo_lint RL010 bans any other host sync in the loop), and a
 finished/cancelled stream frees its slot — and its pages — immediately.
 
+The owned decode loop runs ONE STEP AHEAD of the host (ISSUE 34): the
+bookkeeping of a token step (lengths, pages, retirement by
+``max_new_tokens``) advances by COUNTS when the step is dispatched, the
+next step takes its input tokens from the device's own output
+(:func:`_splice_tokens`), and a boundary's results — the step's tokens
+and the first token of the join prefilled at that boundary — come to the
+host in one fetch AFTER the next step is dispatched (``_land``), so the
+device's work and both round trips lie beside the host's phases instead
+of after them.  What needs token VALUES on the host (a speculative
+round, a disaggregated hand-off, the fleet's ``dispatch_pending``) runs
+the same boundary with nothing in flight.
+
 Three ISSUE 15 mechanisms ride on the page pool:
 
 * **Paged KV** — per-slot state is a page table of gather indices into
@@ -57,6 +69,7 @@ from concurrent.futures import Future
 from typing import Dict, List, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ... import faults
@@ -236,7 +249,8 @@ class _Slot:
 
     __slots__ = ("stream", "prompt", "pages", "draft_pages",
                  "hit_tokens", "next_pos", "chunks", "last_token",
-                 "length", "generated", "prefilling", "t_join", "t_exec")
+                 "length", "generated", "prefilling", "t_join", "t_exec",
+                 "unfetched", "done")
 
     def __init__(self, stream: GenerationStream, prompt: np.ndarray,
                  hit_pages: List[int], page_size: int, t_join: float):
@@ -249,14 +263,54 @@ class _Slot:
         self.hit_tokens = len(hit_pages) * int(page_size)
         self.next_pos = self.hit_tokens  # next prompt position to prefill
         self.chunks = 0
+        # ``length`` / ``generated`` are COUNTS and advance when a
+        # program is dispatched; ``last_token`` is a VALUE and follows
+        # when it is fetched: it is the slot's newest token only while
+        # ``unfetched`` (tokens computed for the slot that are still on
+        # the device) is 0.  ``done``: the stream was handed its end
+        # (finished, failed, cancelled) — what is still in flight for it
+        # is dropped.
         self.last_token = 0
         self.length = 0     # positions materialized in the cache
         self.generated = 0
+        self.unfetched = 0
+        self.done = False
         self.prefilling = True
         self.t_join = t_join
         # dispatch instant of the slot's FIRST prefill chunk — read only
         # while tracing (it splits `prefill` into wait and work)
         self.t_exec: Optional[float] = None
+
+
+class _Flight:
+    """What ONE step boundary left on the device until its one fetch
+    (``GenerationEngine._land``): the token step's ``nxt`` with the
+    slots it advanced (``rows``: ``(slot, _Slot, last)``, ``last`` = the
+    stream retires on this token by ``max_new_tokens``, decided by count
+    at dispatch), and the join's ``first`` token (``join``: ``(slot,
+    _Slot, last, bucket, fn)``)."""
+
+    __slots__ = ("nxt", "rows", "fn", "t0", "step", "first", "join")
+
+    def __init__(self):
+        self.nxt = self.fn = self.first = self.join = None
+        self.rows: List = []
+        self.t0 = 0.0
+        self.step = 0
+
+    def __bool__(self) -> bool:
+        return self.nxt is not None or self.join is not None
+
+
+@jax.jit
+def _splice_tokens(prev, host_tokens, from_host, first, join_slot):
+    """The next token step's input, made ON the device: the last step's
+    output ``prev`` where a slot's newest token has not reached the host,
+    ``host_tokens`` where it has (``from_host``; 0 for a slot that does
+    not decode, as before), and the joined slot's ``first`` token at
+    ``join_slot`` (``slots`` = no join: the write drops)."""
+    tokens = jnp.where(from_host, host_tokens, prev)
+    return tokens.at[join_slot].set(first, mode="drop")
 
 
 class GenerationMetrics(ServingMetrics):
@@ -586,9 +640,22 @@ class GenerationEngine:
         self._caches = None
         self._n_steps = 0
         # step boundaries begun, and the ONE read of `tracer.active`
-        # each boundary makes for all its phases (_begin_boundary)
+        # each boundary makes for all its phases (_run_boundary)
         self._boundary = 0
         self._traced = False
+        # the one-step-ahead pipeline: what this boundary is leaving on
+        # the device (`_cur`), what the last one left there and nobody
+        # has fetched (`_inflight`: at most ONE boundary, depth one), and
+        # the device's own arrays the next splice starts from (the last
+        # step's output, the last join's first token)
+        self._cur = _Flight()
+        self._inflight: Optional[_Flight] = None
+        self._landing: List[_Flight] = []   # being fetched right now
+        self._prev_tokens = None
+        self._prev_first = None
+        self._pipe_ahead = 0
+        self._pipe_drained: Dict[str, int] = {}
+        self._pipe_dropped = 0
         # the open `generate.turn` phase: from the end of a decode's
         # deliver to the next boundary's begin (_open_turn)
         self._turn = None
@@ -722,18 +789,29 @@ class GenerationEngine:
         params = self._params
         no_table = np.full((self._decoder.pages_per_slot,),
                            self._pool.no_page, np.int32)
+        first = None
         for b in self._decoder.buckets:
             fn = self._decoder.prefill_fn(b)
             tokens = np.zeros((1, b), np.int32)
-            _, self._caches = fn(params, self._caches, tokens, no_table,
-                                 np.int32(0), np.int32(0), np.int32(1))
-        nxt, self._caches = self._decoder.decode_fn()(
-            params, self._caches, np.zeros((self.slots,), np.int32),
-            np.zeros((self.slots,), np.int32),
-            np.full((self.slots, self._decoder.pages_per_slot),
-                    self._pool.no_page, np.int32),
-            np.full((self.slots,), self._pool.no_page, np.int32),
-            np.zeros((self.slots,), np.int32))
+            first, self._caches = fn(params, self._caches, tokens, no_table,
+                                     np.int32(0), np.int32(0), np.int32(1))
+        # the token step the way the loop calls it: its tokens spliced ON
+        # the device from a join's first token and the last step's output
+        # (a host array in their place is another program to XLA's cache,
+        # and a compile inside the serving window).  The first splice has
+        # no step behind it yet; the second is the loop's own.
+        self._prev_first = first
+        nobody = (np.zeros((self.slots,), np.int32),
+                  np.ones((self.slots,), bool))
+        for _ in range(2):
+            nxt, self._caches = self._decoder.decode_fn()(
+                params, self._caches, self._spliced(*nobody),
+                np.zeros((self.slots,), np.int32),
+                np.full((self.slots, self._decoder.pages_per_slot),
+                        self._pool.no_page, np.int32),
+                np.full((self.slots,), self._pool.no_page, np.int32),
+                np.zeros((self.slots,), np.int32))
+            self._prev_tokens = nxt
         jax.device_get(nxt)
         if self._spec_on:
             self._warmup_spec()
@@ -948,22 +1026,18 @@ class GenerationEngine:
         by at most one chunk, and advance every active stream one
         token.  Returns the wall seconds spent — the device-time the
         fleet's fair scheduler charges this tenant — or None when
-        nothing was due.  Error containment matches the owned decode
-        loop (a poisoned step fails the active streams, the engine
-        keeps serving)."""
+        nothing was due.  It is the owned loop's boundary
+        (:meth:`_run_boundary`) with NOTHING left in flight: the step it
+        dispatched is fetched and delivered before it returns, so the
+        seconds charged hold the whole step and the fleet never has to
+        come back for tokens.  Error containment is the boundary's own
+        (a poisoned step fails the active streams, the engine keeps
+        serving)."""
         t0 = self.clock()
-        adopted, progressed = self._begin_boundary()
-        if not any(s is not None and not s.prefilling
-                   for s in self._slots_state):
+        adopted, progressed, stepped = self._run_boundary(ahead=False)
+        if not stepped:
             return (max(0.0, self.clock() - t0)
                     if progressed or adopted else None)
-        self._fire_slow_decode()
-        try:
-            self._step_active()
-        except BaseException as e:  # noqa: BLE001 — same containment
-            # as _decode_loop: the step's failure is the streams', not
-            # the fleet dispatcher's
-            self._recover_from_dispatch_error(e, "gen_decode_error")
         self._close_turn()   # what follows is the fleet's time
         return max(0.0, self.clock() - t0)
 
@@ -1141,37 +1215,33 @@ class GenerationEngine:
                "admission": self.admission,
                "max_queue_requests": self.max_queue_requests,
                "peak_queue_requests": self._batcher.peak_rows,
-               "decode_attention": self._decoder.decode_attention()}
+               "decode_attention": self._decoder.decode_attention(),
+               # the one-step-ahead pipeline (docs/observability.md):
+               # token steps dispatched before the previous step's
+               # tokens were on the host, how often and why it ran
+               # empty, tokens computed for a stream that had ended
+               "decode_pipeline": {
+                   "ahead": self._pipe_ahead,
+                   "drained": dict(self._pipe_drained),
+                   "dropped_tokens": self._pipe_dropped}}
         if self._pool_copies is not None:
             out["pool_copies"] = self._pool_copies
         return out
 
     # ---- dispatcher thread ---------------------------------------------
     def _decode_loop(self) -> None:
-        """One iteration per decode step: admit queued prompts into
-        free slots, advance prefill by AT MOST one chunk (the
-        decode-stall cap), then advance every active stream by one
-        token with ONE dispatch + ONE fetch (RL010)."""
+        """One iteration per step boundary (:meth:`_run_boundary`), one
+        step ahead of the host; with nothing to decode, nothing in
+        flight and no prefill under way, wait for a prompt."""
         try:
             while True:
                 if self._abort.is_set():
                     self._abort_active()
                     return
-                _, progressed = self._begin_boundary()
-                if any(s is not None and not s.prefilling
-                       for s in self._slots_state):
-                    self._fire_slow_decode()
-                    try:
-                        self._step_active()
-                    except BaseException as e:  # noqa: BLE001 — one
-                        # poisoned step must fail the ACTIVE streams, not
-                        # kill the dispatcher; queued prompts still served
-                        self._recover_from_dispatch_error(e,
-                                                          "gen_decode_error")
-                    continue
-                if progressed or any(s is not None
-                                     for s in self._slots_state):
-                    continue  # prefill still in flight: keep chunking
+                _, progressed, stepped = self._run_boundary(ahead=True)
+                if stepped or progressed or any(
+                        s is not None for s in self._slots_state):
+                    continue  # decoding, or prefill still in flight
                 with self._phase("generate.idle"):
                     reqs = self._batcher.next_batch(timeout=0.05)
                 if reqs:
@@ -1210,11 +1280,30 @@ class GenerationEngine:
         if turn is not None:
             turn.__exit__(None, None, None)
 
-    def _begin_boundary(self):
-        """The host work every step boundary starts with, for the owned
-        decode loop and the fleet's ``dispatch_pending`` alike: expire,
-        adopt, admit, advance prefill by at most one chunk, grow the
-        active slots' pages.  Returns ``(adopted, progressed)``."""
+    def _run_boundary(self, ahead: bool):
+        """ONE step boundary, the body of the owned decode loop
+        (``ahead`` True) and of the fleet's ``dispatch_pending`` (False):
+        expire, adopt, admit; advance prefill by AT MOST one chunk (the
+        decode-stall cap); grow the active slots' pages; dispatch ONE
+        token step for the whole batch; then the boundary's ONE fetch
+        (:meth:`_land`, RL010) and the hand-over to the streams.
+
+        ``ahead`` is how many token steps may stay in flight past that
+        fetch, 1 or 0.  One ahead, the fetch brings the PREVIOUS
+        boundary's results (its step's tokens, its join's first token)
+        and this boundary's stay on the device until the next one has
+        dispatched its step: the device computes and the clients wake
+        beside the host's phases, not after them.  What the step kind
+        itself needs decides the same way, not a switch: a speculative
+        round places its next window by the accept counts, so it runs
+        with nothing in flight; a boundary with no slot left to decode
+        has nothing to hide its fetch behind and takes it at once.
+
+        The device sees the programs in the order the host dispatches
+        them (step n, boundary n+1's prefill chunk, step n+1), so a page
+        released BY COUNT when step n is dispatched can only be handed
+        to a program that runs after the last one that reads or writes
+        it.  Returns ``(adopted, progressed, stepped)``."""
         self._close_turn()
         self._boundary += 1
         # ONE lock-free tracing check per step boundary, handed down to
@@ -1231,7 +1320,66 @@ class GenerationEngine:
         progressed = self._prefill_step()
         with self._phase("generate.grow_pages"):
             self._grow_active_pages()
-        return adopted, progressed
+        stepped = any(s is not None and not s.prefilling
+                      for s in self._slots_state)
+        try:
+            if not stepped:
+                self._drain("idle")
+            elif self._spec_active():
+                self._fire_slow_decode()
+                self._drain("speculation")
+                self._spec_decode_once()
+            else:
+                self._fire_slow_decode()
+                self._decode_once(ahead)
+        except BaseException as e:  # noqa: BLE001 — one poisoned step
+            # must fail the ACTIVE streams (and those whose tokens were
+            # still in flight), not kill the dispatcher; queued prompts
+            # are still served
+            self._recover_from_dispatch_error(e, "gen_decode_error")
+        return adopted, progressed, stepped
+
+    def _drain(self, reason: str) -> None:
+        """Empty the pipeline: fetch and deliver whatever the last
+        boundary and this one left on the device, with no step dispatched
+        to hide the fetch behind.  Counted by ``reason`` when a token
+        step was in flight (``stats()["decode_pipeline"]["drained"]``)."""
+        prior, self._inflight = self._inflight, None
+        cur, self._cur = self._cur, _Flight()
+        if prior is not None:
+            self._pipe_drained[reason] = \
+                self._pipe_drained.get(reason, 0) + 1
+        self._land(prior, cur)
+
+    def _land(self, *flights) -> None:
+        """THE one host sync of a step boundary (RL010): ONE
+        ``device_get`` for everything ``flights`` (oldest first) left on
+        the device — a step's tokens for the whole batch, a join's first
+        token — then the hand-over to the streams, first tokens before
+        step tokens so that a stream's tokens keep their order.  The
+        clients wake here, straight after the fetch that follows a
+        dispatch: beside the device's step, not beside the next
+        boundary's admit / grow_pages / prepare."""
+        flights = self._landing = [f for f in flights if f]
+        if not flights:
+            return
+        with self._phase("generate.fetch"):
+            host = jax.device_get([(f.nxt, f.first) for f in flights])
+        now = self.clock()
+        if any(f.join is not None for f in flights):
+            with self._phase("gen-prefill.deliver"):
+                for f, (_, first) in zip(flights, host):
+                    if f.join is not None:
+                        slot, st, last, bucket, fn = f.join
+                        if self._deliver_first_token(slot, st, int(first),
+                                                     now, bucket, fn):
+                            self._retire(slot, st, last, now)
+        if any(f.nxt is not None for f in flights):
+            with self._phase("generate.deliver"):
+                for f, (nxt, _) in zip(flights, host):
+                    if f.nxt is not None:
+                        self._deliver_step(f, nxt, now)
+        self._landing = []
 
     def _admit(self) -> None:
         """Join queued prompts into free slots at the step boundary —
@@ -1304,10 +1452,14 @@ class GenerationEngine:
         return False
 
     def _run_chunk(self, slot: int, st: _Slot) -> bool:
-        """Dispatch ONE prefill chunk for the queue-head slot; on the
-        final chunk, fetch the stream's first token (the one host sync
-        per join), activate the slot, and promote its full prompt
-        pages into the prefix cache."""
+        """Dispatch ONE prefill chunk for the queue-head slot.  The
+        final chunk activates the slot BY COUNT (it decodes at this very
+        boundary, its first token spliced into the step's input on the
+        device) and leaves that token on the device for the boundary's
+        one fetch; a stream whose first token is needed on the host at
+        once — a ``handoff`` wants it in its payload, a speculative
+        round starts from it — fetches it here, as every join once
+        did."""
         prompt = st.prompt
         start = st.next_pos
         remaining = int(prompt.size) - start
@@ -1328,6 +1480,8 @@ class GenerationEngine:
             fn = self._decoder.prefill_fn(bucket)
             row = self._table[slot].copy()
         final = start + chunk >= int(prompt.size)
+        at_once = final and (st.stream.handoff is not None
+                             or self._spec_active())
         tok = 0
         if self._traced and st.t_exec is None:
             st.t_exec = self.clock()
@@ -1336,9 +1490,7 @@ class GenerationEngine:
                 first, self._caches = fn(
                     self._params, self._caches, tokens, row,
                     np.int32(slot), np.int32(start), np.int32(chunk))
-                if final:
-                    # one fetch per JOIN (not per chunk): the stream's
-                    # first token comes out of the last chunk itself
+                if at_once:
                     tok = int(jax.device_get(first))
         except BaseException as e:  # noqa: BLE001 — a poisoned chunk
             # fails the joining stream AND (because the dispatch may
@@ -1348,6 +1500,7 @@ class GenerationEngine:
             if st.stream._fail(e):
                 self.metrics.record_failure(e)
                 self._trace_terminal(st.stream, "error", self.clock())
+            st.done = True
             self._recover_from_dispatch_error(e, "gen_prefill_error")
             return True
         st.next_pos = start + chunk
@@ -1356,35 +1509,64 @@ class GenerationEngine:
         if not final:
             return True  # next chunk at a later step boundary
         self._prefill_q.popleft()
+        # the join BY COUNT: the slot decodes from this boundary on
+        st.prefilling = False
+        st.length = int(prompt.size)
+        st.generated = 1
+        st.unfetched += 1
+        last = st.generated >= st.stream.max_new
+        if self._prefix is not None:
+            # promote the freshly-computed full prompt pages (the hit
+            # prefix re-touches its nodes' LRU stamps); whoever reads
+            # them is dispatched after this chunk
+            full = max(0, (int(prompt.size) - 1) // self.page_size)
+            self._prefix.insert(prompt, st.pages[:full])
+        self._prev_first = first
+        if not at_once:
+            self._cur.first = first
+            self._cur.join = (slot, st, last, bucket, fn)
+            if last:
+                self._release_slot(slot, st)
+            return True
         now = self.clock()
         with self._phase("gen-prefill.deliver"):
-            return self._deliver_first_token(slot, st, tok, now, bucket, fn)
+            self._deliver_first_token(slot, st, tok, now, bucket, fn)
+            stream = st.stream
+            if stream.handoff is not None and not (
+                    last or (self.eos_id is not None
+                             and tok == self.eos_id)):
+                # disaggregated serving: offer the freshly-prefilled KV
+                # page chain to the router's handoff.  Streams retiring
+                # at this very boundary (max_new=1, first token is EOS)
+                # stay local — migrating them would ship pages nothing
+                # decodes.
+                if self._migrate_out(slot, st, now):
+                    return True
+            if self._spec_active():
+                self._draft_prefill(slot, st)
+            self._retire(slot, st, last, now)
+        return True
 
     def _deliver_first_token(self, slot: int, st: _Slot, tok: int,
                              now: float, bucket: int, fn) -> bool:
         """The join's hand-over at ``now``, the instant its first token
-        reached the host: activate the slot, emit the token, promote the
-        prompt's pages, record the request's road here, then migrate,
-        mirror into the draft or retire as the stream asks."""
-        prompt = st.prompt
-        st.prefilling = False
-        st.length = int(prompt.size)
+        reached the host: emit the token and record the request's road
+        here.  False when the stream had ended before its first token
+        arrived (the token is dropped)."""
+        st.unfetched -= 1
+        if st.done:
+            self._pipe_dropped += 1
+            return False
         st.last_token = tok
-        st.generated = 1
         stream = st.stream
         stream.ttft = now - stream.t_submit
         stream._emit(tok)
         self.metrics.record_ttft(stream.ttft)
         self.metrics.record_prefill_token()
-        if self._prefix is not None:
-            # promote the freshly-computed full prompt pages (the hit
-            # prefix re-touches its nodes' LRU stamps)
-            full = max(0, (int(prompt.size) - 1) // self.page_size)
-            self._prefix.insert(prompt, st.pages[:full])
         if self._traced and stream.trace is not None:
             tname = self.name or "generate"
             args = dict(slot=slot, phase="target",
-                        prompt_len=int(prompt.size),
+                        prompt_len=int(st.prompt.size),
                         prefix_hit_tokens=st.hit_tokens,
                         prefill_chunks=st.chunks)
             self._tracer.span("queue", stream.trace, stream.t_submit,
@@ -1402,18 +1584,6 @@ class GenerationEngine:
                                   st.t_exec, now, tid=tname,
                                   step=self._boundary, bucket=bucket,
                                   program=_program_name(fn), **args)
-        if stream.handoff is not None and not (
-                st.generated >= stream.max_new
-                or (self.eos_id is not None and tok == self.eos_id)):
-            # disaggregated serving: offer the freshly-prefilled KV
-            # page chain to the router's handoff.  Streams retiring at
-            # this very boundary (max_new=1, first token is EOS) stay
-            # local — migrating them would ship pages nothing decodes.
-            if self._migrate_out(slot, st, now):
-                return True
-        if self._spec_active():
-            self._draft_prefill(slot, st)
-        self._retire(slot, st, now)
         return True
 
     # ---- disaggregated prefill/decode migration ------------------------
@@ -1671,19 +1841,17 @@ class GenerationEngine:
         if st.stream._fail(exc):
             self.metrics.record_failure(exc)
             self._trace_terminal(st.stream, phase, now)
-        self._release_slot(slot, st)
+        self._settle(slot, st)
+
+    def _settle(self, slot: int, st: _Slot) -> None:
+        """The stream was handed its end: what is still in flight for it
+        is dropped when it lands, and the slot goes back where the count
+        (a retirement by ``max_new_tokens``) has not freed it already."""
+        st.done = True
+        if self._slots_state[slot] is st:
+            self._release_slot(slot, st)
 
     # ---- decode --------------------------------------------------------
-    def _step_active(self) -> None:
-        """Advance every active stream one boundary: a speculative
-        draft+verify ROUND when a live draft is attached, else one
-        plain decode step.  Callers wrap this in the dispatch-error
-        containment."""
-        if self._spec_active():
-            self._spec_decode_once()
-        else:
-            self._decode_once()
-
     def _spec_active(self) -> bool:
         return self._spec_on and self._spec_gamma >= 2
 
@@ -1716,72 +1884,123 @@ class GenerationEngine:
             seeds[i] = sp.seed
         return temp, top_k, top_p, seeds
 
-    def _decode_once(self) -> None:
+    def _spliced(self, host_tokens, from_host, join_slot=None):
+        """The token step's input ON the device (:func:`_splice_tokens`):
+        the last step's output where a slot's newest token is still
+        there, ``host_tokens`` where ``from_host``, this boundary's
+        join's first token at ``join_slot``.  With no step or join
+        behind it yet (an engine started without warm-up) the host's
+        arrays stand in."""
+        prev = self._prev_tokens
+        first = self._prev_first
+        return _splice_tokens(
+            host_tokens if prev is None else prev, host_tokens, from_host,
+            np.int32(0) if first is None else first,
+            np.int32(self.slots if join_slot is None else join_slot))
+
+    def _decode_once(self, ahead: bool, why: str = "external") -> None:
         """Advance the whole decode batch one position: one dispatch,
-        one token fetch, scatter to streams.  Write pages/rows are
-        host-computed — inactive and PREFILLING slots ride the pool's
-        OOB sentinel so their dummy writes drop instead of corrupting
-        a (possibly shared) page."""
+        the slots' bookkeeping moved on BY COUNT, then the boundary's
+        one fetch — of the step before this one when running ``ahead``
+        (this step's tokens stay on the device until the next boundary
+        has dispatched), of this step too when not (counted under
+        ``why``).  Write pages/rows are host-computed — inactive and
+        PREFILLING slots ride the pool's OOB sentinel so their dummy
+        writes drop instead of corrupting a (possibly shared) page."""
+        cur = self._cur
         with self._phase("generate.prepare"):
             tokens = np.zeros((self.slots,), np.int32)
+            from_host = np.ones((self.slots,), bool)
             pos = np.zeros((self.slots,), np.int32)
             wp = np.full((self.slots,), self._pool.no_page, np.int32)
             wr = np.zeros((self.slots,), np.int32)
-            nactive = 0
             for i, s in enumerate(self._slots_state):
                 if s is not None and not s.prefilling:
-                    tokens[i] = s.last_token
+                    if s.unfetched:
+                        from_host[i] = False   # its token is on the device
+                    else:
+                        tokens[i] = s.last_token
                     pos[i] = s.length
                     wp[i] = self._table[i, s.length // self.page_size]
                     wr[i] = s.length % self.page_size
-                    nactive += 1
+                    # the step BY COUNT: the slot is one position and
+                    # one token further once this step is dispatched
+                    s.length += 1
+                    s.generated += 1
+                    s.unfetched += 1
+                    cur.rows.append(
+                        (i, s, s.generated >= s.stream.max_new))
+            table = self._table.copy()
             sampled = self._batch_sampling()
-        traced = self._traced   # the boundary's one read of the gate
-        t0 = self.clock()
-        with jax.profiler.StepTraceAnnotation("generate",
-                                              step_num=self._n_steps):
             if sampled:
                 temp, top_k, top_p, seeds = self._sampling_arrays()
-                fn = self._decoder.decode_sampled_fn()
-                with self._phase("generate.dispatch"):
-                    nxt, self._caches = fn(
-                        self._params, self._caches, tokens, pos,
-                        self._table.copy(), wp, wr, temp, top_k, top_p,
-                        seeds)
-            else:
-                fn = self._decoder.decode_fn()
-                with self._phase("generate.dispatch"):
-                    nxt, self._caches = fn(
-                        self._params, self._caches, tokens, pos,
-                        self._table.copy(), wp, wr)
-            # THE one host sync per decode step for the whole batch —
-            # per-stream tokens are scattered from it below (RL010)
-            with self._phase("generate.fetch"):
-                host = np.asarray(jax.device_get(nxt))
-        now = self.clock()
+        cur.t0 = self.clock()
+        cur.step = self._n_steps
+        with jax.profiler.StepTraceAnnotation("generate",
+                                              step_num=self._n_steps):
+            with self._phase("generate.dispatch"):
+                spliced = self._spliced(
+                    tokens, from_host,
+                    None if cur.join is None else cur.join[0])
+                if sampled:
+                    cur.fn = self._decoder.decode_sampled_fn()
+                    cur.nxt, self._caches = cur.fn(
+                        self._params, self._caches, spliced, pos, table,
+                        wp, wr, temp, top_k, top_p, seeds)
+                else:
+                    cur.fn = self._decoder.decode_fn()
+                    cur.nxt, self._caches = cur.fn(
+                        self._params, self._caches, spliced, pos, table,
+                        wp, wr)
         self._n_steps += 1
-        with self._phase("generate.deliver"):
-            for i, s in enumerate(self._slots_state):
-                if s is None or s.prefilling:
-                    continue
-                tok = int(host[i])
-                s.length += 1
-                s.generated += 1
-                s.last_token = tok
-                s.stream._emit(tok)
-                self._retire(i, s, now)
-            if traced:
-                self._tracer.span("decode_step", None, t0, now,
-                                  tid=self.name or "generate",
-                                  step=self._n_steps - 1, active=nactive,
-                                  phase="decode",
-                                  program=_program_name(fn))
-            self.metrics.record_decode_step(nactive, now - t0)
-            self._fire_cancel_at_token(now)
-            if self.stats_every and self._n_steps % self.stats_every == 0:
-                self.metrics.emit(extra={"slots": self.slots,
-                                         "active": nactive})
+        self._prev_tokens = cur.nxt
+        # a slot that retires on this token frees its pages NOW, behind
+        # the step that wrote them: whichever program takes them next is
+        # dispatched after it
+        for i, s, last in cur.rows:
+            if last:
+                self._release_slot(i, s)
+        prior, self._inflight = self._inflight, None
+        self._cur = _Flight()
+        if ahead:
+            if prior is not None:
+                self._pipe_ahead += 1
+            self._inflight = cur
+            self._land(prior)
+        else:
+            self._pipe_drained[why] = self._pipe_drained.get(why, 0) + 1
+            self._land(prior, cur)
         self._open_turn()
+
+    def _deliver_step(self, f: _Flight, host: np.ndarray,
+                      now: float) -> None:
+        """Hand a fetched token step to its streams at ``now``: each
+        slot's token, then its stream's end where the token is its last
+        (by count), the EOS, or a cancel arrived.  A stream that had
+        ended before this step's tokens reached the host (EOS or a
+        cancel are found one step late) gets nothing: the token is
+        dropped and counted."""
+        emitted = 0
+        for i, s, last in f.rows:
+            s.unfetched -= 1
+            if s.done:
+                self._pipe_dropped += 1
+                continue
+            s.last_token = tok = int(host[i])
+            s.stream._emit(tok)
+            emitted += 1
+            self._retire(i, s, last, now)
+        if self._traced:
+            self._tracer.span("decode_step", None, f.t0, now,
+                              tid=self.name or "generate",
+                              step=f.step, active=len(f.rows),
+                              phase="decode",
+                              program=_program_name(f.fn))
+        self.metrics.record_decode_step(emitted, now - f.t0)
+        self._fire_cancel_at_token(f.rows, now)
+        if self.stats_every and (f.step + 1) % self.stats_every == 0:
+            self.metrics.emit(extra={"slots": self.slots,
+                                     "active": len(f.rows)})
 
     # ---- speculative round ---------------------------------------------
     def _spec_decode_once(self) -> None:
@@ -1871,7 +2090,7 @@ class GenerationEngine:
             # the TARGET caches were never touched, so no stream fails;
             # demote and decode this boundary plain
             self._spec_demote("draft_error", e)
-            self._decode_once()
+            self._decode_once(False, "speculation")
             return
         t1 = self.clock()
         if traced:
@@ -1926,7 +2145,7 @@ class GenerationEngine:
                             and tok == self.eos_id):
                         break
                 self._trim_slot_pages(i, s)
-                self._retire(i, s, now)
+                self._retire(i, s, s.generated >= s.stream.max_new, now)
             if traced:
                 self._tracer.span("decode_step", None, t1, now,
                                   tid=self.name or "generate",
@@ -1939,7 +2158,10 @@ class GenerationEngine:
             # GenerationMetrics.snapshot); tokens_per_s stays comparable
             self.metrics.record_decode_step(emitted, now - t0)
             self._spec_account(g, proposed, accepted, now - t0)
-            self._fire_cancel_at_token(now)
+            if self._gen_faults:
+                self._fire_cancel_at_token(
+                    [(i, s, s.generated >= s.stream.max_new)
+                     for i, s in active], now)
             if self.stats_every and self._n_steps % self.stats_every == 0:
                 self.metrics.emit(extra={"slots": self.slots,
                                          "active": nactive})
@@ -2118,18 +2340,23 @@ class GenerationEngine:
         cache (lifetime counters carry over), reallocate the device
         pools, and keep serving queued prompts (the engine recovers; a
         poisoned dispatch must never wedge it on 'Array has been
-        deleted' forever)."""
+        deleted' forever).  With a step in flight the streams of BOTH
+        steps fail, each once: a slot that retired by count still owes
+        its stream the tokens that never came."""
         failed = 0
         now = self.clock()
-        for i, s in enumerate(self._slots_state):
-            if s is None:
-                continue
+        for s in self._unsettled():
             if s.stream._fail(e):
                 self.metrics.record_failure(e)
                 self._trace_terminal(s.stream, "error", now)
                 failed += 1
-            self._slots_state[i] = None
+            s.done = True
+        self._slots_state = [None] * self.slots
         self._prefill_q.clear()
+        # the pipeline is empty again: what was in flight came out of,
+        # or went into, the cache the failed program consumed
+        self._inflight, self._cur, self._landing = None, _Flight(), []
+        self._prev_tokens = self._prev_first = None
         if self._prefix is not None:
             self._evictions_base += self._prefix.evictions
         self._pool_high_base = max(self._pool_high_base,
@@ -2164,39 +2391,65 @@ class GenerationEngine:
                                   "error": f"{type(e).__name__}: {e}"[:300],
                                   "failed_streams": failed})
 
-    def _retire(self, slot: int, s: _Slot, now: float) -> None:
-        """Free the slot — and its pages — if its stream finished or
-        was cancelled; run at every step boundary, so a mid-generation
-        cancel frees KV capacity for the next queued prompt
-        immediately."""
+    def _unsettled(self) -> List[_Slot]:
+        """Every slot state whose stream has not been handed its end:
+        the occupied slots, and the streams a boundary still in flight
+        (or being built) owes tokens to — a slot that retired by count
+        is only there."""
+        out = [s for s in self._slots_state if s is not None]
+        for f in (*self._landing, self._inflight, self._cur):
+            if f is None:
+                continue
+            out.extend(s for _, s, _ in f.rows)
+            if f.join is not None:
+                out.append(f.join[1])
+        return list({id(s): s for s in out if not s.done}.values())
+
+    def _retire(self, slot: int, s: _Slot, last: bool,
+                now: float) -> None:
+        """After a token was handed to ``s``'s stream: end the stream if
+        it was cancelled, if the token was its ``last`` (decided by
+        count when the token's program was dispatched) or the EOS, and
+        free the slot — and its pages — where the count has not already
+        done so.  Run at every hand-over, so a mid-generation cancel
+        frees KV capacity for the next queued prompt at once; what is
+        still in flight for an ended stream is dropped when it lands."""
         if s.stream.cancelled:
             exc = GenerationCancelled(
-                f"stream cancelled after {s.generated} token(s); "
-                f"KV slot {slot} and {len(s.pages)} page(s) freed")
+                f"stream cancelled after {len(s.stream._tokens)} "
+                f"token(s); KV slot {slot} and {len(s.pages)} page(s) "
+                f"freed")
             self._fail_slot(slot, s, exc, "cancelled")
             return
-        done = s.generated >= s.stream.max_new or (
-            self.eos_id is not None and s.last_token == self.eos_id)
-        if done:
+        if last or (self.eos_id is not None
+                    and s.last_token == self.eos_id):
             if s.stream._finish():
                 self.metrics.record_request(now - s.stream.t_submit,
                                             deadlined=s.stream.deadlined)
                 self._trace_terminal(s.stream, "completed", now)
-            self._release_slot(slot, s)
+            self._settle(slot, s)
 
     def _abort_active(self) -> None:
-        """drain(timeout) expired: shed whatever is still decoding or
-        prefilling (pages go back to the pool with the slots)."""
+        """drain(timeout) expired: hand over what already lies on the
+        device, then shed whatever is still decoding or prefilling
+        (pages go back to the pool with the slots)."""
+        try:
+            self._drain("abort")
+        except BaseException as e:  # noqa: BLE001 — what was in flight
+            # fails as a dispatch error; nothing is left to shed then
+            self._recover_from_dispatch_error(e, "gen_decode_error")
         now = self.clock()
-        for i, s in enumerate(self._slots_state):
-            if s is None:
-                continue
-            exc = SheddedError(
-                "engine drained mid-generation (drain timeout)")
+        exc = SheddedError(
+            "engine drained mid-generation (drain timeout)")
+        for s in self._unsettled():
             if s.stream._fail(exc):
                 self.metrics.record_failure(exc)
                 self._trace_terminal(s.stream, "shed", now)
-            self._release_slot(i, s)
+            s.done = True
+        for i, s in enumerate(self._slots_state):
+            if s is not None:
+                self._release_slot(i, s)
+        self._inflight, self._cur, self._landing = None, _Flight(), []
         self._prefill_q.clear()
         while self._adopt_q:
             try:
@@ -2228,19 +2481,22 @@ class GenerationEngine:
                     f"FF_FAULT spec_draft_fail: injected draft "
                     f"failure at round {self._spec_rounds + 1}")
 
-    def _fire_cancel_at_token(self, now: float) -> None:
+    def _fire_cancel_at_token(self, rows, now: float) -> None:
+        """``FF_FAULT=serve_cancel_at_token:N`` — the first stream among
+        the ``rows`` just handed their tokens that holds N of them is
+        cancelled (once)."""
         for st in self._gen_faults:
             if st["kind"] != "serve_cancel_at_token" or st["fired"]:
                 continue
-            for i, s in enumerate(self._slots_state):
-                if s is not None and not s.prefilling \
-                        and s.generated >= st["n"]:
+            for i, s, last in rows:
+                if not s.done and len(s.stream._tokens) >= st["n"]:
                     st["fired"] = 1
                     get_logger("serve").event(
                         "gen_fault_cancel", model=self.name, slot=i,
-                        generated=s.generated, at_token=st["n"])
+                        generated=len(s.stream._tokens),
+                        at_token=st["n"])
                     s.stream.cancel()
-                    self._retire(i, s, now)
+                    self._retire(i, s, last, now)
                     break
 
     # ---- strategy-sharded construction ---------------------------------

@@ -70,13 +70,18 @@ by tier-1 ``tests/test_static_checks.py``).  Rules:
   stack's thread-safety story: the fake-clock tests exercise the
   schedules, RL009 pins the discipline.
 * **RL010 — no host syncs in the token-generation decode loop**
-  (the generation mirror of RL004/RL005, ISSUE 11): inside the decode
-  functions of ``flexflow_tpu/serving/generation/`` (``_decode_loop``
-  / ``_decode_once``), the engine's contract is ONE per-step token
-  fetch for the WHOLE decode batch — the straight-line fetch is
-  sanctioned (as is the ``while`` decode loop, the analogue of the
-  serve/epoch loops); a ``float``/``np.asarray``/``jax.device_get``
-  inside a ``for`` loop there is a per-stream sync and is rejected.
+  (the generation mirror of RL004/RL005, ISSUE 11; the loop's shape
+  since ISSUE 34): inside the step boundary's functions of
+  ``flexflow_tpu/serving/generation/`` (``_decode_loop`` /
+  ``_run_boundary`` / ``_run_chunk`` / ``_decode_once`` / ``_land`` /
+  ``_deliver_step``), the engine's contract is ONE fetch a boundary for
+  the WHOLE decode batch — ``_land``'s straight-line ``device_get`` of
+  what the boundary (or, one step ahead, the boundary before it) left
+  on the device is sanctioned, as are the first token a hand-off or a
+  speculative round needs at once (``_run_chunk``, straight-line) and
+  the ``while`` decode loop (the analogue of the serve/epoch loops); a
+  ``float``/``np.asarray``/``jax.device_get`` inside a ``for`` loop
+  there is a per-stream sync and is rejected.
 * **RL008 — serving code reads time only through the injected clock**
   (ISSUE 8): a bare ``time.time()``/``time.monotonic()`` call inside
   ``flexflow_tpu/serving/`` bypasses the ``clock=`` every serving
@@ -218,10 +223,11 @@ _RL004_FUNCS = ("fit", "evaluate", "predict")
 # engine fetches once per packed batch in straight-line code; for-loops
 # inside these iterate requests
 _RL005_FUNCS = ("_dispatch_loop", "_dispatch_batch")
-# the token-generation decode functions RL010 scopes to (same banned
-# set): one token fetch per decode step in straight-line code;
-# for-loops inside these iterate streams/slots
-_RL010_FUNCS = ("_decode_loop", "_decode_once")
+# the token-generation step boundary's functions RL010 scopes to (same
+# banned set): one fetch a boundary in straight-line code (`_land`);
+# for-loops inside these iterate streams/slots/flights
+_RL010_FUNCS = ("_decode_loop", "_run_boundary", "_run_chunk",
+                "_decode_once", "_land", "_deliver_step")
 
 # wall-clock reads RL008 bans in flexflow_tpu/serving/ (outside
 # default-argument position): every serving class takes an injectable
@@ -697,9 +703,9 @@ class _Visitor(ast.NodeVisitor):
         if self._gen_func is not None and self._gen_loops > 0:
             self._add(node, "RL010",
                       f"{name}() inside a {self._gen_func}() stream "
-                      f"loop is a per-stream host sync — the decode "
-                      f"loop fetches ONE token array per step for the "
-                      f"whole batch and scatters host values "
+                      f"loop is a per-stream host sync — a step "
+                      f"boundary fetches ONCE for the whole batch "
+                      f"(_land) and scatters host values "
                       f"(docs/serving.md 'Token generation')")
 
     def _check_savez(self, node: ast.Call, name: str) -> None:

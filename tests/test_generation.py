@@ -1134,6 +1134,28 @@ class TestGenerationFaults:
             assert (list(int(t) for t in ok.result(timeout=120))
                     == reference_decode(lm, prompts[1], 6))
 
+    def test_cancel_at_token_drops_the_step_in_flight(self, arm, lm,
+                                                      prompts):
+        """One step ahead a cancel is found when the tokens land: the
+        victim holds exactly its N tokens, the token the step in flight
+        computed for it is dropped, never emitted, and the stream beside
+        it is served to its reference."""
+        arm("serve_cancel_at_token:3")
+        eng = GenerationEngine(lm, slots=2)
+        with eng:
+            victim = eng.submit(prompts[0], max_new_tokens=24)
+            other = eng.submit(prompts[1], max_new_tokens=6)
+            with pytest.raises(GenerationCancelled, match="after 3 token"):
+                victim.result(timeout=120)
+            assert ([int(t) for t in other.result(timeout=120)]
+                    == reference_decode(lm, prompts[1], 6))
+            snap = eng.stats()
+        assert victim.tokens_so_far() == \
+            reference_decode(lm, prompts[0], 3)
+        assert snap["decode_pipeline"]["dropped_tokens"] == 1
+        assert snap["cancelled"] == 1 and snap["errors"] == 0
+        assert eng._pool.pages_in_use == 0
+
     def test_spec_draft_fail_demotes_without_failing_streams(
             self, arm, lm, draft_lm, prompts):
         """``FF_FAULT=spec_draft_fail:N``: the Nth draft dispatch
@@ -1828,6 +1850,241 @@ def test_gen_stats_carry_decode_attention(lm, prompts):
         eng.submit(prompts[0]).result(timeout=120)
         assert eng.stats()["decode_attention"] == {"paged": 0,
                                                    "gathered": 2}
+
+
+# ---------------------------------------------------------------------
+# ISSUE 34: the decode loop runs one step ahead of the host
+# ---------------------------------------------------------------------
+def _serve(model, reqs, ahead, eos_id=None, **kw):
+    """Serve ``reqs`` (``(prompt, max_new, sampling)``) on one engine:
+    through its own loop (one step ahead) or one synchronous boundary
+    at a time (``dispatch_pending``, the fleet's entry).  Returns the
+    token lists and ``stats()``."""
+    eng = GenerationEngine(model, slots=2, eos_id=eos_id, **kw)
+    if ahead:
+        eng.start()
+    else:
+        eng.begin_external_dispatch()
+    try:
+        streams = [eng.submit(p, max_new_tokens=n, sampling=sp)
+                   for p, n, sp in reqs]
+        if not ahead:
+            for _ in range(500):
+                if not eng.has_pending:
+                    break
+                eng.dispatch_pending()
+                # the fleet is never owed tokens: nothing stays in flight
+                assert eng._inflight is None and not eng._cur
+        outs = [[int(t) for t in s.result(timeout=120)] for s in streams]
+        return outs, eng.stats()
+    finally:
+        eng.stop()
+
+
+def _shared_prefix_prompts():
+    rng = np.random.default_rng(7)
+    prefix = rng.integers(1, VOCAB, 20).astype(np.int32)
+    return [np.concatenate([prefix,
+                            rng.integers(1, VOCAB, 3).astype(np.int32)])
+            for _ in range(4)]
+
+
+@pytest.mark.parametrize("case", ["greedy", "sampled", "eos", "max_new_1",
+                                  "chunked", "prefix_hit"])
+def test_one_step_ahead_serves_the_synchronous_boundarys_tokens(
+        lm, prompts, case):
+    """Tokens are bit-identical with the pipeline running and with
+    nothing in flight, and equal the predict-style reference wherever
+    decoding is greedy; what the pipeline did shows in
+    ``stats()["decode_pipeline"]`` alone."""
+    new, eos, kw, sp = 6, None, {}, (lambda i: None)
+    ps = prompts[:5]
+    if case == "sampled":
+        def sp(i):
+            return SamplingParams(temperature=0.8, top_k=8, top_p=0.9,
+                                  seed=40 + i)
+    elif case == "eos":
+        # the third token of the first stream ends every stream it
+        # occurs in, mid-batch
+        eos = reference_decode(lm, ps[0], new)[2]
+    elif case == "max_new_1":
+        new = 1
+    elif case == "chunked":
+        kw = {"prefill_chunk": 3}
+    elif case == "prefix_hit":
+        ps, new = _shared_prefix_prompts(), 5
+    reqs = [(p, new, sp(i)) for i, p in enumerate(ps)]
+    ahead, snap = _serve(lm, reqs, True, eos_id=eos, **kw)
+    sync, snap0 = _serve(lm, reqs, False, eos_id=eos, **kw)
+    assert ahead == sync
+    refs = [reference_decode(lm, p, new) for p in ps]
+    ends = [r.index(eos) if eos in r else None for r in refs]
+    if case != "sampled":
+        assert ahead == [r if k is None else r[:k + 1]
+                         for r, k in zip(refs, ends)]
+    pipe, pipe0 = snap["decode_pipeline"], snap0["decode_pipeline"]
+    # nothing in flight: never ahead, every step fetched where it ran
+    assert pipe0["ahead"] == 0 and pipe0["dropped_tokens"] == 0
+    steps = pipe0["drained"].get("external", 0)
+    assert set(pipe0["drained"]) <= {"external"}
+    if case == "max_new_1":
+        assert steps == 0 and pipe["ahead"] == 0     # no token step at all
+    else:
+        assert 0 < pipe["ahead"] < steps + len(ps)
+        assert "external" not in pipe["drained"]
+    if case == "eos":
+        # found one step late: the step dispatched meanwhile computed a
+        # token for a stream that had ended (two where the FIRST token
+        # was the EOS), unless that token was its last by count anyway
+        owed = sum(min(new - (k + 1), 2 if k == 0 else 1)
+                   for k in ends if k is not None)
+        assert owed > 0 and pipe["dropped_tokens"] == owed
+        assert snap["tokens"] == sum(len(o) for o in ahead)
+    else:
+        assert pipe["dropped_tokens"] == 0
+    if case == "prefix_hit":
+        assert snap["prefix_hit_tokens"] > 0
+        assert snap["prefix_hit_tokens"] == snap0["prefix_hit_tokens"]
+    if case == "chunked":
+        assert snap["prefill_chunks"] == snap0["prefill_chunks"] > len(ps)
+
+
+def test_pages_freed_by_count_reach_the_next_join_behind_their_last_reader(
+        lm, prompts, monkeypatch):
+    """The page-order invariant: a slot that retires by count frees its
+    pages when its last step is DISPATCHED, and the next boundary's join
+    takes them.  Every freed page is NaN on the device from its release
+    until it is handed out again (as PR 30's kernel test poisons what a
+    slot must not read): a release ordered before the page's last reader,
+    or a later step reading through a stale table row, would serve NaN's
+    argmax.  Two pages for two slots: every join after the second takes
+    a page a retiring slot has just freed."""
+    eng = GenerationEngine(lm, slots=2, max_new_tokens=5, num_pages=2,
+                           prefix_cache="off")
+    poisoned, reused = set(), []
+    release, alloc = eng._release_slot, eng._alloc_page
+
+    def fill(pages, value):
+        idx = np.asarray(sorted(pages), np.int32)
+        eng._caches = jax.tree_util.tree_map(
+            lambda a: a.at[idx].set(value), eng._caches)
+
+    def release_and_poison(slot, st):
+        pages = list(st.pages)
+        release(slot, st)
+        freed = {p for p in pages if eng._pool.refcount(p) == 0}
+        if freed:
+            fill(freed, jnp.nan)
+            poisoned.update(freed)
+
+    def alloc_and_clear(*a):
+        pg = alloc(*a)
+        if pg in poisoned:
+            poisoned.discard(pg)
+            reused.append(pg)
+            fill({pg}, 0)
+        return pg
+
+    monkeypatch.setattr(eng, "_release_slot", release_and_poison)
+    monkeypatch.setattr(eng, "_alloc_page", alloc_and_clear)
+    with eng:
+        streams = [eng.submit(p) for p in prompts]
+        outs = [[int(t) for t in s.result(timeout=120)] for s in streams]
+        snap = eng.stats()
+    assert outs == [reference_decode(lm, p, 5) for p in prompts]
+    assert len(reused) >= len(prompts) - 2
+    assert snap["decode_pipeline"]["ahead"] > 0
+    assert snap["errors"] == 0 and eng._pool.pages_in_use == 0
+
+
+def test_decode_pipeline_counter_plain_speculating_and_drained(
+        lm, draft_lm, prompts):
+    """``stats()["decode_pipeline"]``: a plain engine runs ahead; a
+    speculating one needs the accept counts on the host and never does;
+    the reading outlives ``drain()``."""
+    eng = GenerationEngine(lm, slots=2, max_new_tokens=6)
+    eng.start()
+    outs = [[int(t) for t in eng.submit(p).result(timeout=120)]
+            for p in prompts[:2]]
+    live = eng.stats()["decode_pipeline"]
+    after = eng.drain(timeout=60)["decode_pipeline"]
+    assert outs == [reference_decode(lm, p, 6) for p in prompts[:2]]
+    # one stream at a time: five token steps each, every one but the
+    # first dispatched before its predecessor's tokens were fetched, and
+    # the pipeline ran empty once a stream, when its batch did
+    assert live == after == {"ahead": 8, "drained": {"idle": 2},
+                             "dropped_tokens": 0}
+    assert eng._inflight is None
+    spec, snap = _run_spec(lm, draft_lm, prompts[:3], max_new=6,
+                           spec_gamma=2)
+    assert spec == [reference_decode(lm, p, 6) for p in prompts[:3]]
+    assert snap["spec"] == "on"
+    assert snap["decode_pipeline"] == {"ahead": 0, "drained": {},
+                                       "dropped_tokens": 0}
+
+
+def test_warm_engine_serves_without_another_lowering(lm, prompts):
+    """``_warmup`` calls the token step the way the loop does (its
+    tokens spliced on the device), so serving adds no entry to the
+    step's or the splice's cache: no trace and no compile inside a
+    window."""
+    from flexflow_tpu.serving.generation import engine as engine_mod
+    eng = GenerationEngine(lm, slots=2, max_new_tokens=6)
+    with eng:
+        step, splice = eng._decoder.decode_fn(), engine_mod._splice_tokens
+        warm = (step._cache_size(), splice._cache_size())
+        streams = [eng.submit(p) for p in prompts[:4]]
+        for s in streams:
+            s.result(timeout=120)
+        assert (step._cache_size(), splice._cache_size()) == warm
+        assert eng.stats()["decode_pipeline"]["ahead"] > 0
+
+
+def test_dispatch_error_with_a_step_in_flight_fails_each_stream_once(
+        lm, prompts, monkeypatch):
+    """A token step that raises while the one before it is still in
+    flight fails the streams of BOTH (the cache it consumed fed them
+    all), each once — the one that had retired by count and was only
+    owed its last token too — and the engine serves the next prompt."""
+    eng = GenerationEngine(lm, slots=2, prefix_cache="off")
+    decode_fn = eng._decoder.decode_fn
+    calls = {"n": 0}
+
+    def hooked():
+        fn = decode_fn()
+
+        def decode(*a, **kw):
+            calls["n"] += 1
+            if calls["n"] == 3:
+                raise RuntimeError("injected: third token step")
+            return fn(*a, **kw)
+
+        return decode
+
+    monkeypatch.setattr(eng._decoder, "decode_fn", hooked)
+    # both queued before the loop starts: `short` joins at the first
+    # boundary and retires by count when the second step is dispatched,
+    # `long` joins at the second; the third step raises with the second
+    # still in flight
+    short = eng.submit(prompts[0], max_new_tokens=3)
+    long = eng.submit(prompts[1], max_new_tokens=12)
+    with capture_events("serve") as events:
+        eng.start(warmup=False)     # no warm-up: the loop's steps alone count
+        try:
+            for s in (short, long):
+                with pytest.raises(RuntimeError, match="third token step"):
+                    s.result(timeout=120)
+            assert len(short.tokens_so_far()) == 2    # the third never came
+            late = eng.submit(prompts[2], max_new_tokens=4)
+            assert ([int(t) for t in late.result(timeout=120)]
+                    == reference_decode(lm, prompts[2], 4))
+            snap = eng.stats()
+        finally:
+            eng.stop()
+    errors = [e for e in events if e.get("event") == "gen_decode_error"]
+    assert len(errors) == 1 and errors[0]["failed_streams"] == 2
+    assert snap["errors"] == 2 and snap["requests"] == 1
+    assert eng._pool.pages_in_use == 0 and eng._inflight is None
 
 
 def test_engine_stats_carry_pool_copies_once_asked(lm, draft_lm, prompts):

@@ -1,7 +1,8 @@
 """Host phases and device-op ownership (ISSUE 24): the generation engine's
-step-boundary phases under a fake clock, the request's road to its first
-token tiled by three spans, tracing off as a no-op, and the compiled train
-step's instructions mapped to the graph ops that own them.
+step-boundary phases under a fake clock, synchronous (the fleet's entry) and
+one step ahead (the engine's own loop, ISSUE 34), the request's road to its
+first token tiled by three spans, tracing off as a no-op, and the compiled
+train step's instructions mapped to the graph ops that own them.
 """
 
 import os
@@ -23,10 +24,12 @@ try:
 finally:
     sys.path.pop(0)
 
+# a boundary with nothing left in flight (ISSUE 34): the join's chunk and the
+# token step are dispatched, then ONE fetch, then both hand-overs
 BOUNDARY = ["generate.admit", "gen-prefill.prepare", "gen-prefill",
-            "gen-prefill.deliver", "generate.grow_pages",
-            "generate.prepare", "generate.dispatch", "generate.fetch",
-            "generate.deliver", "generate.turn"]
+            "generate.grow_pages", "generate.prepare", "generate.dispatch",
+            "generate.fetch", "gen-prefill.deliver", "generate.deliver",
+            "generate.turn"]
 
 
 class TickClock:
@@ -56,22 +59,30 @@ def tracer():
     tr.reset()
 
 
-def _drive(lm, prompts, chunk=0, max_new=4):
-    """Serve ``prompts`` one boundary at a time on the calling thread (the
-    fleet's entry, ``dispatch_pending``) under a TickClock; returns the
-    engine and each stream's tokens."""
+def _drive(lm, prompts, chunk=0, max_new=4, ahead=False):
+    """Serve ``prompts`` under a TickClock, one boundary at a time on the
+    calling thread (the fleet's entry, ``dispatch_pending``) or, ``ahead``,
+    through the engine's own loop, every prompt queued before it starts
+    (so that one thread reads the clock); returns the engine, each stream's
+    tokens and what each ``dispatch_pending`` charged."""
     eng = GenerationEngine(lm, slots=2, max_new_tokens=max_new,
                            prefill_chunk=chunk, prefix_cache="off",
                            clock=TickClock())
-    eng.begin_external_dispatch(warmup=False)
-    streams = [eng.submit(np.asarray(p, np.int32)) for p in prompts]
-    for _ in range(200):
-        if not eng.has_pending:
-            break
-        eng.dispatch_pending()
-    tokens = [list(s.result(timeout=5)) for s in streams]
+    charged = []
+    if ahead:
+        streams = [eng.submit(np.asarray(p, np.int32)) for p in prompts]
+        eng.start(warmup=False)
+    else:
+        eng.begin_external_dispatch(warmup=False)
+        streams = [eng.submit(np.asarray(p, np.int32)) for p in prompts]
+        for _ in range(200):
+            if not eng.has_pending:
+                break
+            charged.append(eng.dispatch_pending())
+            assert eng._inflight is None and not eng._cur
+    tokens = [list(s.result(timeout=60)) for s in streams]
     eng.stop()
-    return eng, tokens
+    return eng, tokens, charged
 
 
 def _by_name(spans, name):
@@ -102,10 +113,91 @@ def test_one_boundary_records_each_phase_once_in_order(lm, tracer):
             <= step["t1_ns"]
 
 
+def _boundaries(spans):
+    """The engine's phase spans by the boundary (``step``) they share, each
+    boundary's in time order; every phase at most once and none overlapping
+    the next."""
+    by = {}
+    for s in spans:
+        if s.get("cat") == "engine":
+            by.setdefault(s["args"]["step"], []).append(s)
+    for step, phases in by.items():
+        phases.sort(key=lambda s: s["t0_ns"])
+        names = [s["name"] for s in phases]
+        assert len(names) == len(set(names)), (step, names)
+        for a, b in zip(phases, phases[1:]):
+            assert a["t1_ns"] <= b["t0_ns"], (step, a["name"], b["name"])
+    assert sorted(by) == list(range(1, len(by) + 1))
+    return by
+
+
+def test_dispatch_pending_leaves_nothing_in_flight_and_charges_its_step(
+        lm, tracer):
+    _, tokens, charged = _drive(lm, [[5, 6, 7]], max_new=4)
+    assert len(tokens[0]) == 4
+    spans = tracer.snapshot()["spans"]
+    by = _boundaries(spans)
+    steps = _by_name(spans, "decode_step")
+    assert len(steps) == 3 == len(by) and len(charged) == 3
+    for k, (step, dt) in enumerate(zip(steps, charged), 1):
+        # the step's dispatch AND its fetch lie in the boundary that ran
+        # it, and the seconds charged cover it from end to end
+        names = {s["name"]: s for s in by[k]}
+        assert step["t0_ns"] <= names["generate.dispatch"]["t0_ns"]
+        assert names["generate.fetch"]["t1_ns"] <= step["t1_ns"]
+        assert dt >= (step["t1_ns"] - step["t0_ns"]) / 1e9 > 0
+
+
+def test_one_step_ahead_each_boundary_keeps_its_phases_once(lm, tracer):
+    """The engine's own loop: one ``step`` a boundary and each phase once
+    in it, but a token step's fetch lies in the boundary AFTER its
+    dispatch, behind the next step's dispatch; its ``decode_step`` span
+    still runs from its dispatch to its tokens on the host, so consecutive
+    spans overlap, and the join's ``prefill_exec`` ends at that same
+    fetch."""
+    _, tokens, _ = _drive(lm, [[5, 6, 7], list(range(1, 11))], max_new=6,
+                          ahead=True)
+    assert [len(t) for t in tokens] == [6, 6]
+    spans = tracer.snapshot()["spans"]
+    by = _boundaries(spans)
+    steps = sorted(_by_name(spans, "decode_step"),
+                   key=lambda s: s["args"]["step"])
+    assert [s["args"]["step"] for s in steps] == list(range(len(steps)))
+    ran_ahead = 0
+    for step in steps:
+        inside = [(b, s) for b, phases in by.items() for s in phases
+                  if s["name"] in ("generate.dispatch", "generate.fetch")
+                  and step["t0_ns"] <= s["t0_ns"]
+                  and s["t1_ns"] <= step["t1_ns"]]
+        b_dispatch = min(b for b, s in inside
+                         if s["name"] == "generate.dispatch")
+        b_fetch = max(b for b, s in inside if s["name"] == "generate.fetch")
+        # never fetched in the boundary that dispatched it; where no step
+        # followed (the batch ran empty) the next boundary fetches at once
+        assert b_fetch == b_dispatch + 1
+        order = [s["name"] for s in by[b_fetch]]
+        if "generate.dispatch" in order:
+            ran_ahead += 1
+            assert order.index("generate.dispatch") \
+                < order.index("generate.fetch") \
+                < order.index("generate.deliver")
+    assert ran_ahead == len(steps) - 1
+    for a, b in zip(steps, steps[1:]):
+        if b["args"]["step"] <= ran_ahead:
+            assert b["t0_ns"] < a["t1_ns"]       # one step in flight
+    # the first tokens came with a step's tokens, in one fetch
+    fetches = [s for phases in by.values() for s in phases
+               if s["name"] == "generate.fetch"]
+    for x in _by_name(spans, "prefill_exec"):
+        assert any(f["t1_ns"] <= x["t1_ns"] <= f["t1_ns"] + 2_000_000
+                   for f in fetches)
+
+
+@pytest.mark.parametrize("ahead", [False, True])
 @pytest.mark.parametrize("chunk, chunks", [(0, 1), (4, 3)])
 def test_queue_wait_and_exec_tile_submit_to_first_token(lm, tracer, chunk,
-                                                        chunks):
-    _drive(lm, [list(range(1, 11)), [9, 8, 7]], chunk=chunk)
+                                                        chunks, ahead):
+    _drive(lm, [list(range(1, 11)), [9, 8, 7]], chunk=chunk, ahead=ahead)
     spans = tracer.snapshot()["spans"]
     requests = _by_name(spans, "request")
     assert len(requests) == 2
@@ -138,11 +230,11 @@ def test_tracing_off_records_nothing_and_serves_the_same_tokens(lm):
     tr.disable()
     tr.reset()
     prompts = [[5, 6, 7], list(range(1, 11))]
-    eng, off = _drive(lm, prompts, chunk=4)
+    eng, off, _ = _drive(lm, prompts, chunk=4)
     assert tr.snapshot()["spans"] == []
     tr.configure(sample_rate=1.0)
     try:
-        _, on = _drive(lm, prompts, chunk=4)
+        _, on, _ = _drive(lm, prompts, chunk=4)
         assert tr.snapshot()["spans"]
     finally:
         tr.disable()

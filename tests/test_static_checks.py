@@ -210,6 +210,46 @@ def test_static_checks_script_passes_on_repo():
      "        for i, s in enumerate(self.streams):\n"
      "            s.emit(int(host[i]))\n",
      None),
+    # the boundary's ONE fetch (ISSUE 34): everything its flights left
+    # on the device in one straight-line device_get, host values
+    # scattered in the loops
+    ("flexflow_tpu/serving/generation/zz_ok_land.py",
+     "import jax\n\n"
+     "class E:\n"
+     "    def _land(self, *flights):\n"
+     "        host = jax.device_get([(f.nxt, f.first) for f in flights])\n"
+     "        for f, (nxt, first) in zip(flights, host):\n"
+     "            self._deliver_step(f, nxt)\n"
+     "    def _deliver_step(self, f, host):\n"
+     "        for i, s in f.rows:\n"
+     "            s.emit(int(host[i]))\n",
+     None),
+    # a fetch per flight, or per stream of the hand-over, is the
+    # per-stream sync again
+    ("flexflow_tpu/serving/generation/zz_bad_land.py",
+     "import jax\n\n"
+     "class E:\n"
+     "    def _land(self, *flights):\n"
+     "        for f in flights:\n"
+     "            self._deliver_step(f, jax.device_get(f.nxt))\n",
+     "RL010"),
+    ("flexflow_tpu/serving/generation/zz_bad_deliver.py",
+     "import numpy as np\n\n"
+     "class E:\n"
+     "    def _deliver_step(self, f, nxt):\n"
+     "        for i, s in f.rows:\n"
+     "            s.emit(int(np.asarray(nxt[i])))\n",
+     "RL010"),
+    # the join's first token fetched at once (a hand-off, a speculative
+    # round) is straight-line in the chunk's function
+    ("flexflow_tpu/serving/generation/zz_ok_chunk.py",
+     "import jax\n\n"
+     "class E:\n"
+     "    def _run_chunk(self, slot, st):\n"
+     "        first = self.prefill(slot)\n"
+     "        if st.at_once:\n"
+     "            st.tok = int(jax.device_get(first))\n",
+     None),
     # the `while` decode loop is the per-step granularity (the RL005
     # serve-loop analogue)
     ("flexflow_tpu/serving/generation/zz_ok_loop.py",
