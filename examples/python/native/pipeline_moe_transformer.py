@@ -19,7 +19,10 @@ SEQ, D_MODEL = 4, 16
 
 def stage(seg, t):
     """One pipeline stage: dense block (TP over `c` when present) + MoE
-    (EP over `e`)."""
+    (EP over `e`: every shard routes over all experts, sorts its (token,
+    choice) pairs by expert and runs the grouped products of the experts
+    it holds; the parts are summed).  `capacity_factor` truncates each
+    expert's group in that same dispatch; `None` would be dropless."""
     h = seg.dense(t, 32, activation="relu")
     h = seg.dense(h, D_MODEL)
     return seg.moe(h, num_experts=2, d_ff=32, k=1, capacity_factor=4.0,

@@ -125,17 +125,34 @@ def default_num_pages(slots: int, max_seq: int,
     return int(slots) * pages_per_slot(max_seq, page_size)
 
 
+def window_rows(window: int, max_seq: int, page_size: int,
+                prefill_chunk: int = 0) -> int:
+    """Rows a slot holds of a layer that reads the last ``window``
+    positions only: the window and the longest prompt chunk written before
+    it is read (``prefill_chunk``; 0 = whole prompts), never more than
+    ``max_seq``, in whole pages."""
+    rows = min(int(window) + (int(prefill_chunk) or int(max_seq)),
+               int(max_seq))
+    return -(-rows // page_size) * page_size
+
+
 def kv_cache_layout(layers: List[Op],
                     mesh_sizes: Optional[Dict[str, int]],
                     slots: int, max_seq: int,
                     page_size: int = DEFAULT_PAGE_SIZE,
-                    num_pages: int = 0) -> Dict[str, Dict]:
+                    num_pages: int = 0,
+                    prefill_chunk: int = 0) -> Dict[str, Dict]:
     """Per-op decode-state geometry: ``{op_name: {"kind":
     "kv"|"state", "shapes": {leaf: shape}, "entries": {leaf:
     PartitionSpec entries}, "dtype": "compute"|"f32"}}``, each entry
     what that op DECLARES (``Op.serve_state`` — a layer's serving form
-    lives in the layer; an op that keeps nothing has no entry).  The one
-    place the declarations are gathered — the generation decoder
+    lives in the layer; an op that keeps nothing has no entry).  A ``"kv"``
+    entry that declares a ``"window"`` is given ROWS OF ITS OWN here, a
+    ring a slot addressed by arithmetic on (slot, position) — leaves
+    ``(slots, rows // page_size, page_size, ..)`` with ``"rows"``
+    (:func:`window_rows`) noted on the entry — instead of pages of the
+    shared pool: the pool's page ids then index the other layers only.
+    The one place the declarations are gathered — the generation decoder
     allocates exactly this (through ``serving/generation/pages.py``, the
     only module allowed to allocate it — repo_lint RL013), and
     :func:`kv_page_plan` integrates exactly this."""
@@ -145,8 +162,18 @@ def kv_cache_layout(layers: List[Op],
     out: Dict[str, Dict] = {}
     for op in layers:
         entry = op.serve_state(int(slots), pool, page_size, mesh_sizes)
-        if entry is not None:
-            out[op.name] = entry
+        if entry is None:
+            continue
+        if entry.get("window"):
+            rows = window_rows(entry["window"], max_seq, page_size,
+                               prefill_chunk)
+            entry = dict(
+                entry, rows=rows,
+                shapes={leaf: (int(slots), rows // page_size) + tuple(
+                    shape[1:]) for leaf, shape in entry["shapes"].items()},
+                entries={leaf: (None,) + tuple(e) for leaf, e in
+                         entry["entries"].items()})
+        out[op.name] = entry
     return out
 
 
@@ -155,15 +182,20 @@ def kv_page_plan(layers: List[Op],
                  slots: int, max_seq: int,
                  kv_dtype_bytes: int = 2,
                  page_size: int = DEFAULT_PAGE_SIZE,
-                 num_pages: int = 0) -> Dict:
+                 num_pages: int = 0,
+                 prefill_chunk: int = 0) -> Dict:
     """THE page-pool accounting: per-DEVICE bytes of the paged decode
-    state.  Returns ``{"page_size", "pages_per_slot", "num_pages",
-    "page_bytes", "pool_bytes", "state_bytes", "total_bytes"}`` where
-    ``page_bytes`` is the per-device cost of ONE page summed over every
-    attention op's K+V pools (``kv_dtype_bytes`` each — the compute
-    dtype, 2 for bf16, 4 for f32 — heads divided over ``c``),
-    ``pool_bytes = num_pages * page_bytes``, and ``state_bytes`` is the
-    f32 LSTM ``(h, c)`` carry (``slots/n x hidden/c``).  Integrates
+    state, each kind of entry at what it holds.  Returns ``{"page_size",
+    "pages_per_slot", "num_pages", "page_bytes", "pool_bytes",
+    "window_bytes", "window_rows", "state_bytes", "total_bytes"}`` where
+    ``page_bytes`` is the per-device cost of ONE page summed over the K+V
+    pools of every attention op that pages (``kv_dtype_bytes`` each — the
+    compute dtype, 2 for bf16, 4 for f32 — heads divided over ``c``),
+    ``pool_bytes = num_pages * page_bytes``, ``window_bytes`` the rows of
+    the WINDOWED entries (``slots x window_rows`` each, whatever the pool:
+    ``prefill_chunk`` sizes them, :func:`window_rows`), and
+    ``state_bytes`` the f32 LSTM ``(h, c)`` carry (``slots/n x hidden/c``)
+    and the ops' counters.  Integrates
     :func:`kv_cache_layout` leaf-for-leaf, so the engine's real
     allocation and these numbers cannot drift apart; the engine's
     high-water mark is ``pages_high_water * page_bytes + state_bytes``
@@ -172,11 +204,14 @@ def kv_page_plan(layers: List[Op],
     page_size = int(page_size) or DEFAULT_PAGE_SIZE
     pool = int(num_pages) or default_num_pages(slots, max_seq, page_size)
     layout = kv_cache_layout(layers, mesh_sizes, slots, max_seq,
-                             page_size=page_size, num_pages=pool)
+                             page_size=page_size, num_pages=pool,
+                             prefill_chunk=prefill_chunk)
     n_deg = slot_shard_degree(slots, mesh_sizes)
     c = _axis(mesh_sizes, "c")
     page_bytes = 0.0
     state_bytes = 0.0
+    window_bytes = 0.0
+    rows = 0
     for entry in layout.values():
         bytes_per = (kv_dtype_bytes if entry["dtype"] == "compute"
                      else STATE_DTYPE_BYTES)
@@ -190,19 +225,24 @@ def kv_page_plan(layers: List[Op],
                     parts *= n_deg
                 elif e == "c":
                     parts *= c
-            if entry["kind"] == "kv":
+            if entry["kind"] != "kv":
+                state_bytes += vol * bytes_per / parts
+            elif entry.get("window"):
+                window_bytes += vol * bytes_per / parts
+                rows = max(rows, entry["rows"])
+            else:
                 # per-page cost: the pool volume divided by its pages
                 page_bytes += vol * bytes_per / parts / pool
-            else:
-                state_bytes += vol * bytes_per / parts
     return {
         "page_size": page_size,
         "pages_per_slot": pages_per_slot(max_seq, page_size),
         "num_pages": pool,
         "page_bytes": page_bytes,
         "pool_bytes": page_bytes * pool,
+        "window_bytes": window_bytes,
+        "window_rows": rows,
         "state_bytes": state_bytes,
-        "total_bytes": page_bytes * pool + state_bytes,
+        "total_bytes": page_bytes * pool + window_bytes + state_bytes,
     }
 
 
@@ -211,7 +251,7 @@ def kv_cache_bytes(layers: List[Op],
                    slots: int, max_seq: int,
                    kv_dtype_bytes: int = 2,
                    page_size: int = DEFAULT_PAGE_SIZE,
-                   num_pages: int = 0) -> float:
+                   num_pages: int = 0, prefill_chunk: int = 0) -> float:
     """Per-DEVICE bytes of the preallocated paged decode state — the
     scalar the FF108/FF121/FF130 gates charge (the ``total_bytes`` of
     :func:`kv_page_plan`).  With the default pool size and a
@@ -222,8 +262,8 @@ def kv_cache_bytes(layers: List[Op],
     docstring's sharding caveat."""
     return kv_page_plan(layers, mesh_sizes, slots, max_seq,
                         kv_dtype_bytes=kv_dtype_bytes,
-                        page_size=page_size,
-                        num_pages=num_pages)["total_bytes"]
+                        page_size=page_size, num_pages=num_pages,
+                        prefill_chunk=prefill_chunk)["total_bytes"]
 
 
 def default_serve_seq(input_tensors) -> Optional[int]:
@@ -250,7 +290,7 @@ def dtype_bytes(dtype_name: str) -> int:
         return 2 if "bfloat16" in str(dtype_name) else 4
 
 
-__all__ = ["kv_cache_layout", "kv_cache_bytes", "kv_page_plan",
+__all__ = ["kv_cache_layout", "kv_cache_bytes", "kv_page_plan", "window_rows",
            "slot_shard_degree", "pages_per_slot", "default_num_pages",
            "dtype_bytes", "default_serve_seq", "STATE_DTYPE_BYTES",
            "DEFAULT_PAGE_SIZE"]

@@ -303,7 +303,8 @@ def explain_report(model_name: str, layers: List[Op],
                    serve_slots: int = 0,
                    serve_seq: int = 0,
                    serve_kv_page: int = 0,
-                   serve_kv_pages: int = 0) -> Dict:
+                   serve_kv_pages: int = 0,
+                   serve_prefill_chunk: int = 0) -> Dict:
     """The full device-free ``flexflow-tpu explain`` payload: propagated
     sharding summary, predicted FF120 fallbacks, the communication plan
     (+ digest), and the liveness HBM timeline.  ``mesh_shape`` defaults
@@ -354,7 +355,8 @@ def explain_report(model_name: str, layers: List[Op],
         kv_plan = kv_page_plan(layers, mesh_shape, serve_slots,
                                serve_seq, kv_dtype_bytes=dtype_bytes,
                                page_size=serve_kv_page,
-                               num_pages=serve_kv_pages)
+                               num_pages=serve_kv_pages,
+                               prefill_chunk=serve_prefill_chunk)
         kv_bytes = kv_plan["total_bytes"]
         kv_section = {"slots": int(serve_slots),
                       "max_seq": int(serve_seq),
@@ -362,6 +364,8 @@ def explain_report(model_name: str, layers: List[Op],
                       "num_pages": kv_plan["num_pages"],
                       "page_bytes": kv_plan["page_bytes"],
                       "pool_bytes": kv_plan["pool_bytes"],
+                      "window_bytes": kv_plan["window_bytes"],
+                      "window_rows": kv_plan["window_rows"],
                       "state_bytes": kv_plan["state_bytes"],
                       "bytes_per_device": kv_bytes}
     timeline = sim.memory_timeline(layers, strategies, mesh_shape,

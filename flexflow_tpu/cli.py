@@ -244,6 +244,10 @@ def lint_main(argv) -> int:
     parser.add_argument("--serve-kv-pages", type=int, default=0,
                         help="KV pool pages (0 = auto, the dense "
                              "worst case slots x ceil(seq/page))")
+    parser.add_argument("--serve-prefill-chunk", type=int, default=0,
+                        help="prompt chunk of the deployment (0 = whole "
+                             "prompts): sizes the rows of windowed "
+                             "attention layers, window + chunk a slot")
     args = parser.parse_args(argv)
 
     if args.concurrency:
@@ -331,7 +335,8 @@ def lint_main(argv) -> int:
             model.layers, shape_for_kv, args.serve_slots, seq,
             kv_dtype_bytes=dtype_bytes(cfg.compute_dtype),
             page_size=args.serve_kv_page,
-            num_pages=args.serve_kv_pages)
+            num_pages=args.serve_kv_pages,
+            prefill_chunk=args.serve_prefill_chunk)
 
     from .analysis import verify
     report = verify(
@@ -457,6 +462,9 @@ def explain_main(argv) -> int:
     parser.add_argument("--serve-kv-pages", type=int, default=0,
                         help="KV pool pages (0 = auto, the dense "
                              "worst case)")
+    parser.add_argument("--serve-prefill-chunk", type=int, default=0,
+                        help="prompt chunk of the deployment (0 = whole "
+                             "prompts): sizes windowed layers' rows")
     args = parser.parse_args(argv)
 
     if args.fleet:
@@ -524,7 +532,8 @@ def explain_main(argv) -> int:
         num_devices=args.devices or None, spec=spec,
         serve_slots=args.serve_slots, serve_seq=serve_seq,
         serve_kv_page=args.serve_kv_page,
-        serve_kv_pages=args.serve_kv_pages)
+        serve_kv_pages=args.serve_kv_pages,
+        serve_prefill_chunk=args.serve_prefill_chunk)
     if args.json:
         import json as _json
         text = _json.dumps(rep, indent=2)

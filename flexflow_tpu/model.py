@@ -245,27 +245,44 @@ class FFModel:
 
     def moe(self, input_tensor, num_experts, d_ff, k=2, capacity_factor=1.25,
             activation="gelu", aux_loss_weight=1e-2, kernel_initializer=None,
+            gated=False, shared_d_ff=0, routed_scale=1.0,
             name=None) -> Tensor:
-        """Mixture-of-Experts FFN with top-k routing and capacity-factor
-        dispatch over the 'e' mesh axis (beyond the reference — its closest
+        """Mixture-of-Experts FFN: softmax router, top-``k`` renormalised
+        (times ``routed_scale``), ONE dispatch by sort — the (token,
+        choice) pairs sorted by expert and two grouped products over the
+        experts held (over the 'e' mesh axis each shard runs its own
+        experts' groups and the parts are summed).  ``capacity_factor``
+        truncates each expert's group (GShard's drop policy); ``None`` is
+        dropless, which is what serves.  ``gated``: SiLU-gated experts
+        without biases; ``shared_d_ff``: one shared gated expert of that
+        width beside the routed ones (beyond the reference — its closest
         analogue is DLRM per-table placement, dlrm.cc:106,469)."""
         from .ops.moe import MoE
         op = MoE(self._uname("moe", name), input_tensor, num_experts, d_ff,
                  k, capacity_factor, activation, aux_loss_weight,
-                 kernel_initializer)
+                 kernel_initializer, gated=gated, shared_d_ff=shared_d_ff,
+                 routed_scale=routed_scale)
         return self._register(op).outputs[0]
 
     def multihead_attention(self, query, key=None, value=None, embed_dim=None,
                             num_heads=8, kdim=0, vdim=0, dropout=0.0,
                             bias=True, causal=False, kernel_initializer=None,
-                            name=None) -> Tensor:
+                            num_kv_heads=None, head_dim=None, rope=None,
+                            gate=False, window=0, name=None) -> Tensor:
+        """``num_kv_heads`` (grouped queries), ``head_dim`` (a head size of
+        its own), ``rope`` (one layer kind's published ``rope_parameters``
+        entry), ``gate`` (per-head sigmoid output gate) and ``window``
+        (causal attention over the last ``window`` positions, with a
+        cache that holds no more) are ``MultiHeadAttention``'s."""
         from .ops.attention import MultiHeadAttention
         key = key if key is not None else query
         value = value if value is not None else key
         embed_dim = embed_dim or query.shape[-1]
         op = MultiHeadAttention(self._uname("attention", name), query, key,
                                 value, embed_dim, num_heads, kdim, vdim,
-                                dropout, bias, causal, kernel_initializer)
+                                dropout, bias, causal, kernel_initializer,
+                                num_kv_heads=num_kv_heads, head_dim=head_dim,
+                                rope=rope, gate=gate, window=window)
         return self._register(op).outputs[0]
 
     def position_embedding(self, input_tensor, max_len=None,

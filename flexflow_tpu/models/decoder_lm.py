@@ -1,0 +1,76 @@
+"""Pre-norm decoder language model with a LAYER LIST: each layer says its
+attention kind (``"full_attention"`` or ``"sliding_attention"``), its
+number of query heads and whether its feed-forward is ``"dense"`` or
+``"sparse"`` (a mixture of experts beside a shared one).  Rotary positions,
+grouped key/value heads, RMSNorm, SiLU-gated feed-forwards, no biases, an
+untied head: the block today's open decoders share, built on ``FFModel``'s
+normal calls, so it trains, is priced by the search and is served by the
+generation engine like any other graph.
+
+    a = RMSNorm(x);  x = x + Attention_l(a)          (rope, groups, gate,
+    b = RMSNorm(x);  x = x + F_l(b)                    window: the op's)
+    F dense:  (silu(b W1) * (b W3)) W2
+    F sparse: shared(b) + scale * sum_k p_k expert_k(b)      (``ops/moe.py``)
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Optional, Sequence, Tuple
+
+from ..config import FFConfig
+from ..model import FFModel
+from ..tensor import Tensor
+
+
+def build_decoder_lm(config: FFConfig, layers: Sequence[Dict],
+                     d_model: int, head_dim: int, num_kv_heads: int,
+                     d_ff: int, vocab_size: int, seq_len: int,
+                     rms_eps: float = 1e-6, window: int = 0,
+                     rope: Optional[Dict[str, Dict]] = None,
+                     gate: bool = False, moe: Optional[Dict] = None,
+                     kernel_initializer=None
+                     ) -> Tuple[FFModel, Tensor, Tensor]:
+    """``layers``: one ``{"attention": kind, "heads": query heads, "mlp":
+    "dense" | "sparse"}`` a layer.  ``rope``: ``{kind: rope_parameters
+    entry}`` (``ops/attention.rope_inv_freq``); ``window`` applies to the
+    ``"sliding_attention"`` layers; ``moe``: ``{"num_experts", "k",
+    "d_ff", "shared_d_ff", "routed_scale"}`` of the sparse layers
+    (dropless).  Returns ``(model, tokens, logits)``."""
+    ff = FFModel(config)
+    init = kernel_initializer
+    tokens = ff.create_tensor((config.batch_size, seq_len), dtype="int32",
+                              name="tokens")
+    x = ff.embedding(tokens, vocab_size, d_model, aggr="none",
+                     kernel_initializer=init, name="tok_embedding")
+    for i, layer in enumerate(layers):
+        kind = layer["attention"]
+        a = ff.rms_norm(x, eps=rms_eps, name=f"ln_attn_{i}")
+        a = ff.multihead_attention(
+            a, num_heads=int(layer["heads"]), num_kv_heads=num_kv_heads,
+            head_dim=head_dim, causal=True, bias=False,
+            rope=(rope or {}).get(kind), gate=gate,
+            window=window if kind == "sliding_attention" else 0,
+            kernel_initializer=init, name=f"attention_{i}")
+        x = ff.add(x, a, name=f"res_attn_{i}")
+        b = ff.rms_norm(x, eps=rms_eps, name=f"ln_ffn_{i}")
+        if layer["mlp"] == "sparse":
+            f = ff.moe(b, moe["num_experts"], moe["d_ff"], k=moe["k"],
+                       capacity_factor=None, aux_loss_weight=0.0,
+                       kernel_initializer=init, gated=True,
+                       shared_d_ff=moe.get("shared_d_ff", 0),
+                       routed_scale=moe.get("routed_scale", 1.0),
+                       name=f"moe_{i}")
+        else:
+            g = ff.dense(b, d_ff, activation="silu", use_bias=False,
+                         kernel_initializer=init, name=f"ffn_gate_{i}")
+            u = ff.dense(b, d_ff, use_bias=False, kernel_initializer=init,
+                         name=f"ffn_up_{i}")
+            f = ff.dense(ff.multiply(g, u, name=f"ffn_act_{i}"), d_model,
+                         use_bias=False, kernel_initializer=init,
+                         name=f"ffn_down_{i}")
+        x = ff.add(x, f, name=f"res_ffn_{i}")
+    x = ff.rms_norm(x, eps=rms_eps, name="ln_final")
+    logits = ff.dense(x, vocab_size, use_bias=False, kernel_initializer=init,
+                      name="lm_head")
+    ff.softmax(logits)
+    return ff, tokens, logits

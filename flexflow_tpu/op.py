@@ -155,7 +155,10 @@ class ServeStep:
     ``write_pages`` / ``write_rows`` (``(slots,)`` or ``(slots, W)``)
     arrive from the host, the pool's ``no_page`` sentinel dropping the
     write of a slot that is not decoding.  The arrays are the ones the
-    engine already computes; a field another kind uses is ``None``."""
+    engine already computes; a field another kind uses is ``None``.
+    ``no_page`` is that sentinel as a Python int (the shared pool's page
+    count), for an op whose state is NOT that pool (a windowed entry, a
+    counter) and that still has to know which slots a step serves."""
 
     kind: str
     table: jax.Array
@@ -165,6 +168,18 @@ class ServeStep:
     pos: Optional[jax.Array] = None
     write_pages: Optional[jax.Array] = None
     write_rows: Optional[jax.Array] = None
+    no_page: Optional[int] = None
+
+    def live(self, width: int):
+        """Which of the step's positions are real: ``(1, width)`` for a
+        chunk (the rows before ``length``), ``(slots, 1)`` or ``(slots,
+        W)`` for a token step or a window (the slots whose write page is
+        not the sentinel)."""
+        import jax.numpy as jnp
+        if self.kind == "chunk":
+            return (jnp.arange(width) < self.length)[None, :]
+        wp = self.write_pages
+        return (wp if wp.ndim == 2 else wp[:, None]) != self.no_page
 
 
 class Op:
@@ -212,12 +227,19 @@ class Op:
                     mesh_sizes: Optional[Dict[str, int]]
                     ) -> Optional[Dict]:
         """What this op keeps between tokens, or ``None`` (the default):
-        ``{"kind": "kv"|"state", "shapes": {leaf: shape}, "entries":
-        {leaf: PartitionSpec entries}, "dtype": "compute"|"f32"}``.
+        ``{"kind": "kv"|"state"|"counter", "shapes": {leaf: shape},
+        "entries": {leaf: PartitionSpec entries}, "dtype":
+        "compute"|"f32"|"i32"}``.
         ``"kv"`` leaves are page-major, ``(num_pages, page_size, ..)``:
-        they page, share prefixes, roll back and migrate; a ``"state"``
-        leaf is a fixed per-slot array, and a graph holding one prefills
-        whole prompts only.  ``analysis/kv_memory.kv_cache_layout``
+        they page, share prefixes, roll back and migrate; a ``"kv"`` entry
+        that also declares ``"window": W`` only ever reads the last ``W``
+        positions, and gets rows of its own instead of pages of the pool
+        (``(slots, rows_per_slot // page_size, page_size, ..)``, a ring
+        addressed by ``(slot, position)``: ``analysis/kv_memory``); a
+        ``"state"`` leaf is a fixed per-slot array, and a graph holding
+        one prefills whole prompts only; a ``"counter"`` leaf is something
+        the op counts on the device for ``stats()`` and nothing reads back
+        into the model.  ``analysis/kv_memory.kv_cache_layout``
         collects these; the engine allocates, the static gates charge and
         ``serve_step`` receives exactly what is declared here."""
         return None

@@ -159,7 +159,98 @@ def _flash_attention(q, k, v, causal: bool, scale: float, mesh=None):
     return kern(*args).reshape(q.shape)
 
 
-def _decode_attention(q, k_cache, v_cache, pos, scale: float):
+def _qk(q, k):
+    """``q k^T`` in f32, ``(n, h, q, k)``: ``q`` (n, q, h, d) against ``k``
+    (n, k, g, d).  With as many key heads as query heads this is the one
+    einsum it always was; with fewer (grouped queries) query head ``i``
+    reads key head ``i // (h / g)``, the key never repeated in memory."""
+    h, g = q.shape[2], k.shape[2]
+    if h == g:
+        return jnp.einsum("nqhd,nkhd->nhqk", q, k,
+                          preferred_element_type=jnp.float32)
+    n, sq, _, d = q.shape
+    s = jnp.einsum("nqgrd,nkgd->ngrqk", q.reshape(n, sq, g, h // g, d), k,
+                   preferred_element_type=jnp.float32)
+    return s.reshape(n, h, sq, k.shape[1])
+
+
+def _pv(probs, v):
+    """``probs v`` in f32, ``(n, q, h, d)``: the twin of :func:`_qk`."""
+    h, g = probs.shape[1], v.shape[2]
+    if h == g:
+        return jnp.einsum("nhqk,nkhd->nqhd", probs, v,
+                          preferred_element_type=jnp.float32)
+    n, _, sq, sk = probs.shape
+    o = jnp.einsum("ngrqk,nkgd->nqgrd",
+                   probs.reshape(n, g, h // g, sq, sk), v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(n, sq, h, v.shape[3])
+
+
+def rope_inv_freq(rope, head_dim: int):
+    """``(inverse frequencies (rot / 2,), factor on cos and sin, rot)`` of
+    a rotary embedding given as the published ``rope_parameters`` entry of
+    one layer kind: ``rope_type`` ``"default"`` (plain, ``rope_theta``) or
+    ``"yarn"`` (Peng et al. 2023 as ``transformers`` computes it: below
+    ``beta_slow`` rotations over ``original_max_position_embeddings`` a
+    dimension is interpolated by ``factor``, above ``beta_fast`` left as
+    it is, a linear ramp between; cos and sin times ``attention_factor``),
+    over the first ``partial_rotary_factor`` of the head."""
+    import numpy as np
+    rot = int(head_dim * float(rope.get("partial_rotary_factor", 1.0)))
+    base = float(rope["rope_theta"])
+    pos_freqs = base ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
+    kind = rope.get("rope_type", "default")
+    if kind == "default":
+        return (1.0 / pos_freqs).astype(np.float32), 1.0, rot
+    if kind != "yarn":
+        raise ValueError(f"rope_type {kind!r}: 'default' or 'yarn'")
+    factor = float(rope["factor"])
+    orig = float(rope["original_max_position_embeddings"])
+
+    def correction_dim(rotations):
+        return rot * math.log(orig / (rotations * 2 * math.pi)) / (
+            2 * math.log(base))
+
+    low = max(math.floor(correction_dim(float(rope["beta_fast"]))), 0)
+    high = min(math.ceil(correction_dim(float(rope["beta_slow"]))), rot - 1)
+    ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    inv = (1.0 / (factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * (1 - ramp)
+    att = rope.get("attention_factor")
+    att = 0.1 * math.log(factor) + 1.0 if att is None else float(att)
+    return inv.astype(np.float32), att, rot
+
+
+def apply_rope(x, positions, rope):
+    """Rotate the first ``rot`` dims of every head of ``x`` (n, s, h, d) to
+    ``positions`` (n, s) or (s,), half-split pairing ``(i, i + rot / 2)``;
+    the other dims pass.  Computed in f32, returned in ``x``'s dtype."""
+    inv, att, rot = rope_inv_freq(rope, x.shape[-1])
+    pos = jnp.asarray(positions, jnp.float32)
+    if pos.ndim == 1:
+        pos = pos[None]
+    ang = pos[..., None] * jnp.asarray(inv)                   # (n, s, rot/2)
+    cos = (jnp.cos(ang) * att)[:, :, None, :]
+    sin = (jnp.sin(ang) * att)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :rot // 2], xf[..., rot // 2:rot]
+    out = jnp.concatenate([a * cos - b * sin, b * cos + a * sin,
+                           xf[..., rot:]], axis=-1)
+    return out.astype(x.dtype)
+
+
+def _window_mask(scores, kpos, qpos, window: int):
+    """Also mask what a window leaves out: keys at ``kpos <= qpos -
+    window``, and ring rows nobody wrote yet (``kpos < 0``).  ``kpos`` and
+    ``qpos`` broadcast against ``scores`` (n, h, q, k)."""
+    if not window:
+        return scores
+    return jnp.where((kpos <= qpos - window) | (kpos < 0), NEG_INF, scores)
+
+
+def _decode_attention(q, k_cache, v_cache, pos, scale: float,
+                      kpos=None, window: int = 0):
     """Single-position attention against a preallocated per-slot KV
     cache (the autoregressive decode kernel — docs/serving.md "Token
     generation").  ``q``: (n, 1, h, d) — each slot's current-token
@@ -180,18 +271,21 @@ def _decode_attention(q, k_cache, v_cache, pos, scale: float):
     identical gemm micro-kernel.  One duplicated query row is noise in
     a decode step."""
     q2 = jnp.concatenate([q, q], axis=1)                      # (n,2,h,d)
-    scores = jnp.einsum("nqhd,nkhd->nhqk", q2, k_cache,
-                        preferred_element_type=jnp.float32) * scale
-    kpos = jnp.arange(k_cache.shape[1])
-    scores = jnp.where(kpos[None, None, None, :]
-                       > pos[:, None, None, None], NEG_INF, scores)
+    scores = _qk(q2, k_cache) * scale
+    if kpos is None:
+        kpos = jnp.arange(k_cache.shape[1])[None, None, None, :]
+    else:       # (n, L): the position each row of a ring view holds
+        kpos = kpos[:, None, None, :]
+    qpos = pos[:, None, None, None]
+    scores = jnp.where(kpos > qpos, NEG_INF, scores)
+    scores = _window_mask(scores, kpos, qpos, window)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("nhqk,nkhd->nqhd", probs.astype(v_cache.dtype),
-                     v_cache, preferred_element_type=jnp.float32)
+    out = _pv(probs.astype(v_cache.dtype), v_cache)
     return out[:, :1]
 
 
-def _paged_chunk_attention(q, kg, vg, qpos, scale: float):
+def _paged_chunk_attention(q, kg, vg, qpos, scale: float, kpos=None,
+                           window: int = 0):
     """Chunked-prefill attention against the gathered page view (the
     paged prefill kernel — docs/serving.md "Paged KV & prefix
     caching").  ``q``: (1, B, h, d) — the chunk's queries at GLOBAL
@@ -204,15 +298,17 @@ def _paged_chunk_attention(q, kg, vg, qpos, scale: float):
     row t reproduces the monolithic forward's row t bit-identically on
     CPU (tests/test_generation.py pins it per chunk size).  Columns
     beyond a row's position (unwritten pool rows, stale page contents)
-    contribute exact zeros, never values."""
-    scores = jnp.einsum("nqhd,nkhd->nhqk", q, kg,
-                        preferred_element_type=jnp.float32) * scale
-    kpos = jnp.arange(kg.shape[1])
-    scores = jnp.where(kpos[None, None, None, :]
-                       > qpos[None, None, :, None], NEG_INF, scores)
+    contribute exact zeros, never values.  ``kpos`` (L,) and ``window``:
+    the ring view of a windowed layer, as in :func:`_decode_attention`."""
+    scores = _qk(q, kg) * scale
+    if kpos is None:
+        kpos = jnp.arange(kg.shape[1])
+    kpos = kpos[None, None, None, :]
+    qpos = qpos[None, None, :, None]
+    scores = jnp.where(kpos > qpos, NEG_INF, scores)
+    scores = _window_mask(scores, kpos, qpos, window)
     probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("nhqk,nkhd->nqhd", probs.astype(vg.dtype), vg,
-                      preferred_element_type=jnp.float32)
+    return _pv(probs.astype(vg.dtype), vg)
 
 
 def _verify_window_attention(q, kg, vg, qpos, scale: float):
@@ -232,33 +328,31 @@ def _verify_window_attention(q, kg, vg, qpos, scale: float):
     not-yet-verified later rows and any stale speculated rows from a
     rolled-back round — contribute exact zeros, never values; rollback
     is free because visibility is the mask, not the write."""
-    scores = jnp.einsum("nqhd,nkhd->nhqk", q, kg,
-                        preferred_element_type=jnp.float32) * scale
+    scores = _qk(q, kg) * scale
     kpos = jnp.arange(kg.shape[1])
     scores = jnp.where(kpos[None, None, None, :]
                        > qpos[:, None, :, None], NEG_INF, scores)
     probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("nhqk,nkhd->nqhd", probs.astype(vg.dtype), vg,
-                      preferred_element_type=jnp.float32)
+    return _pv(probs.astype(vg.dtype), vg)
 
 
 def _dense_attention(q, k, v, causal: bool, scale: float,
-                     dropout_rate: float, rng):
-    """(n,sq,h,d),(n,sk,h,d),(n,sk,h,d) -> (n,sq,h,d); f32 softmax."""
-    scores = jnp.einsum("nqhd,nkhd->nhqk", q, k,
-                        preferred_element_type=jnp.float32) * scale
+                     dropout_rate: float, rng, window: int = 0):
+    """(n,sq,h,d),(n,sk,g,d),(n,sk,g,d) -> (n,sq,h,d); f32 softmax; with
+    ``window`` a query at ``i`` sees keys ``i - window < j <= i`` only."""
+    scores = _qk(q, k) * scale
     if causal:
         sq, sk = scores.shape[2], scores.shape[3]
         qpos = jnp.arange(sq)[:, None]
         kpos = jnp.arange(sk)[None, :]
         scores = jnp.where(kpos > qpos, NEG_INF, scores)
+        scores = _window_mask(scores, kpos, qpos, window)
     probs = jax.nn.softmax(scores, axis=-1)
     if dropout_rate > 0.0 and rng is not None:
         keep = 1.0 - dropout_rate
         mask = jax.random.bernoulli(rng, keep, probs.shape)
         probs = jnp.where(mask, probs / keep, 0.0)
-    return jnp.einsum("nhqk,nkhd->nqhd", probs.astype(v.dtype), v,
-                      preferred_element_type=jnp.float32)
+    return _pv(probs.astype(v.dtype), v)
 
 
 def _ring_attention_local(q, k, v, rng, *, s_axes, ring_size: int,
@@ -356,7 +450,19 @@ class MultiHeadAttention(Op):
 
     def __init__(self, name, query, key, value, embed_dim, num_heads,
                  kdim=0, vdim=0, dropout=0.0, use_bias=True, causal=False,
-                 kernel_initializer=None):
+                 kernel_initializer=None, num_kv_heads=None, head_dim=None,
+                 rope=None, gate=False, window=0):
+        """Beyond the defaults (as many key/value heads as query heads,
+        ``head_dim = embed_dim / num_heads``, learned positions elsewhere):
+        ``num_kv_heads`` key/value heads shared by groups of ``num_heads /
+        num_kv_heads`` query heads; a ``head_dim`` of its own (the
+        projections are then ``embed_dim -> heads * head_dim`` and back);
+        ``rope``, one layer kind's published ``rope_parameters`` entry
+        (:func:`rope_inv_freq`), applied to q and k at their positions
+        before the cores and before the cache's scatter; ``gate``, a
+        per-head sigmoid gate ``sigmoid(x Wg)_h`` on the attention output;
+        ``window``, causal attention over the last ``window`` positions
+        only (and a cache that holds no more: :meth:`serve_state`)."""
         inputs = [query] if key is query and value is query else [
             query, key, value]
         super().__init__(name, inputs)
@@ -367,10 +473,23 @@ class MultiHeadAttention(Op):
         self.vdim = vdim or value.shape[-1]
         assert self.kdim == key.shape[-1], (self.kdim, key.shape)
         assert self.vdim == value.shape[-1], (self.vdim, value.shape)
-        assert embed_dim % num_heads == 0, (embed_dim, num_heads)
-        self.head_dim = embed_dim // num_heads
+        if head_dim is None:
+            assert embed_dim % num_heads == 0, (embed_dim, num_heads)
+        self.head_dim = int(head_dim or embed_dim // num_heads)
+        self.num_kv_heads = int(num_kv_heads or num_heads)
+        assert num_heads % self.num_kv_heads == 0, (num_heads, num_kv_heads)
+        self.q_dim = num_heads * self.head_dim
+        self.kv_dim = self.num_kv_heads * self.head_dim
+        self.rope = dict(rope) if rope else None
+        self.gate, self.window = bool(gate), int(window or 0)
+        assert not self.window or causal, "a window needs causal attention"
         self.dropout, self.causal, self.use_bias = float(dropout), causal, use_bias
         self._self_attn = len(inputs) == 1
+        # what the flash kernels and the ring take: one head count, no
+        # window, positions from elsewhere (ROADMAP M1/M2: their training
+        # forms of grouped heads and of the window are not written)
+        self._plain = (self.num_kv_heads == num_heads and not self.window
+                       and self.rope is None and not self.gate)
         # {training: core} as last traced (see _attend)
         self.kernel_cores = {}
         # "paged" or "gathered": the decode core serve_step("token") got,
@@ -379,13 +498,17 @@ class MultiHeadAttention(Op):
         n, sq, dq = query.shape
         self._add_output((n, sq, embed_dim), query.dtype)
         init = kernel_initializer or GlorotUniform()
-        self.w_q = self._add_weight((embed_dim, dq), init, "wq", sharded_dim=0)
-        self.w_k = self._add_weight((embed_dim, key.shape[-1]), init, "wk",
+        self.w_q = self._add_weight((self.q_dim, dq), init, "wq",
                                     sharded_dim=0)
-        self.w_v = self._add_weight((embed_dim, value.shape[-1]), init, "wv",
+        self.w_k = self._add_weight((self.kv_dim, key.shape[-1]), init, "wk",
                                     sharded_dim=0)
-        self.w_o = self._add_weight((embed_dim, embed_dim), init, "wo",
+        self.w_v = self._add_weight((self.kv_dim, value.shape[-1]), init,
+                                    "wv", sharded_dim=0)
+        self.w_o = self._add_weight((embed_dim, self.q_dim), init, "wo",
                                     sharded_dim=1)
+        if self.gate:
+            self.w_g = self._add_weight((num_heads, dq), init, "wg",
+                                        sharded_dim=0)
         if use_bias:
             self.w_bias = self._add_weight((embed_dim,), ZeroInitializer(),
                                            "bias")
@@ -393,33 +516,48 @@ class MultiHeadAttention(Op):
     def _wants_ring(self, ctx: OpContext) -> bool:
         pc = self.parallel_config
         mesh = ctx.mesh
-        if mesh is None or mesh.axis_size("s") <= 1 or not self._self_attn:
+        if mesh is None or mesh.axis_size("s") <= 1 or not (
+                self._self_attn and self._plain):
             return False
         s_deg = pc.dims[1] if pc is not None and len(pc.dims) >= 2 else (
             mesh.axis_size("s"))
         return (s_deg == mesh.axis_size("s")
                 and self.inputs[0].shape[1] % s_deg == 0)
 
-    def _qkv(self, params, xq, xk, xv, ctx):
+    def _qkv(self, params, xq, xk, xv, ctx, positions=None):
         """The q/k/v projections — ONE implementation shared by
         forward and every kind of serving step (:meth:`serve_step`: a
         prompt chunk, a decode position, a verify window), so the
         cached K/V a decode step attends over carry exactly the bits
-        the full-sequence forward would recompute."""
+        the full-sequence forward would recompute.  ``positions`` (n, s) or
+        (s,): where the rows stand, for an op with rotary positions (the
+        keys are rotated BEFORE they are cached)."""
         n = xq.shape[0]
-        h, hd = self.num_heads, self.head_dim
+        hd = self.head_dim
 
-        def proj(x, w):
+        def proj(x, w, h):
             y = jnp.einsum("nsi,oi->nso", x, cast_compute(params[w.name], ctx),
                            preferred_element_type=jnp.float32)
             return cast_compute(y, ctx).reshape(n, x.shape[1], h, hd)
 
-        return proj(xq, self.w_q), proj(xk, self.w_k), proj(xv, self.w_v)
+        q, k = (proj(xq, self.w_q, self.num_heads),
+                proj(xk, self.w_k, self.num_kv_heads))
+        if self.rope is not None:
+            q, k = (apply_rope(q, positions, self.rope),
+                    apply_rope(k, positions, self.rope))
+        return q, k, proj(xv, self.w_v, self.num_kv_heads)
 
-    def _out_proj(self, params, attn, n, sq, ctx):
+    def _out_proj(self, params, attn, n, sq, ctx, xq=None):
         """The context -> embed output projection (+bias), shared by
-        forward/prefill/decode like :meth:`_qkv`."""
-        attn = cast_compute(attn, ctx).reshape(n, sq, self.embed_dim)
+        forward/prefill/decode like :meth:`_qkv`; the per-head gate, where
+        the op has one, is read off the op's input ``xq`` first."""
+        if self.gate:
+            g = jnp.einsum("nsi,hi->nsh", xq,
+                           cast_compute(params[self.w_g.name], ctx),
+                           preferred_element_type=jnp.float32)
+            attn = attn.reshape(n, sq, self.num_heads, self.head_dim) \
+                * jax.nn.sigmoid(g)[..., None]
+        attn = cast_compute(attn, ctx).reshape(n, sq, self.q_dim)
         out = jnp.einsum("nsi,oi->nso", attn,
                          cast_compute(params[self.w_o.name], ctx),
                          preferred_element_type=jnp.float32)
@@ -432,13 +570,14 @@ class MultiHeadAttention(Op):
         xk = xq if self._self_attn else cast_compute(inputs[1], ctx)
         xv = xq if self._self_attn else cast_compute(inputs[2], ctx)
         n, sq, _ = xq.shape
-        q, k, v = self._qkv(params, xq, xk, xv, ctx)
+        q, k, v = self._qkv(params, xq, xk, xv, ctx,
+                            None if self.rope is None else jnp.arange(sq))
         rng = None
         if ctx.training and self.dropout > 0.0 and ctx.rng is not None:
             rng = jax.random.fold_in(ctx.rng, self.outputs[0].uid)
         attn = self._attend(q, k, v, ctx, rng, ctx.training,
                             self._wants_ring(ctx))
-        return [self._out_proj(params, attn, n, sq, ctx)]
+        return [self._out_proj(params, attn, n, sq, ctx, xq)]
 
     def _attend(self, q, k, v, ctx: OpContext, rng=None,
                 training: bool = False, ring: bool = False):
@@ -453,14 +592,14 @@ class MultiHeadAttention(Op):
             core = "ring"
             attn = ring_attention(q, k, v, ctx.mesh, self.causal, scale,
                                   dropout, rng)
-        elif _use_flash(q, k, ctx.flash_attention, rng is not None,
-                        training=training):
+        elif self._plain and _use_flash(q, k, ctx.flash_attention,
+                                        rng is not None, training=training):
             core = _flash_core(q, k, ctx.mesh)
             attn = _flash_attention(q, k, v, self.causal, scale, ctx.mesh)
         else:
             core = "dense"
             attn = _dense_attention(q, k, v, self.causal, scale, dropout,
-                                    rng)
+                                    rng, self.window)
         self.kernel_cores[training] = core
         return attn
 
@@ -474,7 +613,7 @@ class MultiHeadAttention(Op):
         the donated output, again for the gather and once more for the
         einsums — eight pool-sized copies a layer).  The same bytes in
         the same order, so nothing a CPU parity pin reads changes."""
-        return kv.reshape(kv.shape[:-2] + (self.embed_dim,))
+        return kv.reshape(kv.shape[:-2] + (kv.shape[-2] * kv.shape[-1],))
 
     def _gather_pages(self, pool, table):
         """Each row of ``table`` (n, pages_per_slot) gathered out of
@@ -485,24 +624,31 @@ class MultiHeadAttention(Op):
         would gather NaN, which the exact-zero mask multiplies to NaN,
         not zero."""
         rows = jnp.take(pool, table, axis=0, mode="clip")
-        return rows.reshape(table.shape[0], -1, self.num_heads,
+        return rows.reshape(table.shape[0], -1, self.num_kv_heads,
                             self.head_dim)
 
     def serve_state(self, slots, num_pages, page_size, mesh_sizes):
-        """A K and a V pool, ``(num_pages, page_size, heads * head_dim)``:
-        lane-dense rows (:meth:`_fold_rows`), so that no consumer wants
-        the pool in another layout.  Pages are replicated over ``n``
-        (interchangeable across slots); the folded dim is sharded over
-        ``c`` like the projections feeding it, where ``c`` divides the
-        heads (each shard then holds whole heads)."""
+        """A K and a V pool, ``(num_pages, page_size, kv_heads *
+        head_dim)``: lane-dense rows (:meth:`_fold_rows`), so that no
+        consumer wants the pool in another layout.  Pages are replicated
+        over ``n`` (interchangeable across slots); the folded dim is
+        sharded over ``c`` like the projections feeding it, where ``c``
+        divides the key/value heads (each shard then holds whole heads).
+        An op with a ``window`` declares it: its rows are then a ring of
+        its own a slot, not pages of the pool (``analysis/kv_memory.
+        kv_cache_layout`` reshapes the entry; :meth:`serve_step` reads the
+        geometry off the leaves it is handed)."""
         c = (mesh_sizes or {}).get("c", 1)
-        c_entry = "c" if (c > 1 and self.num_heads % c == 0) else None
-        shape = (num_pages, page_size, self.num_heads * self.head_dim)
+        c_entry = "c" if (c > 1 and self.num_kv_heads % c == 0) else None
+        shape = (num_pages, page_size, self.kv_dim)
         entries = (None, None, c_entry)
-        return {"kind": "kv",
-                "shapes": {"k": shape, "v": shape},
-                "entries": {"k": entries, "v": entries},
-                "dtype": "compute"}
+        out = {"kind": "kv",
+               "shapes": {"k": shape, "v": shape},
+               "entries": {"k": entries, "v": entries},
+               "dtype": "compute"}
+        if self.window:
+            out["window"] = self.window
+        return out
 
     def serve_check(self, max_seq):
         if not (self._self_attn and self.causal):
@@ -551,9 +697,16 @@ class MultiHeadAttention(Op):
         Shares :meth:`_qkv`/:meth:`_out_proj` with forward."""
         xq = cast_compute(inputs[0], ctx)
         n, w, _ = xq.shape
-        q, k, v = self._qkv(params, xq, xq, xq, ctx)
-        k_pool, v_pool = state["k"], state["v"]
         chunk, token = where.kind == "chunk", where.kind == "token"
+        positions = None
+        if self.rope is not None or self.window:
+            positions = ((where.start + jnp.arange(w))[None] if chunk
+                         else where.pos[:, None] + jnp.arange(w)[None, :])
+        q, k, v = self._qkv(params, xq, xq, xq, ctx, positions)
+        if self.window:
+            return self._serve_step_window(params, xq, q, k, v, state,
+                                           where, positions, ctx)
+        k_pool, v_pool = state["k"], state["v"]
         if chunk:
             page = k_pool.shape[1]
             no_page = k_pool.shape[0]
@@ -585,7 +738,7 @@ class MultiHeadAttention(Op):
             from .paged_decode_kernel import paged_decode_attention
             attn = paged_decode_attention(
                 self._fold_rows(q[:, 0]), k_pool, v_pool, where.table,
-                where.pos, wp, self.num_heads, scale)
+                where.pos, wp, self.num_heads, scale, self.num_kv_heads)
         else:
             kg, vg = view(k_pool), view(v_pool)
             if chunk:
@@ -595,8 +748,76 @@ class MultiHeadAttention(Op):
             else:
                 qpos = where.pos[:, None] + jnp.arange(w)[None, :]
                 attn = _verify_window_attention(q, kg, vg, qpos, scale)
-        return ([self._out_proj(params, attn, n, w, ctx)],
+        return ([self._out_proj(params, attn, n, w, ctx, xq)],
                 {"k": k_pool, "v": v_pool})
+
+    def _serve_step_window(self, params, xq, q, k, v, state, where,
+                           positions, ctx: OpContext):
+        """The step of an op with a ``window``, against rows of its own:
+        ``state`` leaves are ``(slots, ring_pages, page, kv_heads *
+        head_dim)``, a ring of ``R = ring_pages * page`` rows a slot in
+        which position ``p`` of slot ``s`` lives at ``(s, (p % R) // page,
+        p % page)`` — arithmetic, so the host allocates and sends nothing
+        for it.  ``R >= window + the longest chunk`` (the engine sizes it),
+        so after a chunk's rows are written every position its first query
+        may see is still there.  The ring row ``r`` of a slot whose newest
+        position is ``last`` holds position ``last - (last - r) % R``:
+        negative for a row this stream has not written (what an earlier
+        stream left there is masked, never read as a value).  A chunk and
+        the gathered token step attend over the slot's whole ring with
+        those positions; the paged kernel copies only the pages that hold
+        ``pos - window + 1 .. pos``.  Speculation's verify window is
+        refused before it gets here (``GraphDecoder.refusal``)."""
+        if where.kind == "window":
+            raise NotImplementedError(
+                f"{self.name}: a verify window over a windowed cache")
+        chunk = where.kind == "chunk"
+        n, w = xq.shape[:2]
+        k_ring, v_ring = state["k"], state["v"]
+        slots, ring_pages, page, e = k_ring.shape
+        ring = ring_pages * page
+        live = where.live(w)                                  # (n, w)
+        slot = (jnp.reshape(where.slot, (1,)) if chunk
+                else jnp.arange(slots))
+        # dropped writes go past the last slot
+        ws = jnp.where(live, slot[:, None], slots)
+        wpg = (positions % ring) // page
+        wr = positions % page
+        k_ring = k_ring.at[ws, wpg, wr].set(self._fold_rows(k), mode="drop")
+        v_ring = v_ring.at[ws, wpg, wr].set(self._fold_rows(v), mode="drop")
+        scale = 1.0 / math.sqrt(self.head_dim)
+        if not chunk:
+            self.decode_core = self._decode_core(k_ring, ctx)
+        if not chunk and self.decode_core == "paged":
+            from .paged_decode_kernel import paged_decode_attention
+            table = (jnp.arange(slots, dtype=jnp.int32)[:, None] * ring_pages
+                     + jnp.arange(ring_pages, dtype=jnp.int32)[None, :])
+            pages = slots * ring_pages
+            attn = paged_decode_attention(
+                self._fold_rows(q[:, 0]), k_ring.reshape(pages, page, e),
+                v_ring.reshape(pages, page, e), table, where.pos,
+                jnp.where(live[:, 0], 0, pages).astype(jnp.int32),
+                self.num_heads, scale, self.num_kv_heads, self.window)
+        else:
+            last = positions[:, -1] if not chunk else (
+                where.start + where.length - 1)[None]
+            r = jnp.arange(ring)[None, :]
+            kpos = last[:, None] - (last[:, None] - r) % ring  # (n, ring)
+
+            def view(pool):
+                rows = jnp.take(pool, slot, axis=0, mode="clip")
+                return rows.reshape(n, ring, self.num_kv_heads,
+                                    self.head_dim)
+
+            kg, vg = view(k_ring), view(v_ring)
+            if chunk:
+                attn = _paged_chunk_attention(q, kg, vg, positions[0], scale,
+                                              kpos[0], self.window)
+            else:
+                attn = _decode_attention(q, kg, vg, where.pos, scale, kpos,
+                                         self.window)
+        return ([self._out_proj(params, attn, n, w, ctx, xq)],
+                {"k": k_ring, "v": v_ring})
 
     def _decode_core(self, pool, ctx: OpContext) -> str:
         """``"paged"`` where the token step can read the pool in place
@@ -607,7 +828,8 @@ class MultiHeadAttention(Op):
         distributed = ctx.mesh is not None and ctx.mesh.is_distributed
         return ("paged" if paged_decode_kernel.supported(
             jax.default_backend(), pool.dtype, self.num_heads,
-            self.head_dim, pool.shape[1], distributed) else "gathered")
+            self.head_dim, pool.shape[-2], distributed,
+            self.num_kv_heads) else "gathered")
 
     def parallel_dims(self):
         # (n, s, c): sample DP, sequence SP (ring), channel TP (heads)
@@ -629,17 +851,22 @@ class MultiHeadAttention(Op):
 
     def flops(self):
         n, s, d = self.outputs[0].shape
-        proj = 4 * 2 * n * s * d * d          # q,k,v,o projections
+        # q, k, v, o (and gate) projections, at their own widths
+        proj = 2 * n * s * sum(w.volume for w in self.weights
+                               if len(w.shape) == 2)
         sk = self.inputs[0].shape[1] if self._self_attn else \
             self.inputs[1].shape[1]
-        scores = 2 * 2 * n * s * sk * d       # qk^T and probs*v
+        if self.window:
+            sk = min(sk, self.window)
+        scores = 2 * 2 * n * s * sk * self.q_dim   # qk^T and probs*v
         return proj + scores
 
     def internal_io_bytes(self, flash_attention=None):
-        """Mirrors ``_use_flash``'s full selection (the cost model must
+        """Mirrors ``_attend``'s full selection (the cost model must
         charge for the kernel that will actually run): the flash kernel
-        needs no attention-prob dropout, 128-aligned seq lens, and a
-        lane-block head_dim; ``flash_attention`` False forces dense, True
+        needs a plain op (one head count, no window, rotary or gate), no
+        attention-prob dropout, 128-aligned seq lens, and a lane-block
+        head_dim; ``flash_attention`` False forces dense, True
         forces flash where legal, None = auto (s >= 512 — the TRAINING
         threshold, since the search objective is a training iteration).
         The backend check in ``_use_flash`` is deliberately absent — the
@@ -647,7 +874,11 @@ class MultiHeadAttention(Op):
         n, sq, _ = self.outputs[0].shape
         sk = self.inputs[0].shape[1] if self._self_attn else \
             self.inputs[1].shape[1]
-        flash_legal = (self.dropout == 0.0
+        # (``_plain``: grouped heads, a window, rotary positions or a gate
+        # take the dense core in ``_attend`` whatever the shapes are; the
+        # window there is a MASK over the whole (sq, sk) score matrix, so
+        # the bytes below are not bounded by it as ``flops()`` is)
+        flash_legal = (self._plain and self.dropout == 0.0
                        and sq % 128 == 0 and sk % 128 == 0
                        and (self.head_dim < 128 or self.head_dim % 128 == 0))
         if flash_attention is None:

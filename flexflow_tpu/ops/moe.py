@@ -1,27 +1,47 @@
-"""Mixture-of-Experts layer with true expert parallelism.
+"""Mixture-of-Experts layer: one dispatch, by sort, for training and serving.
 
 Capability BEYOND the reference: FlexFlow's closest analogue to expert
 parallelism is DLRM's per-embedding-table device placement
 (``examples/cpp/DLRM/dlrm.cc:106,469`` + ``dlrm_strategy_hetero.cc``) — one
-table per device, no token routing.  This op is the real thing, designed
-TPU-first in the GShard/Switch mold:
+table per device, no token routing.  This op routes tokens:
 
-* a router (dense gate) scores every token against every expert in f32;
-* top-k selection with a **capacity factor** — each expert processes at most
-  ``C = ceil(k * T / E * capacity_factor)`` tokens; overflow tokens fall
-  through the (zero-contribution) combine, exactly GShard's drop policy;
-* dispatch and combine are *dense einsums* against a (tokens, E, C) one-hot
-  tensor — static shapes, no gather/scatter, which is what lets XLA tile the
-  expert matmuls onto the MXU and turn the token movement into a single
-  ``all_to_all`` over the ``e`` mesh axis when expert weights are sharded
-  (per-expert FFN weights carry ``shard_axis="e"``);
+* a router (dense gate) scores every token against EVERY expert in f32;
+  softmax, the ``k`` largest kept and renormalised to sum to 1 (times
+  ``routed_scale``);
+* the ``(token, choice)`` pairs are SORTED by expert, so each expert's
+  tokens are one contiguous group of rows, and the experts run as two
+  GROUPED products over the experts held (``jax.lax.ragged_dot``: on a TPU
+  XLA's own grouped-matmul kernel, ``ragged-dot`` in a device trace) —
+  static shapes, ``tokens * k`` rows whatever the routing, nothing dropped,
+  and no ``(tokens, experts, capacity)`` tensor anywhere; the rows are then
+  unsorted and combined with their router weights;
+* a ``capacity_factor``, where a caller still gives one, truncates each
+  expert's group IN THAT SAME PATH: the rows past ``C = ceil(k * T / E *
+  capacity_factor)`` of a group keep their place and get weight zero
+  (GShard's drop policy, by the sorted order: token-major).  ``None`` is
+  dropless, the form that serves;
+* experts are ungated (``act(x W_up + b) W_down + b``) or ``gated``
+  (``(silu(x W1) * (x W3)) W2``, no biases, ``W1 | W3`` stored as one
+  ``(d, 2 f)`` matrix an expert so one product reads ``x`` once), and a
+  ``shared_d_ff`` adds one shared expert of that form and width that every
+  token takes, ungated by the router;
+* the op is told which experts it HOLDS.  Under an ``e`` mesh axis every
+  shard routes over all experts, runs this same body on the groups of the
+  experts it holds (``shard_map``; expert weights carry
+  ``shard_axis="e"``) and the parts are summed;
 * an optional Switch-style load-balancing auxiliary loss
   (``E * sum_e f_e * P_e``) is surfaced through ``ctx.aux_losses`` and added
   to the training objective by the fused step.
 
-Off the expert mesh (e == 1 / single device) the same einsums run locally,
-so numerics are identical by construction and tested to match
-(tests/test_moe.py).
+**Serving** (``docs/serving.md`` "A layer that serves").  Dropless, a
+token's result depends on no other token of its step, so ``forward`` on a
+chunk, a token step or a window IS the serving step; an op with a capacity
+refuses (``serve_check``).  What it keeps between steps is a COUNTER, not
+model state: per expert the live tokens it received, the token steps seen
+and the experts those steps left untouched, accumulated on the device; the
+token step returns a copy beside its tokens (``GraphDecoder.step_tokens``),
+which rides the boundary's one fetch, for ``stats()["moe"]`` and the
+``decode_step`` spans.
 """
 
 from __future__ import annotations
@@ -30,6 +50,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from ..initializers import GlorotUniform, ZeroInitializer
 from ..op import Op, OpContext, OpType
@@ -49,125 +70,265 @@ class _PerExpertInit:
 
 
 class MoE(Op):
-    """Token-routed expert FFN: (n, s, d) -> (n, s, d)."""
+    """Token-routed expert FFN: (n, s, d) -> (n, s, d).  Expert weights are
+    stored ``(experts, in, out)``, the layout the grouped product reads."""
 
     op_type = OpType.MOE
 
     def __init__(self, name, input_tensor, num_experts, d_ff, k=2,
                  capacity_factor=1.25, activation="gelu",
-                 aux_loss_weight=1e-2, kernel_initializer=None):
+                 aux_loss_weight=1e-2, kernel_initializer=None,
+                 gated=False, shared_d_ff=0, routed_scale=1.0):
         super().__init__(name, [input_tensor])
         n, s, d = input_tensor.shape
         self.num_experts = int(num_experts)
         self.d_ff = int(d_ff)
         self.k = min(int(k), self.num_experts)
-        self.capacity_factor = float(capacity_factor)
+        self.capacity_factor = (None if not capacity_factor
+                                else float(capacity_factor))
         self.activation = activation
         self.aux_loss_weight = float(aux_loss_weight)
+        self.gated = bool(gated)
+        self.shared_d_ff = int(shared_d_ff)
+        self.routed_scale = float(routed_scale)
         self._add_output((n, s, d), input_tensor.dtype)
         E = self.num_experts
         base = kernel_initializer or GlorotUniform()
         self.w_gate = self._add_weight((E, d), base, "gate")
-        # per-expert FFN in Linear's (out, in) layout, expert-stacked on dim
-        # 0 and sharded over the 'e' mesh axis (≙ the reference's per-table
-        # placement, dlrm.cc:106,469 — but with token all_to_all routing)
+
+        # per-expert FFN, expert-stacked on dim 0 and sharded over the 'e'
+        # mesh axis (≙ the reference's per-table placement, dlrm.cc:106,469
+        # — but with token routing)
         def ew(shape, init, nm):
             p = self._add_weight((E,) + shape, _PerExpertInit(init, E), nm,
                                  sharded_dim=0)
             p.shard_axis = "e"
             return p
 
-        self.w_up = ew((d_ff, d), base, "w_up")
-        self.w_upb = ew((d_ff,), ZeroInitializer(), "w_up_bias")
-        self.w_dn = ew((d, d_ff), base, "w_down")
-        self.w_dnb = ew((d,), ZeroInitializer(), "w_down_bias")
+        up = d_ff * (2 if self.gated else 1)
+        self.w_up = ew((d, up), base, "w_up")
+        self.w_dn = ew((d_ff, d), base, "w_down")
+        self.w_upb = self.w_dnb = None
+        if not self.gated:
+            self.w_upb = ew((d_ff,), ZeroInitializer(), "w_up_bias")
+            self.w_dnb = ew((d,), ZeroInitializer(), "w_down_bias")
+        self.w_sup = self.w_sdn = None
+        if self.shared_d_ff:
+            self.w_sup = self._add_weight((d, 2 * self.shared_d_ff), base,
+                                          "shared_up")
+            self.w_sdn = self._add_weight((self.shared_d_ff, d), base,
+                                          "shared_down")
+
+    # a dropless op acts on each position alone (serve_check)
+    @property
+    def position_wise(self) -> bool:
+        return self.capacity_factor is None
 
     @property
-    def capacity(self) -> int:
+    def capacity(self):
+        """Rows an expert's group keeps, or ``None`` (dropless)."""
+        if self.capacity_factor is None:
+            return None
         n, s, _ = self.inputs[0].shape
-        tokens = n * s
-        return max(1, math.ceil(self.k * tokens / self.num_experts
+        return max(1, math.ceil(self.k * n * s / self.num_experts
                                 * self.capacity_factor))
 
-    def forward(self, params, inputs, ctx: OpContext):
-        x = inputs[0]
+    # ---- the one dispatch ----------------------------------------------
+    def _route(self, params, xt):
+        """``(top_idx (T, k), gates (T, k) f32, probs (T, E) f32)``."""
+        with jax.named_scope("moe_router"):
+            gate = params[self.w_gate.name].astype(jnp.float32)
+            logits = jnp.einsum("td,ed->te", xt.astype(jnp.float32), gate)
+            probs = jax.nn.softmax(logits, axis=-1)
+            top_probs, top_idx = jax.lax.top_k(probs, self.k)
+            denom = jnp.sum(top_probs, axis=-1, keepdims=True) + 1e-9
+            return top_idx, top_probs / denom * self.routed_scale, probs
+
+    def _experts(self, weights, xt, top_idx, gates, tokens: int, first: int,
+                 ctx: OpContext):
+        """The routed experts' part of the output, (T, d) f32, from the
+        experts ``first .. first + held`` whose stacked weights
+        ``weights`` are (``held`` of ``num_experts``; every pair routed
+        elsewhere contributes zero here).  ``tokens``: how many tokens
+        share the capacity (the whole batch's, also on a token shard)."""
+        (w_up, w_dn), (b_up, b_dn) = weights[:2], weights[2:] or (None, None)
+        held = w_up.shape[0]
+        E, k = self.num_experts, self.k
+        T = xt.shape[0]
+        A = T * k
+        flat = top_idx.reshape(A)
+        order = jnp.argsort(flat, stable=True)          # pairs by expert
+        expert = flat[order]
+        counts = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        starts = jnp.cumsum(counts) - counts
+        weight = gates.reshape(A)[order]
+        if self.capacity_factor is not None:
+            cap = max(1, math.ceil(k * tokens / E * self.capacity_factor))
+            rank = jnp.arange(A) - starts[expert]
+            weight = jnp.where(rank < cap, weight, 0.0)
+        if held != E:
+            # this shard's groups to the front; the rest computes nothing
+            # it keeps (rows past the held groups are masked below)
+            offset = starts[first]
+            order = jnp.roll(order, -offset)
+            expert = jnp.roll(expert, -offset)
+            weight = jnp.roll(weight, -offset)
+            counts = jax.lax.dynamic_slice(counts, (first,), (held,))
+        mine = jnp.arange(A) < jnp.sum(counts)
+        token = order // k
+        xs = xt[token]                                           # (A, d)
+        with jax.named_scope("moe_experts"):
+            h = jax.lax.ragged_dot(xs, cast_compute(w_up, ctx), counts,
+                                   preferred_element_type=jnp.float32)
+            local = jnp.clip(expert - first, 0, held - 1)
+            if self.gated:
+                f = self.d_ff
+                h = jax.nn.silu(h[:, :f]) * h[:, f:]
+            else:
+                h = apply_activation(
+                    h + b_up.astype(h.dtype)[local], self.activation)
+            h = jnp.where(mine[:, None], cast_compute(h, ctx), 0)
+            y = jax.lax.ragged_dot(h, cast_compute(w_dn, ctx), counts,
+                                   preferred_element_type=jnp.float32)
+            if not self.gated:
+                y = y + b_dn.astype(y.dtype)[local]
+        y = jnp.where(mine[:, None], y, 0.0) * weight[:, None]
+        return jnp.zeros((T, xt.shape[1]), jnp.float32).at[token].add(y)
+
+    def _shared(self, params, xt, ctx: OpContext):
+        with jax.named_scope("moe_shared"):
+            f = self.shared_d_ff
+            h = jnp.einsum("td,df->tf", xt,
+                           cast_compute(params[self.w_sup.name], ctx),
+                           preferred_element_type=jnp.float32)
+            h = cast_compute(jax.nn.silu(h[:, :f]) * h[:, f:], ctx)
+            return jnp.einsum("tf,fd->td", h,
+                              cast_compute(params[self.w_sdn.name], ctx),
+                              preferred_element_type=jnp.float32)
+
+    def _moe(self, params, x, ctx: OpContext):
+        """``(out (n, s, d), top_idx (T, k), probs (T, E))``."""
         n, s, d = x.shape
-        T, E, C = n * s, self.num_experts, self.capacity
+        T, E = n * s, self.num_experts
         xt = cast_compute(x.reshape(T, d), ctx)
-        gate = params[self.w_gate.name].astype(jnp.float32)
-        logits = jnp.einsum("td,ed->te", xt.astype(jnp.float32), gate)
-        probs = jax.nn.softmax(logits, axis=-1)              # (T, E) f32
-
-        top_probs, top_idx = jax.lax.top_k(probs, self.k)    # (T, k)
-        denom = jnp.sum(top_probs, axis=-1, keepdims=True) + 1e-9
-        gates_k = top_probs / denom                          # renormalized
-
-        # slot-by-slot position assignment (GShard): slot 0 fills expert
-        # buffers first, tokens in order; overflow positions >= C are cut
-        dispatch = jnp.zeros((T, E, C), jnp.float32)
-        combine = jnp.zeros((T, E, C), jnp.float32)
-        base_count = jnp.zeros((E,), jnp.int32)
-        for j in range(self.k):
-            oh = jax.nn.one_hot(top_idx[:, j], E, dtype=jnp.int32)  # (T, E)
-            pos = jnp.cumsum(oh, axis=0) - 1 + base_count[None]     # (T, E)
-            base_count = base_count + jnp.sum(oh, axis=0)
-            pos_tok = jnp.sum(pos * oh, axis=-1)                    # (T,)
-            keep = (pos_tok < C).astype(jnp.float32)
-            slot = (jax.nn.one_hot(top_idx[:, j], E)
-                    * keep[:, None])[..., None] \
-                * jax.nn.one_hot(jnp.clip(pos_tok, 0, C - 1), C)[:, None, :]
-            dispatch = dispatch + slot
-            combine = combine + slot * gates_k[:, j, None, None]
-
+        top_idx, gates, probs = self._route(params, xt)
+        names = [self.w_up.name, self.w_dn.name] + (
+            [] if self.gated else [self.w_upb.name, self.w_dnb.name])
+        weights = tuple(params[nm] for nm in names)
         mesh = ctx.mesh
-        e_sharded = (mesh is not None and mesh.axis_size("e") > 1
-                     and E % mesh.axis_size("e") == 0)
+        shards = mesh.axis_size("e") if mesh is not None else 1
+        if shards > 1 and E % shards == 0:
+            # every shard: all the router's choices, its own experts
+            e_axes = mesh.subaxes("e")
+            n_axes = mesh.subaxes("n")
+            # a capacity ranks a group over the WHOLE batch: tokens then
+            # stay whole on every shard
+            t_axes = n_axes if (n_axes and self.capacity_factor is None
+                                and T % mesh.axis_size("n") == 0) else None
+            held = E // shards
 
-        def constrain_e(v):
-            if not e_sharded:
-                return v
-            from jax.sharding import PartitionSpec
-            return jax.lax.with_sharding_constraint(
-                v, mesh.sharding(PartitionSpec(
-                    "e", *([None] * (v.ndim - 1)))))
+            def body(xt, top_idx, gates, first, *w):
+                part = self._experts(w, cast_compute(xt, ctx), top_idx,
+                                     gates, T, first[0], ctx)
+                return jax.lax.psum(part, e_axes)
 
-        dd = cast_compute(dispatch, ctx)
-        # all_to_all boundary: (T,E,C)x(T,d) -> (E,C,d) expert batches
-        xe = constrain_e(jnp.einsum("tec,td->ecd", dd, xt,
-                                    preferred_element_type=jnp.float32))
-        xe = cast_compute(xe, ctx)
-        w_up = cast_compute(params[self.w_up.name], ctx)
-        w_dn = cast_compute(params[self.w_dn.name], ctx)
-        h = jnp.einsum("ecd,efd->ecf", xe, w_up,
-                       preferred_element_type=jnp.float32)
-        h = h + params[self.w_upb.name].astype(h.dtype)[:, None, :]
-        h = cast_compute(apply_activation(h, self.activation), ctx)
-        h = constrain_e(h)
-        y = jnp.einsum("ecf,edf->ecd", h, w_dn,
-                       preferred_element_type=jnp.float32)
-        y = y + params[self.w_dnb.name].astype(y.dtype)[:, None, :]
-        y = constrain_e(cast_compute(y, ctx))
-        out = jnp.einsum("tec,ecd->td", cast_compute(combine, ctx), y,
-                         preferred_element_type=jnp.float32)
+            rows = PartitionSpec(t_axes, None)
+            specs = tuple(PartitionSpec(e_axes, *([None] * (w.ndim - 1)))
+                          for w in weights)
+            # inside a pipeline stage the 'p' axes are manual already:
+            # take the context's mesh and make only ours manual (the
+            # tokens cross that inner boundary in f32: XLA's CPU
+            # partitioner aborts on a bf16 operand there)
+            inside = not jax.sharding.get_abstract_mesh().empty
+            where = (dict(axis_names=set(e_axes) | set(t_axes or ()))
+                     if inside else dict(mesh=mesh.mesh))
+            # a shard's first expert rides in as an e-sharded operand
+            # (lax.axis_index does not lower beside auto axes:
+            # parallel/pipeline.py)
+            firsts = jnp.arange(shards, dtype=jnp.int32) * held
+            routed = jax.shard_map(
+                body, in_specs=(rows, rows, rows, PartitionSpec(e_axes))
+                + specs, out_specs=rows, check_vma=False, **where)(
+                    xt.astype(jnp.float32) if inside else xt, top_idx,
+                    gates, firsts, *weights)
+        else:
+            routed = self._experts(weights, xt, top_idx, gates, T, 0, ctx)
+        out = routed
+        if self.shared_d_ff:
+            out = out + self._shared(params, xt, ctx)
+        return cast_compute(out, ctx).reshape(n, s, d), top_idx, probs
 
+    def forward(self, params, inputs, ctx: OpContext):
+        out, top_idx, probs = self._moe(params, inputs[0], ctx)
         if ctx.training and self.aux_loss_weight > 0.0:
             # Switch load-balance loss: E * sum_e (token fraction * mean
             # router prob); differentiable through P_e
+            E = self.num_experts
             f_e = jnp.mean(jax.nn.one_hot(top_idx[:, 0], E), axis=0)
             p_e = jnp.mean(probs, axis=0)
             ctx.aux_losses[self.name] = (self.aux_loss_weight * E
                                          * jnp.sum(f_e * p_e))
-        return [cast_compute(out, ctx).reshape(n, s, d)]
+        return [out]
 
+    # ---- serving --------------------------------------------------------
+    def serve_check(self, max_seq):
+        if self.capacity_factor is not None:
+            raise ValueError(
+                f"{self.name} (moe) cuts tokens past a capacity "
+                f"(capacity_factor={self.capacity_factor}): a token's "
+                f"result would depend on which other tokens share its "
+                f"step; build it dropless (capacity_factor=None) to serve")
+
+    def serve_state(self, slots, num_pages, page_size, mesh_sizes):
+        """Three counters, on the device: ``load`` (experts,), the live
+        tokens each expert received (prompt chunks and token steps);
+        ``token_steps``, the token steps that served anybody; and
+        ``untouched``, summed over those steps, the experts no live token
+        of the step chose."""
+        return {"kind": "counter",
+                "shapes": {"load": (self.num_experts,), "token_steps": (),
+                           "untouched": ()},
+                "entries": {"load": (None,), "token_steps": (),
+                            "untouched": ()},
+                "dtype": "i32"}
+
+    def serve_step(self, params, inputs, state, where, ctx: OpContext):
+        out, top_idx, _ = self._moe(params, inputs[0], ctx)
+        if state is None:
+            return [out], state
+        live = where.live(inputs[0].shape[1]).reshape(-1)          # (T,)
+        picks = jnp.zeros((self.num_experts,), jnp.int32).at[
+            top_idx.reshape(-1)].add(jnp.repeat(live.astype(jnp.int32),
+                                                self.k))
+        new = dict(state, load=state["load"] + picks)
+        if where.kind == "token":
+            served = jnp.any(live).astype(jnp.int32)
+            new["token_steps"] = state["token_steps"] + served
+            new["untouched"] = state["untouched"] + served * jnp.sum(
+                (picks == 0).astype(jnp.int32))
+        return [out], new
+
+    # ---- SOAP legality & cost model -------------------------------------
     def parallel_dims(self):
         # (n, s, c): DP/SP on tokens; the model dim stays whole (expert
         # parallelism rides the dedicated 'e' axis instead)
         return (True, True, False)
 
     def flops(self):
+        """The router over all experts, ``k`` routed experts and the shared
+        one a token: what the grouped products compute, whatever the
+        routing (a capacity only zeroes weights)."""
         n, s, d = self.outputs[0].shape
-        T, E, C = n * s, self.num_experts, self.capacity
-        router = 2 * T * d * E
-        dispatch = 2 * 2 * T * E * C * d        # dispatch + combine einsums
-        experts = 2 * 2 * E * C * d * self.d_ff  # up + down projections
-        return router + dispatch + experts
+        T = n * s
+        up = self.d_ff * (2 if self.gated else 1)
+        router = 2 * T * d * self.num_experts
+        experts = 2 * T * self.k * (d * up + self.d_ff * d)
+        shared = 2 * T * 3 * d * self.shared_d_ff
+        return router + experts + shared
+
+    def internal_io_bytes(self, flash_attention=None):
+        """The sorted copy of the tokens and the experts' hidden rows, each
+        written and read once in the compute dtype (2 B)."""
+        n, s, d = self.outputs[0].shape
+        up = self.d_ff * (2 if self.gated else 1)
+        return 2 * 2 * n * s * self.k * (2 * d + up + self.d_ff)

@@ -36,6 +36,23 @@ zeros the first step stores: finite, and masked to an exact 0.0 weight.
 Pages beyond ``pos`` are never read, so a stale table entry there — a
 page another stream owns by now — costs nothing and leaks nothing.
 
+**Grouped queries.**  With fewer key/value heads than query heads and a
+head that is whole lane tiles (``head_dim % 128 == 0``), the pools are
+``(num_pages, page, kv_heads * head_dim)`` and key/value head ``j`` is
+lanes ``j * head_dim ..`` of a row: its GROUP of query heads arrives as
+``_HEAD_ROWS`` rows (the group, zero-padded) of a ``(kv_heads * 16,
+head_dim)`` block, and the products are ``(16, head_dim) x (head_dim, T)``
+and ``(16, T) x (T, head_dim)`` a key/value head, on lane-aligned slices
+of the same buffers; nothing is block-diagonal and nothing is multiplied
+that is not needed.
+
+**A window.**  With ``window > 0`` a slot reads positions ``pos - window +
+1 .. pos`` only: its first copied page is the one that holds the lower
+bound, the positions below it in that page are masked like the ones above
+``pos``, and the page table is a RING (logical page ``p`` is entry ``p %
+pages_per_slot``: ``MultiHeadAttention._serve_step_window``).  One copy
+group then holds a whole window.
+
 The kernel's ``name=`` is ``paged_decode_attention`` in the device trace
 (not ``flash_..``: ``perfbench/flops``' ``FLASH_KERNELS`` matches on that
 prefix and the train cells' ``flash_share`` must not learn of it).
@@ -60,50 +77,71 @@ _VMEM_LIMIT = 32 << 20
 
 
 def supported(backend: str, dtype, num_heads: int, head_dim: int,
-              page_size: int, distributed: bool) -> bool:
+              page_size: int, distributed: bool,
+              num_kv_heads: int = 0) -> bool:
     """What the in-place read needs (``dtype``: the pool's, an array's
     ``.dtype``): a TPU; a pool dtype the MXU takes;
     the folded row a whole number of 128-lane tiles that no head straddles
     unevenly; a page that is whole sublane tiles of the dtype (a copy
     lands on tile boundaries) and tiles a 128-row chunk or is tiled by
     it; and ONE device — GSPMD would all-gather a sharded pool for an
-    opaque custom call (no cell serves across chips yet: ROADMAP W6)."""
+    opaque custom call (no cell serves across chips yet: ROADMAP W6).
+    Fewer key/value heads than query heads (``num_kv_heads``): a head of
+    whole lane tiles and a group of at most ``_HEAD_ROWS`` query heads."""
     if backend != "tpu" or distributed or dtype not in (jnp.bfloat16,
                                                         jnp.float32):
         return False
     sublanes = 8 * (4 // dtype_itemsize(dtype))
-    return ((num_heads * head_dim) % LANES == 0
+    kv_heads = num_kv_heads or num_heads
+    if kv_heads != num_heads and (head_dim % LANES
+                                  or num_heads // kv_heads > _HEAD_ROWS):
+        return False
+    return ((kv_heads * head_dim) % LANES == 0
             and (LANES % head_dim == 0 or head_dim % LANES == 0)
             and page_size % sublanes == 0
             and (LANES % page_size == 0 or page_size % LANES == 0))
 
 
-def _geometry(page: int, pages_per_slot: int, e: int, itemsize: int):
+def _geometry(page: int, pages_per_slot: int, e: int, itemsize: int,
+              window: int = 0):
     """``(chunk, group)``: key rows of one product (whole pages, 128 or
     one larger page) and of one copy group (whole chunks: no more than a
-    slot can hold, than ``_GROUP_ROWS``, than the buffers' budget)."""
+    slot can hold, than ``_GROUP_ROWS`` or one window and the page it
+    straddles, than the buffers' budget)."""
     chunk = max(page, LANES)
     whole = -(-pages_per_slot * page // chunk) * chunk
     fits = _BUFFER_BYTES // (4 * e * itemsize) // chunk * chunk
-    return chunk, max(chunk, min(whole, _GROUP_ROWS // chunk * chunk, fits))
+    want = _GROUP_ROWS if not window else max(_GROUP_ROWS, window + page)
+    return chunk, max(chunk, min(whole, -(-want // chunk) * chunk, fits))
 
 
 def _kernel(table_ref, pos_ref, wp_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, turn, *, num_heads, scale, page,
-            pages_per_slot, chunk):
+            k_buf, v_buf, sems, turn, *, num_heads, kv_heads, scale, page,
+            pages_per_slot, chunk, window):
     i, slots = pl.program_id(0), pl.num_programs(0)
     no_page = k_hbm.shape[0]
     group = k_buf.shape[1]
     group_pages = group // page
-    e = q_ref.shape[2]
-    head_dim = e // num_heads
+    e = k_buf.shape[2]
+    grouped = kv_heads != num_heads
+    head_dim = e // kv_heads
     rows = -(-num_heads // _HEAD_ROWS) * _HEAD_ROWS
 
     def decodes(s):
         return wp_ref[s] != no_page
 
+    def first_page(s):
+        """The logical page a slot's copies start at: 0, or with a window
+        the page of position ``pos - window + 1``."""
+        if not window:
+            return 0
+        return jnp.maximum(pos_ref[s] - (window - 1), 0) // page
+
     def live_pages(s):
-        return jnp.minimum(pos_ref[s] // page + 1, pages_per_slot)
+        """Logical pages ``first_page(s) ..`` that hold live positions."""
+        if not window:
+            return jnp.minimum(pos_ref[s] // page + 1, pages_per_slot)
+        return pos_ref[s] // page + 1 - first_page(s)
 
     def next_decoding(s):
         """The first slot at or after ``s`` that decodes; ``slots`` if
@@ -119,11 +157,15 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, k_hbm, v_hbm, o_ref,
         ``s``'s group ``g``, into buffer ``buf``."""
         first = g * group_pages
         count = jnp.minimum(live_pages(s) - first, group_pages)
+        base = first_page(s)
 
         def body(p, carry):
             # clipped like the gather's mode="clip": a live page is never
             # the sentinel, and a copy must not leave the pool whatever
-            pid = jnp.minimum(table_ref[s * pages_per_slot + first + p],
+            entry = first + p
+            if window:      # the table is a ring of pages_per_slot pages
+                entry = (base + entry) % pages_per_slot
+            pid = jnp.minimum(table_ref[s * pages_per_slot + entry],
                               no_page - 1)
             dst = pl.ds(pl.multiple_of(p * page, page), page)
             do(pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, dst],
@@ -162,13 +204,32 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, k_hbm, v_hbm, o_ref,
         groups = pl.cdiv(live_pages(i), group_pages)
         buf0 = turn[0]
         after = next_decoding(i + 1)
-        head_of_lane = jax.lax.broadcasted_iota(
-            jnp.int32, (rows, e), 1) // head_dim
-        diagonal = head_of_lane == jax.lax.broadcasted_iota(
-            jnp.int32, (rows, e), 0)
-        # selected in f32: an i32-derived mask does not lay out as bf16's
-        q_heads = jnp.where(diagonal, q_ref[0].astype(jnp.float32),
-                            0.0).astype(q_ref.dtype)
+        # the position the first row of group 0 holds
+        origin = first_page(i) * page
+        if grouped:
+            heads = tuple(range(kv_heads))
+            q_heads = None
+        else:
+            head_of_lane = jax.lax.broadcasted_iota(
+                jnp.int32, (rows, e), 1) // head_dim
+            diagonal = head_of_lane == jax.lax.broadcasted_iota(
+                jnp.int32, (rows, e), 0)
+            # selected in f32: an i32-derived mask does not lay out as bf16's
+            q_heads = jnp.where(diagonal, q_ref[0].astype(jnp.float32),
+                                0.0).astype(q_ref.dtype)
+
+        def softmax_step(carry, q, k, v, kpos):
+            m, l, acc = carry
+            s = _dot(q, k, _NT) * scale                        # (rows, T)
+            dead = kpos > pos
+            if window:
+                dead = jnp.logical_or(dead, kpos <= pos - window)
+            s = jnp.where(dead, NEG_INF, s)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            return (m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
+                    alpha * acc + _dot(p.astype(v.dtype), v))
 
         def one_group(g, carry):
             buf = (buf0 + g) % 2
@@ -182,58 +243,83 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, k_hbm, v_hbm, o_ref,
                 start(after, 0, 1 - buf)
 
             wait(i, g, buf)
-            base = g * group
+            base = origin + g * group
             chunks = pl.cdiv(jnp.minimum(pos + 1 - base, group), chunk)
 
             def one_chunk(c, carry):
-                m, l, acc = carry
                 at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
-                k, v = k_buf[buf, at, :], v_buf[buf, at, :]
-                s = _dot(q_heads, k, _NT) * scale              # (rows, T)
                 kpos = base + c * chunk + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1)
-                s = jnp.where(kpos > pos, NEG_INF, s)
-                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-                p = jnp.exp(s - m_new)
-                alpha = jnp.exp(m - m_new)
-                return (m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
-                        alpha * acc + _dot(p.astype(v.dtype), v))
+                    jnp.int32, (_HEAD_ROWS if grouped else rows, chunk), 1)
+                if not grouped:
+                    return softmax_step(carry, q_heads, k_buf[buf, at, :],
+                                        v_buf[buf, at, :], kpos)
+                out = []
+                for j in heads:     # a key/value head: lanes j * head_dim ..
+                    lanes = pl.ds(j * head_dim, head_dim)
+                    qj = q_ref[0, j * _HEAD_ROWS:(j + 1) * _HEAD_ROWS, :]
+                    out.append(softmax_step(carry[j], qj,
+                                            k_buf[buf, at, lanes],
+                                            v_buf[buf, at, lanes], kpos))
+                return tuple(out)
 
             return jax.lax.fori_loop(0, chunks, one_chunk, carry)
 
-        _, l, acc = jax.lax.fori_loop(
-            0, groups, one_group,
-            (jnp.full((rows, 1), NEG_INF, jnp.float32),
-             jnp.zeros((rows, 1), jnp.float32),
-             jnp.zeros((rows, e), jnp.float32)))
+        def empty(r, width):
+            return (jnp.full((r, 1), NEG_INF, jnp.float32),
+                    jnp.zeros((r, 1), jnp.float32),
+                    jnp.zeros((r, width), jnp.float32))
+
+        init = (tuple(empty(_HEAD_ROWS, head_dim) for _ in heads)
+                if grouped else empty(rows, e))
+        done = jax.lax.fori_loop(0, groups, one_group, init)
         turn[0] = (buf0 + groups) % 2
-        o_ref[0] = jnp.sum(jnp.where(diagonal, acc / l, 0.0), axis=0,
-                           keepdims=True)
+        if grouped:
+            for j, (_, l, acc) in enumerate(done):
+                o_ref[0, j * _HEAD_ROWS:(j + 1) * _HEAD_ROWS, :] = acc / l
+        else:
+            _, l, acc = done
+            o_ref[0] = jnp.sum(jnp.where(diagonal, acc / l, 0.0), axis=0,
+                               keepdims=True)
 
 
 # jitted so that the equal-shaped layers of a model share ONE traced and
 # lowered kernel (flash_kernel.py: tracing it per layer cost 3.5 s of set-up)
-@functools.partial(jax.jit, static_argnums=(6, 7))
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
 def paged_decode_attention(q, k_pool, v_pool, table, pos, write_pages,
-                           num_heads: int, scale: float):
+                           num_heads: int, scale: float,
+                           num_kv_heads: int = 0, window: int = 0):
     """``q``: (slots, h * hd), each slot's current-token query, folded;
-    ``k_pool`` / ``v_pool``: (num_pages, page, h * hd), the new rows
-    already written; ``table``: (slots, pages_per_slot) int32; ``pos``:
-    (slots,) int32 position of the current token; ``write_pages``:
-    (slots,) int32, ``num_pages`` where a slot is not decoding ->
-    (slots, h * hd) f32, folded, zero for such a slot.  The caller checks
-    :func:`supported`."""
-    slots, e = q.shape
-    page, pages_per_slot = k_pool.shape[1], table.shape[1]
-    chunk, group = _geometry(page, pages_per_slot, e, k_pool.dtype.itemsize)
-    row = pl.BlockSpec((1, 1, e), lambda i, *_: (i, 0, 0))
+    ``k_pool`` / ``v_pool``: (num_pages, page, g * hd), the new rows
+    already written (``g`` key/value heads, ``num_kv_heads`` or ``h``);
+    ``table``: (slots, pages_per_slot) int32; ``pos``: (slots,) int32
+    position of the current token; ``write_pages``: (slots,) int32,
+    ``num_pages`` where a slot is not decoding -> (slots, h * hd) f32,
+    folded, zero for such a slot.  ``window``: read positions ``pos -
+    window + 1 .. pos`` only, ``table`` a ring (the module's docstring).
+    The caller checks :func:`supported`."""
+    slots = q.shape[0]
+    kv_heads = num_kv_heads or num_heads
+    page, pages_per_slot, e = k_pool.shape[1], table.shape[1], k_pool.shape[2]
+    chunk, group = _geometry(page, pages_per_slot, e, k_pool.dtype.itemsize,
+                             window)
+    if kv_heads == num_heads:
+        q_rows, width = 1, e
+        q = q[:, None, :]
+    else:       # a key/value head's group of queries as _HEAD_ROWS rows
+        per, width = num_heads // kv_heads, e // kv_heads
+        q_rows = kv_heads * _HEAD_ROWS
+        q = jnp.pad(q.reshape(slots, kv_heads, per, width),
+                    ((0, 0), (0, 0), (0, _HEAD_ROWS - per), (0, 0))
+                    ).reshape(slots, q_rows, width)
+    row = pl.BlockSpec((1, q_rows, width), lambda i, *_: (i, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
     params = None if _interpret() else pltpu.CompilerParams(
         dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT)
     out = pl.pallas_call(
-        functools.partial(_kernel, num_heads=num_heads, scale=scale,
-                          page=page, pages_per_slot=pages_per_slot,
-                          chunk=chunk),
+        functools.partial(_kernel, num_heads=num_heads, kv_heads=kv_heads,
+                          scale=scale, page=page,
+                          pages_per_slot=pages_per_slot, chunk=chunk,
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(slots,),
             in_specs=[row, pool, pool], out_specs=row,
@@ -241,8 +327,11 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, write_pages,
                             pltpu.VMEM((2, group, e), v_pool.dtype),
                             pltpu.SemaphoreType.DMA((2, 2)),
                             pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((slots, 1, e), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((slots, q_rows, width), jnp.float32),
         compiler_params=params, interpret=_interpret(),
         name="paged_decode_attention",
-    )(table.reshape(-1), pos, write_pages, q[:, None, :], k_pool, v_pool)
-    return out[:, 0, :]
+    )(table.reshape(-1), pos, write_pages, q, k_pool, v_pool)
+    if kv_heads == num_heads:
+        return out[:, 0, :]
+    return out.reshape(slots, kv_heads, _HEAD_ROWS, width)[:, :, :per].reshape(
+        slots, num_heads * width)

@@ -134,7 +134,11 @@ def model_residency(spec: TenantSpec, layers, input_tensors, strategies,
             plan = kv_page_plan(layers, mesh_shape, slots, seq,
                                 kv_dtype_bytes=dtype_bytes(compute_dtype),
                                 page_size=kv_page or DEFAULT_PAGE_SIZE,
-                                num_pages=kv_pages)
+                                num_pages=kv_pages,
+                                prefill_chunk=int(
+                                    spec.generation.get("prefill_chunk", 0))
+                                or int(getattr(model_config,
+                                               "serve_prefill_chunk", 0)))
             kv = plan["total_bytes"]
     sim = Simulator(spec=device_spec,
                     num_devices=max(1, mesh.mesh_product),

@@ -168,9 +168,9 @@ def run_static(model, trace, slots: int, max_seq: int,
                 pos[i] = p
                 wp[i] = table[i, p // page]
                 wr[i] = p % page
-            nxt, caches = dec.decode_fn()(model._params, caches, toks,
+            out, caches = dec.decode_fn()(model._params, caches, toks,
                                           pos, table, wp, wr)
-            host = np.asarray(jax.device_get(nxt))
+            host = np.asarray(jax.device_get(dec.step_tokens(out)[0]))
             steps += 1
             for i, st in enumerate(states):
                 st["len"] += 1

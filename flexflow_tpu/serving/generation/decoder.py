@@ -49,12 +49,15 @@ the step stands (``op.ServeStep``); what a layer keeps between tokens,
 whether it can generate at all and how it advances are the op's own
 (``Op.serve_state`` / ``serve_check`` / ``serve_step``), so this module
 names no op class.  Supported graphs: one (n, s) int token input;
-position-wise ops (dense/norms/elementwise/softmax/dropout/embedding),
-causal self-attention, stateless-init LSTM, learned position
+position-wise ops (dense/norms/elementwise/softmax/dropout/embedding, a
+dropless MoE), causal self-attention (grouped heads, rotary positions, a
+window with rows of its own), stateless-init LSTM, learned position
 embeddings, and whatever else writes the contract.  Anything else
-(convs, splits, cross-attention, MoE, pipelines) fails validation
-loudly at construction — a generation engine must never silently
-produce wrong tokens for an unsupported graph.
+(convs, splits, cross-attention, an MoE with a capacity, pipelines) fails
+validation loudly at construction — a generation engine must never
+silently produce wrong tokens for an unsupported graph.  What a graph
+with a WINDOWED entry cannot do yet is refused in one place,
+:meth:`GraphDecoder.refusal`.
 """
 
 from __future__ import annotations
@@ -70,7 +73,7 @@ from ...analysis.kv_memory import (DEFAULT_PAGE_SIZE, default_num_pages,
                                    kv_cache_layout, pages_per_slot)
 from ...op import OpContext, ServeStep
 from . import sampling
-from .pages import alloc_pool_arrays
+from .pages import alloc_pool_arrays, entry_dtype
 
 
 # one computation of a compiled module's text: `%name (params) -> type {`
@@ -149,7 +152,8 @@ class GraphDecoder:
     compiles."""
 
     def __init__(self, model, slots: int, max_seq: int,
-                 page_size: int = 0, num_pages: int = 0):
+                 page_size: int = 0, num_pages: int = 0,
+                 prefill_chunk: int = 0):
         if slots < 2:
             raise ValueError(
                 f"slots must be >= 2, got {slots}: a 1-slot decode "
@@ -182,8 +186,21 @@ class GraphDecoder:
         self.layout = kv_cache_layout(model.layers, self._mesh_sizes,
                                       self.slots, self.max_seq,
                                       page_size=self.page_size,
-                                      num_pages=self.num_pages)
-        kinds = {ent["kind"] for ent in self.layout.values()}
+                                      num_pages=self.num_pages,
+                                      prefill_chunk=int(prefill_chunk))
+        # entries with rows of their own, sized for chunks up to
+        # ``prefill_chunk`` (0 = whole prompts): a longer chunk would
+        # overwrite rows its own first query still reads, so no program
+        # for one is built
+        self.windowed = {name: ent for name, ent in self.layout.items()
+                         if ent.get("window")}
+        if self.windowed and prefill_chunk:
+            self.buckets = prefill_buckets(min(self.max_seq,
+                                               int(prefill_chunk)))
+        # what ops count on the device for stats(), by entry name
+        self.counters = tuple(name for name, ent in self.layout.items()
+                              if ent["kind"] == "counter")
+        kinds = {ent["kind"] for ent in self.layout.values()} - {"counter"}
         # a fixed per-slot "state" leaf cannot page: a chunk at offset k
         # would need the carry from chunk k-1 as a program input —
         # whole-prompt chunks only (the engine enforces it)
@@ -196,6 +213,28 @@ class GraphDecoder:
         self._decode_sampled_fn = None
         self._verify_fns: Dict[Tuple[int, bool], object] = {}
         self._draft_fns: Dict[Tuple[int, bool], object] = {}
+
+    def refusal(self, what: str):
+        """THE gate beside ``pageable``: why this graph's state cannot do
+        ``what`` (``"prefix reuse"``, ``"speculation"``, ``"migration"``),
+        or ``None`` if it can.  Each needs every leaf page-major in the
+        SHARED pool: a reused prefix borrows pages by id, a rejected
+        window is rolled back by position, a migrated stream ships its
+        page chain.  A windowed entry keeps a ring of its own a slot,
+        rewritten in place: it has no page to lend, holds no position
+        older than its window to roll back to, and its rows are not in
+        the chain.  Named, never silently wrong."""
+        if not self.pageable:
+            return (f"{what} needs paged attention state throughout "
+                    f"(a fixed per-slot state leaf cannot page)")
+        if self.windowed:
+            name, ent = next(iter(self.windowed.items()))
+            return (f"{what} is not supported over a windowed cache entry: "
+                    f"{name} keeps the last {ent['window']} positions in "
+                    f"{ent['rows']} rows of its own a slot, outside the "
+                    f"page pool ({len(self.windowed)} such "
+                    f"entr{'y' if len(self.windowed) == 1 else 'ies'})")
+        return None
 
     # ---- validation ----------------------------------------------------
     def _validate(self) -> None:
@@ -272,7 +311,8 @@ class GraphDecoder:
         def prefill(params, caches, tokens, table_row, slot, start,
                     length):
             logits, new = self._walk(params, caches, tokens, ServeStep(
-                "chunk", table_row, start=start, length=length, slot=slot))
+                "chunk", table_row, start=start, length=length, slot=slot,
+                no_page=self.num_pages))
             last = jax.lax.dynamic_index_in_dim(
                 logits, length - 1, axis=1, keepdims=False)[0]
             nxt = jnp.argmax(last).astype(jnp.int32)
@@ -287,7 +327,9 @@ class GraphDecoder:
         """THE decode step, jitted once per geometry:
         ``fn(params, caches, tokens (slots,), pos (slots,), table
         (slots, pages_per_slot), write_pages (slots,), write_rows
-        (slots,)) -> (next_tokens (slots,), caches)``.  Every slot
+        (slots,)) -> (next_tokens (slots,), caches)`` (for a graph that
+        counts, ``((next_tokens, counters), caches)``: :meth:`step_tokens`).
+        Every slot
         advances one position per call — inactive/prefilling slots
         compute on dummy inputs with ``write_pages`` at the pool's OOB
         sentinel (their scatter drops; a write through a stale table
@@ -304,7 +346,7 @@ class GraphDecoder:
                                             table, write_pages,
                                             write_rows)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return nxt, new
+            return self._counted(nxt, new), new
 
         self._decode_fn = jax.jit(decode, donate_argnums=(1,))
         return self._decode_fn
@@ -335,7 +377,7 @@ class GraphDecoder:
             keys = sampling.position_keys(sampling.request_keys(seeds),
                                           pos + 1, sampling.STREAM_MAIN)
             nxt = sampling.categorical(keys, probs)
-            return nxt, new
+            return self._counted(nxt, new), new
 
         self._decode_sampled_fn = jax.jit(decode_s, donate_argnums=(1,))
         return self._decode_sampled_fn
@@ -371,7 +413,7 @@ class GraphDecoder:
         logits, new = self._walk(
             params, caches, tokens[:, None],
             ServeStep("token", table, pos=pos, write_pages=write_pages,
-                      write_rows=write_rows))
+                      write_rows=write_rows, no_page=self.num_pages))
         return logits[:, 0], new
 
     # ---- speculative decoding (docs/serving.md "Speculative
@@ -386,7 +428,7 @@ class GraphDecoder:
         return self._walk(
             params, caches, window,
             ServeStep("window", table, pos=pos, write_pages=write_pages,
-                      write_rows=write_rows))
+                      write_rows=write_rows, no_page=self.num_pages))
 
     def verify_fn(self, width: int, sampled: bool = False):
         """The jitted speculative-VERIFY program for one window width
@@ -546,7 +588,7 @@ class GraphDecoder:
         compute = self.model.config.compute_dtype
         caches = {}
         for name, ent in self.layout.items():
-            dt = compute if ent["dtype"] == "compute" else jnp.float32
+            dt = entry_dtype(ent, compute)
             caches[name] = {
                 leaf: spec(shape, dt, mesh.sharding(PartitionSpec(
                     *ent["entries"][leaf]))
@@ -623,14 +665,77 @@ class GraphDecoder:
         op at trace time (``MultiHeadAttention.decode_core``), like
         ``FFModel.attention_kernels()``; all zero before a token step is
         traced."""
-        cores = [getattr(op, "decode_core", None) for op in self.model.layers]
-        return {core: cores.count(core) for core in ("paged", "gathered")}
+        def count(ops):
+            cores = [getattr(op, "decode_core", None) for op in ops]
+            return {core: cores.count(core) for core in ("paged", "gathered")}
+
+        out = count(self.model.layers)
+        if self.windowed:   # by layer kind, where the graph has two
+            out["windowed"] = count(op for op in self.model.layers
+                                    if op.name in self.windowed)
+        return out
+
+    def _counted(self, nxt, new):
+        """What a token step returns first: its tokens, and for a graph
+        whose ops count on the device (``self.counters``) ``(tokens, a COPY
+        of those counters)`` as the step left them.  The counters
+        themselves live in the cache tree and are donated to the next
+        program; a second output of the step is a buffer of its own, so it
+        can ride the boundary's one fetch whenever that comes, and no
+        other program ever reads a donated buffer (one that did, a copy
+        dispatched behind the step, made the next dispatch wait for the
+        device on a TPU).  A graph that counts nothing returns what it
+        returned before."""
+        if not self.counters:
+            return nxt
+        return nxt, {n: dict(new[n]) for n in self.counters}
+
+    def step_tokens(self, out):
+        """``(tokens, counters or None)`` of a token step's first
+        output (:meth:`_counted`)."""
+        return out if self.counters else (out, None)
+
+    def moe_stats(self, host) -> Dict[str, Dict]:
+        """``{op: {"assignments", "load_max_over_mean", "token_steps",
+        "untouched_share", "load"}}`` for every op that counts its routing
+        on the device (``"counter"`` entries with a ``load`` leaf: the
+        MoE's), from ``host``, a token step's counters fetched."""
+        out = {}
+        for n, c in (host or {}).items():
+            if "load" not in c:
+                continue
+            load = np.asarray(c["load"], np.int64)
+            steps = int(c["token_steps"])
+            mean = float(load.mean())
+            out[n] = {
+                "assignments": int(load.sum()),
+                "load_max_over_mean": (float(load.max()) / mean
+                                       if mean else 0.0),
+                "token_steps": steps,
+                "untouched_share": (float(c["untouched"])
+                                    / (steps * load.size) if steps else 0.0),
+                "load": load.tolist()}
+        return out
+
+    def moe_totals(self, host) -> Dict[str, int]:
+        """What a ``decode_step`` span carries of those counters, summed
+        over the ops, as they stood behind that step: ``moe_expert_steps``
+        (token steps x experts) and ``moe_untouched`` (of them, the experts
+        no live token chose).  The difference between two spans is what
+        the steps between them touched."""
+        moe = [c for c in (host or {}).values() if "load" in c]
+        if not moe:
+            return {}
+        return {"moe_expert_steps": sum(int(c["token_steps"])
+                                        * int(np.size(c["load"]))
+                                        for c in moe),
+                "moe_untouched": sum(int(c["untouched"]) for c in moe)}
 
     # ---- shared-instance registry --------------------------------------
     @classmethod
     def for_model(cls, model, slots: int, max_seq: int,
-                  page_size: int = 0, num_pages: int = 0
-                  ) -> "GraphDecoder":
+                  page_size: int = 0, num_pages: int = 0,
+                  prefill_chunk: int = 0) -> "GraphDecoder":
         """One decoder per (model, slots, max_seq, page geometry):
         engines sharing a geometry share the jitted prefill/decode
         programs (the compile cost is the startup cost, like the
@@ -649,10 +754,15 @@ class GraphDecoder:
                    or (default_num_pages(slots, max_seq, ps)
                        if ps > 0 else 0))
         reg = model.__dict__.setdefault("_gen_decoders", {})
-        key = (int(slots), int(max_seq), ps, pool)
+        # the chunk is part of the geometry only where it sizes something
+        # (a windowed entry's rows): other graphs share their programs
+        # across engines of any chunk
+        chunk = int(prefill_chunk) if any(
+            getattr(op, "window", 0) for op in model.layers) else 0
+        key = (int(slots), int(max_seq), ps, pool, chunk)
         dec = reg.get(key)
         if dec is None:
             dec = cls(model, slots, max_seq, page_size=ps,
-                      num_pages=pool)
+                      num_pages=pool, prefill_chunk=chunk)
             reg[key] = dec
         return dec

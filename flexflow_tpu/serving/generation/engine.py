@@ -288,12 +288,15 @@ class _Flight:
     slots it advanced (``rows``: ``(slot, _Slot, last)``, ``last`` = the
     stream retires on this token by ``max_new_tokens``, decided by count
     at dispatch), and the join's ``first`` token (``join``: ``(slot,
-    _Slot, last, bucket, fn)``)."""
+    _Slot, last, bucket, fn)``), and a copy of what the graph's ops have
+    counted so far (``counters``: a second output of the token step,
+    ``GraphDecoder.step_tokens``; ``None`` where nothing counts)."""
 
-    __slots__ = ("nxt", "rows", "fn", "t0", "step", "first", "join")
+    __slots__ = ("nxt", "rows", "fn", "t0", "step", "first", "join",
+                 "counters")
 
     def __init__(self):
-        self.nxt = self.fn = self.first = self.join = None
+        self.nxt = self.fn = self.first = self.join = self.counters = None
         self.rows: List = []
         self.t0 = 0.0
         self.step = 0
@@ -583,9 +586,18 @@ class GenerationEngine:
         # flight taps installed for post-mortem dumps
         self._tracer = tracer_from_config(cfg)
         get_flight()
+        # chunked prefill: at most one chunk per step boundary; 0 =
+        # whole-prompt chunks (the monolithic baseline).  LSTM graphs
+        # cannot chunk (cell state is not a program input mid-prompt).
+        chunk = int(cfg.serve_prefill_chunk if prefill_chunk is None
+                    else prefill_chunk)
+        if chunk < 0:
+            raise ValueError(f"serve_prefill_chunk must be >= 0, "
+                             f"got {chunk}")
         self._decoder = GraphDecoder.for_model(
             model, self.slots, self.max_seq,
-            page_size=int(page_size or 0), num_pages=int(num_pages or 0))
+            page_size=int(page_size or 0), num_pages=int(num_pages or 0),
+            prefill_chunk=chunk)
         self.page_size = self._decoder.page_size
         self.num_pages = self._decoder.num_pages
         # the ONE KV accounting (analysis.kv_memory): what lint's
@@ -597,16 +609,9 @@ class GenerationEngine:
             dict(model.mesh.sizes) if model.mesh is not None else None,
             self.slots, self.max_seq,
             kv_dtype_bytes=dtype_bytes(cfg.compute_dtype),
-            page_size=self.page_size, num_pages=self.num_pages)
+            page_size=self.page_size, num_pages=self.num_pages,
+            prefill_chunk=chunk)
         self.kv_cache_bytes = self.kv_plan["total_bytes"]
-        # chunked prefill: at most one chunk per step boundary; 0 =
-        # whole-prompt chunks (the monolithic baseline).  LSTM graphs
-        # cannot chunk (cell state is not a program input mid-prompt).
-        chunk = int(cfg.serve_prefill_chunk if prefill_chunk is None
-                    else prefill_chunk)
-        if chunk < 0:
-            raise ValueError(f"serve_prefill_chunk must be >= 0, "
-                             f"got {chunk}")
         self.prefill_chunk = (chunk if self._decoder.supports_chunking
                               else 0)
         # shared-prefix cache: on unless configured off; needs the
@@ -614,9 +619,12 @@ class GenerationEngine:
         # pageable state to share)
         pc = (cfg.serve_prefix_cache if prefix_cache is None
               else prefix_cache)
+        # (GraphDecoder.refusal: the one gate; why it is off, where the
+        # graph's state cannot lend pages, is in stats()["kv_pages"])
+        self.prefix_cache_refused = self._decoder.refusal("prefix reuse")
         self.prefix_cache_enabled = (
             str(pc).lower() not in ("off", "0", "false", "no")
-            and self._decoder.pageable)
+            and self.prefix_cache_refused is None)
         # dispatcher-thread-only state (single writer, no lock)
         self._slots_state: List[Optional[_Slot]] = [None] * self.slots
         self._pool = KVPagePool(self.num_pages, self.page_size)
@@ -651,6 +659,9 @@ class GenerationEngine:
         self._cur = _Flight()
         self._inflight: Optional[_Flight] = None
         self._landing: List[_Flight] = []   # being fetched right now
+        # what the graph's ops counted on the device (an MoE's routing),
+        # as of the last token step landed: a host copy, for stats()
+        self._counters_host = None
         self._prev_tokens = None
         self._prev_first = None
         self._pipe_ahead = 0
@@ -707,11 +718,9 @@ class GenerationEngine:
             if gmax < max(g, 2):
                 raise ValueError(f"spec_gamma_max {gmax} < gamma "
                                  f"{max(g, 2)}")
-            if not self._decoder.pageable:
-                raise ValueError(
-                    "speculative decoding needs a chunkable causal-"
-                    "attention graph (LSTM state cannot roll back to "
-                    "an accept point)")
+            why = self._decoder.refusal("speculative decoding")
+            if why is not None:     # an LSTM carry, a windowed ring: neither
+                raise ValueError(why)   # rolls back to an accept point
             self._draft_decoder = GraphDecoder.for_model(
                 draft_model, self.slots, self.max_seq,
                 page_size=self.page_size, num_pages=self.num_pages)
@@ -804,15 +813,16 @@ class GenerationEngine:
         nobody = (np.zeros((self.slots,), np.int32),
                   np.ones((self.slots,), bool))
         for _ in range(2):
-            nxt, self._caches = self._decoder.decode_fn()(
+            out, self._caches = self._decoder.decode_fn()(
                 params, self._caches, self._spliced(*nobody),
                 np.zeros((self.slots,), np.int32),
                 np.full((self.slots, self._decoder.pages_per_slot),
                         self._pool.no_page, np.int32),
                 np.full((self.slots,), self._pool.no_page, np.int32),
                 np.zeros((self.slots,), np.int32))
+            nxt, counted = self._decoder.step_tokens(out)
             self._prev_tokens = nxt
-        jax.device_get(nxt)
+        _, self._counters_host = jax.device_get((nxt, counted))
         if self._spec_on:
             self._warmup_spec()
 
@@ -1174,6 +1184,7 @@ class GenerationEngine:
             "kv_pages_high_water": hw,
             "kv_high_water_bytes":
                 hw * self.kv_plan["page_bytes"]
+                + self.kv_plan["window_bytes"]
                 + self.kv_plan["state_bytes"],
             "prefix_cache": "on" if prefix is not None else "off",
             "prefix_hit_tokens": self._hit_tokens,
@@ -1185,6 +1196,37 @@ class GenerationEngine:
                           + (prefix.evictions if prefix else 0)),
             "prefill_chunks": self._chunks_total,
         }
+
+    def _paged_caches(self) -> Dict:
+        """The leaves that live in the shared pool's pages (what a
+        migration ships): every ``"kv"`` entry of a graph the gate lets
+        migrate; an op's counters stay where they are."""
+        return {name: sub for name, sub in self._caches.items()
+                if self._decoder.layout[name]["kind"] == "kv"}
+
+    def _kv_pages_stats(self) -> Dict:
+        """``stats()["kv_pages"]``, by kind of entry: the shared pool the
+        host allocates from (``full``) and the rows windowed entries hold
+        a slot, which it never touches (``windowed``)."""
+        pool, plan = self._pool, self.kv_plan
+        out = {"full": {"entries": sum(
+                            ent["kind"] == "kv" and not ent.get("window")
+                            for ent in self._decoder.layout.values()),
+                        "num_pages": self.num_pages,
+                        "in_use": pool.pages_in_use,
+                        "free": pool.pages_free,
+                        "high_water": max(self._pool_high_base,
+                                          pool.high_water),
+                        "bytes": plan["pool_bytes"]}}
+        windowed = self._decoder.windowed
+        if windowed:
+            out["windowed"] = {
+                "entries": len(windowed),
+                "window": max(e["window"] for e in windowed.values()),
+                "rows_per_slot": plan["window_rows"],
+                "bytes": plan["window_bytes"],
+                "refused": self.prefix_cache_refused}
+        return out
 
     def pool_copies(self) -> Dict[str, Dict[str, int]]:
         """``{program: {"count", "bytes"}}``: the pool-sized ``copy``
@@ -1216,6 +1258,7 @@ class GenerationEngine:
                "max_queue_requests": self.max_queue_requests,
                "peak_queue_requests": self._batcher.peak_rows,
                "decode_attention": self._decoder.decode_attention(),
+               "kv_pages": self._kv_pages_stats(),
                # the one-step-ahead pipeline (docs/observability.md):
                # token steps dispatched before the previous step's
                # tokens were on the host, how often and why it ran
@@ -1226,6 +1269,12 @@ class GenerationEngine:
                    "dropped_tokens": self._pipe_dropped}}
         if self._pool_copies is not None:
             out["pool_copies"] = self._pool_copies
+        # per expert the live tokens it received, as of the last token
+        # step landed: the counters' copy rides that boundary's one fetch
+        # (docs/observability.md)
+        moe = self._decoder.moe_stats(self._counters_host)
+        if moe:
+            out["moe"] = moe
         return out
 
     # ---- dispatcher thread ---------------------------------------------
@@ -1364,11 +1413,15 @@ class GenerationEngine:
         if not flights:
             return
         with self._phase("generate.fetch"):
-            host = jax.device_get([(f.nxt, f.first) for f in flights])
+            host = jax.device_get([(f.nxt, f.first, f.counters)
+                                   for f in flights])
+        for f, (_, _, counted) in zip(flights, host):
+            if counted is not None:
+                f.counters = self._counters_host = counted
         now = self.clock()
         if any(f.join is not None for f in flights):
             with self._phase("gen-prefill.deliver"):
-                for f, (_, first) in zip(flights, host):
+                for f, (_, first, _) in zip(flights, host):
                     if f.join is not None:
                         slot, st, last, bucket, fn = f.join
                         if self._deliver_first_token(slot, st, int(first),
@@ -1376,7 +1429,7 @@ class GenerationEngine:
                             self._retire(slot, st, last, now)
         if any(f.nxt is not None for f in flights):
             with self._phase("generate.deliver"):
-                for f, (nxt, _) in zip(flights, host):
+                for f, (nxt, _, _) in zip(flights, host):
                     if f.nxt is not None:
                         self._deliver_step(f, nxt, now)
         self._landing = []
@@ -1486,7 +1539,9 @@ class GenerationEngine:
         if self._traced and st.t_exec is None:
             st.t_exec = self.clock()
         try:
-            with self._phase("gen-prefill", step_num=self._n_steps):
+            # the span says which chunk of the prompt this is and how long
+            with self._phase("gen-prefill", step_num=self._n_steps,
+                             chunk=st.chunks, length=chunk):
                 first, self._caches = fn(
                     self._params, self._caches, tokens, row,
                     np.int32(slot), np.int32(start), np.int32(chunk))
@@ -1598,12 +1653,12 @@ class GenerationEngine:
         stream = st.stream
         t0 = self.clock()
         try:
-            if not self._decoder.pageable:
-                raise RuntimeError(
-                    "graph state is not pageable (no paged attention): "
-                    "KV migration needs a chunkable attention graph")
+            why = self._decoder.refusal("KV migration")
+            if why is not None:
+                raise RuntimeError(why)
             e0 = time.perf_counter()
-            host = export_pages(self._caches, st.pages, self.num_pages,
+            host = export_pages(self._paged_caches(), st.pages,
+                                self.num_pages,
                                 pad_to=self._decoder.pages_per_slot)
             self.migrate_export_ms.append(
                 (time.perf_counter() - e0) * 1e3)
@@ -1736,8 +1791,11 @@ class GenerationEngine:
             return
         try:
             i0 = time.perf_counter()
-            self._caches = import_pages(self._caches, payload["pages"],
-                                        pages)
+            why = self._decoder.refusal("KV migration")
+            if why is not None:
+                raise ValueError(why)
+            self._caches = {**self._caches, **import_pages(
+                self._paged_caches(), payload["pages"], pages)}
             self.migrate_import_ms.append(
                 (time.perf_counter() - i0) * 1e3)
         except BaseException as e:  # noqa: BLE001 — a poisoned import
@@ -1944,14 +2002,15 @@ class GenerationEngine:
                     None if cur.join is None else cur.join[0])
                 if sampled:
                     cur.fn = self._decoder.decode_sampled_fn()
-                    cur.nxt, self._caches = cur.fn(
+                    out, self._caches = cur.fn(
                         self._params, self._caches, spliced, pos, table,
                         wp, wr, temp, top_k, top_p, seeds)
                 else:
                     cur.fn = self._decoder.decode_fn()
-                    cur.nxt, self._caches = cur.fn(
+                    out, self._caches = cur.fn(
                         self._params, self._caches, spliced, pos, table,
                         wp, wr)
+                cur.nxt, cur.counters = self._decoder.step_tokens(out)
         self._n_steps += 1
         self._prev_tokens = cur.nxt
         # a slot that retires on this token frees its pages NOW, behind
@@ -1995,7 +2054,8 @@ class GenerationEngine:
                               tid=self.name or "generate",
                               step=f.step, active=len(f.rows),
                               phase="decode",
-                              program=_program_name(f.fn))
+                              program=_program_name(f.fn),
+                              **self._decoder.moe_totals(f.counters))
         self.metrics.record_decode_step(emitted, now - f.t0)
         self._fire_cancel_at_token(f.rows, now)
         if self.stats_every and (f.step + 1) % self.stats_every == 0:

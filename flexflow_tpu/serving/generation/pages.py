@@ -258,9 +258,20 @@ class PrefixCache:
         self._nodes = 0
 
 
+def entry_dtype(ent: Dict, compute_dtype):
+    """The dtype a declared entry's leaves are held in (``Op.serve_state``'s
+    ``"dtype"``: the compute dtype, float32 or int32)."""
+    import jax.numpy as jnp
+
+    return {"compute": jnp.dtype(compute_dtype),
+            "i32": jnp.dtype(jnp.int32)}.get(ent["dtype"],
+                                             jnp.dtype(jnp.float32))
+
+
 def alloc_pool_arrays(layout: Dict[str, Dict], mesh, compute_dtype):
     """Materialize the ``analysis.kv_memory.kv_cache_layout`` on
-    device: attention K/V page pools and LSTM state pairs, placed under
+    device: attention K/V page pools, the rows of windowed entries, LSTM
+    state pairs and the ops' counters, placed under
     the layout's PartitionSpec entries (K/V leaves in the layout's
     lane-dense ``(num_pages, page_size, heads * head_dim)`` form).
     THE one KV allocation site (repo_lint RL013) — byte-for-byte what
@@ -269,10 +280,9 @@ def alloc_pool_arrays(layout: Dict[str, Dict], mesh, compute_dtype):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec
 
-    compute_dt = jnp.dtype(compute_dtype)
     caches: Dict[str, Dict[str, jax.Array]] = {}
     for name, ent in layout.items():
-        dt = compute_dt if ent["dtype"] == "compute" else jnp.float32
+        dt = entry_dtype(ent, compute_dtype)
         sub: Dict[str, jax.Array] = {}
         for leaf, shape in ent["shapes"].items():
             arr = jnp.zeros(shape, dt)
@@ -398,5 +408,5 @@ def _scatter_rows(arr, idx, val):
     return _SCATTER_ROWS(arr, idx, val)
 
 
-__all__ = ["KVPagePool", "PrefixCache", "alloc_pool_arrays",
+__all__ = ["KVPagePool", "PrefixCache", "alloc_pool_arrays", "entry_dtype",
            "export_pages", "import_pages"]

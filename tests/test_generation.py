@@ -2130,3 +2130,389 @@ def test_spec_bench_smoke():
         assert all(r["tokens_per_s"] > 0 for r in rows)
     assert "device_kind" in p and "comm_plan_digest" in p
     assert p["config"]["draft"].startswith("weight-shared")
+
+
+# ---------------------------------------------------------------------
+# grouped heads, rotary positions, a window with rows of its own, and a
+# dropless MoE behind the serving contract (ISSUE 36)
+# ---------------------------------------------------------------------
+_DEC_LAYERS = [
+    {"attention": "full_attention", "heads": 6, "mlp": "dense"},
+    {"attention": "sliding_attention", "heads": 8, "mlp": "sparse"},
+    {"attention": "sliding_attention", "heads": 8, "mlp": "sparse"},
+    {"attention": "sliding_attention", "heads": 8, "mlp": "sparse"},
+    {"attention": "full_attention", "heads": 6, "mlp": "sparse"}]
+_DEC_ROPE = {
+    "full_attention": {"rope_theta": 500000, "rope_type": "yarn",
+                       "factor": 64, "original_max_position_embeddings": 16,
+                       "beta_slow": 1, "beta_fast": 64,
+                       "attention_factor": 1.4158883083359672,
+                       "partial_rotary_factor": 0.5},
+    "sliding_attention": {"rope_type": "default", "rope_theta": 10000,
+                          "partial_rotary_factor": 1}}
+_DEC_SEQ, _DEC_WINDOW, _DEC_CHUNK = 64, 8, 8
+
+
+def _build_decoder_lm(chunk=_DEC_CHUNK, seed=0):
+    """The pre-norm decoder at a size that keeps every kind: 2 key/value
+    heads under 6 and 8 query heads, head 16, window 8, 8 experts top-2
+    beside a shared one, layer 0 dense, 5 layers; float32."""
+    from flexflow_tpu.models import build_decoder_lm
+    cfg = ff.FFConfig(batch_size=2, compute_dtype="float32", seed=seed)
+    cfg.serve_gen_slots = 2
+    cfg.serve_gen_max_seq = _DEC_SEQ
+    cfg.serve_prefill_chunk = chunk
+    model = build_decoder_lm(
+        cfg, _DEC_LAYERS, d_model=32, head_dim=16, num_kv_heads=2, d_ff=64,
+        vocab_size=VOCAB, seq_len=_DEC_SEQ, window=_DEC_WINDOW,
+        rope=_DEC_ROPE, gate=True,
+        moe={"num_experts": 8, "k": 2, "d_ff": 16, "shared_d_ff": 16,
+             "routed_scale": 2.5})[0]
+    model.compile(ff.SGDOptimizer(lr=0.01), mesh=MachineMesh({"n": 1}))
+    model.init_layers(seed=seed)
+    return model
+
+
+@pytest.fixture(scope="module")
+def decoder_lm():
+    return _build_decoder_lm()
+
+
+def test_windowed_graph_serves_what_its_forward_computes(decoder_lm):
+    """Prefill in chunks of 8 then decoding through the engine's cache
+    against the graph's own full forward at every served position
+    (float32, so bit-equal tokens): contexts shorter than the window, a
+    chunk that straddles it (13 = 8 + 5), contexts that pass it several
+    times over (30 + 12 positions against a window of 8 in a ring of 16
+    rows), two streams at once.  The windowed entries hold ``window +
+    chunk`` rows a slot and no page of the pool; the pool's ids index the
+    two full layers only."""
+    model = decoder_lm
+    rng = np.random.default_rng(36)
+    prompts = [rng.integers(1, VOCAB, n).astype(np.int32)
+               for n in (5, 8, 13, 23, 30)]
+    eng = GenerationEngine(model, slots=2)
+    dec = eng._decoder
+    assert sorted(dec.windowed) == ["attention_1", "attention_2",
+                                    "attention_3"]
+    rows = _DEC_WINDOW + _DEC_CHUNK
+    for name, ent in dec.windowed.items():
+        assert ent["rows"] == rows and ent["window"] == _DEC_WINDOW
+        assert ent["shapes"]["k"] == (2, rows // 16, 16, 2 * 16), ent
+    assert dec.buckets == (2, 4, 8)         # no chunk longer than sized for
+    plan = eng.kv_plan
+    assert plan["window_rows"] == rows
+    assert plan["window_bytes"] == 3 * 2 * 2 * rows * 32 * 4    # K+V, f32
+    assert plan["page_bytes"] == 2 * 2 * 16 * 32 * 4            # 2 layers
+    assert plan["total_bytes"] == plan["pool_bytes"] \
+        + plan["window_bytes"] + plan["state_bytes"]
+    with eng:
+        streams = [eng.submit(p, max_new_tokens=12) for p in prompts]
+        outs = [[int(t) for t in s.result(timeout=300)] for s in streams]
+        snap = eng.stats()
+        caches = eng._caches
+    for p, out in zip(prompts, outs):
+        assert out == reference_decode(model, p, 12, _DEC_SEQ), len(p)
+    got = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+              for name, sub in caches.items() for a in sub.values())
+    assert got == plan["total_bytes"]
+    kv = snap["kv_pages"]
+    assert kv["windowed"]["rows_per_slot"] == rows
+    assert kv["windowed"]["entries"] == 3 and kv["full"]["entries"] == 2
+    assert kv["full"]["in_use"] == 0 and kv["full"]["high_water"] > 0
+    assert snap["prefix_cache"] == "off"
+    assert "windowed cache entry: attention_1" in kv["windowed"]["refused"]
+    assert snap["decode_attention"] == {
+        "paged": 0, "gathered": 5, "windowed": {"paged": 0, "gathered": 3}}
+    moe = snap["moe"]
+    assert sorted(moe) == ["moe_1", "moe_2", "moe_3", "moe_4"]
+    served = sum(len(p) for p in prompts) + 5 * 11 + len(dec.buckets)
+    for m in moe.values():      # 2 choices a live token, the warm-up's
+        assert m["assignments"] == 2 * served, m     # 1-token chunks too
+        assert len(m["load"]) == 8 and m["token_steps"] > 0
+        assert 0.0 <= m["untouched_share"] < 1.0
+        assert m["load_max_over_mean"] >= 1.0
+
+
+def test_counters_ride_the_boundarys_fetch_and_its_decode_step_span(
+        decoder_lm):
+    """What the MoE ops count on the device reaches the host as a COPY
+    beside each boundary's tokens (the counters themselves are donated to
+    the next program): ``stats()`` read from another thread while streams
+    are served never touches a device buffer, so it never meets a deleted
+    one, and what it reads only grows; every ``decode_step`` span carries
+    the totals as they stood behind its step, and a graph that counts
+    nothing carries none."""
+    import threading
+
+    from flexflow_tpu.obs.trace import get_tracer
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, VOCAB, n).astype(np.int32)
+               for n in (9, 17, 6, 12)]
+    tr = get_tracer()
+    tr.reset()
+    tr.configure(sample_rate=1.0)
+    seen, errors, done = [], [], threading.Event()
+    try:
+        with GenerationEngine(decoder_lm, slots=2) as eng:
+            def poll():
+                while not done.is_set():
+                    try:
+                        seen.append(sum(m["assignments"] for m in
+                                        eng.stats()["moe"].values()))
+                    except BaseException as e:  # noqa: BLE001
+                        errors.append(e)
+                        return
+            t = threading.Thread(target=poll)
+            t.start()
+            for s in [eng.submit(p, max_new_tokens=10) for p in prompts]:
+                s.result(timeout=300)
+            done.set()
+            t.join()
+            final = eng.stats()["moe"]
+        steps = sorted((s for s in tr.snapshot()["spans"]
+                        if s["name"] == "decode_step"),
+                       key=lambda s: s["args"]["step"])
+        tr.reset()
+        with GenerationEngine(_build_lm(), slots=2) as plain:
+            plain.submit(prompts[0] % 50, max_new_tokens=4).result(
+                timeout=300)
+            assert "moe" not in plain.stats()
+        assert plain._counters_host is None
+        uncounted = [s["args"] for s in tr.snapshot()["spans"]
+                     if s["name"] == "decode_step"]
+        assert uncounted and not any("moe_untouched" in a for a in uncounted)
+    finally:
+        tr.disable()
+        tr.reset()
+    assert not errors, errors
+    assert seen and seen == sorted(seen) and seen[-1] <= sum(
+        m["assignments"] for m in final.values())
+    totals = [(s["args"]["moe_expert_steps"], s["args"]["moe_untouched"])
+              for s in steps]
+    assert len(totals) >= 10 and totals == sorted(totals)
+    assert totals[-1] == (
+        sum(m["token_steps"] * 8 for m in final.values()),
+        round(sum(m["untouched_share"] * m["token_steps"] * 8
+                  for m in final.values())))
+    # 4 sparse layers of 8 experts count every step once
+    assert totals[-1][0] - totals[0][0] == (len(steps) - 1) * 4 * 8
+    assert 0 <= totals[-1][1] < totals[-1][0]
+
+
+def test_a_released_windowed_row_is_never_read(decoder_lm):
+    """Every row of every windowed entry poisoned (1e6, finite so that a
+    row read AS A VALUE shows and a masked one multiplies to zero) between
+    two requests: the second stream starts in rings full of what an
+    earlier stream left and serves the same tokens as the forward."""
+    model = decoder_lm
+    rng = np.random.default_rng(5)
+    first, second = (rng.integers(1, VOCAB, n).astype(np.int32)
+                     for n in (27, 11))
+    eng = GenerationEngine(model, slots=2)
+    with eng:
+        eng.submit(first, max_new_tokens=20).result(timeout=300)
+        for name in eng._decoder.windowed:      # the engine is idle
+            eng._caches[name] = {leaf: jnp.full_like(a, 1e6) for leaf, a in
+                                 eng._caches[name].items()}
+        out = [int(t) for t in
+               eng.submit(second, max_new_tokens=20).result(timeout=300)]
+    assert out == reference_decode(model, second, 20, _DEC_SEQ)
+
+
+def test_what_a_windowed_entry_cannot_do_is_refused_by_name(decoder_lm):
+    """ONE gate (``GraphDecoder.refusal``): speculation raises, migration
+    raises, the prefix cache stays off and says why; a pageable graph is
+    refused nothing."""
+    model = decoder_lm
+    dec = GraphDecoder.for_model(model, 2, _DEC_SEQ, prefill_chunk=_DEC_CHUNK)
+    for what in ("prefix reuse", "speculation", "migration"):
+        why = dec.refusal(what)
+        assert why.startswith(what) and "attention_1" in why, why
+    with pytest.raises(ValueError, match="windowed cache entry"):
+        GenerationEngine(model, slots=2, draft_model=model, spec_gamma=2)
+    plain = GraphDecoder.for_model(_build_lm(), 2, SEQ)
+    assert plain.refusal("prefix reuse") is None and not plain.windowed
+    # the chunk sizes nothing of a pageable graph: one decoder for all
+    assert GraphDecoder.for_model(plain.model, 2, SEQ, prefill_chunk=4) \
+        is plain
+
+
+def test_rotary_positions_match_a_complex_rotation():
+    """Both rotary forms against a rotation written by hand in complex
+    numbers, at three positions: plain (the whole head, theta 10 000,
+    pairs (i, i + d/2)) and partial with YaRN (the first half of the head,
+    pairs (i, i + d/4), frequencies interpolated by ``factor`` below
+    ``beta_slow`` rotations over the original length, kept above
+    ``beta_fast``, cos and sin times ``attention_factor``; the other half
+    passes).  Tolerance 1e-5: float32 cos and sin of angles up to 1e3."""
+    import math
+
+    from flexflow_tpu.ops.attention import apply_rope, rope_inv_freq
+    d = 16
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((1, 3, 2, d)).astype(np.float32)
+    pos = np.array([0, 7, 1000])
+    for kind, rope in _DEC_ROPE.items():
+        got = np.asarray(apply_rope(jnp.asarray(x), jnp.asarray(pos), rope))
+        rot = int(d * rope["partial_rotary_factor"])
+        half = rot // 2
+        theta = float(rope["rope_theta"])
+        freq = np.array([theta ** (-2.0 * i / rot) for i in range(half)])
+        att = 1.0
+        if rope["rope_type"] == "yarn":
+            f, orig = rope["factor"], rope["original_max_position_embeddings"]
+
+            def dim(rotations):
+                return rot * math.log(orig / (rotations * 2 * math.pi)) / (
+                    2 * math.log(theta))
+
+            lo = max(math.floor(dim(rope["beta_fast"])), 0)
+            hi = min(math.ceil(dim(rope["beta_slow"])), rot - 1)
+            ramp = np.clip((np.arange(half) - lo) / max(hi - lo, 1e-3), 0, 1)
+            freq = freq / f * ramp + freq * (1 - ramp)
+            att = rope["attention_factor"]
+        z = (x[..., :half] + 1j * x[..., half:rot]) * att * np.exp(
+            1j * pos[None, :, None, None] * freq)
+        want = np.concatenate([z.real, z.imag, x[..., rot:]], axis=-1)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5,
+                                   err_msg=kind)
+        inv, factor, r = rope_inv_freq(rope, d)
+        assert r == rot and factor == pytest.approx(att)
+        np.testing.assert_allclose(inv, freq, rtol=1e-6)
+
+
+def test_post_norm_graph_serves_the_tokens_it_served_before():
+    """``build_transformer_lm``'s tiny graph (what ``gpt1`` is built of)
+    through the engine, whole prompts and chunks of 4: the tokens the
+    PARENT of ISSUE 36's change served, recorded from its tree (seed 0,
+    prompts from ``default_rng(36)``), bit for bit: the attention op's
+    defaults trace what they traced."""
+    before = [[3, 41, 11, 45, 45, 54, 21, 19, 37, 6],
+              [45, 44, 37, 19, 37, 6, 56, 13, 13, 60],
+              [37, 56, 13, 13, 22, 16, 49, 24, 37, 17],
+              [37, 6, 53, 35, 3, 37, 37, 45, 32, 13]]
+    model = _build_lm()
+    rng = np.random.default_rng(36)
+    prompts = [rng.integers(1, VOCAB, n).astype(np.int32)
+               for n in (3, 7, 12, 17)]
+    for chunk in (0, 4):
+        with GenerationEngine(model, slots=2, prefill_chunk=chunk) as eng:
+            outs = [[int(t) for t in
+                     eng.submit(p, max_new_tokens=10).result(timeout=120)]
+                    for p in prompts]
+        assert outs == before, chunk
+
+
+@pytest.mark.parametrize("window,dtype,tol", [
+    (0, "float32", 1e-5), (32, "float32", 1e-5), (32, "bfloat16", 2e-2)])
+def test_paged_decode_kernel_takes_groups_and_a_window(window, dtype, tol):
+    """The kernel (interpret mode) with 12 query heads over 2 key/value
+    heads of size 128, without and with a window over a RING table,
+    against ``_decode_attention`` on the gathered view: positions before
+    the window fills, at its edge, after the ring wraps once and three
+    times, and one idle slot.  Every page the kernel may not read (beyond
+    a slot's position; with a window, the pages that hold nothing of the
+    last 32 positions) is NaN in the pools it gets.  Tolerances as in
+    ``test_paged_decode_kernel_matches_gathered_decode``."""
+    from flexflow_tpu.ops.attention import _decode_attention
+    from flexflow_tpu.ops.paged_decode_kernel import paged_decode_attention
+
+    H, G, hd, page = 12, 2, 128, 16
+    e = G * hd
+    if window:
+        pps = (window + 64) // page
+        ring = pps * page
+        pos = np.array([0, 5, window - 1, window, 77, window + 3, ring - 1,
+                        ring, ring + 17, 3 * ring + 5], np.int32)
+    else:
+        pps, ring = 40, 40 * page
+        pos = np.array([0, page - 1, page, 530, 77, ring - 1], np.int32)
+    slots, idle = len(pos), 4
+    rng = np.random.default_rng(window)
+    num_pages = slots * pps
+    table = (np.arange(slots)[:, None] * pps
+             + np.arange(pps)[None]).astype(np.int32)
+    wp = np.zeros(slots, np.int32)
+    wp[idle] = num_pages
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), dtype)
+               for shape in ((slots, H * hd), (num_pages, page, e),
+                             (num_pages, page, e)))
+
+    def view(pool):
+        return jnp.take(pool, table, axis=0).reshape(slots, ring, G, hd)
+
+    scale = 1.0 / np.sqrt(hd)
+    kpos = None
+    if window:
+        r = np.arange(ring)[None]
+        kpos = jnp.asarray(pos[:, None] - (pos[:, None] - r) % ring)
+    want = _decode_attention(q.reshape(slots, 1, H, hd), view(k), view(v),
+                             jnp.asarray(pos), scale, kpos, window)
+    stale = np.ones(num_pages, bool)
+    for i in range(slots):
+        if i != idle:
+            first = max(pos[i] - window + 1, 0) // page if window else 0
+            for lp in range(first, pos[i] // page + 1):
+                stale[table[i, lp % pps]] = False
+    poison = jnp.asarray(stale)[:, None, None]
+    got = paged_decode_attention(
+        q, jnp.where(poison, jnp.nan, k), jnp.where(poison, jnp.nan, v),
+        jnp.asarray(table), jnp.asarray(pos), jnp.asarray(wp), H, scale, G,
+        window)
+    assert got.dtype == jnp.float32 and got.shape == (slots, H * hd)
+    decoding = np.arange(slots) != idle
+    np.testing.assert_allclose(
+        np.asarray(got)[decoding],
+        np.asarray(want).reshape(slots, H * hd)[decoding], rtol=tol,
+        atol=tol)
+    assert np.all(np.asarray(got)[idle] == 0.0)
+
+
+@pytest.mark.parametrize("why,args,ok", [
+    ("6 query heads a key/value head of 128", ("tpu", "bfloat16", 48, 128,
+                                               16, False, 8), True),
+    ("8 a head", ("tpu", "bfloat16", 64, 128, 16, False, 8), True),
+    ("a grouped head of half a tile", ("tpu", "bfloat16", 16, 64, 16, False,
+                                       4), False),
+    ("a group wider than the sublane tile", ("tpu", "bfloat16", 64, 128, 16,
+                                            False, 2), False),
+], ids=lambda x: x if isinstance(x, str) else "")
+def test_paged_decode_supported_says_which_groups_it_takes(why, args, ok):
+    from flexflow_tpu.ops.paged_decode_kernel import supported
+    backend, dtype, *rest = args
+    assert supported(backend, jnp.dtype(dtype), *rest) == ok, why
+
+
+@pytest.mark.parametrize("heads,pages_per_slot,window", [
+    (64, 64, 512), (48, 256, 0)], ids=["sliding", "full"])
+def test_grouped_paged_decode_kernel_compiles_for_the_chip(
+        v5e_device, monkeypatch, heads, pages_per_slot, window):
+    """The kernel at the widths ISSUE 36's configuration serves (8 key/
+    value heads of 128 under 64 and 48 query heads, bf16 pools, 128 slots,
+    pages of 16; a 512 window over a ring of 64 pages, 4 096 positions
+    whole), compiled by the TPU's compiler for a described v5e: what
+    interpret mode cannot show (tile alignment of the lane slices, the
+    buffers' fit in VMEM)."""
+    from flexflow_tpu.ops import flash_kernel, paged_decode_kernel as pk
+    from jax.sharding import SingleDeviceSharding
+
+    monkeypatch.setattr(flash_kernel, "_interpret", lambda: False)
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    chip = SingleDeviceSharding(v5e_device)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, page, e = 128, 16, 8 * 128
+    pages = slots * pages_per_slot
+    args = (sd((slots, heads * 128), jnp.bfloat16),
+            sd((pages, page, e), jnp.bfloat16),
+            sd((pages, page, e), jnp.bfloat16),
+            sd((slots, pages_per_slot), jnp.int32), sd((slots,), jnp.int32),
+            sd((slots,), jnp.int32))
+    with _no_compilation_cache():
+        text = _within(_COMPILE_LIMIT_S, lambda: pk.paged_decode_attention
+                       .lower(*args, heads, 0.088, 8, window).compile()
+                       .as_text())
+    assert "paged_decode_attention" in text
