@@ -10,11 +10,19 @@ table per device, no token routing.  This op routes tokens:
   ``routed_scale``);
 * the ``(token, choice)`` pairs are SORTED by expert, so each expert's
   tokens are one contiguous group of rows, and the experts run as two
-  GROUPED products over the experts held (``jax.lax.ragged_dot``: on a TPU
-  XLA's own grouped-matmul kernel, ``ragged-dot`` in a device trace) —
-  static shapes, ``tokens * k`` rows whatever the routing, nothing dropped,
-  and no ``(tokens, experts, capacity)`` tensor anywhere; the rows are then
-  unsorted and combined with their router weights;
+  GROUPED products over the experts held — static shapes, ``tokens * k``
+  rows whatever the routing, nothing dropped, and no ``(tokens, experts,
+  capacity)`` tensor anywhere; the rows are then unsorted and combined
+  with their router weights.  WHICH grouped product is chosen at trace
+  time from what the code can see (:meth:`MoE._grouped_core`): with few
+  rows a group on one TPU — a token step, a prompt chunk — the repo's own
+  kernel (``ops/grouped_matmul_kernel.py``, ``"rows"``: row tiles of
+  16-128, a group's weights read once, an untouched expert not at all); else
+  ``jax.lax.ragged_dot`` (``"library"``: XLA's own grouped matmul, tiled
+  512 rows a group, right where a batch gives every expert thousands of
+  rows, and the only one a gradient is taken through).  Both are
+  ``ragged-dot*`` in a device trace; the op notes which it took in
+  ``grouped_product``, per program traced;
 * a ``capacity_factor``, where a caller still gives one, truncates each
   expert's group IN THAT SAME PATH: the rows past ``C = ceil(k * T / E *
   capacity_factor)`` of a group keep their place and get weight zero
@@ -46,6 +54,7 @@ which rides the boundary's one fetch, for ``stats()["moe"]`` and the
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -118,6 +127,11 @@ class MoE(Op):
                                           "shared_up")
             self.w_sdn = self._add_weight((self.shared_d_ff, d), base,
                                           "shared_down")
+        # {program: "rows" or "library"}: the grouped product each traced
+        # program got, noted at trace time like
+        # MultiHeadAttention.decode_core; a program is ("forward" or a
+        # serving step's kind, its tokens)
+        self.grouped_product = {}
 
     # a dropless op acts on each position alone (serve_check)
     @property
@@ -144,13 +158,29 @@ class MoE(Op):
             denom = jnp.sum(top_probs, axis=-1, keepdims=True) + 1e-9
             return top_idx, top_probs / denom * self.routed_scale, probs
 
+    def _grouped_core(self, xs, w_up, w_dn, ctx: OpContext) -> str:
+        """``"rows"`` where both grouped products (operands as they are
+        multiplied) can take the repo's own kernel and it is the right one
+        (:mod:`grouped_matmul_kernel`, from what the code can see:
+        backend, operand dtype, rows a group, lane alignment, one device,
+        no gradient), else ``"library"``."""
+        from . import grouped_matmul_kernel
+        distributed = ctx.mesh is not None and ctx.mesh.is_distributed
+        return "rows" if all(
+            w.dtype == xs.dtype and grouped_matmul_kernel.supported(
+                jax.default_backend(), xs.dtype, xs.shape[0], w.shape[0],
+                w.shape[1], w.shape[2], distributed, ctx.training)
+            for w in (w_up, w_dn)) else "library"
+
     def _experts(self, weights, xt, top_idx, gates, tokens: int, first: int,
-                 ctx: OpContext):
+                 ctx: OpContext, program):
         """The routed experts' part of the output, (T, d) f32, from the
         experts ``first .. first + held`` whose stacked weights
         ``weights`` are (``held`` of ``num_experts``; every pair routed
         elsewhere contributes zero here).  ``tokens``: how many tokens
-        share the capacity (the whole batch's, also on a token shard)."""
+        share the capacity (the whole batch's, also on a token shard).
+        ``program``: the ``(kind, tokens)`` the core chosen is noted
+        under in ``grouped_product``."""
         (w_up, w_dn), (b_up, b_dn) = weights[:2], weights[2:] or (None, None)
         held = w_up.shape[0]
         E, k = self.num_experts, self.k
@@ -177,9 +207,18 @@ class MoE(Op):
         mine = jnp.arange(A) < jnp.sum(counts)
         token = order // k
         xs = xt[token]                                           # (A, d)
+        w_up, w_dn = cast_compute(w_up, ctx), cast_compute(w_dn, ctx)
+        core = self._grouped_core(xs, w_up, w_dn, ctx)
+        self.grouped_product[program] = core
+        if core == "rows":
+            from .grouped_matmul_kernel import ragged_dot_rows, visits
+            grouped = functools.partial(ragged_dot_rows,
+                                        walk=visits(counts, A))
+        else:
+            grouped = functools.partial(jax.lax.ragged_dot,
+                                        preferred_element_type=jnp.float32)
         with jax.named_scope("moe_experts"):
-            h = jax.lax.ragged_dot(xs, cast_compute(w_up, ctx), counts,
-                                   preferred_element_type=jnp.float32)
+            h = grouped(xs, w_up, counts)
             local = jnp.clip(expert - first, 0, held - 1)
             if self.gated:
                 f = self.d_ff
@@ -188,8 +227,7 @@ class MoE(Op):
                 h = apply_activation(
                     h + b_up.astype(h.dtype)[local], self.activation)
             h = jnp.where(mine[:, None], cast_compute(h, ctx), 0)
-            y = jax.lax.ragged_dot(h, cast_compute(w_dn, ctx), counts,
-                                   preferred_element_type=jnp.float32)
+            y = grouped(h, w_dn, counts)
             if not self.gated:
                 y = y + b_dn.astype(y.dtype)[local]
         y = jnp.where(mine[:, None], y, 0.0) * weight[:, None]
@@ -206,10 +244,13 @@ class MoE(Op):
                               cast_compute(params[self.w_sdn.name], ctx),
                               preferred_element_type=jnp.float32)
 
-    def _moe(self, params, x, ctx: OpContext):
-        """``(out (n, s, d), top_idx (T, k), probs (T, E))``."""
+    def _moe(self, params, x, ctx: OpContext, kind: str = "forward"):
+        """``(out (n, s, d), top_idx (T, k), probs (T, E))``.  ``kind``:
+        which program this is traced into (a serving step's, or
+        ``"forward"``), for ``grouped_product``."""
         n, s, d = x.shape
         T, E = n * s, self.num_experts
+        program = (kind, T)
         xt = cast_compute(x.reshape(T, d), ctx)
         top_idx, gates, probs = self._route(params, xt)
         names = [self.w_up.name, self.w_dn.name] + (
@@ -229,7 +270,7 @@ class MoE(Op):
 
             def body(xt, top_idx, gates, first, *w):
                 part = self._experts(w, cast_compute(xt, ctx), top_idx,
-                                     gates, T, first[0], ctx)
+                                     gates, T, first[0], ctx, program)
                 return jax.lax.psum(part, e_axes)
 
             rows = PartitionSpec(t_axes, None)
@@ -252,7 +293,8 @@ class MoE(Op):
                     xt.astype(jnp.float32) if inside else xt, top_idx,
                     gates, firsts, *weights)
         else:
-            routed = self._experts(weights, xt, top_idx, gates, T, 0, ctx)
+            routed = self._experts(weights, xt, top_idx, gates, T, 0, ctx,
+                                   program)
         out = routed
         if self.shared_d_ff:
             out = out + self._shared(params, xt, ctx)
@@ -293,7 +335,7 @@ class MoE(Op):
                 "dtype": "i32"}
 
     def serve_step(self, params, inputs, state, where, ctx: OpContext):
-        out, top_idx, _ = self._moe(params, inputs[0], ctx)
+        out, top_idx, _ = self._moe(params, inputs[0], ctx, where.kind)
         if state is None:
             return [out], state
         live = where.live(inputs[0].shape[1]).reshape(-1)          # (T,)

@@ -675,6 +675,19 @@ class GraphDecoder:
                                     if op.name in self.windowed)
         return out
 
+    def grouped_product(self) -> Dict[str, int]:
+        """How many (mixture-of-experts op, serving program) pairs got
+        which grouped product when the programs were traced (every chunk
+        bucket, the token step, a window): ``{"rows", "library"}`` —
+        ``"rows"`` is the repo's own kernel
+        (:mod:`flexflow_tpu.ops.grouped_matmul_kernel`), ``"library"``
+        ``jax.lax.ragged_dot``.  Noted by the op at trace time
+        (``MoE.grouped_product``), like :meth:`decode_attention`."""
+        cores = [core for op in self.model.layers
+                 for (kind, _), core in tuple(getattr(
+                     op, "grouped_product", {}).items()) if kind != "forward"]
+        return {core: cores.count(core) for core in ("rows", "library")}
+
     def _counted(self, nxt, new):
         """What a token step returns first: its tokens, and for a graph
         whose ops count on the device (``self.counters``) ``(tokens, a COPY

@@ -1274,7 +1274,10 @@ class GenerationEngine:
         # (docs/observability.md)
         moe = self._decoder.moe_stats(self._counters_host)
         if moe:
-            out["moe"] = moe
+            # beside the ops: which grouped product their programs took
+            # at trace time (host memory only, like the rest)
+            out["moe"] = {**moe, "grouped_product":
+                          self._decoder.grouped_product()}
         return out
 
     # ---- dispatcher thread ---------------------------------------------

@@ -2224,7 +2224,10 @@ def test_windowed_graph_serves_what_its_forward_computes(decoder_lm):
     assert "windowed cache entry: attention_1" in kv["windowed"]["refused"]
     assert snap["decode_attention"] == {
         "paged": 0, "gathered": 5, "windowed": {"paged": 0, "gathered": 3}}
-    moe = snap["moe"]
+    moe = dict(snap["moe"])
+    # every sparse op in every program traced (three chunk buckets and
+    # the token step), the library's on the CPU
+    assert moe.pop("grouped_product") == {"rows": 0, "library": 16}
     assert sorted(moe) == ["moe_1", "moe_2", "moe_3", "moe_4"]
     served = sum(len(p) for p in prompts) + 5 * 11 + len(dec.buckets)
     for m in moe.values():      # 2 choices a live token, the warm-up's
@@ -2232,6 +2235,58 @@ def test_windowed_graph_serves_what_its_forward_computes(decoder_lm):
         assert len(m["load"]) == 8 and m["token_steps"] > 0
         assert 0.0 <= m["untouched_share"] < 1.0
         assert m["load_max_over_mean"] >= 1.0
+
+
+def _moe_ops(snap):
+    """``stats()["moe"]`` without the key that is not an op's."""
+    return {n: m for n, m in snap["moe"].items() if n != "grouped_product"}
+
+
+def test_grouped_product_counts_every_sparse_op_of_every_program():
+    """``stats()["moe"]["grouped_product"]``: which grouped product each
+    sparse op took in each serving program traced, noted by the op at
+    trace time.  On the CPU the library's everywhere; it counts chunk
+    programs and the token step alike (a decoder of its own, so only what
+    is traced here: one chunk bucket, then the token step; a model of its
+    own, since an op notes a program when it is TRACED), grows as programs
+    are traced, and is read from host memory only: with every device
+    buffer of the engine out of reach it still answers."""
+    from flexflow_tpu.serving.generation.decoder import GraphDecoder
+
+    decoder_lm = _build_decoder_lm(seed=37)
+    ops = [op for op in decoder_lm.layers if hasattr(op, "grouped_product")]
+    assert [op.name for op in ops] == ["moe_1", "moe_2", "moe_3", "moe_4"]
+    dec = GraphDecoder(decoder_lm, 2, _DEC_SEQ)
+    assert dec.grouped_product() == {"rows": 0, "library": 0}
+    args = {key: (fn, a) for key, fn, a in (
+        dec.prefill_fn(8), dec.decode_fn(), *dec._program_specs())[2:]}
+    fn, a = args["jit_prefill.8"]
+    fn.trace(*a)
+    assert dec.grouped_product() == {"rows": 0, "library": 4}
+    assert ops[0].grouped_product == {("chunk", 8): "library"}
+    fn, a = args["jit_decode"]
+    fn.trace(*a)
+    assert dec.grouped_product() == {"rows": 0, "library": 8}
+    assert ops[0].grouped_product == {("chunk", 8): "library",
+                                      ("token", 2): "library"}
+    # a forward outside serving is noted by the op and not counted here
+    ops[0].grouped_product[("forward", 128)] = "library"
+    assert dec.grouped_product() == {"rows": 0, "library": 8}
+    ops[0].grouped_product[("token", 2)] = "rows"       # as a TPU answers
+    assert dec.grouped_product() == {"rows": 1, "library": 7}
+    del ops[0].grouped_product[("forward", 128)]
+
+    eng = GenerationEngine(decoder_lm, slots=2)
+    with eng:
+        eng.submit(np.arange(1, 6, dtype=np.int32),
+                   max_new_tokens=3).result(timeout=300)
+        caches, eng._caches = eng._caches, None     # no device array left
+        try:
+            got = eng.stats()["moe"]["grouped_product"]
+        finally:
+            eng._caches = caches
+    # the engine's own programs: every chunk bucket and the token step
+    assert got == {"rows": 0, "library": 4 * (len(eng._decoder.buckets) + 1)}
 
 
 def test_counters_ride_the_boundarys_fetch_and_its_decode_step_span(
@@ -2259,7 +2314,7 @@ def test_counters_ride_the_boundarys_fetch_and_its_decode_step_span(
                 while not done.is_set():
                     try:
                         seen.append(sum(m["assignments"] for m in
-                                        eng.stats()["moe"].values()))
+                                        _moe_ops(eng.stats()).values()))
                     except BaseException as e:  # noqa: BLE001
                         errors.append(e)
                         return
@@ -2269,7 +2324,7 @@ def test_counters_ride_the_boundarys_fetch_and_its_decode_step_span(
                 s.result(timeout=300)
             done.set()
             t.join()
-            final = eng.stats()["moe"]
+            final = _moe_ops(eng.stats())
         steps = sorted((s for s in tr.snapshot()["spans"]
                         if s["name"] == "decode_step"),
                        key=lambda s: s["args"]["step"])
