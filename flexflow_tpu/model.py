@@ -1038,17 +1038,27 @@ class FFModel:
         placement, strategy sharding, or replication.  The one placement
         spelling shared by :meth:`init_layers` and :meth:`reshard` (the
         latter re-places live training state after a mesh change)."""
+        sharding = self._param_sharding(p)
+        if sharding is None:
+            return jnp.asarray(val)
         if p.name in getattr(self, "_host_shardings", {}):
-            return jax.device_put(val, self._host_shardings[p.name])
+            return jax.device_put(val, sharding)
+        return self._put_global(val, sharding)
+
+    def _param_sharding(self, p):
+        """The sharding :meth:`_placed_param` places ``p`` under, or
+        None (the default device): what describes a parameter that is
+        not installed yet (``GraphDecoder._program_specs``)."""
+        if p.name in getattr(self, "_host_shardings", {}):
+            return self._host_shardings[p.name]
         if self.mesh is not None and self.mesh.is_distributed:
             pc = None
             for lop in self.layers:
                 if p in lop.weights:
                     pc = lop.parallel_config
                     break
-            spec = param_spec(p, pc, self.mesh)
-            return self._put_global(val, self.mesh.sharding(spec))
-        return jnp.asarray(val)
+            return self.mesh.sharding(param_spec(p, pc, self.mesh))
+        return None
 
     def _trainable_on_device(self, params: Dict[str, jax.Array]
                              ) -> Dict[str, jax.Array]:

@@ -1,12 +1,16 @@
-"""Device time of the fused train step, read by graph op.
+"""Device time of a fused program, read by graph op.
 
-XLA fuses the whole step into one program, but every instruction of the
+XLA fuses a whole step into one program, but every instruction of the
 compiled module still carries the name stack it was traced under in its
 ``metadata={op_name="..."}``, and ``FFModel`` runs each graph op, the loss,
 the metrics and the optimizer update under a ``jax.named_scope`` of its own.
 So a profiler trace of the REAL step can be summed by graph op, forward and
 backward apart — where ``flexflow_tpu/profiling.py`` times each op compiled
-in isolation.
+in isolation.  The serving programs (``GraphDecoder``) run each op's
+``serve_step`` under the same scope and what they do outside any graph op
+under :data:`SERVE_OWNERS`; an op that opens scopes of its own inside
+(``Op.scopes``: the MoE's router, experts and shared expert) is told apart
+by PART where the train step is told apart by pass.
 
 :func:`table_from_hlo` maps every instruction of a compiled module's text to
 its owner; :func:`attribute` sums a trace's ``[name, start, duration]``
@@ -25,6 +29,11 @@ from typing import Dict, Iterable, Optional, Sequence, Tuple
 
 # the scopes FFModel's train step opens beside one per graph op
 STEP_OWNERS = ("loss", "metrics", "optimizer")
+# the scopes the serving programs open beside one per graph op: the argmax
+# or the sampler with the indexing of the row it reads (``sample``), the
+# token splice and a draft program's transposes (``step_io``), a verify
+# program's window and accept logic (``speculate``)
+SERVE_OWNERS = ("sample", "step_io", "speculate")
 
 Owner = Tuple[Optional[str], Optional[str]]
 
@@ -42,37 +51,76 @@ _RESULT = re.compile(
     r'metadata=\{[^}]*?op_name="(?P<path>[^";]*)', re.M)
 
 
-def _owner(path: str, owners) -> Owner:
+def _owner(path: str, owners, parts=frozenset()) -> Owner:
     """The first component of the name stack ``path`` that is one of
-    ``owners`` once its transform frames are taken off, and whether the
-    stack lies in the backward pass (``transpose(`` round a frame; the
-    primitive called ``transpose`` has no parenthesis) or the forward pass
-    of a differentiated function (``jvp(``)."""
-    for part in path.split("/"):
+    ``owners`` once its transform frames are taken off, and beside it the
+    innermost component below it that is one of ``parts`` or, where there
+    is none, whether the stack lies in the backward pass (``transpose(``
+    round a frame; the primitive called ``transpose`` has no parenthesis)
+    or the forward pass of a differentiated function (``jvp(``)."""
+    stack = path.split("/")
+    for depth, part in enumerate(stack):
         while part not in owners:
             m = _FRAME.match(part)
             if m is None:
                 break
             part = m.group(1)
         else:
-            phase = ("bwd" if "transpose(" in path
-                     else "fwd" if "jvp(" in path else None)
-            return part, phase
+            inner = next((p for p in reversed(stack[depth + 1:])
+                          if p in parts), None)
+            return part, inner or ("bwd" if "transpose(" in path
+                                   else "fwd" if "jvp(" in path else None)
     return None, None
 
 
-def table_from_hlo(text: str, owners: Iterable[str]) -> Dict[str, Owner]:
-    """``{instruction name: (owner | None, "fwd" | "bwd" | None)}`` for every
-    instruction of the compiled module ``text`` (``compiled.as_text()``) that
-    carries an ``op_name``.  The names are the ones the profiler's ``XLA
-    Ops`` line prints (``fusion.12``); a Pallas kernel's custom call is
-    named by XLA after the kernel (``flash_mha_bwd_dkv_...512.3``) and is
-    found the same way.  ``owners`` are the scope names to look for: the
-    graph ops' and :data:`STEP_OWNERS`.  Nothing is guessed: an instruction
-    whose name stack holds none of them is owned by ``None``."""
-    owners = frozenset(owners)
-    return {m.group("name"): _owner(m.group("path"), owners)
+def table_from_hlo(text: str, owners: Iterable[str],
+                   parts: Iterable[str] = ()) -> Dict[str, Owner]:
+    """``{instruction name: (owner | None, part | "fwd" | "bwd" | None)}``
+    for every instruction of the compiled module ``text``
+    (``compiled.as_text()``) that carries an ``op_name``.  The names are the
+    ones the profiler's ``XLA Ops`` line prints (``fusion.12``); a Pallas
+    kernel's custom call is named by XLA after the kernel
+    (``flash_mha_bwd_dkv_...512.3``) and is found the same way.  ``owners``
+    are the scope names to look for: the graph ops' and :data:`STEP_OWNERS`
+    or :data:`SERVE_OWNERS`; ``parts`` the scopes the ops open inside their
+    own (``Op.scopes``).  Nothing is guessed: an instruction whose name
+    stack holds none of them is owned by ``None``."""
+    owners, parts = frozenset(owners), frozenset(parts)
+    return {m.group("name"): _owner(m.group("path"), owners, parts)
             for m in _INSTRUCTION.finditer(text)}
+
+
+_LOCATION = re.compile(r'loc\("([^"]+)"')
+
+
+def unnamed_owners(lowered, table: Dict[str, Owner],
+                   owners: Iterable[str]) -> list:
+    """Those of ``owners`` under which ``lowered`` (a ``jax.stages.Lowered``)
+    traced operations and to which ``table``, read from its compiled text,
+    gives no instruction.  jax leaves metadata out of the compilation
+    cache's key, so a cache may answer a lowering with an executable
+    compiled from a tree whose scopes were others (none at all, an op since
+    renamed, a scope since added), and the table read from it is then
+    wrong for this tree's program: every owner the lowered text names has
+    to turn up in the compiled one.  ``[]`` for an executable compiled
+    from this lowering."""
+    owners = frozenset(owners)
+    traced = {_owner(path, owners)[0]
+              for path in _LOCATION.findall(lowered.as_text(debug_info=True))}
+    return sorted(traced - {None} - {owner for owner, _ in table.values()})
+
+
+def stale_cache_error(unnamed: Dict[str, list]) -> RuntimeError:
+    """The error for programs whose tables leave owners out
+    (``{program: unnamed_owners(...)}``): never a guess."""
+    return RuntimeError(
+        "the compiled text of " + "; ".join(
+            f"{name} gives no instruction to {', '.join(who)}"
+            for name, who in sorted(unnamed.items()))
+        + ", which the lowered text traces: it was loaded from a "
+        "compilation cache written by a tree whose serving programs had "
+        "other scopes or none: clear the cache directory (its entries "
+        "are named after the program: <name>-<key>)")
 
 
 def attribute(ops: Sequence, table: Dict[str, Owner]) -> Dict[Owner, float]:

@@ -224,15 +224,9 @@ class Tracer:
             spans = list(self._spans)
             dropped = self._dropped
             rate = self.sample_rate
-        # ONE clock anchor, the two reads back to back: it puts spans
-        # taken on ``time.monotonic`` (the engines' default clock) on the
-        # wall clock, where a profiler session's start is known too
-        anchor = {"monotonic_ns": time.monotonic_ns(),
-                  "unix_ns": time.time_ns()}
         return {"schema": RAW_SCHEMA, "pid": os.getpid(),
                 "sample_rate": rate, "dropped": dropped,
-                "created_unix": round(time.time(), 3),
-                "clock_anchor": anchor, "spans": spans}
+                "created_unix": round(time.time(), 3), "spans": spans}
 
     def save(self, path: str) -> Dict:
         """Write the raw snapshot to ``path`` (atomic) and return it."""

@@ -191,6 +191,10 @@ class Op:
     # acts on each sequence position alone: ``forward`` on one position,
     # a window or a prompt chunk IS the serving step (``serve_check``)
     position_wise: bool = False
+    # the ``jax.named_scope``s the op opens INSIDE its own (which the
+    # programs open round it): the parts an owner table tells apart
+    # (``obs/device_ops.table_from_hlo``)
+    scopes: Tuple[str, ...] = ()
 
     def __init__(self, name: str, inputs: Sequence[Tensor]):
         self.name = name

@@ -83,6 +83,9 @@ class MoE(Op):
     stored ``(experts, in, out)``, the layout the grouped product reads."""
 
     op_type = OpType.MOE
+    # what is outside all three is the dispatch: the sort, the gather of
+    # the rows, the weighted combine
+    scopes = ("moe_router", "moe_experts", "moe_shared")
 
     def __init__(self, name, input_tensor, num_experts, d_ff, k=2,
                  capacity_factor=1.25, activation="gelu",

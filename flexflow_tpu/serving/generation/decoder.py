@@ -48,10 +48,16 @@ _walk`) that hands each op its inputs, its declared state and where
 the step stands (``op.ServeStep``); what a layer keeps between tokens,
 whether it can generate at all and how it advances are the op's own
 (``Op.serve_state`` / ``serve_check`` / ``serve_step``), so this module
-names no op class.  Supported graphs: one (n, s) int token input;
-position-wise ops (dense/norms/elementwise/softmax/dropout/embedding, a
-dropless MoE), causal self-attention (grouped heads, rotary positions, a
-window with rows of its own), stateless-init LSTM, learned position
+names no op class.  The walk runs each op under
+``jax.named_scope(op.name)`` as ``FFModel``'s forward does, and what a
+program does outside any graph op under one of
+``obs.device_ops.SERVE_OWNERS``, so a profiler trace of a serving
+program can be summed by graph op
+(:meth:`GraphDecoder.program_op_tables`).  Supported graphs: one (n, s)
+int token input; position-wise ops
+(dense/norms/elementwise/softmax/dropout/embedding, a dropless MoE),
+causal self-attention (grouped heads, rotary positions, a window with
+rows of its own), stateless-init LSTM, learned position
 embeddings, and whatever else writes the contract.  Anything else
 (convs, splits, cross-attention, an MoE with a capacity, pipelines) fails
 validation loudly at construction — a generation engine must never
@@ -71,6 +77,8 @@ import numpy as np
 
 from ...analysis.kv_memory import (DEFAULT_PAGE_SIZE, default_num_pages,
                                    kv_cache_layout, pages_per_slot)
+from ...obs.device_ops import (SERVE_OWNERS, stale_cache_error,
+                               table_from_hlo, unnamed_owners)
 from ...op import OpContext, ServeStep
 from . import sampling
 from .pages import alloc_pool_arrays, entry_dtype
@@ -129,6 +137,14 @@ def count_copies(hlo_text: str, elements) -> Dict[str, int]:
                 count += 1
                 nbytes += vol * _HLO_BYTES.get(m.group("dtype"), 1)
     return {"count": count, "bytes": nbytes}
+
+
+def program_name(fn) -> str:
+    """The name a profiler trace prints for the jitted ``fn``'s program
+    (``jit_decode`` for ``decode``, ``jit_prefill_512`` for the 512-token
+    chunk's): THE one rule, for a span's ``program``, a trace's ``XLA
+    Modules`` line and :meth:`GraphDecoder.program_op_tables`' keys."""
+    return "jit_" + getattr(fn, "__name__", "")
 
 
 def prefill_buckets(max_seq: int) -> Tuple[int, ...]:
@@ -213,6 +229,10 @@ class GraphDecoder:
         self._decode_sampled_fn = None
         self._verify_fns: Dict[Tuple[int, bool], object] = {}
         self._draft_fns: Dict[Tuple[int, bool], object] = {}
+        # what the compiled text of a program said, read once a program
+        # and device: (pool-sized copies, owner table, the owners the
+        # table leaves out) (_read_programs)
+        self._program_reads: Dict[Tuple[str, object], Tuple] = {}
 
     def refusal(self, what: str):
         """THE gate beside ``pageable``: why this graph's state cannot do
@@ -313,11 +333,14 @@ class GraphDecoder:
             logits, new = self._walk(params, caches, tokens, ServeStep(
                 "chunk", table_row, start=start, length=length, slot=slot,
                 no_page=self.num_pages))
-            last = jax.lax.dynamic_index_in_dim(
-                logits, length - 1, axis=1, keepdims=False)[0]
-            nxt = jnp.argmax(last).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                last = jax.lax.dynamic_index_in_dim(
+                    logits, length - 1, axis=1, keepdims=False)[0]
+                nxt = jnp.argmax(last).astype(jnp.int32)
             return nxt, new
 
+        # a program a bucket, told apart by name in a trace
+        prefill.__name__ = f"prefill_{bucket}"
         fn = jax.jit(prefill, donate_argnums=(1,))
         self._prefill_fns[bucket] = fn
         return fn
@@ -345,7 +368,8 @@ class GraphDecoder:
             logits, new = self._walk_decode(params, caches, tokens, pos,
                                             table, write_pages,
                                             write_rows)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return self._counted(nxt, new), new
 
         self._decode_fn = jax.jit(decode, donate_argnums=(1,))
@@ -373,10 +397,11 @@ class GraphDecoder:
             logits, new = self._walk_decode(params, caches, tokens, pos,
                                             table, write_pages,
                                             write_rows)
-            probs = sampling.filtered_probs(logits, temp, top_k, top_p)
-            keys = sampling.position_keys(sampling.request_keys(seeds),
-                                          pos + 1, sampling.STREAM_MAIN)
-            nxt = sampling.categorical(keys, probs)
+            with jax.named_scope("sample"):
+                probs = sampling.filtered_probs(logits, temp, top_k, top_p)
+                keys = sampling.position_keys(sampling.request_keys(seeds),
+                                              pos + 1, sampling.STREAM_MAIN)
+                nxt = sampling.categorical(keys, probs)
             return self._counted(nxt, new), new
 
         self._decode_sampled_fn = jax.jit(decode_s, donate_argnums=(1,))
@@ -396,8 +421,11 @@ class GraphDecoder:
         new: Dict[str, Dict[str, jax.Array]] = {}
         for op in self.model.layers:
             ins = [values[t.uid] for t in op.inputs]
-            outs, state = op.serve_step(params, ins, caches.get(op.name),
-                                        where, ctx)
+            # metadata at trace time only: the compiled program is the
+            # same, and its instructions name the op that owns them
+            with jax.named_scope(op.name):
+                outs, state = op.serve_step(params, ins,
+                                            caches.get(op.name), where, ctx)
             if state is not None:
                 new[op.name] = state
             for t, val in zip(op.outputs, outs):
@@ -462,40 +490,48 @@ class GraphDecoder:
         w = int(width)
 
         def verify(params, caches, first, d, pos, table, wp, wr):
-            window = jnp.concatenate([first[:, None], d[:, :-1]],
-                                     axis=1)
+            with jax.named_scope("speculate"):
+                window = jnp.concatenate([first[:, None], d[:, :-1]],
+                                         axis=1)
             logits, new = self._walk_window(params, caches, window, pos,
                                             table, wp, wr)
-            tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            eq = (d == tgt).astype(jnp.int32)
-            n_acc = jnp.sum(jnp.cumprod(eq, axis=1),
-                            axis=1).astype(jnp.int32)
+            with jax.named_scope("sample"):
+                tgt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("speculate"):
+                eq = (d == tgt).astype(jnp.int32)
+                n_acc = jnp.sum(jnp.cumprod(eq, axis=1),
+                                axis=1).astype(jnp.int32)
             return (n_acc, tgt), new
 
         def verify_s(params, caches, first, d, q, pos, table, wp, wr,
                      temp, top_k, top_p, seeds):
             slots = first.shape[0]
-            window = jnp.concatenate([first[:, None], d[:, :-1]],
-                                     axis=1)
+            with jax.named_scope("speculate"):
+                window = jnp.concatenate([first[:, None], d[:, :-1]],
+                                         axis=1)
             logits, new = self._walk_window(params, caches, window, pos,
                                             table, wp, wr)
-            flat = logits.reshape(slots * w, -1)
-            rep = lambda a: jnp.repeat(a, w)
-            p = sampling.filtered_probs(flat, rep(temp), rep(top_k),
-                                        rep(top_p))
-            p = p.reshape(slots, w, -1)
-            base = jnp.repeat(sampling.request_keys(seeds), w, axis=0)
-            tpos = (pos[:, None] + 1 + jnp.arange(w)).reshape(-1)
-            akeys = sampling.position_keys(
-                base, tpos, sampling.STREAM_ACCEPT).reshape(slots, w, 2)
-            rkeys = sampling.position_keys(
-                base, tpos, sampling.STREAM_RESIDUAL).reshape(slots, w,
-                                                             2)
-            n_acc, out = sampling.speculative_accept(d, p, q, akeys,
-                                                     rkeys)
+            with jax.named_scope("sample"):
+                flat = logits.reshape(slots * w, -1)
+                rep = lambda a: jnp.repeat(a, w)
+                p = sampling.filtered_probs(flat, rep(temp), rep(top_k),
+                                            rep(top_p))
+                p = p.reshape(slots, w, -1)
+            with jax.named_scope("speculate"):
+                base = jnp.repeat(sampling.request_keys(seeds), w, axis=0)
+                tpos = (pos[:, None] + 1 + jnp.arange(w)).reshape(-1)
+                akeys = sampling.position_keys(
+                    base, tpos, sampling.STREAM_ACCEPT).reshape(slots, w, 2)
+                rkeys = sampling.position_keys(
+                    base, tpos, sampling.STREAM_RESIDUAL).reshape(slots, w,
+                                                                 2)
+                n_acc, out = sampling.speculative_accept(d, p, q, akeys,
+                                                         rkeys)
             return (n_acc, out), new
 
-        fn = jax.jit(verify_s if sampled else verify, donate_argnums=(1,))
+        fn = verify_s if sampled else verify
+        fn.__name__ += f"_{w}"      # a program a width: verify_4, verify_s_4
+        fn = jax.jit(fn, donate_argnums=(1,))
         self._verify_fns[key] = fn
         return fn
 
@@ -529,13 +565,15 @@ class GraphDecoder:
                 wp_t, wr_t, t = xs
                 logits, kv = self._walk_decode(params, kv, tok, pos + t,
                                                table, wp_t, wr_t)
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("sample"):
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return (nxt, kv), nxt
 
             (_, new), d = jax.lax.scan(
                 step, (first, caches),
                 (wp, wr, jnp.arange(int(gamma))))
-            return jnp.transpose(d), new                 # (slots, γ)
+            with jax.named_scope("step_io"):
+                return jnp.transpose(d), new             # (slots, γ)
 
         def draft_s(params, caches, first, pos, table, wp, wr, temp,
                     top_k, top_p, seeds):
@@ -546,34 +584,44 @@ class GraphDecoder:
                 wp_t, wr_t, t = xs
                 logits, kv = self._walk_decode(params, kv, tok, pos + t,
                                                table, wp_t, wr_t)
-                q = sampling.filtered_probs(logits, temp, top_k, top_p)
-                keys = sampling.position_keys(base, pos + t + 1,
-                                              sampling.STREAM_DRAFT)
-                nxt = sampling.categorical(keys, q)
+                with jax.named_scope("sample"):
+                    q = sampling.filtered_probs(logits, temp, top_k, top_p)
+                    keys = sampling.position_keys(base, pos + t + 1,
+                                                  sampling.STREAM_DRAFT)
+                    nxt = sampling.categorical(keys, q)
                 return (nxt, kv), (nxt, q)
 
             (_, new), (d, q) = jax.lax.scan(
                 step, (first, caches),
                 (wp, wr, jnp.arange(int(gamma))))
-            return (jnp.transpose(d),
-                    jnp.transpose(q, (1, 0, 2))), new
+            with jax.named_scope("step_io"):
+                return (jnp.transpose(d),
+                        jnp.transpose(q, (1, 0, 2))), new
 
-        fn = jax.jit(draft_s if sampled else draft, donate_argnums=(1,))
+        fn = draft_s if sampled else draft
+        fn.__name__ += f"_{int(gamma)}"     # draft_4, draft_s_4
+        fn = jax.jit(fn, donate_argnums=(1,))
         self._draft_fns[key] = fn
         return fn
 
-    # ---- what the compiler made of the pool (ISSUE 25) ------------------
+    # ---- what the compiler made of the programs (ISSUES 25, 39) ----------
     def _program_specs(self, device=None):
-        """``(key, jitted fn, abstract arguments)`` of every program
-        this decoder has built, with the shapes and dtypes the engine
-        calls it with (its warm-up's and its dispatches' are the same:
-        ``engine._warmup``), so that lowering them again asks the
-        compilation cache for the executable that serves.  ``device``
-        places every argument on one (possibly only DESCRIBED) device
-        instead of the model's."""
+        """``(key, trace name, jitted fn, abstract arguments)`` of every
+        program this decoder has built, with the shapes and dtypes the
+        engine calls it with (its warm-up's and its dispatches' are the
+        same: ``engine._warmup``), so that lowering them again asks the
+        compilation cache for the executable that serves.  ``key`` is the
+        documented one (``jit_prefill.<bucket>``), the trace name what a
+        profiler prints (:func:`program_name`).  ``device`` places every
+        argument on one (possibly only DESCRIBED) device instead of the
+        model's.  The parameters' shapes are the installed weights', or
+        the graph's own (``model.parameters``) where none are installed;
+        the pools are described from the layout, never touched: a
+        compiled model with no weights and no pool can answer."""
         from jax.sharding import PartitionSpec, SingleDeviceSharding
 
-        mesh = self.model.mesh
+        model = self.model
+        mesh = model.mesh
         one = None if device is None else SingleDeviceSharding(device)
         if one is not None and mesh is not None and mesh.is_distributed:
             raise ValueError("pool_copies(device=...) describes ONE "
@@ -583,9 +631,16 @@ class GraphDecoder:
             return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype),
                                         sharding=one or sharding)
 
-        params = {k: spec(v.shape, v.dtype, getattr(v, "sharding", None))
-                  for k, v in self.model._params.items()}
-        compute = self.model.config.compute_dtype
+        if model._params:
+            params = {k: spec(v.shape, v.dtype,
+                              getattr(v, "sharding", None))
+                      for k, v in model._params.items()}
+        else:       # as init_layers would make and place them
+            params = {p.name: spec(
+                p.shape, model.config.param_dtype
+                if p.dtype == "float32" else p.dtype,
+                model._param_sharding(p)) for p in model.parameters}
+        compute = model.config.compute_dtype
         caches = {}
         for name, ent in self.layout.items():
             dt = entry_dtype(ent, compute)
@@ -626,8 +681,33 @@ class GraphDecoder:
             args = (i32(s), i32(s), i32(s, pps), i32(g, s), i32(g, s))
             out.append((f"jit_draft{'_s' if sampled else ''}.{g}", fn,
                         args + (strategy if sampled else ())))
-        return [(key, fn, (params, caches) + args)
+        return [(key, program_name(fn), fn, (params, caches) + args)
                 for key, fn, args in out]
+
+    def _read_programs(self, device=None):
+        """``{key: (trace name, pool-sized copies, owner table, owners
+        the table leaves out)}``: each program lowered again with the
+        shapes the engine calls it with and compiled (the persistent
+        compilation cache answers where the serving executable came
+        from it or went to it), its text read ONCE for both
+        :meth:`pool_copies` and :meth:`program_op_tables` and not kept;
+        a program already read for ``device`` is not compiled again."""
+        leaves = {int(np.prod(shape))
+                  for ent in self.layout.values() if ent["kind"] == "kv"
+                  for shape in ent["shapes"].values()}
+        owners = [op.name for op in self.model.layers] + list(SERVE_OWNERS)
+        parts = {s for op in self.model.layers for s in op.scopes}
+        out = {}
+        for key, name, fn, args in self._program_specs(device):
+            if (key, device) not in self._program_reads:
+                lowered = fn.lower(*args)
+                text = lowered.compile().as_text()
+                table = table_from_hlo(text, owners, parts)
+                self._program_reads[key, device] = (
+                    count_copies(text, leaves), table,
+                    unnamed_owners(lowered, table, owners))
+            out[key] = (name,) + self._program_reads[key, device]
+        return out
 
     def pool_copies(self, device=None) -> Dict[str, Dict[str, int]]:
         """What says from inside the program that the pools are not
@@ -641,19 +721,35 @@ class GraphDecoder:
         page, heads, head_dim)`` form the TPU compile of one decode
         layer held eight such copies, 100 MB each in the serve cell.
 
-        Computed ON DEMAND, never by ``start()``: each program is
-        lowered again with the shapes the engine calls it with and
-        compiled, which the persistent compilation cache answers where
-        the serving executable came from it or went to it.  ``device``
-        compiles for one given device instead — a TPU that
-        ``jax.experimental.topologies`` only describes will do, which
-        is how a test without a chip reads the TPU's compiler."""
-        leaves = {int(np.prod(shape))
-                  for ent in self.layout.values() if ent["kind"] == "kv"
-                  for shape in ent["shapes"].values()}
-        return {key: count_copies(
-                    fn.lower(*args).compile().as_text(), leaves)
-                for key, fn, args in self._program_specs(device)}
+        Computed ON DEMAND, never by ``start()``
+        (:meth:`_read_programs`).  ``device`` compiles for one given
+        device instead — a TPU that ``jax.experimental.topologies`` only
+        describes will do, which is how a test without a chip reads the
+        TPU's compiler."""
+        return {key: copies for key, (_, copies, _, _)
+                in self._read_programs(device).items()}
+
+    def program_op_tables(self, device=None) -> Dict[str, Dict[str, tuple]]:
+        """Which graph op each instruction of each compiled serving
+        program belongs to: ``{program name as the profiler prints it
+        (``jit_prefill_512``, ``jit_decode``): {instruction name: (owner
+        | None, part | None)}}`` for every program this decoder has
+        built.  Owners are the graph ops' names and
+        ``obs.device_ops.SERVE_OWNERS``, parts the scopes an op opens
+        inside its own (``Op.scopes``: ``moe_router``, ``moe_experts``,
+        ``moe_shared``); sum a profiler trace's operations inside a
+        program's ``XLA Modules`` events by that program's table.  ON
+        DEMAND like :meth:`pool_copies`, and one compile a program with
+        it.  A compiled text that gives no instruction to an owner
+        its lowered text traces is an executable the compilation cache
+        kept from a tree whose programs had other scopes, or none (jax
+        leaves metadata out of the cache's key;
+        ``obs.device_ops.unnamed_owners``): an error, never a guess."""
+        reads = self._read_programs(device).values()
+        stale = {name: unnamed for name, _, _, unnamed in reads if unnamed}
+        if stale:
+            raise stale_cache_error(stale)
+        return {name: table for name, _, table, _ in reads}
 
     def decode_attention(self) -> Dict[str, int]:
         """How many attention ops of the graph got which decode core when
@@ -697,8 +793,10 @@ class GraphDecoder:
         can ride the boundary's one fetch whenever that comes, and no
         other program ever reads a donated buffer (one that did, a copy
         dispatched behind the step, made the next dispatch wait for the
-        device on a TPU).  A graph that counts nothing returns what it
-        returned before."""
+        device on a TPU).  The copy is XLA's own (the same value leaves
+        the program twice), so it is traced under no scope and an owner
+        table gives it to nobody.  A graph that counts nothing returns
+        what it returned before."""
         if not self.counters:
             return nxt
         return nxt, {n: dict(new[n]) for n in self.counters}

@@ -76,6 +76,8 @@ from ... import faults
 from ...compile_cache import enable as _enable_compile_cache
 from ...fflogger import get_logger
 from ...obs import lockwatch
+from ...obs.device_ops import (SERVE_OWNERS, stale_cache_error,
+                               table_from_hlo, unnamed_owners)
 from ...obs.flight import flight_dump, get_flight
 from ...obs.trace import phase_of, tracer_from_config
 from ...profiling import quantiles
@@ -83,18 +85,11 @@ from ..batcher import MicroBatcher, Request
 from ..errors import (GenerationCancelled, KVCacheExhausted,
                       OverloadError, SheddedError)
 from ..metrics import ServingMetrics
-from .decoder import GraphDecoder
+from .decoder import GraphDecoder, program_name as _program_name
 from .pages import KVPagePool, PrefixCache, export_pages, import_pages
 from .sampling import SamplingParams
 
 _END = object()  # token-stream sentinel
-
-
-def _program_name(fn) -> str:
-    """The name a profiler trace prints for the jitted ``fn``'s program
-    (``jit_decode`` for ``decode``), so a span's reader finds the
-    program's device time without knowing the decoder."""
-    return "jit_" + getattr(fn, "__name__", "")
 
 
 def _resolve(fut: Future, out) -> bool:
@@ -305,15 +300,23 @@ class _Flight:
         return self.nxt is not None or self.join is not None
 
 
-@jax.jit
 def _splice_tokens(prev, host_tokens, from_host, first, join_slot):
     """The next token step's input, made ON the device: the last step's
     output ``prev`` where a slot's newest token has not reached the host,
     ``host_tokens`` where it has (``from_host``; 0 for a slot that does
     not decode, as before), and the joined slot's ``first`` token at
     ``join_slot`` (``slots`` = no join: the write drops)."""
-    tokens = jnp.where(from_host, host_tokens, prev)
-    return tokens.at[join_slot].set(first, mode="drop")
+    with jax.named_scope("step_io"):
+        tokens = jnp.where(from_host, host_tokens, prev)
+        return tokens.at[join_slot].set(first, mode="drop")
+
+
+# a program name of its own like the decoder's (``jit_splice_tokens`` in a
+# trace), and NEW with the scope: the scope changes no instruction and jax
+# leaves metadata out of the compilation cache's key, so under the name it
+# had a cache could answer with the scope-less program of an older tree
+_splice_tokens.__name__ = "splice_tokens"
+_splice_tokens = jax.jit(_splice_tokens)
 
 
 class GenerationMetrics(ServingMetrics):
@@ -1248,6 +1251,36 @@ class GenerationEngine:
         self._pool_copies = out
         return out
 
+    def program_op_tables(self) -> Dict[str, Dict[str, tuple]]:
+        """``{program name as a profiler trace prints it: {instruction
+        name: (owner | None, part | None)}}``: which graph op (or which
+        of ``obs.device_ops.SERVE_OWNERS``) each instruction of every
+        serving program this engine's decoders have built belongs to —
+        the draft's under ``draft/``, the token splice's under its own
+        name — as :meth:`GraphDecoder.program_op_tables` reads them.
+        ON DEMAND only, like :meth:`pool_copies`, with which it shares
+        the one compile a program; it touches no weight and no pool, so
+        an engine that was never started can answer for the programs
+        its decoder has built."""
+        out = dict(self._decoder.program_op_tables(device=self.device))
+        if self._draft_decoder is not None:
+            out.update(
+                ("draft/" + name, table) for name, table in
+                self._draft_decoder.program_op_tables(
+                    device=self.device).items())
+        slots = jax.ShapeDtypeStruct((self.slots,), np.int32)
+        one = jax.ShapeDtypeStruct((), np.int32)
+        splice = _program_name(_splice_tokens)
+        lowered = _splice_tokens.lower(
+            slots, slots, jax.ShapeDtypeStruct((self.slots,), bool),
+            one, one)
+        out[splice] = table_from_hlo(lowered.compile().as_text(),
+                                     SERVE_OWNERS)
+        unnamed = unnamed_owners(lowered, out[splice], SERVE_OWNERS)
+        if unnamed:
+            raise stale_cache_error({splice: unnamed})
+        return out
+
     def stats(self) -> Dict:
         active = sum(1 for s in self._slots_state if s is not None)
         out = {**self.metrics.snapshot(), "slots": self.slots,
@@ -1539,12 +1572,17 @@ class GenerationEngine:
         at_once = final and (st.stream.handoff is not None
                              or self._spec_active())
         tok = 0
-        if self._traced and st.t_exec is None:
-            st.t_exec = self.clock()
+        program = None
+        if self._traced:
+            program = _program_name(fn)
+            if st.t_exec is None:
+                st.t_exec = self.clock()
         try:
-            # the span says which chunk of the prompt this is and how long
+            # the span says which chunk of the prompt this is, how long,
+            # and which program ran it (the name a device trace prints)
             with self._phase("gen-prefill", step_num=self._n_steps,
-                             chunk=st.chunks, length=chunk):
+                             chunk=st.chunks, length=chunk, bucket=bucket,
+                             program=program):
                 first, self._caches = fn(
                     self._params, self._caches, tokens, row,
                     np.int32(slot), np.int32(start), np.int32(chunk))

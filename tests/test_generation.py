@@ -212,7 +212,7 @@ def test_position_embedding_decode_matches_forward():
 # engine-level: GenerationEngine == replicated predict-style decode
 # ---------------------------------------------------------------------
 def _build_lm(seed=0, mesh_shape=None, slots=2, num_layers=2, d_model=32,
-              d_ff=64, compute_dtype="float32"):
+              d_ff=64, compute_dtype="float32", weights=True):
     from flexflow_tpu.models import build_transformer_lm
     cfg = ff.FFConfig(batch_size=4, compute_dtype=compute_dtype, seed=seed)
     cfg.serve_gen_slots = slots
@@ -221,7 +221,8 @@ def _build_lm(seed=0, mesh_shape=None, slots=2, num_layers=2, d_model=32,
                                  seq_len=SEQ, vocab_size=VOCAB)[0]
     model.compile(ff.SGDOptimizer(lr=0.01),
                   mesh=MachineMesh(mesh_shape or {"n": 1}))
-    model.init_layers(seed=seed)
+    if weights:
+        model.init_layers(seed=seed)
     return model
 
 
@@ -1808,7 +1809,7 @@ def test_decode_reads_the_pool_in_place_tpu(v5e_device, monkeypatch):
         with _no_compilation_cache():
             return _within(_COMPILE_LIMIT_S, lambda: {
                 key: fn.lower(*args).compile().as_text()
-                for key, fn, args in dec._program_specs(v5e_device)})
+                for key, _, fn, args in dec._program_specs(v5e_device)})
 
     # a decoder of its own: a program is traced once, as the backend
     # answered then
@@ -2153,7 +2154,7 @@ _DEC_ROPE = {
 _DEC_SEQ, _DEC_WINDOW, _DEC_CHUNK = 64, 8, 8
 
 
-def _build_decoder_lm(chunk=_DEC_CHUNK, seed=0):
+def _build_decoder_lm(chunk=_DEC_CHUNK, seed=0, weights=True):
     """The pre-norm decoder at a size that keeps every kind: 2 key/value
     heads under 6 and 8 query heads, head 16, window 8, 8 experts top-2
     beside a shared one, layer 0 dense, 5 layers; float32."""
@@ -2169,7 +2170,8 @@ def _build_decoder_lm(chunk=_DEC_CHUNK, seed=0):
         moe={"num_experts": 8, "k": 2, "d_ff": 16, "shared_d_ff": 16,
              "routed_scale": 2.5})[0]
     model.compile(ff.SGDOptimizer(lr=0.01), mesh=MachineMesh({"n": 1}))
-    model.init_layers(seed=seed)
+    if weights:
+        model.init_layers(seed=seed)
     return model
 
 
@@ -2258,7 +2260,7 @@ def test_grouped_product_counts_every_sparse_op_of_every_program():
     assert [op.name for op in ops] == ["moe_1", "moe_2", "moe_3", "moe_4"]
     dec = GraphDecoder(decoder_lm, 2, _DEC_SEQ)
     assert dec.grouped_product() == {"rows": 0, "library": 0}
-    args = {key: (fn, a) for key, fn, a in (
+    args = {key: (fn, a) for key, _, fn, a in (
         dec.prefill_fn(8), dec.decode_fn(), *dec._program_specs())[2:]}
     fn, a = args["jit_prefill.8"]
     fn.trace(*a)
@@ -2437,26 +2439,40 @@ def test_rotary_positions_match_a_complex_rotation():
         np.testing.assert_allclose(inv, freq, rtol=1e-6)
 
 
-def test_post_norm_graph_serves_the_tokens_it_served_before():
+@pytest.mark.parametrize("rate", [0.0, 1.0])
+def test_post_norm_graph_serves_the_tokens_it_served_before(rate):
     """``build_transformer_lm``'s tiny graph (what ``gpt1`` is built of)
     through the engine, whole prompts and chunks of 4: the tokens the
     PARENT of ISSUE 36's change served, recorded from its tree (seed 0,
     prompts from ``default_rng(36)``), bit for bit: the attention op's
-    defaults trace what they traced."""
+    defaults trace what they traced, the scopes the walk runs them under
+    (ISSUE 39) change no program, and tracing on (``trace_sample_rate``
+    1) serves what tracing off serves."""
+    from flexflow_tpu.obs.trace import get_tracer
     before = [[3, 41, 11, 45, 45, 54, 21, 19, 37, 6],
               [45, 44, 37, 19, 37, 6, 56, 13, 13, 60],
               [37, 56, 13, 13, 22, 16, 49, 24, 37, 17],
               [37, 6, 53, 35, 3, 37, 37, 45, 32, 13]]
     model = _build_lm()
+    model.config.trace_sample_rate = rate
     rng = np.random.default_rng(36)
     prompts = [rng.integers(1, VOCAB, n).astype(np.int32)
                for n in (3, 7, 12, 17)]
-    for chunk in (0, 4):
-        with GenerationEngine(model, slots=2, prefill_chunk=chunk) as eng:
-            outs = [[int(t) for t in
-                     eng.submit(p, max_new_tokens=10).result(timeout=120)]
+    tracer = get_tracer()
+    tracer.disable()
+    tracer.reset()
+    try:
+        for chunk in (0, 4):
+            with GenerationEngine(model, slots=2,
+                                  prefill_chunk=chunk) as eng:
+                outs = [[int(t) for t in eng.submit(
+                    p, max_new_tokens=10).result(timeout=120)]
                     for p in prompts]
-        assert outs == before, chunk
+            assert outs == before, chunk
+        assert bool(tracer.snapshot()["spans"]) == bool(rate)
+    finally:
+        tracer.disable()
+        tracer.reset()
 
 
 @pytest.mark.parametrize("window,dtype,tol", [
@@ -2571,3 +2587,147 @@ def test_grouped_paged_decode_kernel_compiles_for_the_chip(
                        .lower(*args, heads, 0.088, 8, window).compile()
                        .as_text())
     assert "paged_decode_attention" in text
+
+
+# ----------------------------------------------------------------------
+# the serving programs' device time by graph op (ISSUE 39)
+# ----------------------------------------------------------------------
+def _owned(table):
+    """``{owner: {part}}`` of a program's owner table."""
+    out = {}
+    for owner, part in table.values():
+        out.setdefault(owner, set()).add(part)
+    return out
+
+
+@pytest.mark.parametrize("build, bucket", [(_build_lm, 16),
+                                           (_build_decoder_lm, 8)])
+def test_every_graph_op_owns_instructions_of_the_serving_programs(build,
+                                                                   bucket):
+    """The token step and one chunk program of the tiny post-norm decoder
+    and of the tiny laguna graph, compiled here: every graph op owns at
+    least one instruction of each, ``sample`` owns the argmax, a
+    mixture-of-experts op's instructions carry its own scopes as parts,
+    and nothing but the graph's ops and ``SERVE_OWNERS`` owns anything.
+    The model has NO weights installed and no pool is allocated: the
+    table is a matter of shapes."""
+    from flexflow_tpu.obs.device_ops import SERVE_OWNERS
+    from flexflow_tpu.serving.generation.decoder import GraphDecoder
+
+    model = build(weights=False)
+    assert model._params == {}
+    seq = model.input_tensors[0].shape[1]
+    dec = GraphDecoder(model, 2, seq)
+    dec.decode_fn()
+    dec.prefill_fn(bucket)
+    tables = _within(_COMPILE_LIMIT_S, dec.program_op_tables)
+    assert set(tables) == {"jit_decode", f"jit_prefill_{bucket}"}
+    ops = {op.name for op in model.layers}
+    for name, table in tables.items():
+        owned = _owned(table)
+        assert ops <= set(owned), (name, ops - set(owned))
+        assert set(owned) <= ops | {None, "sample"}
+        assert set(owned) & set(SERVE_OWNERS) == {"sample"}
+        assert any(re.search("reduce|argmax", ins) for ins, (owner, _)
+                   in table.items() if owner == "sample"), name
+        for op in model.layers:
+            assert owned[op.name] == {None, *op.scopes}, (name, op.name)
+    if build is _build_decoder_lm:
+        assert _owned(tables["jit_decode"])["moe_1"] == {
+            None, "moe_router", "moe_experts", "moe_shared"}
+    # asked again, nothing is compiled again; the copies' count shares it
+    reads = dict(dec._program_reads)
+    assert set(dec.pool_copies()) == {"jit_decode", f"jit_prefill.{bucket}"}
+    assert dec.program_op_tables() == tables
+    assert all(dec._program_reads[k] is v for k, v in reads.items())
+
+
+def test_an_engine_never_started_names_its_programs_and_their_owners():
+    """``GenerationEngine.program_op_tables()`` is ON DEMAND and needs no
+    pool: an engine that was never started answers for the programs its
+    decoder has built, each chunk bucket under a name of its own (as a
+    profiler trace prints it), the token splice under ``step_io``."""
+    # a model of its own: a decoder is shared by the engines of a model
+    eng = GenerationEngine(_build_lm(weights=False), slots=2,
+                           max_new_tokens=3)
+    assert eng._caches is None
+    dec = eng._decoder
+    for b in dec.buckets[:2]:
+        dec.prefill_fn(b)
+    dec.decode_fn()
+    tables = _within(_COMPILE_LIMIT_S, eng.program_op_tables)
+    assert set(tables) == {"jit_decode", "jit_splice_tokens",
+                           *(f"jit_prefill_{b}" for b in dec.buckets[:2])}
+    assert len(dec.buckets[:2]) == 2 and eng._caches is None
+    assert "step_io" in _owned(tables["jit_splice_tokens"])
+    # the documented keys of pool_copies() stay
+    assert set(dec.pool_copies()) == {
+        "jit_decode", *(f"jit_prefill.{b}" for b in dec.buckets[:2])}
+
+
+@pytest.mark.parametrize("lost", ["every owner", "attention_1", "sample"])
+def test_a_table_that_leaves_an_owner_out_is_an_error_not_a_guess(
+        lm, monkeypatch, lost):
+    """Metadata is not in the compilation cache's key, so a cache may
+    answer with an executable compiled by a tree whose programs had other
+    scopes: none at all, an op under another name, one scope fewer.  Every
+    owner the LOWERED text traces has to own an instruction of the
+    compiled one; the error names the program and who is missing."""
+    from flexflow_tpu.obs import device_ops
+    from flexflow_tpu.serving.generation import decoder as decoder_mod
+
+    dec = decoder_mod.GraphDecoder(lm, 2, SEQ)
+    dec.decode_fn()
+
+    def stale(text, owners, parts=()):
+        return {ins: (None, None) if lost in ("every owner", owner)
+                else (owner, part) for ins, (owner, part) in
+                device_ops.table_from_hlo(text, owners, parts).items()}
+
+    monkeypatch.setattr(decoder_mod, "table_from_hlo", stale)
+    with pytest.raises(RuntimeError, match="clear the cache") as e:
+        dec.program_op_tables()
+    assert "jit_decode gives no instruction to" in str(e.value)
+    if lost != "every owner":
+        assert f"to {lost}," in str(e.value)
+    # the copies' count is no matter of scopes: it answers all the same
+    assert set(dec.pool_copies()) == {"jit_decode"}
+
+
+def test_a_stale_token_splice_is_an_error_too(monkeypatch):
+    from flexflow_tpu.serving.generation import engine as engine_mod
+
+    eng = GenerationEngine(_build_lm(weights=False), slots=2,
+                           max_new_tokens=3)
+    monkeypatch.setattr(engine_mod, "table_from_hlo",
+                        lambda text, owners: {"fusion.1": (None, None)})
+    with pytest.raises(RuntimeError, match="jit_splice_tokens gives no "
+                       "instruction to step_io.*clear the cache"):
+        eng.program_op_tables()
+
+
+@pytest.mark.parametrize("build, bucket", [(_build_lm, 16),
+                                           (_build_decoder_lm, 8)])
+def test_the_scopes_change_no_lowered_program(build, bucket, monkeypatch):
+    """The token step and one chunk program lowered with the scopes and
+    with ``jax.named_scope`` made a no-op: the same text, so the same
+    sha256 (a scope is metadata, which the text carries only when asked
+    for locations).  Lowered, never compiled: nothing without scopes
+    reaches the compilation cache."""
+    import hashlib
+    from flexflow_tpu.serving.generation.decoder import GraphDecoder
+
+    def digests():
+        model = build(weights=False)
+        dec = GraphDecoder(model, 2, model.input_tensors[0].shape[1])
+        dec.decode_fn()
+        dec.prefill_fn(bucket)
+        return {name: hashlib.sha256(
+                    fn.lower(*args).as_text().encode()).hexdigest()
+                for _, name, fn, args in dec._program_specs()}
+
+    scoped = digests()
+    assert set(scoped) == {"jit_decode", f"jit_prefill_{bucket}"}
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    assert digests() == scoped

@@ -227,7 +227,7 @@ def _token_program_text(model, slots, seq):
 
     dec = GraphDecoder(model, slots, seq)
     dec.decode_fn()
-    (fn, args), = [(fn, args) for key, fn, args in dec._program_specs()
+    (fn, args), = [(fn, args) for key, _, fn, args in dec._program_specs()
                    if key == "jit_decode"]
     return dec, fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
 

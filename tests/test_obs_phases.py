@@ -242,10 +242,25 @@ def test_tracing_off_records_nothing_and_serves_the_same_tokens(lm):
     assert on == off
 
 
-def test_the_snapshot_carries_one_clock_anchor(tracer):
-    a = tracer.snapshot()["clock_anchor"]
-    assert set(a) == {"monotonic_ns", "unix_ns"}
-    assert a["monotonic_ns"] > 0 and a["unix_ns"] > 10 ** 18
+def test_each_chunk_phase_names_the_program_it_dispatched(lm, tracer):
+    """Two chunk buckets are two programs with two names (``XLA Modules``
+    tells them apart), and every ``gen-prefill`` phase span carries the
+    name of the one it dispatched and its bucket: a 10-token prompt in
+    chunks of 4 runs the 4-token program twice and the 2-token one once;
+    the request's ``prefill_exec`` names its last."""
+    eng, _, _ = _drive(lm, [list(range(1, 11))], chunk=4)
+    spans = tracer.snapshot()["spans"]
+    chunks = sorted(_by_name(spans, "gen-prefill"), key=lambda s: s["t0_ns"])
+    assert [(s["args"]["chunk"], s["args"]["length"], s["args"]["bucket"],
+             s["args"]["program"]) for s in chunks] == [
+        (0, 4, 4, "jit_prefill_4"), (1, 4, 4, "jit_prefill_4"),
+        (2, 2, 2, "jit_prefill_2")]
+    dec = eng._decoder
+    names = {b: "jit_" + dec.prefill_fn(b).__name__ for b in (2, 4)}
+    assert names == {2: "jit_prefill_2", 4: "jit_prefill_4"}
+    last, = _by_name(spans, "prefill_exec")
+    assert (last["args"]["program"], last["args"]["bucket"]) \
+        == ("jit_prefill_2", 2)
 
 
 # ----------------------------------------------------------------------
