@@ -679,6 +679,7 @@ class GenerationEngine:
         # lifetime counters preserved across pool rebuilds (a poisoned
         # dispatch rebuilds pool+prefix; totals must stay monotonic)
         self._evictions_base = 0
+        self._evict_scanned_base = 0
         self._pool_high_base = 0
         self.metrics.pool_stats_fn = self._pool_stats
         # ---- speculative decoding (docs/serving.md "Speculative
@@ -1197,6 +1198,8 @@ class GenerationEngine:
             "prefix_pages_cached": len(prefix) if prefix else 0,
             "evictions": (self._evictions_base
                           + (prefix.evictions if prefix else 0)),
+            "evict_scanned": (self._evict_scanned_base
+                              + (prefix.evict_scanned if prefix else 0)),
             "prefill_chunks": self._chunks_total,
         }
 
@@ -1889,10 +1892,11 @@ class GenerationEngine:
     def _ensure_pages(self, slot: int, st: _Slot,
                       upto_pos: int) -> bool:
         """Grow the slot's page table to cover positions
-        ``[0, upto_pos)``.  The whole deficit is evicted in ONE trie
-        walk up front (PrefixCache.evict batches the LRU scan) — a
-        per-allocation evict_one loop would rescan the trie per page
-        under exactly the pool pressure that makes the trie large."""
+        ``[0, upto_pos)``.  The whole deficit is evicted up front, as
+        one batch of the prefix cache's leaf order: a victim costs a
+        pop of that order, not a walk of the trie, whether the deficit
+        is a joining prompt's six pages or the one page of each
+        decoding slot that crosses a page edge."""
         need = (int(upto_pos) - 1) // self.page_size + 1
         deficit = need - len(st.pages) - self._pool.pages_free
         if deficit > 0 and self._prefix is not None:
@@ -2460,6 +2464,7 @@ class GenerationEngine:
         self._prev_tokens = self._prev_first = None
         if self._prefix is not None:
             self._evictions_base += self._prefix.evictions
+            self._evict_scanned_base += self._prefix.evict_scanned
         self._pool_high_base = max(self._pool_high_base,
                                    self._pool.high_water)
         self._pool = KVPagePool(self.num_pages, self.page_size)

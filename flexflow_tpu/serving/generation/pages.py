@@ -34,6 +34,7 @@ so no second allocation path can drift from the
 
 from __future__ import annotations
 
+import heapq
 from typing import Dict, List, Optional, Tuple
 
 from ...analysis.kv_memory import DEFAULT_PAGE_SIZE
@@ -120,7 +121,22 @@ class PrefixCache:
     node; lookups take an extra reference per matched page for the
     joining slot.  Eviction is LRU over leaf nodes nobody else
     references — interior nodes and pages still held by live slots are
-    never evicted."""
+    never evicted.
+
+    The cache knows its next victim without walking the trie: the
+    LEAVES are kept in a heap of ``(last_used, seq, node)``, pushed
+    where the trie changes — an insert's or a lookup's deepest node if
+    it is a leaf, a parent its last child's eviction exposes (at its
+    OWN ``last_used``, which is not the newest).  An entry is never
+    updated in place; it is STALE once its node was touched again, got
+    a child or left the trie, which all show as ``node.children`` or a
+    ``last_used`` other than the entry's (a node that gets a child is
+    touched by that insert), and a stale entry is dropped when popped.
+    Two leaves never share a ``last_used`` (a tick touches one
+    root-to-node path, and of one path only the deepest node can be a
+    leaf), so the order is total.  Whether a live slot holds a leaf's
+    page changes in the pool, out of the trie's sight, and is asked at
+    pop time (:meth:`evict`)."""
 
     def __init__(self, pool: KVPagePool):
         self.pool = pool
@@ -128,9 +144,15 @@ class PrefixCache:
         self._root: Dict[Tuple[int, ...], _TrieNode] = {}
         self._nodes = 0
         self._clock = 0  # LRU tick (monotonic counter, no wall time)
+        self._leaves: List[Tuple[int, int, _TrieNode]] = []
+        self._seq = 0  # heap tie-break: a stale entry may share a tick
         self.hits = 0
         self.misses = 0
         self.evictions = 0
+        # heap entries evict() popped — stale and held ones included:
+        # evict_scanned / evictions says how often the order answered
+        # at once (docs/observability.md)
+        self.evict_scanned = 0
 
     def __len__(self) -> int:
         return self._nodes
@@ -138,6 +160,25 @@ class PrefixCache:
     def _tick(self) -> int:
         self._clock += 1
         return self._clock
+
+    def _push_leaf(self, node: Optional[_TrieNode]) -> None:
+        """Enter ``node`` in the leaf order at its ``last_used`` if it
+        is a leaf.  Stale entries only leave when popped, so a cache
+        that is hit often and evicts seldom would grow the heap by one
+        a lookup: past twice the trie's size it is rebuilt from its
+        live entries (amortised O(1) a push)."""
+        if node is None or node.children:
+            return
+        self._seq += 1
+        heapq.heappush(self._leaves, (node.last_used, self._seq, node))
+        if len(self._leaves) > 2 * self._nodes + 64:
+            self._leaves = [e for e in self._leaves if self._live(e)]
+            heapq.heapify(self._leaves)
+
+    @staticmethod
+    def _live(entry: Tuple[int, int, _TrieNode]) -> bool:
+        stamp, _, node = entry
+        return node.last_used == stamp and not node.children
 
     @staticmethod
     def _pages_of(tokens, page_size: int) -> List[Tuple[int, ...]]:
@@ -159,6 +200,7 @@ class PrefixCache:
         starts at ``len(result) * page_size``."""
         out: List[int] = []
         level = self._root
+        last: Optional[_TrieNode] = None
         now = self._tick()
         for key in self._pages_of(tokens, self.page_size):
             node = level.get(key)
@@ -167,7 +209,9 @@ class PrefixCache:
             node.last_used = now
             self.pool.ref(node.page)
             out.append(node.page)
+            last = node
             level = node.children
+        self._push_leaf(last)
         if out:
             self.hits += 1
         else:
@@ -198,49 +242,58 @@ class PrefixCache:
                 node.last_used = now
             parent = node
             level = node.children
+        self._push_leaf(parent)
         return added
-
-    def _evictable(self) -> List[_TrieNode]:
-        out: List[_TrieNode] = []
-        stack = list(self._root.values())
-        while stack:
-            node = stack.pop()
-            if node.children:
-                stack.extend(node.children.values())
-            elif self.pool.refcount(node.page) == 1:
-                # a leaf only the trie references: safe to drop
-                out.append(node)
-        return out
 
     def _evict_node(self, node: _TrieNode) -> None:
         level = (node.parent.children if node.parent is not None
                  else self._root)
         del level[node.key]
+        node.last_used = -1  # no entry of a node that left is live
         self._nodes -= 1
         self.pool.release(node.page)
         self.evictions += 1
 
     def evict(self, count: int) -> int:
         """Free up to ``count`` least-recently-used unreferenced LEAF
-        pages back to the pool (page-pool pressure).  ONE evictability
-        walk covers a whole batch — evicting a leaf can only ever
-        EXPOSE its parent as a new leaf, never invalidate another
-        collected victim, so the sorted victim list stays valid while
-        it drains; only when it runs dry mid-batch (freed leaves'
-        parents now evictable) does another walk happen.  Returns the
-        number of pages freed — 0 means every cached page backs a
-        live slot."""
+        pages back to the pool (page-pool pressure): pop the leaf
+        order until ``count`` victims are found.  A popped leaf a live
+        slot still holds (``pool.refcount > 1``: a live request's last
+        prompt page, so young, and seldom ahead of a victim) is set
+        aside and put back after the batch, so one eviction costs
+        O((1 + held leaves older than the victim) log n) whatever the
+        trie's size.  A batch's victims are the leaves of the moment
+        it began, oldest first; a parent its child's eviction exposes
+        joins the order when those run dry, or after the batch.
+        Returns the number of pages freed — fewer than ``count`` means
+        every cached page left backs a live slot or an interior node."""
         freed = 0
+        held: List[Tuple[int, int, _TrieNode]] = []
+        exposed: List[_TrieNode] = []
         while freed < count:
-            victims = sorted(self._evictable(),
-                             key=lambda n: n.last_used)
-            if not victims:
-                break
-            for node in victims:
-                if freed >= count:
+            if not self._leaves:
+                if not exposed:
                     break
-                self._evict_node(node)
-                freed += 1
+                for node in exposed:
+                    self._push_leaf(node)
+                exposed = []
+                continue
+            entry = heapq.heappop(self._leaves)
+            self.evict_scanned += 1
+            if not self._live(entry):
+                continue
+            node = entry[2]
+            if self.pool.refcount(node.page) > 1:
+                held.append(entry)
+                continue
+            self._evict_node(node)
+            freed += 1
+            if node.parent is not None and not node.parent.children:
+                exposed.append(node.parent)
+        for entry in held:
+            heapq.heappush(self._leaves, entry)
+        for node in exposed:
+            self._push_leaf(node)
         return freed
 
     def evict_one(self) -> bool:
@@ -256,6 +309,7 @@ class PrefixCache:
             stack.extend(node.children.values())
             self.pool.release(node.page)
         self._nodes = 0
+        self._leaves = []
 
 
 def entry_dtype(ent: Dict, compute_dtype):

@@ -793,6 +793,243 @@ def test_prefix_trie_lookup_insert_evict():
     assert trie.evictions == 2
 
 
+def _walk_cache(pool):
+    """The oracle of the leaf order: a ``PrefixCache`` whose ``evict``
+    is the one PR 40 deleted from the package, a depth-first walk of
+    the WHOLE trie for the leaves only the trie references, sorted by
+    ``last_used``, walked again when the list runs dry."""
+    from flexflow_tpu.serving.generation.pages import PrefixCache
+
+    class WalkCache(PrefixCache):
+        def _evictable(self):
+            out = []
+            stack = list(self._root.values())
+            while stack:
+                node = stack.pop()
+                if node.children:
+                    stack.extend(node.children.values())
+                elif self.pool.refcount(node.page) == 1:
+                    out.append(node)
+            return out
+
+        def evict(self, count):
+            freed = 0
+            while freed < count:
+                victims = sorted(self._evictable(),
+                                 key=lambda n: n.last_used)
+                if not victims:
+                    break
+                for node in victims:
+                    if freed >= count:
+                        break
+                    self._evict_node(node)
+                    freed += 1
+            return freed
+
+    return WalkCache(pool)
+
+
+def _logged(trie):
+    """``trie`` with its victims written down, in order: the page and
+    the token chain of every node ``_evict_node`` removes."""
+    log = []
+    evict_node = trie._evict_node
+
+    def spy(node):
+        chain, n = [], node
+        while n is not None:
+            chain.append(n.key)
+            n = n.parent
+        log.append((node.page, tuple(reversed(chain))))
+        evict_node(node)
+
+    trie._evict_node = spy
+    return log
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_leaf_order_evicts_what_the_walk_evicted(seed):
+    """ISSUE 40's property: on branching tries (shared prefixes, leaves
+    live slots hold, parents a batch exposes, ``evict`` past what is
+    evictable, ``clear``) the heap of leaves gives the victims of the
+    whole-trie walk, in its order, and leaves the same trie and the
+    same pool behind."""
+    from flexflow_tpu.serving.generation.pages import (KVPagePool,
+                                                       PrefixCache)
+    rng = np.random.default_rng(4000 + seed)
+    # every third seed hits much on a pool that never fills and evicts
+    # once in 250 steps, so that the order outgrows the trie and is
+    # rebuilt from its live entries; the others run a small pool full
+    roomy = seed % 3 == 0
+    page, pages = 2, 400 if roomy else 48
+    sides = []
+    for make in (PrefixCache, _walk_cache):
+        pool = KVPagePool(pages, page_size=page)
+        trie = make(pool)
+        sides.append((pool, trie, _logged(trie)))
+    slots = []      # what live requests hold: one page list a side
+    prompts = []    # every prompt seen: later ones branch off them
+
+    def both(fn):
+        got = [fn(pool, trie) for pool, trie, _ in sides]
+        assert got[0] == got[1]
+        return got
+
+    def admit(pool, trie):
+        """A join as the engine makes it: borrow the cached prefix,
+        evict the deficit in one batch, allocate, promote."""
+        held = trie.lookup(prompt)
+        need = (len(prompt) - 1) // page + 1
+        deficit = need - len(held) - pool.pages_free
+        if deficit > 0:
+            trie.evict(deficit)
+        while len(held) < need:
+            pg = pool.alloc()
+            if pg is None:      # every page backs a live request
+                for p in held:
+                    pool.release(p)
+                return None
+            held.append(pg)
+        trie.insert(prompt, held[:(len(prompt) - 1) // page])
+        return held
+
+    share = ({"admit": .3, "finish": .2, "touch": .5} if roomy else
+             {"admit": .55, "finish": .25, "evict": .12, "touch": .07,
+              "clear": .01})
+    rebuilt = 0
+    for step in range(1500 if roomy else 400):
+        op = rng.choice(list(share), p=list(share.values()))
+        if roomy and step % 250 == 249:
+            op = "evict"
+        before = len(sides[0][1]._leaves)
+        if op == "admit":
+            prompt = list(rng.integers(0, 3, int(rng.integers(2, 13))))
+            if prompts and rng.random() < 0.6:
+                base = prompts[int(rng.integers(len(prompts)))]
+                prompt = base[:int(rng.integers(1, len(base) + 1))] \
+                    + prompt[:int(rng.integers(1, 7))]
+            prompts.append(prompt)
+            got = both(admit)
+            if got[0] is not None:
+                slots.append(got)
+        elif op == "finish" and slots:
+            for (pool, _, _), held in zip(
+                    sides, slots.pop(int(rng.integers(len(slots))))):
+                for p in held:
+                    pool.release(p)
+        elif op == "evict":
+            k = int(rng.choice([1, 1, 2, 3, 5, 8, 10 * pages],
+                               p=[.2, .2, .15, .15, .15, .1, .05]))
+            both(lambda pool, trie: trie.evict(k))
+        elif op == "touch" and prompts:
+            prompt = prompts[int(rng.integers(len(prompts)))]
+
+            def touch(pool, trie):
+                hit = trie.lookup(prompt)
+                for p in hit:
+                    pool.release(p)
+                return hit
+            both(touch)
+        elif op == "clear":
+            both(lambda pool, trie: trie.clear())
+        (pool, trie, log), (wpool, walk, wlog) = sides
+        assert log == wlog
+        assert (trie.evictions, len(trie)) == (walk.evictions, len(walk))
+        assert pool._free == wpool._free and pool._refs == wpool._refs
+        # the order holds no more than the trie's size twice over
+        assert len(trie._leaves) <= 2 * len(trie) + 64
+        rebuilt += op != "evict" and len(trie._leaves) < before
+    # the run evicted enough to tell, and a roomy one rebuilt the order
+    assert len(sides[0][2]) > (5 if roomy else 20)
+    assert rebuilt or not roomy
+
+
+def _full_pool(pool, trie, chains, nodes, held):
+    """A pool run full the way ``gpt1.serve.closed-128`` runs it:
+    ``nodes`` cached prompt pages in ``chains`` distinct prompts, no
+    shared prefix, the YOUNGEST ``held`` prompts still live (their
+    slots hold every page, so their leaves are held leaves).  Returns
+    the page lists of the live prompts."""
+    size = pool.page_size
+    lengths = [nodes // chains + (i < nodes % chains)
+               for i in range(chains)]
+    live = []
+    for i, n in enumerate(lengths):
+        prompt = [i] + [7] * (n * size)      # n full pages, and one on
+        pages = [pool.alloc() for _ in range(n)]
+        assert trie.insert(prompt, pages) == n
+        if i < chains - held:
+            for p in pages:
+                pool.release(p)
+        else:
+            live.append(pages)
+    assert len(trie) == nodes
+    return live
+
+
+# what one eviction may pop off the leaf order, stale and held entries
+# included, where the walk visited every node of the trie (4 000 here)
+SCANNED_A_VICTIM = 4
+
+
+def test_an_eviction_costs_pops_not_a_walk():
+    """The cost as a COUNT: 4 000 cached pages in 650 prompts, 96 of
+    them live; 200 single evictions scan a small constant each."""
+    from flexflow_tpu.serving.generation.pages import (KVPagePool,
+                                                       PrefixCache)
+    pool = KVPagePool(4000, page_size=4)
+    trie = PrefixCache(pool)
+    _full_pool(pool, trie, chains=650, nodes=4000, held=96)
+    assert pool.pages_free == 0
+    for call in range(1, 201):
+        assert trie.evict(1) == 1
+        assert trie.evict_scanned <= SCANNED_A_VICTIM * call
+    assert trie.evictions == 200 and len(trie) == 3800
+    # the leaves live requests hold are young: none was asked about
+    assert trie.evict_scanned == 200
+    # a cache that is hit often and evicts seldom pushes an entry a hit
+    # and pops none: the order stays within twice the trie all the same
+    hot = [649] + [7] * 28
+    for _ in range(10000):
+        for p in trie.lookup(hot):
+            pool.release(p)
+    assert len(trie._leaves) <= 2 * len(trie) + 64
+    assert trie.evict(1) == 1 and trie.evict_scanned <= 201 + 96
+
+
+def test_grow_active_pages_scans_a_few_nodes(lm):
+    """A boundary of the host-bound cell: 128 decoding slots on a pool
+    full of cached prompts, 8 of them at a page edge.  Each brings its
+    own deficit of one; together they scan under 40 nodes."""
+    from flexflow_tpu.serving.generation.engine import _Slot
+    eng = GenerationEngine(lm, slots=128, page_size=4, num_pages=4000,
+                           prefix_cache="on")
+    pool, trie = eng._pool, eng._prefix
+    live = _full_pool(pool, trie, chains=650, nodes=3904, held=96)
+    # 96 slots decode on their cached prompt's pages, 32 on pages of
+    # their own (prompts under a page: nothing of theirs is cached)
+    live += [[pool.alloc() for _ in range(3)] for _ in range(32)]
+    assert pool.pages_free == 0 and len(live) == 128
+    edge = set(range(0, 128, 16))
+    for i, pages in enumerate(live):
+        s = _Slot(None, np.zeros(1, np.int32), pages, 4, 0.0)
+        s.prefilling = False
+        # the next position opens a new page at an edge, else not
+        s.length = len(pages) * 4 - (0 if i in edge else 2)
+        eng._slots_state[i] = s
+        eng._table[i, :len(pages)] = pages
+    before = [len(p) for p in live]
+    eng._grow_active_pages()
+    grown = [len(s.pages) - n
+             for s, n in zip(eng._slots_state, before)]
+    assert grown == [int(i in edge) for i in range(128)]
+    assert trie.evictions == 8 and pool.pages_free == 0
+    assert trie.evict_scanned < 40
+    snap = eng._pool_stats()
+    assert (snap["evictions"], snap["evict_scanned"]) \
+        == (8, trie.evict_scanned)
+
+
 def test_prefix_cache_on_off_bit_identical(lm):
     """THE ISSUE 15 correctness anchor: the same shared-prefix trace
     decodes to bit-identical tokens with the prefix cache on and off,
@@ -1046,7 +1283,8 @@ def test_gen_stats_carry_pool_fields(lm, prompts):
     for key in ("kv_pages_in_use", "kv_pages_high_water",
                 "kv_page_size", "kv_num_pages", "kv_high_water_bytes",
                 "prefix_hit_rate", "prefix_hit_tokens", "evictions",
-                "prefill_chunks", "prefix_pages_cached"):
+                "evict_scanned", "prefill_chunks",
+                "prefix_pages_cached"):
         assert key in snap, key
     assert snap["kv_pages_high_water"] >= 1
     assert snap["kv_high_water_bytes"] <= eng.kv_cache_bytes
