@@ -147,6 +147,11 @@ def kv_cache_layout(layers: List[Op],
     PartitionSpec entries}, "dtype": "compute"|"f32"}}``, each entry
     what that op DECLARES (``Op.serve_state`` — a layer's serving form
     lives in the layer; an op that keeps nothing has no entry).  A ``"kv"``
+    entry's leaves are page-major ``(num_pages, page_size, width)`` with
+    whatever ``width`` the op DECLARES: a K and a V leaf of ``kv_heads *
+    head_dim`` each (``MultiHeadAttention``), or one leaf of a compressed
+    row all heads share, padded to whole lane tiles (``LatentAttention``:
+    576 values stored as 640); nothing here derives a width from heads.  An
     entry that declares a ``"window"`` is given ROWS OF ITS OWN here, a
     ring a slot addressed by arithmetic on (slot, position) — leaves
     ``(slots, rows // page_size, page_size, ..)`` with ``"rows"``
@@ -188,9 +193,10 @@ def kv_page_plan(layers: List[Op],
     state, each kind of entry at what it holds.  Returns ``{"page_size",
     "pages_per_slot", "num_pages", "page_bytes", "pool_bytes",
     "window_bytes", "window_rows", "state_bytes", "total_bytes"}`` where
-    ``page_bytes`` is the per-device cost of ONE page summed over the K+V
-    pools of every attention op that pages (``kv_dtype_bytes`` each — the
-    compute dtype, 2 for bf16, 4 for f32 — heads divided over ``c``),
+    ``page_bytes`` is the per-device cost of ONE page summed over the
+    leaves, at their declared widths, of every attention op that pages (a K
+    and a V pool, or one latent pool; ``kv_dtype_bytes`` each — the compute
+    dtype, 2 for bf16, 4 for f32 — a leaf sharded over ``c`` divided by it),
     ``pool_bytes = num_pages * page_bytes``, ``window_bytes`` the rows of
     the WINDOWED entries (``slots x window_rows`` each, whatever the pool:
     ``prefill_chunk`` sizes them, :func:`window_rows`), and

@@ -246,9 +246,12 @@ class FFModel:
     def moe(self, input_tensor, num_experts, d_ff, k=2, capacity_factor=1.25,
             activation="gelu", aux_loss_weight=1e-2, kernel_initializer=None,
             gated=False, shared_d_ff=0, routed_scale=1.0,
-            name=None) -> Tensor:
-        """Mixture-of-Experts FFN: softmax router, top-``k`` renormalised
-        (times ``routed_scale``), ONE dispatch by sort — the (token,
+            scoring="softmax", held=None, name=None) -> Tensor:
+        """Mixture-of-Experts FFN: a router scored by ``scoring``
+        (``"softmax"`` over the experts or ``"sigmoid"`` of each logit),
+        top-``k`` renormalised (times ``routed_scale``); ``held=(first,
+        count)`` where this chip has only those experts' weights of an
+        expert-parallel deployment; ONE dispatch by sort — the (token,
         choice) pairs sorted by expert and two grouped products over the
         experts held (over the 'e' mesh axis each shard runs its own
         experts' groups and the parts are summed).  ``capacity_factor``
@@ -261,7 +264,22 @@ class FFModel:
         op = MoE(self._uname("moe", name), input_tensor, num_experts, d_ff,
                  k, capacity_factor, activation, aux_loss_weight,
                  kernel_initializer, gated=gated, shared_d_ff=shared_d_ff,
-                 routed_scale=routed_scale)
+                 routed_scale=routed_scale, scoring=scoring, held=held)
+        return self._register(op).outputs[0]
+
+    def latent_attention(self, input_tensor, num_heads, q_rank, kv_rank,
+                         nope_dim, rope_dim, v_dim, rope_theta=10000.0,
+                         eps=1e-6, kernel_initializer=None,
+                         name=None) -> Tensor:
+        """Causal multi-head LATENT attention (``ops/latent_attention.py``):
+        queries through a rank-``q_rank`` bottleneck with a norm inside,
+        keys and values expanded from ONE ``kv_rank``-wide normed row a
+        token beside ``rope_dim`` rotary values all heads share; that row
+        is what the serving cache holds."""
+        from .ops.latent_attention import LatentAttention
+        op = LatentAttention(self._uname("attention", name), input_tensor,
+                             num_heads, q_rank, kv_rank, nope_dim, rope_dim,
+                             v_dim, rope_theta, eps, kernel_initializer)
         return self._register(op).outputs[0]
 
     def multihead_attention(self, query, key=None, value=None, embed_dim=None,

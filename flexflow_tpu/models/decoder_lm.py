@@ -1,7 +1,8 @@
 """Pre-norm decoder language model with a LAYER LIST: each layer says its
-attention kind (``"full_attention"`` or ``"sliding_attention"``), its
-number of query heads and whether its feed-forward is ``"dense"`` or
-``"sparse"`` (a mixture of experts beside a shared one).  Rotary positions,
+attention kind (``"full_attention"``, ``"sliding_attention"`` or
+``"latent_attention"``), its number of query heads and whether its
+feed-forward is ``"dense"`` or ``"sparse"`` (a mixture of experts beside a
+shared one).  Rotary positions,
 grouped key/value heads, RMSNorm, SiLU-gated feed-forwards, no biases, an
 untied head: the block today's open decoders share, built on ``FFModel``'s
 normal calls, so it trains, is priced by the search and is served by the
@@ -11,6 +12,9 @@ generation engine like any other graph.
     b = RMSNorm(x);  x = x + F_l(b)                    window: the op's)
     F dense:  (silu(b W1) * (b W3)) W2
     F sparse: shared(b) + scale * sum_k p_k expert_k(b)      (``ops/moe.py``)
+
+With ``sandwich=True`` each sublayer's OUTPUT is normed too before the
+residual add: ``x = x + RMSNorm(Attention_l(a))``, ``x = x + RMSNorm(F_l(b))``.
 """
 
 from __future__ import annotations
@@ -28,14 +32,21 @@ def build_decoder_lm(config: FFConfig, layers: Sequence[Dict],
                      rms_eps: float = 1e-6, window: int = 0,
                      rope: Optional[Dict[str, Dict]] = None,
                      gate: bool = False, moe: Optional[Dict] = None,
-                     kernel_initializer=None
+                     kernel_initializer=None, sandwich: bool = False,
+                     latent: Optional[Dict] = None
                      ) -> Tuple[FFModel, Tensor, Tensor]:
     """``layers``: one ``{"attention": kind, "heads": query heads, "mlp":
     "dense" | "sparse"}`` a layer.  ``rope``: ``{kind: rope_parameters
     entry}`` (``ops/attention.rope_inv_freq``); ``window`` applies to the
     ``"sliding_attention"`` layers; ``moe``: ``{"num_experts", "k",
-    "d_ff", "shared_d_ff", "routed_scale"}`` of the sparse layers
-    (dropless).  Returns ``(model, tokens, logits)``."""
+    "d_ff", "shared_d_ff", "routed_scale", "scoring", "held"}`` of the
+    sparse layers (dropless; ``scoring`` ``"softmax"`` or ``"sigmoid"``,
+    ``held`` the ``(first, count)`` of the experts this chip has, all by
+    default); ``latent``: ``{"q_rank", "kv_rank", "nope_dim", "rope_dim",
+    "v_dim", "rope_theta"}`` of the ``"latent_attention"`` layers
+    (``head_dim`` and ``num_kv_heads`` are the other kinds'); ``sandwich``:
+    a norm on each sublayer's output as well (``ln_attn_out_<i>``,
+    ``ln_ffn_out_<i>``).  Returns ``(model, tokens, logits)``."""
     ff = FFModel(config)
     init = kernel_initializer
     tokens = ff.create_tensor((config.batch_size, seq_len), dtype="int32",
@@ -45,12 +56,19 @@ def build_decoder_lm(config: FFConfig, layers: Sequence[Dict],
     for i, layer in enumerate(layers):
         kind = layer["attention"]
         a = ff.rms_norm(x, eps=rms_eps, name=f"ln_attn_{i}")
-        a = ff.multihead_attention(
-            a, num_heads=int(layer["heads"]), num_kv_heads=num_kv_heads,
-            head_dim=head_dim, causal=True, bias=False,
-            rope=(rope or {}).get(kind), gate=gate,
-            window=window if kind == "sliding_attention" else 0,
-            kernel_initializer=init, name=f"attention_{i}")
+        if kind == "latent_attention":
+            a = ff.latent_attention(
+                a, num_heads=int(layer["heads"]), eps=rms_eps,
+                kernel_initializer=init, name=f"attention_{i}", **latent)
+        else:
+            a = ff.multihead_attention(
+                a, num_heads=int(layer["heads"]), num_kv_heads=num_kv_heads,
+                head_dim=head_dim, causal=True, bias=False,
+                rope=(rope or {}).get(kind), gate=gate,
+                window=window if kind == "sliding_attention" else 0,
+                kernel_initializer=init, name=f"attention_{i}")
+        if sandwich:
+            a = ff.rms_norm(a, eps=rms_eps, name=f"ln_attn_out_{i}")
         x = ff.add(x, a, name=f"res_attn_{i}")
         b = ff.rms_norm(x, eps=rms_eps, name=f"ln_ffn_{i}")
         if layer["mlp"] == "sparse":
@@ -59,7 +77,8 @@ def build_decoder_lm(config: FFConfig, layers: Sequence[Dict],
                        kernel_initializer=init, gated=True,
                        shared_d_ff=moe.get("shared_d_ff", 0),
                        routed_scale=moe.get("routed_scale", 1.0),
-                       name=f"moe_{i}")
+                       scoring=moe.get("scoring", "softmax"),
+                       held=moe.get("held"), name=f"moe_{i}")
         else:
             g = ff.dense(b, d_ff, activation="silu", use_bias=False,
                          kernel_initializer=init, name=f"ffn_gate_{i}")
@@ -68,6 +87,8 @@ def build_decoder_lm(config: FFConfig, layers: Sequence[Dict],
             f = ff.dense(ff.multiply(g, u, name=f"ffn_act_{i}"), d_model,
                          use_bias=False, kernel_initializer=init,
                          name=f"ffn_down_{i}")
+        if sandwich:
+            f = ff.rms_norm(f, eps=rms_eps, name=f"ln_ffn_out_{i}")
         x = ff.add(x, f, name=f"res_ffn_{i}")
     x = ff.rms_norm(x, eps=rms_eps, name="ln_final")
     logits = ff.dense(x, vocab_size, use_bias=False, kernel_initializer=init,

@@ -234,7 +234,10 @@ class Op:
         ``{"kind": "kv"|"state"|"counter", "shapes": {leaf: shape},
         "entries": {leaf: PartitionSpec entries}, "dtype":
         "compute"|"f32"|"i32"}``.
-        ``"kv"`` leaves are page-major, ``(num_pages, page_size, ..)``:
+        ``"kv"`` leaves are page-major, ``(num_pages, page_size, ..)``, as
+        many and as wide as the op says (a K and a V pool; one latent row
+        all heads share, which may add ``"values": {leaf: n}``, the values
+        a token NEEDS of a row stored wider):
         they page, share prefixes, roll back and migrate; a ``"kv"`` entry
         that also declares ``"window": W`` only ever reads the last ``W``
         positions, and gets rows of its own instead of pages of the pool

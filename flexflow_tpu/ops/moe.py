@@ -5,8 +5,9 @@ parallelism is DLRM's per-embedding-table device placement
 (``examples/cpp/DLRM/dlrm.cc:106,469`` + ``dlrm_strategy_hetero.cc``) — one
 table per device, no token routing.  This op routes tokens:
 
-* a router (dense gate) scores every token against EVERY expert in f32;
-  softmax, the ``k`` largest kept and renormalised to sum to 1 (times
+* a router (dense gate) scores every token against EVERY expert in f32:
+  ``scoring="softmax"`` over the experts, or ``"sigmoid"`` of each logit
+  alone; the ``k`` largest scores kept and renormalised to sum to 1 (times
   ``routed_scale``);
 * the ``(token, choice)`` pairs are SORTED by expert, so each expert's
   tokens are one contiguous group of rows, and the experts run as two
@@ -36,7 +37,14 @@ table per device, no token routing.  This op routes tokens:
 * the op is told which experts it HOLDS.  Under an ``e`` mesh axis every
   shard routes over all experts, runs this same body on the groups of the
   experts it holds (``shard_map``; expert weights carry
-  ``shard_axis="e"``) and the parts are summed;
+  ``shard_axis="e"``) and the parts are summed.  On ONE chip of an
+  expert-parallel deployment, with no mesh to say so, ``held=(first,
+  count)`` says it: the router keeps all ``num_experts`` outputs and every
+  token its ``k`` choices, the expert weights are ``(count, ..)``, the
+  same body runs on the groups of experts ``first .. first + count`` and a
+  pair routed elsewhere adds nothing here (the chips that hold the others
+  add theirs; no code stands in for them).  ``flops()``, the counters and
+  ``stats()["moe"]`` then speak of the experts HELD;
 * an optional Switch-style load-balancing auxiliary loss
   (``E * sum_e f_e * P_e``) is surfaced through ``ctx.aux_losses`` and added
   to the training objective by the fused step.
@@ -90,7 +98,8 @@ class MoE(Op):
     def __init__(self, name, input_tensor, num_experts, d_ff, k=2,
                  capacity_factor=1.25, activation="gelu",
                  aux_loss_weight=1e-2, kernel_initializer=None,
-                 gated=False, shared_d_ff=0, routed_scale=1.0):
+                 gated=False, shared_d_ff=0, routed_scale=1.0,
+                 scoring="softmax", held=None):
         super().__init__(name, [input_tensor])
         n, s, d = input_tensor.shape
         self.num_experts = int(num_experts)
@@ -103,10 +112,20 @@ class MoE(Op):
         self.gated = bool(gated)
         self.shared_d_ff = int(shared_d_ff)
         self.routed_scale = float(routed_scale)
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"{name}: scoring {scoring!r}: 'softmax' or "
+                             f"'sigmoid'")
+        self.scoring = scoring
+        # the experts whose weights this op has: all, or (first, count)
+        self.first, self.held = ((0, self.num_experts) if held is None
+                                 else (int(held[0]), int(held[1])))
+        if not (0 <= self.first and self.held >= 1
+                and self.first + self.held <= self.num_experts):
+            raise ValueError(f"{name}: held {held} of {num_experts} experts")
         self._add_output((n, s, d), input_tensor.dtype)
-        E = self.num_experts
+        E = self.held
         base = kernel_initializer or GlorotUniform()
-        self.w_gate = self._add_weight((E, d), base, "gate")
+        self.w_gate = self._add_weight((self.num_experts, d), base, "gate")
 
         # per-expert FFN, expert-stacked on dim 0 and sharded over the 'e'
         # mesh axis (≙ the reference's per-table placement, dlrm.cc:106,469
@@ -156,9 +175,12 @@ class MoE(Op):
         with jax.named_scope("moe_router"):
             gate = params[self.w_gate.name].astype(jnp.float32)
             logits = jnp.einsum("td,ed->te", xt.astype(jnp.float32), gate)
-            probs = jax.nn.softmax(logits, axis=-1)
+            if self.scoring == "sigmoid":
+                probs, tiny = jax.nn.sigmoid(logits), 1e-20
+            else:
+                probs, tiny = jax.nn.softmax(logits, axis=-1), 1e-9
             top_probs, top_idx = jax.lax.top_k(probs, self.k)
-            denom = jnp.sum(top_probs, axis=-1, keepdims=True) + 1e-9
+            denom = jnp.sum(top_probs, axis=-1, keepdims=True) + tiny
             return top_idx, top_probs / denom * self.routed_scale, probs
 
     def _grouped_core(self, xs, w_up, w_dn, ctx: OpContext) -> str:
@@ -261,6 +283,11 @@ class MoE(Op):
         weights = tuple(params[nm] for nm in names)
         mesh = ctx.mesh
         shards = mesh.axis_size("e") if mesh is not None else 1
+        if shards > 1 and self.held != E:
+            raise ValueError(
+                f"{self.name}: told to hold experts {self.first}.."
+                f"{self.first + self.held} of {E} AND given an 'e' mesh "
+                f"axis of {shards}: one or the other says what it holds")
         if shards > 1 and E % shards == 0:
             # every shard: all the router's choices, its own experts
             e_axes = mesh.subaxes("e")
@@ -296,8 +323,8 @@ class MoE(Op):
                     xt.astype(jnp.float32) if inside else xt, top_idx,
                     gates, firsts, *weights)
         else:
-            routed = self._experts(weights, xt, top_idx, gates, T, 0, ctx,
-                                   program)
+            routed = self._experts(weights, xt, top_idx, gates, T,
+                                   self.first, ctx, program)
         out = routed
         if self.shared_d_ff:
             out = out + self._shared(params, xt, ctx)
@@ -325,26 +352,36 @@ class MoE(Op):
                 f"step; build it dropless (capacity_factor=None) to serve")
 
     def serve_state(self, slots, num_pages, page_size, mesh_sizes):
-        """Three counters, on the device: ``load`` (experts,), the live
-        tokens each expert received (prompt chunks and token steps);
-        ``token_steps``, the token steps that served anybody; and
-        ``untouched``, summed over those steps, the experts no live token
-        of the step chose."""
+        """Three counters, on the device, of the experts HELD: ``load``
+        (held,), the live tokens each received (prompt chunks and token
+        steps); ``token_steps``, the token steps that served anybody; and
+        ``untouched``, summed over those steps, the held experts no live
+        token of the step chose."""
         return {"kind": "counter",
-                "shapes": {"load": (self.num_experts,), "token_steps": (),
+                "shapes": {"load": (self.held,), "token_steps": (),
                            "untouched": ()},
                 "entries": {"load": (None,), "token_steps": (),
                             "untouched": ()},
                 "dtype": "i32"}
+
+    def _held_index(self, expert):
+        """Expert numbers -> indices among the experts held; a choice that
+        fell on an expert held elsewhere goes past the end."""
+        if self.held == self.num_experts:
+            return expert
+        local = expert - self.first
+        return jnp.where((local >= 0) & (local < self.held), local,
+                         self.held)
 
     def serve_step(self, params, inputs, state, where, ctx: OpContext):
         out, top_idx, _ = self._moe(params, inputs[0], ctx, where.kind)
         if state is None:
             return [out], state
         live = where.live(inputs[0].shape[1]).reshape(-1)          # (T,)
-        picks = jnp.zeros((self.num_experts,), jnp.int32).at[
-            top_idx.reshape(-1)].add(jnp.repeat(live.astype(jnp.int32),
-                                                self.k))
+        # (out-of-bounds updates are dropped: ``.at[].add``'s default)
+        picks = jnp.zeros((self.held,), jnp.int32).at[
+            self._held_index(top_idx.reshape(-1))].add(
+                jnp.repeat(live.astype(jnp.int32), self.k))
         new = dict(state, load=state["load"] + picks)
         if where.kind == "token":
             served = jnp.any(live).astype(jnp.int32)
@@ -360,20 +397,25 @@ class MoE(Op):
         return (True, True, False)
 
     def flops(self):
-        """The router over all experts, ``k`` routed experts and the shared
-        one a token: what the grouped products compute, whatever the
-        routing (a capacity only zeroes weights)."""
+        """The router over all experts, the routed experts a token takes
+        AMONG THOSE HELD (``k`` of them where all are; ``k * held /
+        num_experts`` at the expectation where only some are) and the shared
+        one: what the grouped products compute, whatever the routing (a
+        capacity only zeroes weights)."""
         n, s, d = self.outputs[0].shape
         T = n * s
         up = self.d_ff * (2 if self.gated else 1)
         router = 2 * T * d * self.num_experts
-        experts = 2 * T * self.k * (d * up + self.d_ff * d)
+        experts = (2 * T * self.k * (d * up + self.d_ff * d)
+                   * self.held // self.num_experts)
         shared = 2 * T * 3 * d * self.shared_d_ff
         return router + experts + shared
 
     def internal_io_bytes(self, flash_attention=None):
-        """The sorted copy of the tokens and the experts' hidden rows, each
+        """The sorted copy of the tokens (every pair: the sort is over all
+        the router's choices) and the hidden rows of the experts held, each
         written and read once in the compute dtype (2 B)."""
         n, s, d = self.outputs[0].shape
         up = self.d_ff * (2 if self.gated else 1)
-        return 2 * 2 * n * s * self.k * (2 * d + up + self.d_ff)
+        return 2 * 2 * n * s * self.k * (
+            2 * d + (up + self.d_ff) * self.held // self.num_experts)

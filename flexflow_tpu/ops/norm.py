@@ -122,6 +122,13 @@ class LayerNorm(Op):
         return 8 * self.inputs[0].volume
 
 
+def rms_normalize(x, scale, eps):
+    """``x`` over its last axis's root mean square, times ``scale``; f32."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return xf * jax.lax.rsqrt(ms + eps) * scale
+
+
 class RMSNorm(Op):
     op_type = OpType.RMSNORM
     position_wise = True
@@ -134,10 +141,8 @@ class RMSNorm(Op):
         self.w_scale = self._add_weight((d,), ConstantInitializer(1.0), "scale")
 
     def forward(self, params, inputs, ctx: OpContext):
-        xf = inputs[0].astype(jnp.float32)
-        ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(ms + self.eps) * params[self.w_scale.name]
-        return [cast_compute(y, ctx)]
+        return [cast_compute(rms_normalize(
+            inputs[0], params[self.w_scale.name], self.eps), ctx)]
 
     def parallel_dims(self):
         nd = self.outputs[0].num_dims

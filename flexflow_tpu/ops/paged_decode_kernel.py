@@ -53,6 +53,14 @@ bound, the positions below it in that page are masked like the ones above
 pages_per_slot``: ``MultiHeadAttention._serve_step_window``).  One copy
 group then holds a whole window.
 
+**A latent row.**  With ``value_lanes > 0`` there is ONE pool: a row is
+what every query head shares (``LatentAttention``'s compressed key/value
+row, its rotary part beside it, zero-padded to whole lane tiles: 576 values
+stored as 640), scores contract the whole row and the VALUES are the first
+``value_lanes`` lanes of the same rows, so a page is copied once for both
+products.  It is the grouped form with one key/value head whose group is
+all the query heads (rounded up to sublane tiles), one product a chunk.
+
 The kernel's ``name=`` is ``paged_decode_attention`` in the device trace
 (not ``flash_..``: ``perfbench/flops``' ``FLASH_KERNELS`` matches on that
 prefix and the train cells' ``flash_share`` must not learn of it).
@@ -76,9 +84,13 @@ _BUFFER_BYTES = 8 << 20     # the four group buffers (K, V; two each)
 _VMEM_LIMIT = 32 << 20
 
 
+_LATENT_ROWS = 256          # query heads over one latent row, at most
+_LATENT_CHUNK = 512         # key rows of one product over latent rows
+
+
 def supported(backend: str, dtype, num_heads: int, head_dim: int,
               page_size: int, distributed: bool,
-              num_kv_heads: int = 0) -> bool:
+              num_kv_heads: int = 0, value_lanes: int = 0) -> bool:
     """What the in-place read needs (``dtype``: the pool's, an array's
     ``.dtype``): a TPU; a pool dtype the MXU takes;
     the folded row a whole number of 128-lane tiles that no head straddles
@@ -87,11 +99,25 @@ def supported(backend: str, dtype, num_heads: int, head_dim: int,
     it; and ONE device — GSPMD would all-gather a sharded pool for an
     opaque custom call (no cell serves across chips yet: ROADMAP W6).
     Fewer key/value heads than query heads (``num_kv_heads``): a head of
-    whole lane tiles and a group of at most ``_HEAD_ROWS`` query heads."""
+    whole lane tiles and a group of at most ``_HEAD_ROWS`` query heads.
+    A latent row (``value_lanes > 0``, ``head_dim`` the STORED row's width,
+    ``num_kv_heads`` 1): taken where the row and its value part are whole
+    lane tiles and the heads are at most ``_LATENT_ROWS``.  A bare 576-wide
+    row (512 compressed values and 64 rotary ones) is NOT taken: it is four
+    and a half lane tiles, so a page's copy would end mid-tile and the
+    scores' contraction would read the half tile beside it;
+    ``LatentAttention`` therefore stores the row padded with zeros to 640,
+    which is what the TPU's tiled memory holds for a 576-wide array anyway,
+    and that row is taken."""
     if backend != "tpu" or distributed or dtype not in (jnp.bfloat16,
                                                         jnp.float32):
         return False
     sublanes = 8 * (4 // dtype_itemsize(dtype))
+    if value_lanes:
+        return (head_dim % LANES == 0 and value_lanes % LANES == 0
+                and value_lanes <= head_dim and num_heads <= _LATENT_ROWS
+                and page_size % sublanes == 0
+                and (LANES % page_size == 0 or page_size % LANES == 0))
     kv_heads = num_kv_heads or num_heads
     if kv_heads != num_heads and (head_dim % LANES
                                   or num_heads // kv_heads > _HEAD_ROWS):
@@ -115,9 +141,13 @@ def _geometry(page: int, pages_per_slot: int, e: int, itemsize: int,
     return chunk, max(chunk, min(whole, -(-want // chunk) * chunk, fits))
 
 
-def _kernel(table_ref, pos_ref, wp_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, turn, *, num_heads, kv_heads, scale, page,
-            pages_per_slot, chunk, window):
+def _kernel(table_ref, pos_ref, wp_ref, q_ref, *refs, num_heads, kv_heads,
+            scale, page, pages_per_slot, chunk, window, value_lanes=0):
+    if value_lanes:     # one pool: the values are lanes of the key rows
+        k_hbm, o_ref, k_buf, sems, turn = refs
+        v_hbm = v_buf = None
+    else:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, turn = refs
     i, slots = pl.program_id(0), pl.num_programs(0)
     no_page = k_hbm.shape[0]
     group = k_buf.shape[1]
@@ -126,6 +156,8 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, k_hbm, v_hbm, o_ref,
     grouped = kv_heads != num_heads
     head_dim = e // kv_heads
     rows = -(-num_heads // _HEAD_ROWS) * _HEAD_ROWS
+    # query rows a key/value head: a sublane tile, or all of a latent row's
+    head_rows = rows if value_lanes else _HEAD_ROWS
 
     def decodes(s):
         return wp_ref[s] != no_page
@@ -170,8 +202,9 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, k_hbm, v_hbm, o_ref,
             dst = pl.ds(pl.multiple_of(p * page, page), page)
             do(pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, dst],
                                      sems.at[0, buf]))
-            do(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, dst],
-                                     sems.at[1, buf]))
+            if v_hbm is not None:
+                do(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, dst],
+                                         sems.at[1, buf]))
             return carry
 
         jax.lax.fori_loop(0, count, body, 0)
@@ -186,7 +219,8 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, k_hbm, v_hbm, o_ref,
     def _():
         # finite tails for the first groups (see the module's docstring)
         k_buf[...] = jnp.zeros(k_buf.shape, k_buf.dtype)
-        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+        if v_buf is not None:
+            v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
         turn[0] = 0
         first = next_decoding(0)
 
@@ -249,17 +283,18 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, k_hbm, v_hbm, o_ref,
             def one_chunk(c, carry):
                 at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
                 kpos = base + c * chunk + jax.lax.broadcasted_iota(
-                    jnp.int32, (_HEAD_ROWS if grouped else rows, chunk), 1)
+                    jnp.int32, (head_rows if grouped else rows, chunk), 1)
                 if not grouped:
                     return softmax_step(carry, q_heads, k_buf[buf, at, :],
                                         v_buf[buf, at, :], kpos)
                 out = []
                 for j in heads:     # a key/value head: lanes j * head_dim ..
                     lanes = pl.ds(j * head_dim, head_dim)
-                    qj = q_ref[0, j * _HEAD_ROWS:(j + 1) * _HEAD_ROWS, :]
-                    out.append(softmax_step(carry[j], qj,
-                                            k_buf[buf, at, lanes],
-                                            v_buf[buf, at, lanes], kpos))
+                    qj = q_ref[0, j * head_rows:(j + 1) * head_rows, :]
+                    kj = k_buf[buf, at, lanes]
+                    vj = (kj[:, :value_lanes] if value_lanes
+                          else v_buf[buf, at, lanes])
+                    out.append(softmax_step(carry[j], qj, kj, vj, kpos))
                 return tuple(out)
 
             return jax.lax.fori_loop(0, chunks, one_chunk, carry)
@@ -269,17 +304,70 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, k_hbm, v_hbm, o_ref,
                     jnp.zeros((r, 1), jnp.float32),
                     jnp.zeros((r, width), jnp.float32))
 
-        init = (tuple(empty(_HEAD_ROWS, head_dim) for _ in heads)
-                if grouped else empty(rows, e))
+        init = (tuple(empty(head_rows, value_lanes or head_dim)
+                      for _ in heads) if grouped else empty(rows, e))
         done = jax.lax.fori_loop(0, groups, one_group, init)
         turn[0] = (buf0 + groups) % 2
         if grouped:
             for j, (_, l, acc) in enumerate(done):
-                o_ref[0, j * _HEAD_ROWS:(j + 1) * _HEAD_ROWS, :] = acc / l
+                o_ref[0, j * head_rows:(j + 1) * head_rows, :] = acc / l
         else:
             _, l, acc = done
             o_ref[0] = jnp.sum(jnp.where(diagonal, acc / l, 0.0), axis=0,
                                keepdims=True)
+
+
+def _paged_call(q, pools, table, pos, write_pages, static, group: int,
+                out_width: int, name: str):
+    """The one ``pallas_call``: ``q`` (slots, query rows, width) against
+    ``pools`` (K and V, or the one latent pool), the scalars prefetched,
+    the pools left in HBM, two ``group``-row buffers a pool; -> (slots,
+    query rows, ``out_width``) f32."""
+    slots, q_rows, width = q.shape
+    page, e = pools[0].shape[1], pools[0].shape[2]
+    row = pl.BlockSpec((1, q_rows, width), lambda i, *_: (i, 0, 0))
+    out_row = pl.BlockSpec((1, q_rows, out_width), lambda i, *_: (i, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    params = None if _interpret() else pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT)
+    return pl.pallas_call(
+        functools.partial(_kernel, page=page,
+                          pages_per_slot=table.shape[1], **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(slots,),
+            in_specs=[row] + [pool] * len(pools), out_specs=out_row,
+            scratch_shapes=[pltpu.VMEM((2, group, e), p.dtype)
+                            for p in pools]
+            + [pltpu.SemaphoreType.DMA((2, 2)),
+               pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((slots, q_rows, out_width),
+                                       jnp.float32),
+        compiler_params=params, interpret=_interpret(), name=name,
+    )(table.reshape(-1), pos, write_pages, q, *pools)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def paged_latent_attention(q, pool, table, pos, write_pages, scale: float,
+                           value_lanes: int):
+    """The token step over LATENT pages: ``q`` (slots, heads, e), every
+    head's query against the one row a position stores (``e`` lanes, the
+    padding zero on both sides); ``pool`` (num_pages, page, e), the new
+    rows already written; ``table`` / ``pos`` / ``write_pages`` as
+    :func:`paged_decode_attention` takes them -> (slots, heads,
+    ``value_lanes``) f32: each head's softmax-weighted sum of the rows'
+    first ``value_lanes`` lanes, zero for a slot that is not decoding.
+    The caller checks :func:`supported` (``value_lanes`` given)."""
+    slots, heads, e = q.shape
+    page, pages_per_slot = pool.shape[1], table.shape[1]
+    _, group = _geometry(page, pages_per_slot, e, pool.dtype.itemsize)
+    rows = -(-heads // _HEAD_ROWS) * _HEAD_ROWS
+    q = jnp.pad(q, ((0, 0), (0, rows - heads), (0, 0)))
+    out = _paged_call(q, (pool,), table, pos, write_pages,
+                      dict(num_heads=heads, kv_heads=1, scale=scale,
+                           chunk=min(group, _LATENT_CHUNK), window=0,
+                           value_lanes=value_lanes),
+                      group, value_lanes, "paged_latent_attention")
+    return out[:, :heads]
 
 
 # jitted so that the equal-shaped layers of a model share ONE traced and
@@ -311,26 +399,10 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, write_pages,
         q = jnp.pad(q.reshape(slots, kv_heads, per, width),
                     ((0, 0), (0, 0), (0, _HEAD_ROWS - per), (0, 0))
                     ).reshape(slots, q_rows, width)
-    row = pl.BlockSpec((1, q_rows, width), lambda i, *_: (i, 0, 0))
-    pool = pl.BlockSpec(memory_space=pl.ANY)
-    params = None if _interpret() else pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT)
-    out = pl.pallas_call(
-        functools.partial(_kernel, num_heads=num_heads, kv_heads=kv_heads,
-                          scale=scale, page=page,
-                          pages_per_slot=pages_per_slot, chunk=chunk,
-                          window=window),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(slots,),
-            in_specs=[row, pool, pool], out_specs=row,
-            scratch_shapes=[pltpu.VMEM((2, group, e), k_pool.dtype),
-                            pltpu.VMEM((2, group, e), v_pool.dtype),
-                            pltpu.SemaphoreType.DMA((2, 2)),
-                            pltpu.SMEM((1,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((slots, q_rows, width), jnp.float32),
-        compiler_params=params, interpret=_interpret(),
-        name="paged_decode_attention",
-    )(table.reshape(-1), pos, write_pages, q, k_pool, v_pool)
+    out = _paged_call(q, (k_pool, v_pool), table, pos, write_pages,
+                      dict(num_heads=num_heads, kv_heads=kv_heads,
+                           scale=scale, chunk=chunk, window=window),
+                      group, width, "paged_decode_attention")
     if kv_heads == num_heads:
         return out[:, 0, :]
     return out.reshape(slots, kv_heads, _HEAD_ROWS, width)[:, :, :per].reshape(

@@ -57,7 +57,8 @@ program can be summed by graph op
 int token input; position-wise ops
 (dense/norms/elementwise/softmax/dropout/embedding, a dropless MoE),
 causal self-attention (grouped heads, rotary positions, a window with
-rows of its own), stateless-init LSTM, learned position
+rows of its own; latent attention, one shared row a token in one
+page-major leaf), stateless-init LSTM, learned position
 embeddings, and whatever else writes the contract.  Anything else
 (convs, splits, cross-attention, an MoE with a capacity, pipelines) fails
 validation loudly at construction — a generation engine must never
@@ -206,13 +207,9 @@ class GraphDecoder:
                                       prefill_chunk=int(prefill_chunk))
         # entries with rows of their own, sized for chunks up to
         # ``prefill_chunk`` (0 = whole prompts): a longer chunk would
-        # overwrite rows its own first query still reads, so no program
-        # for one is built
+        # overwrite rows its own first query still reads
         self.windowed = {name: ent for name, ent in self.layout.items()
                          if ent.get("window")}
-        if self.windowed and prefill_chunk:
-            self.buckets = prefill_buckets(min(self.max_seq,
-                                               int(prefill_chunk)))
         # what ops count on the device for stats(), by entry name
         self.counters = tuple(name for name, ent in self.layout.items()
                               if ent["kind"] == "counter")
@@ -221,6 +218,14 @@ class GraphDecoder:
         # would need the carry from chunk k-1 as a program input —
         # whole-prompt chunks only (the engine enforces it)
         self.supports_chunking = "state" not in kinds
+        # an engine that prefills in chunks of ``prefill_chunk`` dispatches
+        # no longer one, so no program for one is built, warmed or read: at
+        # a long ``max_seq`` the buckets past the chunk cost minutes of
+        # set-up, and the whole-prompt one need not even fit the device (a
+        # 12 800-token chunk of a wide sparse layer did not: PERF.md, PR 41)
+        if prefill_chunk and self.supports_chunking:
+            self.buckets = prefill_buckets(min(self.max_seq,
+                                               int(prefill_chunk)))
         # every leaf page-major: what prefix reuse, speculation's
         # rollback and KV migration each need of the graph's state
         self.pageable = kinds == {"kv"}
@@ -769,6 +774,10 @@ class GraphDecoder:
         if self.windowed:   # by layer kind, where the graph has two
             out["windowed"] = count(op for op in self.model.layers
                                     if op.name in self.windowed)
+        latent = [op for op in self.model.layers
+                  if getattr(op, "decode_kind", None) == "latent"]
+        if latent:          # one shared row a token: a kind of its own
+            out["latent"] = count(latent)
         return out
 
     def grouped_product(self) -> Dict[str, int]:
@@ -807,10 +816,14 @@ class GraphDecoder:
         return out if self.counters else (out, None)
 
     def moe_stats(self, host) -> Dict[str, Dict]:
-        """``{op: {"assignments", "load_max_over_mean", "token_steps",
-        "untouched_share", "load"}}`` for every op that counts its routing
-        on the device (``"counter"`` entries with a ``load`` leaf: the
-        MoE's), from ``host``, a token step's counters fetched."""
+        """``{op: {"held", "assignments", "load_max_over_mean",
+        "token_steps", "untouched_share", "load"}}`` for every op that
+        counts its routing on the device (``"counter"`` entries with a
+        ``load`` leaf: the MoE's), from ``host``, a token step's counters
+        fetched.  All of it is said of the experts the op HOLDS (``held``
+        of them: every expert, or this chip's share of an expert-parallel
+        deployment): the pairs that fell on them, the largest load over
+        their mean, the share of (token step, held expert) nobody chose."""
         out = {}
         for n, c in (host or {}).items():
             if "load" not in c:
@@ -819,6 +832,7 @@ class GraphDecoder:
             steps = int(c["token_steps"])
             mean = float(load.mean())
             out[n] = {
+                "held": int(load.size),
                 "assignments": int(load.sum()),
                 "load_max_over_mean": (float(load.max()) / mean
                                        if mean else 0.0),
@@ -831,9 +845,9 @@ class GraphDecoder:
     def moe_totals(self, host) -> Dict[str, int]:
         """What a ``decode_step`` span carries of those counters, summed
         over the ops, as they stood behind that step: ``moe_expert_steps``
-        (token steps x experts) and ``moe_untouched`` (of them, the experts
-        no live token chose).  The difference between two spans is what
-        the steps between them touched."""
+        (token steps x experts HELD) and ``moe_untouched`` (of them, the
+        experts no live token chose).  The difference between two spans is
+        what the steps between them touched."""
         moe = [c for c in (host or {}).values() if "load" in c]
         if not moe:
             return {}
@@ -865,11 +879,9 @@ class GraphDecoder:
                    or (default_num_pages(slots, max_seq, ps)
                        if ps > 0 else 0))
         reg = model.__dict__.setdefault("_gen_decoders", {})
-        # the chunk is part of the geometry only where it sizes something
-        # (a windowed entry's rows): other graphs share their programs
-        # across engines of any chunk
-        chunk = int(prefill_chunk) if any(
-            getattr(op, "window", 0) for op in model.layers) else 0
+        # the chunk is part of the geometry: it ends the list of chunk
+        # buckets, and sizes a windowed entry's rows
+        chunk = int(prefill_chunk)
         key = (int(slots), int(max_seq), ps, pool, chunk)
         dec = reg.get(key)
         if dec is None:

@@ -1214,10 +1214,18 @@ class GenerationEngine:
         """``stats()["kv_pages"]``, by kind of entry: the shared pool the
         host allocates from (``full``) and the rows windowed entries hold
         a slot, which it never touches (``windowed``)."""
+        from ...analysis.kv_memory import dtype_bytes
         pool, plan = self._pool, self.kv_plan
-        out = {"full": {"entries": sum(
-                            ent["kind"] == "kv" and not ent.get("window")
-                            for ent in self._decoder.layout.values()),
+        itemsize = dtype_bytes(self.model.config.compute_dtype)
+        paged = {name: ent for name, ent in self._decoder.layout.items()
+                 if ent["kind"] == "kv" and not ent.get("window")}
+        out = {"full": {"entries": len(paged),
+                        # a token's bytes by entry, as STORED (every leaf's
+                        # declared width, lane padding included)
+                        "bytes_per_token": {
+                            name: itemsize * sum(int(shape[-1]) for shape
+                                                 in ent["shapes"].values())
+                            for name, ent in paged.items()},
                         "num_pages": self.num_pages,
                         "in_use": pool.pages_in_use,
                         "free": pool.pages_free,

@@ -2413,6 +2413,36 @@ def _build_decoder_lm(chunk=_DEC_CHUNK, seed=0, weights=True):
     return model
 
 
+_LAT_SEQ, _LAT_CHUNK = 64, 8
+_LAT = {"q_rank": 24, "kv_rank": 32, "nope_dim": 16, "rope_dim": 8,
+        "v_dim": 16, "rope_theta": 25.6e6}
+_LAT_LAYERS = [{"attention": "latent_attention", "heads": 4, "mlp": "dense"},
+               {"attention": "latent_attention", "heads": 4,
+                "mlp": "sparse"}]
+
+
+def _build_latent_lm(chunk=_LAT_CHUNK, seed=0, weights=True):
+    """The sandwich-norm decoder with LATENT attention at a small size:
+    hidden 64, 4 heads of nope 16 / rope 8 / v 16 over one row of 32 + 8
+    values, q rank 24, a dense layer then a sparse one whose sigmoid router
+    scores 16 experts top-4 and whose chip holds 8; float32."""
+    from flexflow_tpu.models import build_decoder_lm
+    cfg = ff.FFConfig(batch_size=2, compute_dtype="float32", seed=seed)
+    cfg.serve_gen_slots = 2
+    cfg.serve_gen_max_seq = _LAT_SEQ
+    cfg.serve_prefill_chunk = chunk
+    model = build_decoder_lm(
+        cfg, _LAT_LAYERS, d_model=64, head_dim=0, num_kv_heads=0, d_ff=128,
+        vocab_size=VOCAB, seq_len=_LAT_SEQ, rms_eps=1e-5, sandwich=True,
+        latent=_LAT,
+        moe={"num_experts": 16, "k": 4, "d_ff": 16, "shared_d_ff": 16,
+             "routed_scale": 2.5, "scoring": "sigmoid", "held": (0, 8)})[0]
+    model.compile(ff.SGDOptimizer(lr=0.01), mesh=MachineMesh({"n": 1}))
+    if weights:
+        model.init_layers(seed=seed)
+    return model
+
+
 @pytest.fixture(scope="module")
 def decoder_lm():
     return _build_decoder_lm()
@@ -2628,9 +2658,14 @@ def test_what_a_windowed_entry_cannot_do_is_refused_by_name(decoder_lm):
         GenerationEngine(model, slots=2, draft_model=model, spec_gamma=2)
     plain = GraphDecoder.for_model(_build_lm(), 2, SEQ)
     assert plain.refusal("prefix reuse") is None and not plain.windowed
-    # the chunk sizes nothing of a pageable graph: one decoder for all
+    # engines of one chunk share a decoder; another chunk ends the list of
+    # chunk buckets elsewhere, so it is a decoder of its own
+    assert GraphDecoder.for_model(plain.model, 2, SEQ) is plain
+    chunked = GraphDecoder.for_model(plain.model, 2, SEQ, prefill_chunk=4)
+    assert chunked is not plain and chunked.buckets == (2, 4)
+    assert plain.buckets[-1] == SEQ
     assert GraphDecoder.for_model(plain.model, 2, SEQ, prefill_chunk=4) \
-        is plain
+        is chunked
 
 
 def test_rotary_positions_match_a_complex_rotation():
@@ -2839,7 +2874,8 @@ def _owned(table):
 
 
 @pytest.mark.parametrize("build, bucket", [(_build_lm, 16),
-                                           (_build_decoder_lm, 8)])
+                                           (_build_decoder_lm, 8),
+                                           (_build_latent_lm, 8)])
 def test_every_graph_op_owns_instructions_of_the_serving_programs(build,
                                                                    bucket):
     """The token step and one chunk program of the tiny post-norm decoder
@@ -2869,10 +2905,17 @@ def test_every_graph_op_owns_instructions_of_the_serving_programs(build,
         assert any(re.search("reduce|argmax", ins) for ins, (owner, _)
                    in table.items() if owner == "sample"), name
         for op in model.layers:
-            assert owned[op.name] == {None, *op.scopes}, (name, op.name)
+            # (an op whose every instruction lies in a scope of its own
+            # leaves nothing to the part ``None``)
+            assert owned[op.name] | {None} == {None, *op.scopes}, (
+                name, op.name)
     if build is _build_decoder_lm:
         assert _owned(tables["jit_decode"])["moe_1"] == {
             None, "moe_router", "moe_experts", "moe_shared"}
+    if build is _build_latent_lm:   # the chunk's loop body keeps its parts
+        for table in tables.values():
+            assert _owned(table)["attention_1"] - {None} == {
+                "mla_q", "mla_latent", "mla_absorb", "mla_core", "mla_out"}
     # asked again, nothing is compiled again; the copies' count shares it
     reads = dict(dec._program_reads)
     assert set(dec.pool_copies()) == {"jit_decode", f"jit_prefill.{bucket}"}
@@ -2945,7 +2988,8 @@ def test_a_stale_token_splice_is_an_error_too(monkeypatch):
 
 
 @pytest.mark.parametrize("build, bucket", [(_build_lm, 16),
-                                           (_build_decoder_lm, 8)])
+                                           (_build_decoder_lm, 8),
+                                           (_build_latent_lm, 8)])
 def test_the_scopes_change_no_lowered_program(build, bucket, monkeypatch):
     """The token step and one chunk program lowered with the scopes and
     with ``jax.named_scope`` made a no-op: the same text, so the same
@@ -2969,3 +3013,284 @@ def test_the_scopes_change_no_lowered_program(build, bucket, monkeypatch):
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     assert digests() == scoped
+
+
+# ----------------------------------------------------------------------
+# latent attention: one shared row a token in one page-major leaf
+# (ISSUE 41)
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def latent_lm():
+    return _build_latent_lm()
+
+
+def _latent_op(seed=41, dtype="float32"):
+    """A ``LatentAttention`` op of the small size with random weights."""
+    from flexflow_tpu.ops.latent_attention import LatentAttention
+    x = Tensor(shape=(1, 48, 64), dtype="float32", name="x")
+    op = LatentAttention("attention_0", x, 4, eps=1e-5, **_LAT)
+    rng = np.random.default_rng(seed)
+    params = {w.name: jnp.asarray(
+        1.0 + 0.1 * rng.standard_normal(w.shape) if w.name.endswith("norm")
+        else rng.standard_normal(w.shape) / np.sqrt(w.shape[-1]), dtype)
+        for w in op.weights}
+    return op, params
+
+
+def test_the_absorbed_steps_equal_the_expanded_forward_row_for_row():
+    """One op, float32: ``forward`` (EXPANDED, dense core) over 48
+    positions against the same positions SERVED through a paged latent
+    cache: prefill in chunks of 8 (the last one five real rows of eight:
+    the chunk's blocked, expanded core), then a verify window of three and
+    token steps (both ABSORBED, over the gathered rows) for slot 0 of two,
+    slot 1 idle, its writes dropped.  Every row within 2e-5 of the
+    forward's: the two forms are the same mathematics reassociated, and
+    float32 sums in another order.  The cache is ONE leaf of 128-lane rows
+    whose padding stays zero."""
+    op, params = _latent_op()
+    ctx = OpContext(training=False, compute_dtype="float32", mesh=None)
+    x = jnp.asarray(np.random.default_rng(3).standard_normal((1, 48, 64)),
+                    jnp.float32)
+    want = np.asarray(op.forward(params, [x], ctx)[0])[0]
+    ent = op.serve_state(2, 8, 16, None)
+    assert list(ent["shapes"]) == ["kv"] and ent["kind"] == "kv"
+    assert ent["shapes"]["kv"] == (8, 16, 128) and op.row_values == 40
+    state = {"kv": jnp.zeros(ent["shapes"]["kv"], jnp.float32)}
+    table = jnp.asarray([[5, 2, 7, 8], [8, 8, 8, 8]], jnp.int32)
+    got = np.zeros_like(want)
+    for start, length in ((0, 8), (8, 8), (16, 5)):
+        rows = jnp.pad(x[:, start:start + length],
+                       ((0, 0), (0, 8 - length), (0, 0)))
+        out, state = op.serve_step(params, [rows], state, ServeStep(
+            "chunk", table[0], start=jnp.int32(start),
+            length=jnp.int32(length), slot=jnp.int32(0), no_page=8), ctx)
+        got[start:start + length] = np.asarray(out[0])[0, :length]
+
+    def step(kind, start, width):
+        nonlocal state
+        pos = np.arange(start, start + width)
+        wp = np.stack([np.asarray(table)[0, pos // 16], np.full(width, 8)])
+        wr = np.stack([pos % 16, np.zeros(width, np.int64)])
+        if kind == "token":
+            wp, wr = wp[:, 0], wr[:, 0]
+        rows = jnp.concatenate([x[:, start:start + width],
+                                jnp.zeros((1, width, 64))], axis=0)
+        out, state = op.serve_step(params, [rows], state, ServeStep(
+            kind, table, pos=jnp.asarray([start, 0], jnp.int32),
+            write_pages=jnp.asarray(wp, jnp.int32),
+            write_rows=jnp.asarray(wr, jnp.int32), no_page=8), ctx)
+        got[start:start + width] = np.asarray(out[0])[0]
+
+    step("window", 21, 3)
+    for t in range(24, 48):
+        step("token", t, 1)
+    assert op.decode_core == "gathered"
+    np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
+    pool = np.asarray(state["kv"])
+    assert np.all(pool[..., 40:] == 0) and np.abs(pool[5, :, :40]).min() > 0
+    assert np.all(pool[[0, 1, 3, 4, 6]] == 0)      # pages of nobody
+
+
+def test_latent_graph_serves_what_its_forward_computes(latent_lm):
+    """Prefill in chunks of 8 then decoding through the engine's latent
+    pages against the graph's own full forward at every served position
+    (float32: the same tokens), prompts that pass several pages and
+    chunks, two streams at once.  The graph's state is one leaf a layer in
+    the SHARED pool, so nothing is refused; ``stats()`` says what a token
+    costs by entry, counts the latent layers under a kind of their own and
+    the routing of the experts HELD."""
+    model = latent_lm
+    rng = np.random.default_rng(41)
+    prompts = [rng.integers(1, VOCAB, n).astype(np.int32)
+               for n in (5, 8, 13, 23, 37)]
+    eng = GenerationEngine(model, slots=2)
+    dec = eng._decoder
+    assert dec.pageable and not dec.windowed
+    for what in ("prefix reuse", "speculation", "migration"):
+        assert dec.refusal(what) is None
+    for name in ("attention_0", "attention_1"):
+        assert dec.layout[name]["shapes"] == {"kv": (dec.num_pages, 16, 128)}
+    plan = eng.kv_plan
+    assert plan["page_bytes"] == 2 * 16 * 128 * 4 and not plan["window_bytes"]
+    with eng:
+        outs = [[int(t) for t in s.result(timeout=300)] for s in
+                [eng.submit(p, max_new_tokens=16) for p in prompts]]
+        snap = eng.stats()
+    for p, out in zip(prompts, outs):
+        assert out == reference_decode(model, p, 16, _LAT_SEQ)
+    assert snap["decode_attention"] == {
+        "paged": 0, "gathered": 2, "latent": {"paged": 0, "gathered": 2}}
+    assert snap["kv_pages"]["full"]["bytes_per_token"] == {
+        "attention_0": 512, "attention_1": 512}
+    moe = snap["moe"]["moe_1"]
+    assert moe["held"] == 8 and len(moe["load"]) == 8
+    # 4 choices a token over 16 experts of which 8 are here: about half
+    tokens = sum(len(p) for p in prompts) + 5 * 15
+    assert 0.25 * 4 * tokens < moe["assignments"] < 0.75 * 4 * tokens
+
+
+def test_latent_pages_are_lent_rolled_back_and_shipped(latent_lm):
+    """The three things a windowed entry refuses, on the latent graph:
+    a prompt that REUSES another's first two pages, a divergent draft whose
+    windows are partly REJECTED, and a stream that prefills on one engine
+    and decodes on another each serve the tokens the graph's own forward
+    gives (float32)."""
+    from flexflow_tpu.fflogger import silenced
+    from flexflow_tpu.serving.cluster.bench import build_disagg
+
+    model = latent_lm
+    rng = np.random.default_rng(42)
+    first = rng.integers(1, VOCAB, 40).astype(np.int32)
+    second = np.concatenate([first[:33], rng.integers(1, VOCAB, 6)]).astype(
+        np.int32)
+    refs = [reference_decode(model, p, 10, _LAT_SEQ) for p in (first, second)]
+    with silenced("serve"):
+        with GenerationEngine(model, slots=2) as eng:
+            outs = [[int(t) for t in eng.submit(
+                p, max_new_tokens=10).result(timeout=300)]
+                for p in (first, second)]
+            snap = eng.stats()
+        assert outs == refs and snap["prefix_hit_tokens"] == 32
+        draft = _build_latent_lm(seed=7)
+        with GenerationEngine(model, slots=2, draft_model=draft,
+                              spec_gamma=3) as eng:
+            outs = [[int(t) for t in eng.submit(
+                p, max_new_tokens=10).result(timeout=300)]
+                for p in (first, second)]
+            snap = eng.stats()
+        assert outs == refs and snap["spec"] == "on"
+        assert 0 < snap["spec_proposed_tokens"] > snap["spec_accepted_tokens"]
+        router, fleets, (pf_eng, dc_eng) = build_disagg(
+            model, 2, _LAT_SEQ, _LAT_CHUNK, prefix_cache="off", pf_pace_s=0.0)
+        try:
+            outs = [[int(t) for t in router.submit(
+                "lm", p, max_new_tokens=10).result(timeout=300)]
+                for p in (first, second)]
+            stats = router.stats()
+        finally:
+            router.stop()
+            for f in fleets:
+                f.stop()
+    assert outs == refs
+    assert stats["migrations"] == 2 and stats["migrated_bytes"] > 0
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-5),
+                                       ("bfloat16", 2e-2)])
+def test_paged_latent_kernel_matches_the_gathered_rows(dtype, tol):
+    """The kernel's latent form (interpret mode): 20 query heads over ONE
+    row of 256 lanes whose first 128 are the values, against the absorbed
+    scores and values over the gathered rows, at positions inside the first
+    page, at a page's edge, past one copy group and one idle slot.  Every
+    page the kernel may not read is NaN in the pool it gets.  Tolerances as
+    in ``test_paged_decode_kernel_matches_gathered_decode``."""
+    from flexflow_tpu.ops.paged_decode_kernel import paged_latent_attention
+
+    H, e, lanes, page, pps = 20, 256, 128, 16, 40
+    pos = np.array([0, page - 1, page, 530, 77, pps * page - 1], np.int32)
+    slots, idle = len(pos), 4
+    rng = np.random.default_rng(5)
+    num_pages = slots * pps
+    table = (np.arange(slots)[:, None] * pps
+             + np.arange(pps)[None]).astype(np.int32)
+    wp = np.zeros(slots, np.int32)
+    wp[idle] = num_pages
+    q = jnp.asarray(rng.standard_normal((slots, H, e)), dtype)
+    pool = jnp.asarray(rng.standard_normal((num_pages, page, e)), dtype)
+    scale = 1.0 / np.sqrt(e)
+    view = jnp.take(pool, table, axis=0).reshape(slots, pps * page, e)
+    s = jnp.einsum("nhe,nle->nhl", q, view,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(np.arange(pps * page)[None, None] > pos[:, None, None],
+                  -1e30, s)
+    want = jnp.einsum("nhl,nlc->nhc", jax.nn.softmax(s, axis=-1).astype(
+        view.dtype), view[..., :lanes], preferred_element_type=jnp.float32)
+    stale = np.ones(num_pages, bool)
+    for i in range(slots):
+        if i != idle:
+            stale[table[i, :pos[i] // page + 1]] = False
+    got = paged_latent_attention(
+        q, jnp.where(jnp.asarray(stale)[:, None, None], jnp.nan, pool),
+        jnp.asarray(table), jnp.asarray(pos), jnp.asarray(wp), scale, lanes)
+    assert got.dtype == jnp.float32 and got.shape == (slots, H, lanes)
+    decoding = np.arange(slots) != idle
+    np.testing.assert_allclose(np.asarray(got)[decoding],
+                               np.asarray(want)[decoding], rtol=tol, atol=tol)
+    assert np.all(np.asarray(got)[idle] == 0.0)
+
+
+@pytest.mark.parametrize("why,args,ok", [
+    ("a 576-value row stored as five lane tiles",
+     ("tpu", "bfloat16", 128, 640, 16, False, 1, 512), True),
+    ("the bare 576-wide row: four and a half tiles",
+     ("tpu", "bfloat16", 128, 576, 16, False, 1, 512), False),
+    ("values that end mid-tile",
+     ("tpu", "bfloat16", 128, 640, 16, False, 1, 448), False),
+    ("more heads than the rows of one product",
+     ("tpu", "bfloat16", 512, 640, 16, False, 1, 512), False),
+    ("across chips", ("tpu", "bfloat16", 128, 640, 16, True, 1, 512), False),
+    ("no TPU", ("cpu", "bfloat16", 128, 640, 16, False, 1, 512), False),
+], ids=lambda x: x if isinstance(x, str) else "")
+def test_paged_decode_supported_says_which_latent_rows_it_takes(why, args,
+                                                                ok):
+    from flexflow_tpu.ops.paged_decode_kernel import supported
+    backend, dtype, *rest = args
+    assert supported(backend, jnp.dtype(dtype), *rest[:-1],
+                     value_lanes=rest[-1]) == ok, why
+
+
+def test_paged_latent_kernel_compiles_for_the_chip(v5e_device, monkeypatch):
+    """The kernel at the widths ISSUE 41's configuration serves (128 query
+    heads over one row of 640 lanes, values its first 512, bf16, 32 slots
+    of 800 pages of 16), compiled by the TPU's compiler for a described
+    v5e: what interpret mode cannot show."""
+    from flexflow_tpu.ops import flash_kernel, paged_decode_kernel as pk
+    from jax.sharding import SingleDeviceSharding
+
+    monkeypatch.setattr(flash_kernel, "_interpret", lambda: False)
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    chip = SingleDeviceSharding(v5e_device)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, pps = 32, 800
+    args = (sd((slots, 128, 640), jnp.bfloat16),
+            sd((slots * pps, 16, 640), jnp.bfloat16),
+            sd((slots, pps), jnp.int32), sd((slots,), jnp.int32),
+            sd((slots,), jnp.int32))
+    with _no_compilation_cache():
+        text = _within(_COMPILE_LIMIT_S, lambda: pk.paged_latent_attention
+                       .lower(*args, 0.0722, 512).compile().as_text())
+    assert "paged_latent_attention" in text
+
+
+def test_the_serving_programs_of_the_graphs_that_were_there_are_the_parents():
+    """The token step and one chunk program of the tiny post-norm decoder
+    (``gpt1``'s family) and of the tiny laguna graph lower to the text they
+    lowered to before latent attention, a sigmoid router and held experts
+    arrived (sha256 of the lowered text, read on the parent commit under
+    this suite's ``conftest.py``, whose matmul precision the text carries): what
+    this PR added is beside their paths, not in them.  A PR that changes
+    one of these programs on purpose says which and why, and moves the pin."""
+    import hashlib
+
+    want = {
+        (_build_lm, "jit_prefill_16"):
+        "b09e3d9aefb39bba60b5c0c82dd722b992124c433f32427ef418ae293e338166",
+        (_build_lm, "jit_decode"):
+        "880cc7faadb841ee3bfb4b793a375b4811c3673ff5e5208d71706c99fa4174b7",
+        (_build_decoder_lm, "jit_prefill_8"):
+        "e7323eba5c2197689466a7f701e6cce5562086a5afd78e677a332b0c02227a43",
+        (_build_decoder_lm, "jit_decode"):
+        "115301c4e49c0081d844432de4388659bd14b1c596d1e7c0976ef60b20068389"}
+    got = {}
+    for build, bucket in ((_build_lm, 16), (_build_decoder_lm, 8)):
+        model = build(weights=False)
+        dec = GraphDecoder(model, 2, model.input_tensors[0].shape[1])
+        dec.decode_fn()
+        dec.prefill_fn(bucket)
+        for _, name, fn, args in dec._program_specs():
+            got[build, name] = hashlib.sha256(
+                fn.lower(*args).as_text().encode()).hexdigest()
+    assert got == want

@@ -239,3 +239,159 @@ def test_the_simulator_prices_the_ops_of_a_sparse_windowed_decoder():
     assert np.isfinite(t) and t > 0
     best = search(model.layers, 4, budget=20, seed=0)
     assert best
+
+
+# ---------------------------------------------------------------------------
+# a sigmoid router, and an op told which experts it holds (ISSUE 41)
+# ---------------------------------------------------------------------------
+_SHARE_SZ = {"layers": [{"attention": "latent_attention", "heads": 4,
+                         "mlp": "sparse"}],
+             "d_model": 64, "q_rank": 24, "kv_rank": 32, "nope": 16,
+             "rope": 8, "v": 16, "rope_theta": 25.6e6, "d_ff": 128,
+             "vocab": 64, "eps": 1e-5, "experts": 16, "router_experts": 16,
+             "first_expert": 0, "k": 4, "expert_ff": 16, "shared_ff": 16,
+             "routed_scale": 2.5, "positions": 32, "weight_dtype": "float32"}
+
+
+def _pangu_reference():
+    """``perfbench/reference/pangu_moe.py``, loaded by its path."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench", "reference", "pangu_moe.py")
+    spec = importlib.util.spec_from_file_location("pangu_moe_ref", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _share(ref, first, held, seed=5):
+    """``(op, params, the share's sizes, the reference's leaves)`` of the
+    sparse layer's op holding experts ``first .. first + held`` of 16, with
+    the reference's weights for exactly those experts."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops.moe import MoE
+    from flexflow_tpu.tensor import Tensor
+
+    sz = dict(_SHARE_SZ, experts=held, first_expert=first)
+    p = ref.init_params(sz, seed).layer(0)
+    x = Tensor(shape=(2, 24, sz["d_model"]), dtype="float32", name="x")
+    op = MoE("moe", x, sz["router_experts"], sz["expert_ff"], k=sz["k"],
+             capacity_factor=None, aux_loss_weight=0.0, gated=True,
+             shared_d_ff=sz["shared_ff"], routed_scale=sz["routed_scale"],
+             scoring="sigmoid", held=(first, held))
+    params = {"moe/gate": p["wr"].T,
+              "moe/w_up": jnp.concatenate([p["e1"], p["e3"]], axis=-1),
+              "moe/w_down": p["e2"],
+              "moe/shared_up": jnp.concatenate([p["s1"], p["s3"]], axis=-1),
+              "moe/shared_down": p["s2"]}
+    assert {w.name: tuple(w.shape) for w in op.weights} == {
+        k: tuple(v.shape) for k, v in params.items()}
+    return op, params, sz, p
+
+
+def test_the_shares_of_a_sparse_layer_add_up_to_the_uncut_layer():
+    """Four ops that each hold 4 of 16 experts (router whole, 4 choices a
+    token, sigmoid scores), and the uncut op: every share's output is the
+    reference's for that share, the uncut op's the uncut reference's, and
+    the ROUTED parts of the four shares with the shared expert counted
+    once add up to the uncut reference's layer output.  Tolerance 2e-5:
+    float32 on both sides, the op's grouped products against the
+    reference's ``Precision.HIGHEST`` loop over every held expert."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.op import OpContext
+
+    ref = _pangu_reference()
+    ctx = OpContext(training=False, compute_dtype="float32", mesh=None)
+    b = jnp.asarray(np.random.default_rng(6).standard_normal(
+        (2, 24, _SHARE_SZ["d_model"])), jnp.float32)
+    flat = b.reshape(-1, b.shape[-1])
+
+    def run(first, held):
+        op, params, sz, p = _share(ref, first, held)
+        got = np.asarray(op.forward(params, [b], ctx)[0]).reshape(flat.shape)
+        want = np.asarray(ref.moe(flat, p, sz))
+        np.testing.assert_allclose(got, want, rtol=0, atol=2e-5)
+        shared = np.asarray(ref.gated_ffn(flat, p["s1"], p["s3"], p["s2"]))
+        return got, shared, op
+
+    whole, shared, op = run(0, 16)
+    assert op.flops() > run(0, 4)[2].flops()    # of the experts HELD
+    parts = [run(first, 4) for first in (0, 4, 8, 12)]
+    for got, sh, _ in parts:
+        np.testing.assert_array_equal(sh, shared)   # every chip's alike
+        assert np.abs(got - sh).max() > 1e-3        # and its experts add
+    total = shared + sum(got - sh for got, sh, _ in parts)
+    np.testing.assert_allclose(total, whole, rtol=0, atol=4e-5)
+
+
+def test_the_sigmoid_router_picks_and_weights_as_the_reference_ties_included():
+    """``scoring="sigmoid"``: the 4 largest sigmoid scores of 16, in float32,
+    renormalised then times 2.5, against the reference's ``route``; two
+    router rows made EQUAL (a tie at every token, inside the top four for
+    most: a direction every token shares) are chosen lower number first on
+    both sides.  Softmax scoring keeps choosing what it chose."""
+    import jax.numpy as jnp
+
+    ref = _pangu_reference()
+    op, params, sz, p = _share(ref, 0, 16)
+    rng = np.random.default_rng(7)
+    xt = rng.standard_normal((48, sz["d_model"])).astype(np.float32)
+    xt[:, 0] = 2.0 + np.abs(xt[:, 0])
+    wr = np.array(p["wr"])
+    wr[0, 5] = 1.0                 # expert 5 high everywhere ...
+    wr[:, 9] = wr[:, 5]            # ... and expert 9 its twin
+    params = dict(params, **{"moe/gate": jnp.asarray(wr.T)})
+    idx, gates, scores = op._route(params, jnp.asarray(xt))
+    want_idx, want_w = ref.route(jnp.asarray(xt), jnp.asarray(wr), sz)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_allclose(np.asarray(gates), np.asarray(want_w),
+                               rtol=0, atol=1e-6)
+    idx = np.asarray(idx)
+    both = (idx == 5).any(axis=1) & (idx == 9).any(axis=1)
+    assert both.mean() > 0.5
+    for row in idx[both]:          # of equal scores, the lower number first
+        assert list(row).index(5) + 1 == list(row).index(9)
+    np.testing.assert_allclose(np.asarray(gates).sum(axis=1), 2.5, atol=1e-5)
+    assert float(np.asarray(scores).max()) < 1.0    # sigmoid, not softmax
+    assert np.asarray(scores).sum(axis=1).max() > 1.5
+    op.scoring = "softmax"
+    _, soft, probs = op._route(params, jnp.asarray(xt))
+    np.testing.assert_allclose(np.asarray(probs).sum(axis=1), 1.0, atol=1e-5)
+    with pytest.raises(ValueError, match="scoring"):
+        _share_bad = ff.FFModel(ff.FFConfig(batch_size=2))
+        _share_bad.moe(_share_bad.create_tensor((2, 4, 8), name="x"), 4, 8,
+                       scoring="tanh")
+
+
+def test_the_counters_of_a_share_speak_of_the_experts_held():
+    """``serve_state`` and ``serve_step`` of an op that holds experts 4-7 of
+    16: ``load`` has 4 entries and counts only the pairs that fell on them
+    (a choice of an expert held elsewhere, below OR above, is dropped, not
+    wrapped), ``untouched`` is of those 4."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.op import OpContext, ServeStep
+
+    ref = _pangu_reference()
+    op, params, sz, p = _share(ref, 4, 4)
+    ent = op.serve_state(2, 8, 16, None)
+    assert ent["shapes"]["load"] == (4,)
+    ctx = OpContext(training=False, compute_dtype="float32", mesh=None)
+    b = jnp.asarray(np.random.default_rng(8).standard_normal(
+        (2, 1, sz["d_model"])), jnp.float32)
+    state = {"load": jnp.zeros((4,), jnp.int32),
+             "token_steps": jnp.zeros((), jnp.int32),
+             "untouched": jnp.zeros((), jnp.int32)}
+    where = ServeStep("token", None, pos=jnp.zeros((2,), jnp.int32),
+                      write_pages=jnp.asarray([0, 8], jnp.int32),
+                      write_rows=jnp.zeros((2,), jnp.int32), no_page=8)
+    _, new = op.serve_step(params, [b], state, where, ctx)
+    idx, _ = ref.route(b[:1, 0], p["wr"], sz)      # slot 0 is the live one
+    want = np.bincount([i - 4 for i in np.asarray(idx)[0] if 4 <= i < 8],
+                       minlength=4)
+    np.testing.assert_array_equal(np.asarray(new["load"]), want)
+    assert int(new["token_steps"]) == 1
+    assert int(new["untouched"]) == int((want == 0).sum())
