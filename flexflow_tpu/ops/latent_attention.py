@@ -28,12 +28,18 @@ Which is taken is read off the step's kind at trace time, never a flag:
 ``forward`` (training, ``predict``) expands with the dense core;
 ``serve_step("chunk")`` expands the slot's history a block of keys at a time
 under an online softmax, only the blocks the chunk's last row can see (a
-12 800-position table costs what the prompt so far costs); ``"token"`` and
-``"window"`` absorb, the token step through
+12 800-position table costs what the prompt so far costs): inside
+``latent_chunk_kernel.latent_chunk_attention`` where that applies (a TPU, one
+device, bfloat16, whole lane tiles: a block's expanded keys, values and scores
+stay in VMEM), and under XLA's loop over key blocks
+(:meth:`LatentAttention._over_key_blocks`) elsewhere — the CPU, a mesh, an odd
+shape — which is also what the tests compare the kernel against; ``"token"``
+and ``"window"`` absorb, the token step through
 ``paged_decode_kernel.paged_latent_attention`` where that applies (a TPU, one
 device: the latent pages are read once for scores AND values) and over the
 gathered view elsewhere.  ``scripts/latent_chunk_forms.py`` times the chunk
-both ways on the chip (PERF.md section 5 has the numbers the choice rests on).
+three ways on the chip: the loop expanded, the loop absorbed, the kernel
+(PERF.md section 5 has the numbers the choice rests on).
 
 **The cache** is one page-major leaf ``"kv"``, ``(num_pages, page, row)``
 with ``row`` = ``kv_rank + rope_dim`` rounded up to whole 128-lane tiles, the
@@ -58,7 +64,7 @@ from .common import cast_compute
 from .norm import rms_normalize
 
 _LANES = 128
-_KEY_BLOCK = 512    # keys a block of the chunk's expanded history
+_KEY_BLOCK = 512    # keys a block of the chunk's expanded history, either core
 
 
 class LatentAttention(Op):
@@ -89,6 +95,9 @@ class LatentAttention(Op):
         # traced (GraphDecoder.decode_attention sums it under "latent")
         self.decode_core = None
         self.decode_kind = "latent"
+        # {chunk bucket: "kernel" or "loop"}: the core each traced chunk
+        # program got (GraphDecoder.chunk_attention sums it under "latent")
+        self.chunk_core = {}
         self._add_output((n, s, d), x.dtype)
         init = kernel_initializer or GlorotUniform()
         H = self.num_heads
@@ -265,9 +274,21 @@ class LatentAttention(Op):
 
     def _chunk_expanded(self, params, q_nope, q_pe, pool, where, ctx):
         """A prompt chunk's attention over the slot's pages, EXPANDED: each
-        block of cached rows becomes per-head keys and values
-        (:meth:`_over_key_blocks` has the loop).  -> (1, B, H, v) f32."""
-        H = self.num_heads
+        block of cached rows becomes per-head keys and values, inside
+        ``latent_chunk_kernel.latent_chunk_attention`` where
+        :meth:`_chunk_core` says ``"kernel"`` (the blocks' transients stay
+        in VMEM), else under :meth:`_over_key_blocks`' loop; which one this
+        program got is noted in ``self.chunk_core``.  -> (1, B, H, v)
+        f32."""
+        core = self._chunk_core(q_nope, pool, ctx)
+        self.chunk_core[q_nope.shape[1]] = core
+        if core == "kernel":
+            from .latent_chunk_kernel import latent_chunk_attention
+            with jax.named_scope("mla_core"):
+                return latent_chunk_attention(
+                    q_nope[0], q_pe[0], pool, where.table,
+                    self._kvb(params, ctx), where.start, where.length,
+                    scale=self.scale, rank=self.kv_rank, keys=_KEY_BLOCK)
         q = jnp.concatenate([q_nope, q_pe], axis=-1)[0]          # (B, H, e)
 
         def block(rows):
@@ -331,6 +352,19 @@ class LatentAttention(Op):
         _, l, acc = jax.lax.fori_loop(0, last // keys + 1, one, init)
         with jax.named_scope("mla_core"):
             return jnp.transpose(acc / l[..., None], (1, 0, 2))[None]
+
+    def _chunk_core(self, q, pool, ctx: OpContext) -> str:
+        """``"kernel"`` where a chunk's expanded core can keep a block's
+        transients in VMEM (:func:`latent_chunk_kernel.supported`, from
+        what the code can see: queries and pool of one dtype), else
+        ``"loop"``."""
+        from . import latent_chunk_kernel
+        distributed = ctx.mesh is not None and ctx.mesh.is_distributed
+        return ("kernel" if q.dtype == pool.dtype
+                and latent_chunk_kernel.supported(
+            jax.default_backend(), pool.dtype, self.kv_rank, self.row_width,
+            self.nope_dim, self.v_dim, pool.shape[-2], _KEY_BLOCK,
+            distributed, ctx.training) else "loop")
 
     def _decode_core(self, pool, ctx: OpContext) -> str:
         """``"paged"`` where the token step can read the latent pages in

@@ -780,6 +780,24 @@ class GraphDecoder:
             out["latent"] = count(latent)
         return out
 
+    def chunk_attention(self) -> Dict[str, Dict[str, int]]:
+        """How many (latent attention op, chunk program) pairs got which
+        chunk core when the programs were traced (every bucket built):
+        ``{"latent": {"kernel", "loop"}}`` — ``"kernel"`` keeps a key
+        block's expanded keys, values and scores in VMEM
+        (:mod:`flexflow_tpu.ops.latent_chunk_kernel`), ``"loop"`` is XLA's
+        loop over key blocks.  Noted by the op at trace time
+        (``LatentAttention.chunk_core``), like :meth:`grouped_product`;
+        ``{}`` for a graph without such an op."""
+        latent = [op for op in self.model.layers
+                  if getattr(op, "decode_kind", None) == "latent"]
+        if not latent:
+            return {}
+        cores = [core for op in latent
+                 for core in tuple(op.chunk_core.values())]
+        return {"latent": {core: cores.count(core)
+                           for core in ("kernel", "loop")}}
+
     def grouped_product(self) -> Dict[str, int]:
         """How many (mixture-of-experts op, serving program) pairs got
         which grouped product when the programs were traced (every chunk

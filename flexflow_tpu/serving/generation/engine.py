@@ -1311,6 +1311,9 @@ class GenerationEngine:
                    "ahead": self._pipe_ahead,
                    "drained": dict(self._pipe_drained),
                    "dropped_tokens": self._pipe_dropped}}
+        chunk_attention = self._decoder.chunk_attention()
+        if chunk_attention:     # a graph with latent attention only
+            out["chunk_attention"] = chunk_attention
         if self._pool_copies is not None:
             out["pool_copies"] = self._pool_copies
         # per expert the live tokens it received, as of the last token

@@ -1,20 +1,25 @@
 #!/usr/bin/env python3
-"""Times a prompt chunk's latent attention BOTH ways on the chip, at the
+"""Times a prompt chunk's latent attention THREE ways on the chip, at the
 widths of ``perfbench/configs/pangu-ultra-moe-718b.json``:
 
     chiprun --chips 1 -- python3 scripts/latent_chunk_forms.py
 
-* ``expanded``: what ``LatentAttention.serve_step("chunk")`` does, the
-  slot's cached rows expanded to per-head keys and values a block at a time;
+* ``expanded``: the slot's cached rows expanded to per-head keys and values
+  a block at a time under XLA's loop (``LatentAttention._over_key_blocks``):
+  what ``serve_step("chunk")`` does wherever the kernel is not taken;
 * ``absorbed``: the form the token step takes, applied to the chunk's 512
   queries (``W_UK`` moved to the queries, scores and values over the latent
   rows as they lie, ``W_UV`` after), written HERE only, over the same blocks
-  under the same online softmax (``LatentAttention._over_key_blocks``).
+  under the same loop;
+* ``kernel``: the expanded form inside the repo's own
+  ``ops/latent_chunk_kernel.py`` (a block's keys, values and scores stay in
+  VMEM): what ``serve_step("chunk")`` does on a TPU, the op as it is.
 
 One layer's whole chunk step (projections, cache write, core, output) is
 timed for a 512-token chunk whose last row stands at ``L`` = 2 048 and
-12 288, the two differing in the core alone.  The tree keeps the one that
-wins (PERF.md section 5 has the numbers); this script is how to ask again.
+12 288, the three differing in the core alone.  The tree keeps the form
+that wins (PERF.md section 5 has the numbers); this script is how to ask
+again.
 """
 
 import json
@@ -33,7 +38,14 @@ from flexflow_tpu.ops.latent_attention import LatentAttention
 from flexflow_tpu.tensor import Tensor
 
 
-class AbsorbedChunk(LatentAttention):
+class ExpandedLoop(LatentAttention):
+    """The same op held to the loop over key blocks."""
+
+    def _chunk_core(self, q, pool, ctx):
+        return "loop"
+
+
+class AbsorbedChunk(ExpandedLoop):
     """The same op with the chunk's core absorbed."""
 
     def _chunk_expanded(self, params, q_nope, q_pe, pool, where, ctx):
@@ -72,8 +84,11 @@ def main():
     pps = max_seq // page
     key = jax.random.PRNGKey(0)
     results = {}
-    for name, cls in (("expanded", LatentAttention),
-                      ("absorbed", AbsorbedChunk)):
+    for name, cls in (("expanded", ExpandedLoop),
+                      ("absorbed", AbsorbedChunk),
+                      ("kernel", LatentAttention)):
+        if small and name == "kernel":
+            continue    # the CPU's answer is the loop again
         op = cls("attention_0", x, heads, **kw)
         params = {w.name: (jnp.ones(w.shape, jnp.bfloat16)
                            if w.name.endswith("norm") else
@@ -109,13 +124,19 @@ def main():
                 out, np.float32))
             print(f"{name} L={L}: median {np.median(ts):.3f} ms of 5 "
                   f"(min {min(ts):.3f}) a layer's chunk step", flush=True)
+        if name == "kernel":
+            assert op.chunk_core == {chunk: "kernel"}, op.chunk_core
     for L in (2048, 12288):
-        a, b = results["expanded", L][1], results["absorbed", L][1]
-        print(f"L={L}: largest difference of the two forms' outputs "
-              f"{np.abs(a - b).max():.4g} (largest output "
-              f"{np.abs(a).max():.4g}); expanded/absorbed "
-              f"{results['expanded', L][0] / results['absorbed', L][0]:.3f}",
-              flush=True)
+        a = results["expanded", L][1]
+        for other in ("absorbed", "kernel"):
+            if (other, L) not in results:
+                continue
+            b = results[other, L][1]
+            print(f"L={L}: largest difference of expanded and {other} "
+                  f"{np.abs(a - b).max():.4g} (largest output "
+                  f"{np.abs(a).max():.4g}); expanded/{other} "
+                  f"{results['expanded', L][0] / results[other, L][0]:.3f}",
+                  flush=True)
     return 0
 
 

@@ -3265,6 +3265,36 @@ def test_paged_latent_kernel_compiles_for_the_chip(v5e_device, monkeypatch):
     assert "paged_latent_attention" in text
 
 
+@pytest.mark.parametrize("bucket", [512, 2])
+def test_latent_chunk_kernel_compiles_for_the_chip(v5e_device, monkeypatch,
+                                                   bucket):
+    """The chunk's kernel (``ops/latent_chunk_kernel.py``) at the widths
+    that configuration serves (128 heads of nope 128 / rope 64 / v 128 over
+    rows of 640 lanes, 800 pages of 16 a slot, bf16), the widest and the
+    narrowest bucket, compiled by the TPU's compiler for a described v5e:
+    the tiling, the copies and the VMEM budget, which interpret mode cannot
+    show (``tests/test_latent_chunk_kernel.py`` has the arithmetic)."""
+    from flexflow_tpu.ops import latent_chunk_kernel as lk
+    from jax.sharding import SingleDeviceSharding
+
+    monkeypatch.setattr(lk, "_interpret", lambda: False)
+    chip = SingleDeviceSharding(v5e_device)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    args = (sd((bucket, 128, 128), jnp.bfloat16),
+            sd((bucket, 128, 64), jnp.bfloat16),
+            sd((32 * 800 + 1, 16, 640), jnp.bfloat16), sd((800,), jnp.int32),
+            sd((128, 256, 512), jnp.bfloat16), sd((), jnp.int32),
+            sd((), jnp.int32))
+    with _no_compilation_cache():
+        text = _within(_COMPILE_LIMIT_S, lambda: lk.latent_chunk_attention
+                       .lower(*args, scale=0.0722, rank=512, keys=512)
+                       .compile().as_text())
+    assert "latent_chunk_attention" in text and "while" not in text
+
+
 def test_the_serving_programs_of_the_graphs_that_were_there_are_the_parents():
     """The token step and one chunk program of the tiny post-norm decoder
     (``gpt1``'s family) and of the tiny laguna graph lower to the text they
