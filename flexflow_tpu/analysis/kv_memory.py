@@ -136,6 +136,11 @@ def window_rows(window: int, max_seq: int, page_size: int,
     return -(-rows // page_size) * page_size
 
 
+# the layout entry of what an op with page-major leaves ALSO counts on the
+# device (``Op.serve_state``'s ``"counters"``), beside the op's own
+COUNTERS = "/counters"
+
+
 def kv_cache_layout(layers: List[Op],
                     mesh_sizes: Optional[Dict[str, int]],
                     slots: int, max_seq: int,
@@ -157,6 +162,11 @@ def kv_cache_layout(layers: List[Op],
     ``(slots, rows // page_size, page_size, ..)`` with ``"rows"``
     (:func:`window_rows`) noted on the entry — instead of pages of the
     shared pool: the pool's page ids then index the other layers only.
+    A ``"kv"`` entry that also declares ``"counters"`` (``{"shapes",
+    "entries"}``: what the op counts on the device beside its pages) gets
+    them as an entry of their own, kind ``"counter"``, named ``<op> +
+    COUNTERS``, so that everything said of a ``"kv"`` entry stays true of
+    it; :meth:`GraphDecoder._walk` hands the op both.
     The one place the declarations are gathered — the generation decoder
     allocates exactly this (through ``serving/generation/pages.py``, the
     only module allowed to allocate it — repo_lint RL013), and
@@ -178,6 +188,11 @@ def kv_cache_layout(layers: List[Op],
                     shape[1:]) for leaf, shape in entry["shapes"].items()},
                 entries={leaf: (None,) + tuple(e) for leaf, e in
                          entry["entries"].items()})
+        counters = entry.get("counters")
+        if counters:    # an op that pages AND counts: an entry for each
+            entry = {k: v for k, v in entry.items() if k != "counters"}
+            out[op.name + COUNTERS] = dict(counters, kind="counter",
+                                           dtype="i32")
         out[op.name] = entry
     return out
 

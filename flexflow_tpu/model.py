@@ -286,12 +286,17 @@ class FFModel:
                             num_heads=8, kdim=0, vdim=0, dropout=0.0,
                             bias=True, causal=False, kernel_initializer=None,
                             num_kv_heads=None, head_dim=None, rope=None,
-                            gate=False, window=0, name=None) -> Tensor:
+                            gate=False, window=0, qk_norm=None, sparse=None,
+                            name=None) -> Tensor:
         """``num_kv_heads`` (grouped queries), ``head_dim`` (a head size of
         its own), ``rope`` (one layer kind's published ``rope_parameters``
-        entry), ``gate`` (per-head sigmoid output gate) and ``window``
+        entry), ``gate`` (per-head sigmoid output gate), ``window``
         (causal attention over the last ``window`` positions, with a
-        cache that holds no more) are ``MultiHeadAttention``'s."""
+        cache that holds no more), ``qk_norm`` (the eps of an RMSNorm on
+        every query and key head before the rotation) and ``sparse``
+        (``{"index_heads", "index_dim", "topk"}``: a learned indexer
+        chooses the ``topk`` keys a query attends over) are
+        ``MultiHeadAttention``'s."""
         from .ops.attention import MultiHeadAttention
         key = key if key is not None else query
         value = value if value is not None else key
@@ -300,7 +305,8 @@ class FFModel:
                                 value, embed_dim, num_heads, kdim, vdim,
                                 dropout, bias, causal, kernel_initializer,
                                 num_kv_heads=num_kv_heads, head_dim=head_dim,
-                                rope=rope, gate=gate, window=window)
+                                rope=rope, gate=gate, window=window,
+                                qk_norm=qk_norm, sparse=sparse)
         return self._register(op).outputs[0]
 
     def position_embedding(self, input_tensor, max_len=None,

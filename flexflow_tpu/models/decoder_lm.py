@@ -33,7 +33,9 @@ def build_decoder_lm(config: FFConfig, layers: Sequence[Dict],
                      rope: Optional[Dict[str, Dict]] = None,
                      gate: bool = False, moe: Optional[Dict] = None,
                      kernel_initializer=None, sandwich: bool = False,
-                     latent: Optional[Dict] = None
+                     latent: Optional[Dict] = None,
+                     qk_norm: Optional[float] = None,
+                     sparse: Optional[Dict] = None
                      ) -> Tuple[FFModel, Tensor, Tensor]:
     """``layers``: one ``{"attention": kind, "heads": query heads, "mlp":
     "dense" | "sparse"}`` a layer.  ``rope``: ``{kind: rope_parameters
@@ -46,7 +48,11 @@ def build_decoder_lm(config: FFConfig, layers: Sequence[Dict],
     "v_dim", "rope_theta"}`` of the ``"latent_attention"`` layers
     (``head_dim`` and ``num_kv_heads`` are the other kinds'); ``sandwich``:
     a norm on each sublayer's output as well (``ln_attn_out_<i>``,
-    ``ln_ffn_out_<i>``).  Returns ``(model, tokens, logits)``."""
+    ``ln_ffn_out_<i>``); ``qk_norm`` (an eps) and ``sparse``
+    (``{"index_heads", "index_dim", "topk"}``) are the ``"full_attention"``
+    layers': an RMSNorm on every query and key head before the rotation,
+    and a learned indexer that chooses the keys a query attends over
+    (``MultiHeadAttention``).  Returns ``(model, tokens, logits)``."""
     ff = FFModel(config)
     init = kernel_initializer
     tokens = ff.create_tensor((config.batch_size, seq_len), dtype="int32",
@@ -66,6 +72,8 @@ def build_decoder_lm(config: FFConfig, layers: Sequence[Dict],
                 head_dim=head_dim, causal=True, bias=False,
                 rope=(rope or {}).get(kind), gate=gate,
                 window=window if kind == "sliding_attention" else 0,
+                qk_norm=qk_norm if kind == "full_attention" else None,
+                sparse=sparse if kind == "full_attention" else None,
                 kernel_initializer=init, name=f"attention_{i}")
         if sandwich:
             a = ff.rms_norm(a, eps=rms_eps, name=f"ln_attn_out_{i}")

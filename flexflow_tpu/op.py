@@ -246,7 +246,9 @@ class Op:
         ``"state"`` leaf is a fixed per-slot array, and a graph holding
         one prefills whole prompts only; a ``"counter"`` leaf is something
         the op counts on the device for ``stats()`` and nothing reads back
-        into the model.  ``analysis/kv_memory.kv_cache_layout``
+        into the model (an op with ``"kv"`` leaves that counts too adds
+        ``"counters": {"shapes", "entries"}`` to its entry and is handed
+        those leaves beside its pages).  ``analysis/kv_memory.kv_cache_layout``
         collects these; the engine allocates, the static gates charge and
         ``serve_step`` receives exactly what is declared here."""
         return None

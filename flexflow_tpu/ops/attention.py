@@ -20,6 +20,7 @@ rotation), so there is no hand-written backward.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from functools import partial
 
@@ -27,11 +28,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from ..initializers import GlorotUniform, ZeroInitializer
+from ..initializers import (ConstantInitializer, GlorotUniform,
+                            ZeroInitializer)
 from ..op import Op, OpContext, OpType
 from .common import cast_compute
+from .norm import rms_normalize
 
 NEG_INF = -1e30  # finite mask value: keeps online-softmax exp() NaN-free
+_LANES = 128
+_KEY_BLOCK = 512    # keys a block of a sparse op's long chunk history
+_WIDE = 24          # bits of a wide counter's low word (``_add_wide``)
 
 
 def _use_flash(q, k, ctx_flag, training_dropout: bool,
@@ -249,8 +255,98 @@ def _window_mask(scores, kpos, qpos, window: int):
     return jnp.where((kpos <= qpos - window) | (kpos < 0), NEG_INF, scores)
 
 
+def _keep_mask(scores, keep):
+    """Also mask what a learned selection left out: ``keep`` broadcasts
+    against ``scores`` (n, h, q, k); ``None`` (an op that chooses nothing,
+    or a history no longer than its ``topk``) is the scores themselves, so
+    the traced program is the one it always was."""
+    if keep is None:
+        return scores
+    return jnp.where(keep, scores, NEG_INF)
+
+
+def index_scores(qi, ki, wi):
+    """The indexer's score of every (query, key) pair, f32 ``(n, q, k)``:
+    ``sum_j wi[q, j] * relu(qi[q, j] . ki[k])`` over the index heads ``j``
+    — ``qi`` (n, q, Hi, di), ``ki`` (n, k, di) (ONE key head), ``wi`` (n, q,
+    Hi) f32.  The weighted sum is an elementwise product and a sum in f32,
+    not a second matrix product (which a TPU would round to bfloat16)."""
+    s = jnp.einsum("nqhd,nkd->nqhk", qi, ki,
+                   preferred_element_type=jnp.float32)
+    # (+ 0.0: a sum of products by negative weights can be -0.0, which
+    # equals +0.0 and which a sort would put under it; one zero only)
+    return jnp.sum(jax.nn.relu(s) * wi[..., None], axis=2) + 0.0
+
+
+def select_threshold(scores, topk: int):
+    """``(thr, last)`` of each row of ``scores`` (.., L) f32, ``L > topk``:
+    the ``topk``-th largest score and the highest position among the chosen
+    that hold exactly it — of equal scores the lower position is chosen
+    first, so the chosen set is every position over ``thr`` and those AT it
+    up to ``last`` (:func:`selected`): exact, ties and all.  Positions a row
+    may not see carry ``NEG_INF`` and are chosen last.
+
+    No sort (``jax.lax.top_k`` of 2 048 is, to the TPU's compiler, a full
+    sort of every row): ``thr`` is found BIT BY BIT on the scores'
+    order-preserving unsigned image, 32 passes that each count a row's
+    scores at or over a candidate; ``last`` is ``L`` (every tied position is
+    chosen) unless some row has more scores AT its threshold than it still
+    needs, and only then a second search, over positions, finds where that
+    row's need is met."""
+    L = scores.shape[-1]
+    # (-0.0 + 0.0 is +0.0: one image for the two zeros, which compare equal)
+    bits = jax.lax.bitcast_convert_type(scores + 0.0, jnp.uint32)
+    top = jnp.uint32(1 << 31)
+    u = jnp.where(bits >= top, ~bits, bits | top)
+
+    def count(mask):
+        return jnp.sum(mask, axis=-1, dtype=jnp.int32)
+
+    def bit(i, ans):
+        cand = ans | (top >> i.astype(jnp.uint32))
+        return jnp.where(count(u >= cand[..., None]) >= topk, cand, ans)
+
+    ans = jax.lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1],
+                                                  jnp.uint32))
+    thr = jax.lax.bitcast_convert_type(
+        jnp.where(ans >= top, ans ^ top, ~ans), jnp.float32)
+    tied = u == ans[..., None]
+    need = topk - count(u > ans[..., None])            # >= 1 of the tied
+    kpos = jnp.arange(L)
+    width = L.bit_length()
+
+    def where_need_is_met():
+        # the largest P with fewer than ``need`` tied positions under it
+        def step(i, p):
+            cand = p | (1 << (width - 1 - i))
+            return jnp.where(count(tied & (kpos < cand[..., None])) < need,
+                             cand, p)
+        return jax.lax.fori_loop(0, width, step, jnp.zeros_like(need))
+
+    last = jax.lax.cond(jnp.all(count(tied) == need),
+                        lambda: jnp.full_like(need, L), where_need_is_met)
+    return thr, last
+
+
+def selected(scores, kpos, thr, last):
+    """The chosen set as a mask over ``scores`` (.., q, k): ``kpos``
+    broadcasts against it, ``thr`` / ``last`` (.., q) are
+    :func:`select_threshold`'s."""
+    thr, last = thr[..., None], last[..., None]
+    return (scores > thr) | ((scores == thr) & (kpos <= last))
+
+
+def _add_wide(counts, x):
+    """``counts`` (.., 2) int32, each a ``[high, low]`` pair in base ``2 **
+    _WIDE``, plus ``x`` (..,) int32 < 2 ** 30: sums of live positions pass
+    2 ** 31 within minutes of serving, and x64 is off."""
+    low = counts[..., 1] + x
+    return jnp.stack([counts[..., 0] + (low >> _WIDE),
+                      low & ((1 << _WIDE) - 1)], axis=-1)
+
+
 def _decode_attention(q, k_cache, v_cache, pos, scale: float,
-                      kpos=None, window: int = 0):
+                      kpos=None, window: int = 0, keep=None):
     """Single-position attention against a preallocated per-slot KV
     cache (the autoregressive decode kernel — docs/serving.md "Token
     generation").  ``q``: (n, 1, h, d) — each slot's current-token
@@ -279,13 +375,14 @@ def _decode_attention(q, k_cache, v_cache, pos, scale: float,
     qpos = pos[:, None, None, None]
     scores = jnp.where(kpos > qpos, NEG_INF, scores)
     scores = _window_mask(scores, kpos, qpos, window)
+    scores = _keep_mask(scores, keep)
     probs = jax.nn.softmax(scores, axis=-1)
     out = _pv(probs.astype(v_cache.dtype), v_cache)
     return out[:, :1]
 
 
 def _paged_chunk_attention(q, kg, vg, qpos, scale: float, kpos=None,
-                           window: int = 0):
+                           window: int = 0, keep=None):
     """Chunked-prefill attention against the gathered page view (the
     paged prefill kernel — docs/serving.md "Paged KV & prefix
     caching").  ``q``: (1, B, h, d) — the chunk's queries at GLOBAL
@@ -307,11 +404,12 @@ def _paged_chunk_attention(q, kg, vg, qpos, scale: float, kpos=None,
     qpos = qpos[None, None, :, None]
     scores = jnp.where(kpos > qpos, NEG_INF, scores)
     scores = _window_mask(scores, kpos, qpos, window)
+    scores = _keep_mask(scores, keep)
     probs = jax.nn.softmax(scores, axis=-1)
     return _pv(probs.astype(vg.dtype), vg)
 
 
-def _verify_window_attention(q, kg, vg, qpos, scale: float):
+def _verify_window_attention(q, kg, vg, qpos, scale: float, keep=None):
     """Speculative-verify attention: a W-position window PER SLOT
     against each slot's gathered page view (docs/serving.md
     "Speculative decoding & sampling").  ``q``: (n, W, h, d) — slot i's
@@ -332,14 +430,16 @@ def _verify_window_attention(q, kg, vg, qpos, scale: float):
     kpos = jnp.arange(kg.shape[1])
     scores = jnp.where(kpos[None, None, None, :]
                        > qpos[:, None, :, None], NEG_INF, scores)
+    scores = _keep_mask(scores, keep)
     probs = jax.nn.softmax(scores, axis=-1)
     return _pv(probs.astype(vg.dtype), vg)
 
 
 def _dense_attention(q, k, v, causal: bool, scale: float,
-                     dropout_rate: float, rng, window: int = 0):
+                     dropout_rate: float, rng, window: int = 0, keep=None):
     """(n,sq,h,d),(n,sk,g,d),(n,sk,g,d) -> (n,sq,h,d); f32 softmax; with
-    ``window`` a query at ``i`` sees keys ``i - window < j <= i`` only."""
+    ``window`` a query at ``i`` sees keys ``i - window < j <= i`` only; with
+    ``keep`` (n, 1, sq, sk) only the keys it marks (:func:`_keep_mask`)."""
     scores = _qk(q, k) * scale
     if causal:
         sq, sk = scores.shape[2], scores.shape[3]
@@ -347,6 +447,7 @@ def _dense_attention(q, k, v, causal: bool, scale: float,
         kpos = jnp.arange(sk)[None, :]
         scores = jnp.where(kpos > qpos, NEG_INF, scores)
         scores = _window_mask(scores, kpos, qpos, window)
+    scores = _keep_mask(scores, keep)
     probs = jax.nn.softmax(scores, axis=-1)
     if dropout_rate > 0.0 and rng is not None:
         keep = 1.0 - dropout_rate
@@ -451,7 +552,7 @@ class MultiHeadAttention(Op):
     def __init__(self, name, query, key, value, embed_dim, num_heads,
                  kdim=0, vdim=0, dropout=0.0, use_bias=True, causal=False,
                  kernel_initializer=None, num_kv_heads=None, head_dim=None,
-                 rope=None, gate=False, window=0):
+                 rope=None, gate=False, window=0, qk_norm=None, sparse=None):
         """Beyond the defaults (as many key/value heads as query heads,
         ``head_dim = embed_dim / num_heads``, learned positions elsewhere):
         ``num_kv_heads`` key/value heads shared by groups of ``num_heads /
@@ -462,7 +563,16 @@ class MultiHeadAttention(Op):
         before the cores and before the cache's scatter; ``gate``, a
         per-head sigmoid gate ``sigmoid(x Wg)_h`` on the attention output;
         ``window``, causal attention over the last ``window`` positions
-        only (and a cache that holds no more: :meth:`serve_state`)."""
+        only (and a cache that holds no more: :meth:`serve_state`);
+        ``qk_norm``, the eps of an RMSNorm over ``head_dim`` on every query
+        and key head between the projection and the rotation (one learned
+        scale each, shared by the heads); ``sparse``, ``{"index_heads",
+        "index_dim", "topk"}`` (and ``"eps"``, of the LayerNorm on the
+        indexer's key, 1e-6 unless given): a learned INDEXER scores every cached
+        position for each query (:func:`index_scores`) and the heads attend
+        over the ``topk`` best only (all of them while the history is
+        shorter), with a third cache leaf for the indexer's one key head
+        (:meth:`_serve_step_sparse`)."""
         inputs = [query] if key is query and value is query else [
             query, key, value]
         super().__init__(name, inputs)
@@ -488,8 +598,11 @@ class MultiHeadAttention(Op):
         # what the flash kernels and the ring take: one head count, no
         # window, positions from elsewhere (ROADMAP M1/M2: their training
         # forms of grouped heads and of the window are not written)
+        self.qk_norm = None if qk_norm is None else float(qk_norm)
+        self.sparse = dict(sparse) if sparse else None
         self._plain = (self.num_kv_heads == num_heads and not self.window
-                       and self.rope is None and not self.gate)
+                       and self.rope is None and not self.gate
+                       and self.qk_norm is None and self.sparse is None)
         # {training: core} as last traced (see _attend)
         self.kernel_cores = {}
         # "paged" or "gathered": the decode core serve_step("token") got,
@@ -512,6 +625,37 @@ class MultiHeadAttention(Op):
         if use_bias:
             self.w_bias = self._add_weight((embed_dim,), ZeroInitializer(),
                                            "bias")
+        one = ConstantInitializer(1.0)
+        if self.qk_norm is not None:
+            self.w_qn = self._add_weight((self.head_dim,), one, "q_norm")
+            self.w_kn = self._add_weight((self.head_dim,), one, "k_norm")
+        if self.sparse:
+            assert causal and self._self_attn and not self.window, (
+                "a learned selection needs causal self-attention, no window")
+            hi, di = (int(self.sparse["index_heads"]),
+                      int(self.sparse["index_dim"]))
+            self.index_heads, self.index_dim = hi, di
+            self.topk = int(self.sparse["topk"])
+            self.index_eps = float(self.sparse.get("eps", 1e-6))
+            # the indexer's key as stored: whole lane tiles, the padding
+            # zero (what the TPU's tiled memory holds for it anyway)
+            self.index_width = -(-di // _LANES) * _LANES
+            # the indexer turns its whole head, plainly, at the op's theta
+            self.index_rope = (None if self.rope is None else
+                               {"rope_theta": self.rope["rope_theta"]})
+            # the parts an owner table tells apart (``Op.scopes``); outside
+            # them: q/k/v, the K/V write, the output projection
+            self.scopes = ("dsa_index", "dsa_select", "dsa_core")
+            self.decode_kind = "sparse"
+            # {chunk bucket: "mask" | "loop" | "dense"}, as traced
+            self.chunk_core = {}
+            self.w_iq = self._add_weight((hi * di, dq), init, "wiq",
+                                         sharded_dim=0)
+            self.w_ik = self._add_weight((di, dq), init, "wik")
+            self.w_iw = self._add_weight((hi, dq), init, "wiw")
+            self.w_ikn = self._add_weight((di,), one, "ik_norm")
+            self.w_ikb = self._add_weight((di,), ZeroInitializer(),
+                                          "ik_bias")
 
     def _wants_ring(self, ctx: OpContext) -> bool:
         pc = self.parallel_config
@@ -542,10 +686,45 @@ class MultiHeadAttention(Op):
 
         q, k = (proj(xq, self.w_q, self.num_heads),
                 proj(xk, self.w_k, self.num_kv_heads))
+        if self.qk_norm is not None:    # between projection and rotation
+            q = cast_compute(rms_normalize(q, params[self.w_qn.name],
+                                           self.qk_norm), ctx)
+            k = cast_compute(rms_normalize(k, params[self.w_kn.name],
+                                           self.qk_norm), ctx)
         if self.rope is not None:
             q, k = (apply_rope(q, positions, self.rope),
                     apply_rope(k, positions, self.rope))
         return q, k, proj(xv, self.w_v, self.num_kv_heads)
+
+    def _index(self, params, x, positions, ctx):
+        """The indexer's three projections of ``x`` (n, s, d), beside
+        :meth:`_qkv` and shared like it by forward and every serving step:
+        ``(qI (n, s, Hi, di), kI (n, s, di), w (n, s, Hi) f32)`` — ``kI``
+        is ONE key head, LayerNorm-ed (scale and bias) before its rotation;
+        ``w`` weighs the index heads from the token itself, the two
+        ``** -0.5`` factors folded in."""
+        with jax.named_scope("dsa_index"):
+            n, s, _ = x.shape
+            hi, di = self.index_heads, self.index_dim
+
+            def proj(w):
+                return jnp.einsum("nsi,oi->nso", x,
+                                  cast_compute(params[w.name], ctx),
+                                  preferred_element_type=jnp.float32)
+
+            qi = cast_compute(proj(self.w_iq), ctx).reshape(n, s, hi, di)
+            kf = proj(self.w_ik)
+            mu = jnp.mean(kf, axis=-1, keepdims=True)
+            var = jnp.mean(jnp.square(kf - mu), axis=-1, keepdims=True)
+            ki = cast_compute((kf - mu) * jax.lax.rsqrt(var + self.index_eps)
+                              * params[self.w_ikn.name]
+                              + params[self.w_ikb.name], ctx)
+            wi = proj(self.w_iw) * (hi ** -0.5 * di ** -0.5)
+            if self.index_rope is not None:
+                qi = apply_rope(qi, positions, self.index_rope)
+                ki = apply_rope(ki[:, :, None, :], positions,
+                                self.index_rope)[:, :, 0, :]
+            return qi, ki, wi
 
     def _out_proj(self, params, attn, n, sq, ctx, xq=None):
         """The context -> embed output projection (+bias), shared by
@@ -575,12 +754,24 @@ class MultiHeadAttention(Op):
         rng = None
         if ctx.training and self.dropout > 0.0 and ctx.rng is not None:
             rng = jax.random.fold_in(ctx.rng, self.outputs[0].uid)
+        keep = None
+        if self.sparse and sq > self.topk:
+            # the dense core under a mask from the chosen sets (training
+            # and ``predict``; the serving steps have forms of their own)
+            qi, ki, wi = self._index(params, xq, jnp.arange(sq), ctx)
+            pos = jnp.arange(sq)
+            with jax.named_scope("dsa_index"):
+                scores = jnp.where(pos[None, :] > pos[:, None], NEG_INF,
+                                   index_scores(qi, ki, wi))
+            with jax.named_scope("dsa_select"):
+                keep = selected(scores, pos, *select_threshold(
+                    scores, self.topk))[:, None]
         attn = self._attend(q, k, v, ctx, rng, ctx.training,
-                            self._wants_ring(ctx))
+                            self._wants_ring(ctx), keep)
         return [self._out_proj(params, attn, n, sq, ctx, xq)]
 
     def _attend(self, q, k, v, ctx: OpContext, rng=None,
-                training: bool = False, ring: bool = False):
+                training: bool = False, ring: bool = False, keep=None):
         """The full-sequence attention core, chosen from what the
         operands look like, and noted at trace time in
         ``self.kernel_cores[training]`` (``"ring"``, ``"owned"``,
@@ -598,8 +789,10 @@ class MultiHeadAttention(Op):
             attn = _flash_attention(q, k, v, self.causal, scale, ctx.mesh)
         else:
             core = "dense"
-            attn = _dense_attention(q, k, v, self.causal, scale, dropout,
-                                    rng, self.window)
+            with (jax.named_scope("dsa_core") if self.sparse
+                  else contextlib.nullcontext()):
+                attn = _dense_attention(q, k, v, self.causal, scale, dropout,
+                                        rng, self.window, keep)
         self.kernel_cores[training] = core
         return attn
 
@@ -648,6 +841,18 @@ class MultiHeadAttention(Op):
                "dtype": "compute"}
         if self.window:
             out["window"] = self.window
+        if self.sparse:
+            # a third page-major leaf in the SHARED pool: the indexer's one
+            # key head, ``index_dim`` values a token stored ``index_width``
+            # wide; and what the op counts of its choosing, on the device
+            # (``counts`` rows: queries, of them with a history no longer
+            # than ``topk``, positions chosen, positions live; each a
+            # ``[high, low]`` pair, :func:`_add_wide`)
+            out["shapes"]["ik"] = (num_pages, page_size, self.index_width)
+            out["entries"]["ik"] = (None, None, None)
+            out["values"] = {"ik": self.index_dim}
+            out["counters"] = {"shapes": {"counts": (4, 2)},
+                               "entries": {"counts": (None, None)}}
         return out
 
     def serve_check(self, max_seq):
@@ -699,7 +904,7 @@ class MultiHeadAttention(Op):
         n, w, _ = xq.shape
         chunk, token = where.kind == "chunk", where.kind == "token"
         positions = None
-        if self.rope is not None or self.window:
+        if self.rope is not None or self.window or self.sparse:
             positions = ((where.start + jnp.arange(w))[None] if chunk
                          else where.pos[:, None] + jnp.arange(w)[None, :])
         q, k, v = self._qkv(params, xq, xq, xq, ctx, positions)
@@ -707,18 +912,14 @@ class MultiHeadAttention(Op):
             return self._serve_step_window(params, xq, q, k, v, state,
                                            where, positions, ctx)
         k_pool, v_pool = state["k"], state["v"]
-        if chunk:
-            page = k_pool.shape[1]
-            no_page = k_pool.shape[0]
-            qpos = where.start + jnp.arange(w)
-            # mode="clip" everywhere: the sentinel id is OOB by design, and
-            # jnp.take's default "fill" mode would gather NaN — which the
-            # exact-zero mask multiplies to NaN, not zero
-            wp = jnp.take(where.table, qpos // page, mode="clip")
-            wp = jnp.where(jnp.arange(w) < where.length, wp, no_page)
-            wr = qpos % page
-        else:
-            wp, wr = where.write_pages, where.write_rows
+        # a learned selection can leave something out only of a table
+        # longer than its ``topk``: under it the op IS dense attention
+        if self.sparse and where.table.shape[-1] * k_pool.shape[1] \
+                > self.topk:
+            return self._serve_step_sparse(params, xq, q, k, v, state,
+                                           where, positions, ctx)
+        qpos = where.start + jnp.arange(w) if chunk else None
+        wp, wr = self._write_indices(where, qpos, k_pool)
 
         def rows(kv):
             # the new rows as the write indices address them, folded
@@ -748,8 +949,27 @@ class MultiHeadAttention(Op):
             else:
                 qpos = where.pos[:, None] + jnp.arange(w)[None, :]
                 attn = _verify_window_attention(q, kg, vg, qpos, scale)
-        return ([self._out_proj(params, attn, n, w, ctx, xq)],
-                {"k": k_pool, "v": v_pool})
+        new = dict(state, k=k_pool, v=v_pool)
+        if self.sparse:     # chose nothing: every live position was read
+            if chunk:
+                self.chunk_core[w] = "dense"
+            new = self._counted(new, where, positions, None)
+        return [self._out_proj(params, attn, n, w, ctx, xq)], new
+
+    @staticmethod
+    def _write_indices(where, qpos, pool):
+        """``(write pages, write rows)`` of a step's new rows: the host's for
+        a token step or a window; for a chunk at positions ``qpos`` computed
+        here from the slot's table row, pad rows sent to the sentinel."""
+        if where.kind != "chunk":
+            return where.write_pages, where.write_rows
+        no_page, page = pool.shape[:2]
+        # mode="clip" everywhere: the sentinel id is OOB by design, and
+        # jnp.take's default "fill" mode would gather NaN — which the
+        # exact-zero mask multiplies to NaN, not zero
+        wp = jnp.take(where.table, qpos // page, mode="clip")
+        wp = jnp.where(jnp.arange(qpos.shape[0]) < where.length, wp, no_page)
+        return wp, qpos % page
 
     def _serve_step_window(self, params, xq, q, k, v, state, where,
                            positions, ctx: OpContext):
@@ -819,6 +1039,252 @@ class MultiHeadAttention(Op):
         return ([self._out_proj(params, attn, n, w, ctx, xq)],
                 {"k": k_ring, "v": v_ring})
 
+
+    def _serve_step_sparse(self, params, xq, q, k, v, state, where,
+                           positions, ctx: OpContext):
+        """The step of an op with a learned selection (``sparse=``) over a
+        table longer than ``topk``.  ``state``: ``{"k", "v", "ik",
+        "counts"}`` — the indexer's key of every position is written to
+        ``ik`` with the indices that write ``k`` and ``v``, so it pages,
+        shares prefixes, rolls back and migrates with them.  Each query
+        scores every live position of its slot (``dsa_index``:
+        :func:`index_scores` in f32 against the rows of ``ik``), chooses its
+        ``topk`` best (``dsa_select``: EXACT, of equal scores the lower
+        position first; every live position while there are no more than
+        ``topk``) and attends over the chosen ones only (``dsa_core``).
+        The forms, read off the step's kind and the shapes at trace time,
+        never a flag:
+
+        * ``"chunk"`` over a table of one key block (``_KEY_BLOCK``) at
+          most: :func:`_paged_chunk_attention` under the chosen sets as a
+          MASK (``chunk_core`` ``"mask"``), bit for bit the dense op while
+          the history is under ``topk``; over a longer table
+          :meth:`_sparse_over_blocks` (``"loop"``): scores and core a block
+          of keys at a time, never ``(heads, chunk, max_seq)`` at once;
+        * ``"token"``: where :meth:`_decode_core` says the in-place read
+          applies (a TPU, one device) the chosen ROWS are copied out of the
+          pools, ``topk`` a slot, and attended over densely
+          (``decode_core`` ``"rows"``: the core then reads ``topk`` rows a
+          slot whatever the history); elsewhere the slot's whole view under
+          a mask (``"gathered"``), bit for bit the dense op under ``topk``;
+        * ``"window"``: each row its own set, as a mask over the views,
+          bit for bit on the CPU the sequential token step's."""
+        n, w = xq.shape[:2]
+        chunk, token = where.kind == "chunk", where.kind == "token"
+        k_pool, v_pool, i_pool = state["k"], state["v"], state["ik"]
+        table = where.table[None] if chunk else where.table      # (n, pps)
+        L = table.shape[1] * k_pool.shape[1]
+        wp, wr = self._write_indices(where, positions[0] if chunk else None,
+                                     k_pool)
+
+        def rows(x):    # the new rows as the write indices address them
+            return x[0] if chunk else x[:, 0] if token else x
+
+        k_pool = k_pool.at[wp, wr].set(rows(self._fold_rows(k)), mode="drop")
+        v_pool = v_pool.at[wp, wr].set(rows(self._fold_rows(v)), mode="drop")
+        qi, ki, wi = self._index(params, xq, positions, ctx)
+        with jax.named_scope("dsa_index"):
+            pad = self.index_width - self.index_dim
+            i_pool = i_pool.at[wp, wr].set(
+                rows(jnp.pad(ki, ((0, 0), (0, 0), (0, pad)))), mode="drop")
+        scale = 1.0 / math.sqrt(self.head_dim)
+        new = dict(state, k=k_pool, v=v_pool, ik=i_pool)
+
+        def index_keys():   # (n, L, di): each slot's view of ``ik``
+            g = jnp.take(i_pool, table, axis=0, mode="clip")
+            return g.reshape(n, L, -1)[..., :self.index_dim]
+
+        if token:
+            self.decode_core = ("rows" if self._decode_core(k_pool, ctx)
+                                == "paged" else "gathered")
+        if chunk:
+            self.chunk_core[w] = "loop" if L > _KEY_BLOCK else "mask"
+        if chunk and L > _KEY_BLOCK:
+            attn, chosen = self._sparse_over_blocks(
+                q, qi, wi, k_pool, v_pool, i_pool, where, scale)
+        elif token and self.decode_core == "rows":
+            with jax.named_scope("dsa_index"):
+                ik = index_keys()
+            attn, chosen = self._sparse_rows(q, qi, wi, k_pool, v_pool, ik,
+                                             where, scale)
+        else:
+            kpos = jnp.arange(L)
+            with jax.named_scope("dsa_index"):
+                # (a token step's one query twice, row 0 kept: a one-row
+                # product drifts an ulp from a window's, _decode_attention)
+                dup = 2 if token else 1
+                scores = index_scores(jnp.tile(qi, (1, dup, 1, 1)),
+                                      index_keys(),
+                                      jnp.tile(wi, (1, dup, 1)))[:, :w]
+                scores = jnp.where(kpos[None, None, :]
+                                   > positions[:, :, None], NEG_INF, scores)
+            with jax.named_scope("dsa_select"):
+                keep = selected(scores, kpos, *select_threshold(
+                    scores, self.topk))
+                chosen = jnp.sum(keep & (scores > NEG_INF / 2)
+                                 & where.live(w)[:, :, None])
+                keep = keep[:, None]                        # (n, 1, w, L)
+            with jax.named_scope("dsa_core"):
+                kg = self._gather_pages(k_pool, table)
+                vg = self._gather_pages(v_pool, table)
+                if chunk:
+                    attn = _paged_chunk_attention(q, kg, vg, positions[0],
+                                                  scale, keep=keep)
+                elif token:
+                    attn = _decode_attention(q, kg, vg, where.pos, scale,
+                                             keep=keep)
+                else:
+                    attn = _verify_window_attention(q, kg, vg, positions,
+                                                    scale, keep=keep)
+        return ([self._out_proj(params, attn, n, w, ctx, xq)],
+                self._counted(new, where, positions, chosen))
+
+    def _counted(self, state, where, positions, chosen):
+        """``state`` with the step added to the op's counters, where the
+        engine keeps them (``counts``: queries, of them with no more than
+        ``topk`` live positions, positions ``chosen`` (``None``: all that
+        were live), positions live; live rows of the step only)."""
+        if "counts" not in state:
+            return state
+        live = where.live(positions.shape[1])
+        seen = jnp.where(live, positions + 1, 0)
+        step = jnp.stack([
+            jnp.sum(live), jnp.sum(live & (seen <= self.topk)),
+            jnp.sum(seen) if chosen is None else chosen, jnp.sum(seen)])
+        return dict(state, counts=_add_wide(state["counts"],
+                                            step.astype(jnp.int32)))
+
+    def selection_stats(self, counts):
+        """The op's counters as fetched (``counts`` (4, 2), :meth:`_counted`)
+        -> ``{"topk", "queries", "dense_queries", "chosen_mean",
+        "live_mean"}``: a mean is over the live queries."""
+        import numpy as np
+        wide = np.asarray(counts, np.int64)
+        queries, dense, chosen, live = (
+            int(v) for v in (wide[:, 0] << _WIDE) + wide[:, 1])
+        return {"topk": self.topk, "queries": queries,
+                "dense_queries": dense,
+                "chosen_mean": chosen / queries if queries else 0.0,
+                "live_mean": live / queries if queries else 0.0}
+
+    def _sparse_rows(self, q, qi, wi, k_pool, v_pool, ik, where, scale):
+        """A token step that COPIES the chosen rows: each slot's one query
+        scores its slot's view of ``ik`` (n, L, di), ``jax.lax.top_k`` names
+        the ``topk`` best positions, their K and V rows are gathered out of
+        the pools where they lie (``topk`` rows a slot, whatever the
+        history) and :func:`_decode_attention` runs over them, each row at
+        the position it holds; a slot with fewer live positions than
+        ``topk`` gets dead rows, masked.  -> (attention (n, 1, h, d), the
+        count of live rows chosen by decoding slots)."""
+        n, page = q.shape[0], k_pool.shape[1]
+        pos = where.pos
+        with jax.named_scope("dsa_index"):
+            scores = index_scores(qi, ik, wi)[:, 0]                # (n, L)
+            scores = jnp.where(jnp.arange(scores.shape[1])[None, :]
+                               > pos[:, None], NEG_INF, scores)
+        with jax.named_scope("dsa_select"):
+            vals, idx = jax.lax.top_k(scores, self.topk)
+            alive = vals > NEG_INF / 2
+            chosen = jnp.sum(alive & where.live(1))
+            # a dead row stands at a position no query reaches
+            kpos = jnp.where(alive, idx, jnp.iinfo(jnp.int32).max)
+            pid = jnp.take_along_axis(where.table, idx // page, axis=1)
+        with jax.named_scope("dsa_core"):
+            def rows(pool):
+                g = pool.at[pid, idx % page].get(mode="clip")
+                return g.reshape(n, self.topk, self.num_kv_heads,
+                                 self.head_dim)
+            attn = _decode_attention(q, rows(k_pool), rows(v_pool), pos,
+                                     scale, kpos=kpos)
+        return attn, chosen
+
+    def _sparse_over_blocks(self, q, qi, wi, k_pool, v_pool, i_pool, where,
+                            scale):
+        """A prompt chunk's ``B`` queries against a LONG table, a block of
+        ``_KEY_BLOCK`` keys at a time and only the blocks the chunk's last
+        real row can see (the scheme of ``LatentAttention.
+        _over_key_blocks``): first the indexer's scores into a ``(B, L)``
+        f32 buffer (``dsa_index``; the per-head scores live a block long),
+        then each row's threshold over the whole of it (``dsa_select``),
+        then the core under an online softmax with the chosen set as a MASK
+        on blocks read whole (``dsa_core``: f32 statistics, the finite
+        ``NEG_INF``, probabilities rounded to the values' dtype).  A row may
+        keep nothing of a block, the first one too, so masked entries are
+        zeroed explicitly (``exp(NEG_INF - NEG_INF)`` is 1, not 0).
+        -> (attention (1, B, h, d) f32, live positions chosen)."""
+        B, H, hd = q.shape[1:]
+        G = self.num_kv_heads
+        page, no_page = k_pool.shape[1], k_pool.shape[0]
+        block_pages = max(1, _KEY_BLOCK // page)
+        keys = block_pages * page
+        pps = where.table.shape[0]
+        blocks = -(-pps // block_pages)
+        table = jnp.pad(where.table, (0, blocks * block_pages - pps),
+                        constant_values=no_page)
+        qpos = where.start + jnp.arange(B)
+        real = jnp.arange(B) < where.length
+        last = where.start + jnp.maximum(where.length, 1) - 1
+        seen = last // keys + 1
+
+        def block_rows(pool, b):
+            pages = jax.lax.dynamic_slice(table, (b * block_pages,),
+                                          (block_pages,))
+            return jnp.take(pool, pages, axis=0, mode="clip").reshape(
+                keys, pool.shape[-1])
+
+        def dead(b):    # (B, keys): what a row may not see of block b
+            return (b * keys + jnp.arange(keys))[None, :] > qpos[:, None]
+
+        def score(b, buf):
+            with jax.named_scope("dsa_index"):
+                ik = block_rows(i_pool, b)[None, :, :self.index_dim]
+                s = jnp.where(dead(b), NEG_INF, index_scores(qi, ik, wi)[0])
+                return jax.lax.dynamic_update_slice(buf, s, (0, b * keys))
+
+        with jax.named_scope("dsa_index"):
+            buf = jnp.full((B, blocks * keys), NEG_INF, jnp.float32)
+        buf = jax.lax.fori_loop(0, seen, score, buf)
+        with jax.named_scope("dsa_select"):
+            thr, top = select_threshold(buf, self.topk)
+        qg = q[0].reshape(B, G, H // G, hd)
+
+        def one(b, carry):
+            m, l, acc, count = carry
+            with jax.named_scope("dsa_select"):
+                s_i = jax.lax.dynamic_slice(buf, (0, b * keys), (B, keys))
+                kpos = b * keys + jnp.arange(keys)
+                keep = selected(s_i, kpos[None, :], thr, top) & ~dead(b)
+                count = count + jnp.sum(keep & real[:, None])
+            with jax.named_scope("dsa_core"):
+                kb = block_rows(k_pool, b).reshape(keys, G, hd)
+                vb = block_rows(v_pool, b).reshape(keys, G, hd)
+                s = jnp.einsum("qgrd,kgd->grqk", qg, kb,
+                               preferred_element_type=jnp.float32) * scale
+                s = jnp.where(keep[None, None], s, NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+                p = jnp.where(keep[None, None],
+                              jnp.exp(s - m_new[..., None]), 0.0)
+                alpha = jnp.exp(m - m_new)
+                l = alpha * l + jnp.sum(p, axis=-1)
+                acc = alpha[..., None] * acc + jnp.einsum(
+                    "grqk,kgd->grqd", p.astype(vb.dtype), vb,
+                    preferred_element_type=jnp.float32)
+            return m_new, l, acc, count
+
+        r = H // G
+        init = (jnp.full((G, r, B), NEG_INF, jnp.float32),
+                jnp.zeros((G, r, B), jnp.float32),
+                jnp.zeros((G, r, B, hd), jnp.float32),
+                jnp.zeros((), jnp.int32))
+        _, l, acc, count = jax.lax.fori_loop(0, seen, one, init)
+        with jax.named_scope("dsa_core"):
+            # every row, a pad row too, keeps at least one of the positions
+            # it sees (a row's topk is never empty), so l > 0; the guard is
+            # against a row the loop never reached
+            out = acc / jnp.maximum(l, 1e-30)[..., None]
+            return (jnp.transpose(out, (2, 0, 1, 3)).reshape(1, B, H, hd),
+                    count)
+
     def _decode_core(self, pool, ctx: OpContext) -> str:
         """``"paged"`` where the token step can read the pool in place
         (:mod:`paged_decode_kernel`, from what the code can see: backend,
@@ -858,8 +1324,12 @@ class MultiHeadAttention(Op):
             self.inputs[1].shape[1]
         if self.window:
             sk = min(sk, self.window)
+        index = 0
+        if self.sparse:     # every pair scored, the topk best attended over
+            index = 2 * n * s * sk * self.index_heads * self.index_dim
+            sk = min(sk, self.topk)
         scores = 2 * 2 * n * s * sk * self.q_dim   # qk^T and probs*v
-        return proj + scores
+        return proj + scores + index
 
     def internal_io_bytes(self, flash_attention=None):
         """Mirrors ``_attend``'s full selection (the cost model must
@@ -892,7 +1362,12 @@ class MultiHeadAttention(Op):
         # this term the attn768 forward under-predicted ~3x; with it the
         # round-5 attn768 row agrees within 5% (seed CalibrationTable,
         # search/calibration_seed.json attention row).
-        return 12 * n * self.num_heads * sq * sk
+        index = 0
+        if self.sparse and sk > self.topk:
+            # the indexer's per-head scores and their weighted sum, f32,
+            # written and read; the core itself runs dense under a mask
+            index = 8 * n * (self.index_heads + 1) * sq * sk
+        return 12 * n * self.num_heads * sq * sk + index
 
 
 class PositionEmbedding(Op):

@@ -57,7 +57,8 @@ program can be summed by graph op
 int token input; position-wise ops
 (dense/norms/elementwise/softmax/dropout/embedding, a dropless MoE),
 causal self-attention (grouped heads, rotary positions, a window with
-rows of its own; latent attention, one shared row a token in one
+rows of its own; a learned selection of keys, its indexer's keys a third
+page-major leaf; latent attention, one shared row a token in one
 page-major leaf), stateless-init LSTM, learned position
 embeddings, and whatever else writes the contract.  Anything else
 (convs, splits, cross-attention, an MoE with a capacity, pipelines) fails
@@ -76,8 +77,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...analysis.kv_memory import (DEFAULT_PAGE_SIZE, default_num_pages,
-                                   kv_cache_layout, pages_per_slot)
+from ...analysis.kv_memory import (COUNTERS, DEFAULT_PAGE_SIZE,
+                                   default_num_pages, kv_cache_layout,
+                                   pages_per_slot)
 from ...obs.device_ops import (SERVE_OWNERS, stale_cache_error,
                                table_from_hlo, unnamed_owners)
 from ...op import OpContext, ServeStep
@@ -426,11 +428,20 @@ class GraphDecoder:
         new: Dict[str, Dict[str, jax.Array]] = {}
         for op in self.model.layers:
             ins = [values[t.uid] for t in op.inputs]
+            state = caches.get(op.name)
+            # an op that pages AND counts is handed both entries' leaves
+            counted = caches.get(op.name + COUNTERS)
+            if counted is not None:
+                state = dict(state, **counted)
             # metadata at trace time only: the compiled program is the
             # same, and its instructions name the op that owns them
             with jax.named_scope(op.name):
-                outs, state = op.serve_step(params, ins,
-                                            caches.get(op.name), where, ctx)
+                outs, state = op.serve_step(params, ins, state, where, ctx)
+            if counted is not None:
+                new[op.name + COUNTERS] = {leaf: state[leaf]
+                                           for leaf in counted}
+                state = {leaf: val for leaf, val in state.items()
+                         if leaf not in counted}
             if state is not None:
                 new[op.name] = state
             for t, val in zip(op.outputs, outs):
@@ -766,19 +777,26 @@ class GraphDecoder:
         op at trace time (``MultiHeadAttention.decode_core``), like
         ``FFModel.attention_kernels()``; all zero before a token step is
         traced."""
-        def count(ops):
+        def count(ops, kinds=("paged", "gathered")):
             cores = [getattr(op, "decode_core", None) for op in ops]
-            return {core: cores.count(core) for core in ("paged", "gathered")}
+            return {core: cores.count(core) for core in kinds}
 
         out = count(self.model.layers)
         if self.windowed:   # by layer kind, where the graph has two
             out["windowed"] = count(op for op in self.model.layers
                                     if op.name in self.windowed)
-        latent = [op for op in self.model.layers
-                  if getattr(op, "decode_kind", None) == "latent"]
+        latent = self._of_kind("latent")
         if latent:          # one shared row a token: a kind of its own
             out["latent"] = count(latent)
+        sparse = self._of_kind("sparse")
+        if sparse:          # a learned selection: ``"rows"`` copies the
+            # chosen rows out of the pools, ``"gathered"`` masks the view
+            out["sparse"] = count(sparse, ("rows", "gathered"))
         return out
+
+    def _of_kind(self, kind):
+        return [op for op in self.model.layers
+                if getattr(op, "decode_kind", None) == kind]
 
     def chunk_attention(self) -> Dict[str, Dict[str, int]]:
         """How many (latent attention op, chunk program) pairs got which
@@ -786,17 +804,23 @@ class GraphDecoder:
         ``{"latent": {"kernel", "loop"}}`` — ``"kernel"`` keeps a key
         block's expanded keys, values and scores in VMEM
         (:mod:`flexflow_tpu.ops.latent_chunk_kernel`), ``"loop"`` is XLA's
-        loop over key blocks.  Noted by the op at trace time
-        (``LatentAttention.chunk_core``), like :meth:`grouped_product`;
-        ``{}`` for a graph without such an op."""
-        latent = [op for op in self.model.layers
-                  if getattr(op, "decode_kind", None) == "latent"]
-        if not latent:
-            return {}
-        cores = [core for op in latent
-                 for core in tuple(op.chunk_core.values())]
-        return {"latent": {core: cores.count(core)
-                           for core in ("kernel", "loop")}}
+        loop over key blocks — and, for attention with a learned selection,
+        ``{"sparse": {"mask", "gather", "loop", "dense"}}``: the chosen set
+        as a mask over the whole table at once, as gathered rows (no
+        program takes that form yet), as a mask on key blocks under a loop,
+        or nothing to choose (a table no longer than ``topk``).  Noted by
+        the op at trace time (``chunk_core``), like
+        :meth:`grouped_product`; ``{}`` for a graph without such an op."""
+        out = {}
+        for kind, names in (("latent", ("kernel", "loop")),
+                            ("sparse", ("mask", "gather", "loop",
+                                        "dense"))):
+            ops = self._of_kind(kind)
+            if ops:
+                cores = [core for op in ops
+                         for core in tuple(op.chunk_core.values())]
+                out[kind] = {core: cores.count(core) for core in names}
+        return out
 
     def grouped_product(self) -> Dict[str, int]:
         """How many (mixture-of-experts op, serving program) pairs got
@@ -859,6 +883,18 @@ class GraphDecoder:
                                     / (steps * load.size) if steps else 0.0),
                 "load": load.tolist()}
         return out
+
+    def sparse_stats(self, host) -> Dict[str, Dict]:
+        """``{op: {"topk", "queries", "dense_queries", "chosen_mean",
+        "live_mean"}}`` for every attention op that counts its choosing on
+        the device (``<op> + COUNTERS`` entries with a ``counts`` leaf),
+        from ``host``, a token step's counters fetched: the live queries it
+        served (chunk rows, token steps, window rows), those whose history
+        was no longer than ``topk`` (dense attention), and the mean number
+        of positions a query attended over and had live."""
+        ops = {op.name + COUNTERS: op for op in self._of_kind("sparse")}
+        return {ops[n].name: ops[n].selection_stats(c["counts"])
+                for n, c in (host or {}).items() if n in ops}
 
     def moe_totals(self, host) -> Dict[str, int]:
         """What a ``decode_step`` span carries of those counters, summed

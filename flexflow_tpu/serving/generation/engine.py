@@ -1312,7 +1312,7 @@ class GenerationEngine:
                    "drained": dict(self._pipe_drained),
                    "dropped_tokens": self._pipe_dropped}}
         chunk_attention = self._decoder.chunk_attention()
-        if chunk_attention:     # a graph with latent attention only
+        if chunk_attention:     # latent attention, or a learned selection
             out["chunk_attention"] = chunk_attention
         if self._pool_copies is not None:
             out["pool_copies"] = self._pool_copies
@@ -1325,6 +1325,10 @@ class GenerationEngine:
             # at trace time (host memory only, like the rest)
             out["moe"] = {**moe, "grouped_product":
                           self._decoder.grouped_product()}
+        # what attention with a learned selection chose, the same way
+        sparse = self._decoder.sparse_stats(self._counters_host)
+        if sparse:
+            out["sparse_attention"] = sparse
         return out
 
     # ---- dispatcher thread ---------------------------------------------
