@@ -45,6 +45,16 @@ table per device, no token routing.  This op routes tokens:
   pair routed elsewhere adds nothing here (the chips that hold the others
   add theirs; no code stands in for them).  ``flops()``, the counters and
   ``stats()["moe"]`` then speak of the experts HELD;
+* an op that holds FEWER experts than its router scores (``held=``, or a
+  shard under an ``e`` axis) and takes no gradient runs everything after
+  the sort on its OWN pairs only, a block of rows at a time under a loop
+  (:meth:`MoE._experts`, :meth:`MoE.block_rows`): 16 of 256 experts held
+  are about 256 of a 512-token chunk's 4 096 pairs, one block of 512, and
+  the other 3 840 are neither gathered, multiplied, masked nor combined.
+  Exact and dropless for every routing (all 4 096 here would be eight
+  blocks); an op that holds every expert, and any op under a gradient,
+  keeps the one pass over all pairs.  The op notes the form in
+  ``dispatch``, per program traced, as it notes ``grouped_product``;
 * an optional Switch-style load-balancing auxiliary loss
   (``E * sum_e f_e * P_e``) is surfaced through ``ctx.aux_losses`` and added
   to the training objective by the fused step.
@@ -54,10 +64,11 @@ token's result depends on no other token of its step, so ``forward`` on a
 chunk, a token step or a window IS the serving step; an op with a capacity
 refuses (``serve_check``).  What it keeps between steps is a COUNTER, not
 model state: per expert the live tokens it received, the token steps seen
-and the experts those steps left untouched, accumulated on the device; the
-token step returns a copy beside its tokens (``GraphDecoder.step_tokens``),
-which rides the boundary's one fetch, for ``stats()["moe"]`` and the
-``decode_step`` spans.
+and the experts those steps left untouched, and for an op that holds a
+share the pairs that were its own and the blocks they took, accumulated on
+the device; the token step returns a copy beside its tokens
+(``GraphDecoder.step_tokens``), which rides the boundary's one fetch, for
+``stats()["moe"]`` and the ``decode_step`` spans.
 """
 
 from __future__ import annotations
@@ -72,6 +83,10 @@ from jax.sharding import PartitionSpec
 from ..initializers import GlorotUniform, ZeroInitializer
 from ..op import Op, OpContext, OpType
 from .common import apply_activation, cast_compute
+
+
+# entries of a block's one-hot combine, (tokens, rows) float32: 16 MB
+_ONE_HOT = 1 << 22
 
 
 class _PerExpertInit:
@@ -154,6 +169,9 @@ class MoE(Op):
         # MultiHeadAttention.decode_core; a program is ("forward" or a
         # serving step's kind, its tokens)
         self.grouped_product = {}
+        # {program: "whole" or {"rows": C, "of": A}}: the form of the
+        # dispatch each traced program got (_experts), noted the same way
+        self.dispatch = {}
 
     # a dropless op acts on each position alone (serve_check)
     @property
@@ -185,10 +203,10 @@ class MoE(Op):
 
     def _grouped_core(self, xs, w_up, w_dn, ctx: OpContext) -> str:
         """``"rows"`` where both grouped products (operands as they are
-        multiplied) can take the repo's own kernel and it is the right one
-        (:mod:`grouped_matmul_kernel`, from what the code can see:
-        backend, operand dtype, rows a group, lane alignment, one device,
-        no gradient), else ``"library"``."""
+        multiplied; of ``xs`` its shape and dtype) can take the repo's own
+        kernel and it is the right one (:mod:`grouped_matmul_kernel`, from
+        what the code can see: backend, operand dtype, rows a group, lane
+        alignment, one device, no gradient), else ``"library"``."""
         from . import grouped_matmul_kernel
         distributed = ctx.mesh is not None and ctx.mesh.is_distributed
         return "rows" if all(
@@ -197,16 +215,51 @@ class MoE(Op):
                 w.shape[1], w.shape[2], distributed, ctx.training)
             for w in (w_up, w_dn)) else "library"
 
+    @staticmethod
+    def block_rows(tokens: int, k: int, held: int, experts: int) -> int:
+        """Rows of a block of :meth:`_experts`' walk over an op's OWN pairs,
+        from the static shapes: whole row tiles of the grouped kernel
+        (``row_tile`` of the ``tokens * k`` pairs; 16 where the kernel
+        takes no such shape anyway), as many as come nearest TWICE the
+        share the op expects, ``pairs * held / experts``: one at least,
+        all the pairs at most, and no more than keep a block's one-hot
+        combine, ``(tokens, rows)`` float32, within ``_ONE_HOT`` entries.
+        A 512-token chunk's 4 096 pairs on 16 of 256 experts: 256
+        expected, blocks of 512; a token step's 256 pairs: 16 expected,
+        one tile of 128.  Twice, so that an uneven router still ends in
+        one block nearly always while a block costs little more than its
+        rows (the chip's table, blocks of 256 / 512 / 1 024: PERF.md
+        section 5, PR 45)."""
+        from .grouped_matmul_kernel import row_tile
+        pairs = tokens * k
+        tm = row_tile(pairs) or 16
+        tiles = max(1, min(round(2 * pairs * held / experts / tm),
+                           _ONE_HOT // (tokens * tm)))
+        return min(pairs, tm * tiles)
+
     def _experts(self, weights, xt, top_idx, gates, tokens: int, first: int,
                  ctx: OpContext, program):
-        """The routed experts' part of the output, (T, d) f32, from the
-        experts ``first .. first + held`` whose stacked weights
-        ``weights`` are (``held`` of ``num_experts``; every pair routed
-        elsewhere contributes zero here).  ``tokens``: how many tokens
-        share the capacity (the whole batch's, also on a token shard).
-        ``program``: the ``(kind, tokens)`` the core chosen is noted
-        under in ``grouped_product``."""
-        (w_up, w_dn), (b_up, b_dn) = weights[:2], weights[2:] or (None, None)
+        """``(part, ran)``: the routed experts' part of the output, (T, d)
+        f32, from the experts ``first .. first + held`` whose stacked
+        weights ``weights`` are (``held`` of ``num_experts``; every pair
+        routed elsewhere contributes zero here).  ``tokens``: how many
+        tokens share the capacity (the whole batch's, also on a token
+        shard).  ``program``: the ``(kind, tokens)`` under which the core
+        chosen is noted in ``grouped_product`` and the form in
+        ``dispatch``.
+
+        Two forms, by what the code can see.  An op that holds EVERY
+        expert, or one a gradient is taken through (a loop with a
+        data-dependent trip count has no reverse mode), runs everything
+        after the sort once over all ``A = T * k`` pairs (``"whole"``;
+        ``ran`` is ``None``).  An op that holds FEWER than the router
+        scores has only ``own`` of them, at the front of the rolled order:
+        it takes those :meth:`block_rows` at a time, ``ceil(own / C)``
+        blocks and none where ``own`` is 0 (``{"rows": C, "of": A}``;
+        ``ran`` is ``(own, blocks)``, int32 scalars).  Exact and dropless
+        for every routing: an op sent all ``A`` pairs runs ``A / C``
+        blocks."""
+        w_up, w_dn = weights[:2]
         held = w_up.shape[0]
         E, k = self.num_experts, self.k
         T = xt.shape[0]
@@ -222,28 +275,77 @@ class MoE(Op):
             rank = jnp.arange(A) - starts[expert]
             weight = jnp.where(rank < cap, weight, 0.0)
         if held != E:
-            # this shard's groups to the front; the rest computes nothing
-            # it keeps (rows past the held groups are masked below)
+            # this shard's groups to the front: its pairs are rows
+            # 0 .. own of the rolled order, grouped by expert
             offset = starts[first]
             order = jnp.roll(order, -offset)
             expert = jnp.roll(expert, -offset)
             weight = jnp.roll(weight, -offset)
             counts = jax.lax.dynamic_slice(counts, (first,), (held,))
-        mine = jnp.arange(A) < jnp.sum(counts)
-        token = order // k
-        xs = xt[token]                                           # (A, d)
-        w_up, w_dn = cast_compute(w_up, ctx), cast_compute(w_dn, ctx)
+        weights = (cast_compute(w_up, ctx), cast_compute(w_dn, ctx),
+                   *weights[2:])
+        rows = functools.partial(self._rows, xt, weights, first, ctx,
+                                 program)
+        if held == E or ctx.training:
+            self.dispatch[program] = "whole"
+            token, y = rows(order, expert, weight, counts,
+                            jnp.arange(A) < jnp.sum(counts))
+            return jnp.zeros((T, xt.shape[1]), jnp.float32).at[token].add(
+                y), None
+        C = self.block_rows(T, k, held, E)
+        self.dispatch[program] = {"rows": C, "of": A}
+        own = jnp.sum(counts)
+        # a last block may reach past A: its rows are past ``own`` too
+        order, expert, weight = (jnp.pad(v, (0, -A % C))
+                                 for v in (order, expert, weight))
+        ends = jnp.cumsum(counts)
+        begins = ends - counts
+
+        def block(i, part):
+            lo = i * C
+            o, e, w = (jax.lax.dynamic_slice(v, (lo,), (C,))
+                       for v in (order, expert, weight))
+            # each held group's overlap with rows lo .. lo + C
+            sizes = jnp.maximum(jnp.minimum(ends, lo + C)
+                                - jnp.maximum(begins, lo), 0)
+            token, y = rows(o, e, w, sizes, lo + jnp.arange(C) < own)
+            # a row of y to its token's row of the part, as a product
+            # with a one-hot: exact (ones and zeros), and on a TPU a
+            # fraction of what a scatter-add costs, which goes a
+            # destination row at a time (a block of 512 rows of 7 680:
+            # 0.07 against 1.37 ms, my chip run, PR 45)
+            hot = token[None, :] == jnp.arange(T)[:, None]
+            return part + jnp.dot(hot.astype(jnp.float32), y,
+                                  precision=jax.lax.Precision.HIGHEST)
+
+        blocks = (own + C - 1) // C
+        part = jax.lax.fori_loop(0, blocks, block,
+                                 jnp.zeros((T, xt.shape[1]), jnp.float32))
+        return part, (own, blocks)
+
+    def _rows(self, xt, weights, first: int, ctx: OpContext, program,
+              order, expert, weight, sizes, mine):
+        """``(token (R,), y (R, d) f32)``: ``R`` pairs of the sorted order
+        through their experts (``weights``: the two products' in the
+        compute dtype), weighted, to be added to their tokens' rows.
+        ``order`` / ``expert`` / ``weight``: the pairs, grouped by expert in
+        groups of ``sizes`` (held,); ``mine`` (R,): the rows that ARE pairs
+        of a held expert (the rest compute nothing that is kept)."""
+        (w_up, w_dn), (b_up, b_dn) = weights[:2], weights[2:] or (None, None)
+        held, R = w_up.shape[0], order.shape[0]
+        token = order // self.k
+        xs = xt[token]                                           # (R, d)
         core = self._grouped_core(xs, w_up, w_dn, ctx)
         self.grouped_product[program] = core
         if core == "rows":
             from .grouped_matmul_kernel import ragged_dot_rows, visits
             grouped = functools.partial(ragged_dot_rows,
-                                        walk=visits(counts, A))
+                                        walk=visits(sizes, R))
         else:
             grouped = functools.partial(jax.lax.ragged_dot,
                                         preferred_element_type=jnp.float32)
         with jax.named_scope("moe_experts"):
-            h = grouped(xs, w_up, counts)
+            h = grouped(xs, w_up, sizes)
             local = jnp.clip(expert - first, 0, held - 1)
             if self.gated:
                 f = self.d_ff
@@ -252,11 +354,10 @@ class MoE(Op):
                 h = apply_activation(
                     h + b_up.astype(h.dtype)[local], self.activation)
             h = jnp.where(mine[:, None], cast_compute(h, ctx), 0)
-            y = grouped(h, w_dn, counts)
+            y = grouped(h, w_dn, sizes)
             if not self.gated:
                 y = y + b_dn.astype(y.dtype)[local]
-        y = jnp.where(mine[:, None], y, 0.0) * weight[:, None]
-        return jnp.zeros((T, xt.shape[1]), jnp.float32).at[token].add(y)
+        return token, jnp.where(mine[:, None], y, 0.0) * weight[:, None]
 
     def _shared(self, params, xt, ctx: OpContext):
         with jax.named_scope("moe_shared"):
@@ -270,9 +371,11 @@ class MoE(Op):
                               preferred_element_type=jnp.float32)
 
     def _moe(self, params, x, ctx: OpContext, kind: str = "forward"):
-        """``(out (n, s, d), top_idx (T, k), probs (T, E))``.  ``kind``:
-        which program this is traced into (a serving step's, or
-        ``"forward"``), for ``grouped_product``."""
+        """``(out (n, s, d), top_idx (T, k), probs (T, E), ran)``.
+        ``kind``: which program this is traced into (a serving step's, or
+        ``"forward"``), for ``grouped_product`` and ``dispatch``.  ``ran``:
+        :meth:`_experts`' ``(own, blocks)`` of an op TOLD what it holds,
+        else ``None`` (the shards of an ``e`` axis keep theirs)."""
         n, s, d = x.shape
         T, E = n * s, self.num_experts
         program = (kind, T)
@@ -299,8 +402,8 @@ class MoE(Op):
             held = E // shards
 
             def body(xt, top_idx, gates, first, *w):
-                part = self._experts(w, cast_compute(xt, ctx), top_idx,
-                                     gates, T, first[0], ctx, program)
+                part, _ = self._experts(w, cast_compute(xt, ctx), top_idx,
+                                        gates, T, first[0], ctx, program)
                 return jax.lax.psum(part, e_axes)
 
             rows = PartitionSpec(t_axes, None)
@@ -322,16 +425,17 @@ class MoE(Op):
                 + specs, out_specs=rows, check_vma=False, **where)(
                     xt.astype(jnp.float32) if inside else xt, top_idx,
                     gates, firsts, *weights)
+            ran = None
         else:
-            routed = self._experts(weights, xt, top_idx, gates, T,
-                                   self.first, ctx, program)
+            routed, ran = self._experts(weights, xt, top_idx, gates, T,
+                                        self.first, ctx, program)
         out = routed
         if self.shared_d_ff:
             out = out + self._shared(params, xt, ctx)
-        return cast_compute(out, ctx).reshape(n, s, d), top_idx, probs
+        return cast_compute(out, ctx).reshape(n, s, d), top_idx, probs, ran
 
     def forward(self, params, inputs, ctx: OpContext):
-        out, top_idx, probs = self._moe(params, inputs[0], ctx)
+        out, top_idx, probs, _ = self._moe(params, inputs[0], ctx)
         if ctx.training and self.aux_loss_weight > 0.0:
             # Switch load-balance loss: E * sum_e (token fraction * mean
             # router prob); differentiable through P_e
@@ -343,6 +447,9 @@ class MoE(Op):
         return [out]
 
     # ---- serving --------------------------------------------------------
+    # what an op that holds a share counts of its dispatch (serve_state)
+    _DISPATCHED = ("dispatches", "routed", "own_pairs", "blocks", "past_one")
+
     def serve_check(self, max_seq):
         if self.capacity_factor is not None:
             raise ValueError(
@@ -356,13 +463,38 @@ class MoE(Op):
         (held,), the live tokens each received (prompt chunks and token
         steps); ``token_steps``, the token steps that served anybody; and
         ``untouched``, summed over those steps, the held experts no live
-        token of the step chose."""
+        token of the step chose.  An op told to hold FEWER experts than
+        its router scores counts its dispatch too (:meth:`_experts`), over
+        every step of any kind, pad rows and idle slots included (they
+        are dispatched like the rest): ``routed``, the tokens of those
+        ``dispatches``; ``own_pairs``, the (token, choice) pairs that were
+        this op's; ``blocks``, the blocks they took; ``past_one``, the
+        steps that took more than one."""
+        scalars = ("token_steps", "untouched") + (
+            () if self.held == self.num_experts else self._DISPATCHED)
         return {"kind": "counter",
-                "shapes": {"load": (self.held,), "token_steps": (),
-                           "untouched": ()},
-                "entries": {"load": (None,), "token_steps": (),
-                            "untouched": ()},
+                "shapes": {"load": (self.held,), **{n: () for n in scalars}},
+                "entries": {"load": (None,), **{n: () for n in scalars}},
                 "dtype": "i32"}
+
+    def dispatch_stats(self, counters) -> dict:
+        """What ``stats()["moe"]`` says of this op's dispatch, from its
+        counters as fetched: ``"dispatch"``, the form each serving program
+        was traced with (``{"<kind>:<tokens>": "whole" or {"rows": C,
+        "of": A}}``), and for an op that holds a share ``own_share`` (of
+        all pairs routed, the op's own), ``blocks_per_step`` and
+        ``past_one_block_share`` (the steps that needed a second block)."""
+        out = {"dispatch": {f"{kind}:{tokens}": form for (kind, tokens), form
+                            in tuple(self.dispatch.items())
+                            if kind != "forward"}}
+        if "own_pairs" in counters:
+            c = {n: int(counters[n]) for n in self._DISPATCHED}
+            steps = max(c["dispatches"], 1)
+            pairs = max(c["routed"], 1) * self.k
+            out.update(own_share=c["own_pairs"] / pairs,
+                       blocks_per_step=c["blocks"] / steps,
+                       past_one_block_share=c["past_one"] / steps)
+        return out
 
     def _held_index(self, expert):
         """Expert numbers -> indices among the experts held; a choice that
@@ -374,7 +506,7 @@ class MoE(Op):
                          self.held)
 
     def serve_step(self, params, inputs, state, where, ctx: OpContext):
-        out, top_idx, _ = self._moe(params, inputs[0], ctx, where.kind)
+        out, top_idx, _, ran = self._moe(params, inputs[0], ctx, where.kind)
         if state is None:
             return [out], state
         live = where.live(inputs[0].shape[1]).reshape(-1)          # (T,)
@@ -383,6 +515,12 @@ class MoE(Op):
             self._held_index(top_idx.reshape(-1))].add(
                 jnp.repeat(live.astype(jnp.int32), self.k))
         new = dict(state, load=state["load"] + picks)
+        if "own_pairs" in state:
+            own, blocks = ran
+            step = (1, live.shape[0], own, blocks, (blocks > 1).astype(
+                jnp.int32))
+            new.update({n: state[n] + v
+                        for n, v in zip(self._DISPATCHED, step)})
         if where.kind == "token":
             served = jnp.any(live).astype(jnp.int32)
             new["token_steps"] = state["token_steps"] + served

@@ -865,7 +865,9 @@ class GraphDecoder:
         fetched.  All of it is said of the experts the op HOLDS (``held``
         of them: every expert, or this chip's share of an expert-parallel
         deployment): the pairs that fell on them, the largest load over
-        their mean, the share of (token step, held expert) nobody chose."""
+        their mean, the share of (token step, held expert) nobody chose;
+        and what the op says of its dispatch (``MoE.dispatch_stats``)."""
+        ops = {op.name: op for op in self.model.layers}
         out = {}
         for n, c in (host or {}).items():
             if "load" not in c:
@@ -881,7 +883,7 @@ class GraphDecoder:
                 "token_steps": steps,
                 "untouched_share": (float(c["untouched"])
                                     / (steps * load.size) if steps else 0.0),
-                "load": load.tolist()}
+                "load": load.tolist(), **ops[n].dispatch_stats(c)}
         return out
 
     def sparse_stats(self, host) -> Dict[str, Dict]:

@@ -395,3 +395,141 @@ def test_the_counters_of_a_share_speak_of_the_experts_held():
     np.testing.assert_array_equal(np.asarray(new["load"]), want)
     assert int(new["token_steps"]) == 1
     assert int(new["untouched"]) == int((want == 0).sum())
+
+
+# ---------------------------------------------------------------------------
+# an op that holds FEWER experts than its router scores dispatches its own
+# pairs only, a block of rows at a time (ISSUE 45)
+# ---------------------------------------------------------------------------
+_OWN = {"tokens": 50, "d": 24, "experts": 16, "k": 4, "first": 4, "held": 4}
+_OWN_BLOCK = 96     # MoE.block_rows(50, 4, 4, 16): 16-row tiles, 6.25 -> 6
+
+
+def _rigged_share(own, gated, capacity_factor=None):
+    """``(op, params, x (1, 50, d))`` of an op that holds experts 4-7 of 16
+    with the ROUTER's weights set (row ``e`` reads input value ``e`` alone)
+    and the inputs carrying each token's scores, so that exactly ``own`` of
+    the 50 x 4 pairs fall on the held experts, spread over the tokens."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.ops.moe import MoE
+    from flexflow_tpu.tensor import Tensor
+
+    T, d, E, k = (_OWN[n] for n in ("tokens", "d", "experts", "k"))
+    first, held = _OWN["first"], _OWN["held"]
+    rng = np.random.default_rng(own)
+    op = MoE("moe", Tensor(shape=(1, T, d), dtype="float32", name="x"), E, 12,
+             k=k, capacity_factor=capacity_factor, aux_loss_weight=0.0,
+             gated=gated, held=(first, held))
+    params = {w.name: rng.standard_normal(w.shape).astype(np.float32) * 0.05
+              for w in op.weights}            # outputs of order 1
+    gate = np.zeros((E, d), np.float32)
+    gate[np.arange(E), np.arange(E)] = 1.0
+    params["moe/gate"] = gate
+    x = rng.standard_normal((T, d)).astype(np.float32)
+    x[:, :E] = -4.0
+    each = np.full(T, own // T)
+    each[:own % T] += 1
+    elsewhere = np.setdiff1d(np.arange(E), np.arange(first, first + held))
+    for t, n in enumerate(rng.permutation(each)):
+        chosen = np.concatenate([
+            first + rng.choice(held, n, replace=False),
+            rng.choice(elsewhere, k - n, replace=False)])
+        x[t, chosen] = rng.permutation(1.0 + np.arange(k))   # distinct
+    return (op, {n: jnp.asarray(v) for n, v in params.items()},
+            jnp.asarray(x[None]))
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "biased"])
+@pytest.mark.parametrize("own", [
+    0, 1, _OWN_BLOCK - 1, _OWN_BLOCK, _OWN_BLOCK + 1, 2 * _OWN_BLOCK + 5, 200])
+def test_an_op_that_holds_a_share_dispatches_its_own_pairs_in_blocks(
+        own, gated):
+    """The walk over an op's OWN pairs in blocks of ``block_rows`` against
+    the one pass over all pairs (``ctx.training=True``, forward only: the
+    form a gradient is taken through), on an op holding 4 of 16 experts
+    with the routing rigged to give it no pair, one, a block less one, a
+    block, a block and one, three blocks with a ragged tail (2 x 96 + 5)
+    and ALL 200: the same output in float32 (1e-6 on outputs of order 1:
+    the same products, a token's up to 4 of them summed by a one-hot
+    product where the one pass scatter-added them and exact zeros), and
+    the counters of the serving step exact."""
+    import jax.numpy as jnp
+
+    from flexflow_tpu.op import OpContext, ServeStep
+    from flexflow_tpu.ops.moe import MoE
+
+    T, k = _OWN["tokens"], _OWN["k"]
+    assert MoE.block_rows(T, k, _OWN["held"], _OWN["experts"]) == _OWN_BLOCK
+    op, params, x = _rigged_share(own, gated)
+    serving = OpContext(training=False, compute_dtype="float32", mesh=None)
+    want = np.asarray(op.forward(params, [x], OpContext(
+        training=True, compute_dtype="float32", mesh=None))[0])
+    assert op.dispatch == {("forward", T): "whole"}
+    got = np.asarray(op.forward(params, [x], serving)[0])
+    assert op.dispatch == {("forward", T): {"rows": _OWN_BLOCK, "of": T * k}}
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+    assert (np.abs(want).max() > 0.01) == (own > 0)
+    assert np.abs(want).max() < 4.0
+    ent = op.serve_state(2, 8, 16, None)
+    assert set(ent["shapes"]) == {"load", "token_steps", "untouched",
+                                  "dispatches", "routed", "own_pairs",
+                                  "blocks", "past_one"}
+    state = {n: jnp.full(shape, 7, jnp.int32)
+             for n, shape in ent["shapes"].items()}
+    where = ServeStep("chunk", None, start=jnp.int32(0),
+                      length=jnp.int32(T - 3), slot=jnp.int32(0), no_page=8)
+    out, new = op.serve_step(params, [x], state, where, serving)
+    np.testing.assert_array_equal(np.asarray(out[0]), got)
+    blocks = -(-own // _OWN_BLOCK)
+    assert {n: int(new[n]) - 7 for n in op._DISPATCHED} == {
+        "dispatches": 1, "routed": T, "own_pairs": own, "blocks": blocks,
+        "past_one": int(blocks > 1)}
+    # the histogram is of LIVE tokens (the chunk's last 3 rows are pad)
+    assert 0 <= int(np.asarray(new["load"] - 7).sum()) <= own
+
+
+def test_a_capacity_zeroes_by_rank_before_the_blocks_are_cut():
+    """With a capacity (4 pairs an expert here) the weights past an
+    expert's rank are zeroed on the SORTED pairs, before the op's own are
+    cut into blocks: the walk agrees with the one pass, and with a
+    capacity no pair could pass, it differs."""
+    from flexflow_tpu.op import OpContext
+
+    op, params, x = _rigged_share(2 * _OWN_BLOCK + 5, True,
+                                  capacity_factor=0.32)
+    assert op.capacity == 4
+    out = {t: np.asarray(op.forward(params, [x], OpContext(
+        training=t, compute_dtype="float32", mesh=None))[0])
+        for t in (True, False)}
+    np.testing.assert_allclose(out[False], out[True], rtol=0, atol=1e-6)
+    loose = _rigged_share(2 * _OWN_BLOCK + 5, True, capacity_factor=8.0)[0]
+    got = np.asarray(loose.forward(params, [x], OpContext(
+        training=False, compute_dtype="float32", mesh=None))[0])
+    assert np.abs(got - out[False]).max() > 1e-3
+
+
+@pytest.mark.parametrize("E, cf", [(4, 1.25), (16, None)],
+                         ids=["capacity", "dropless"])
+def test_the_shards_of_an_e_axis_dispatch_their_own_pairs(E, cf):
+    """Under ``{"n": 2, "expert": 4}`` every shard holds ``E / 4`` experts:
+    ``predict`` walks each shard's own pairs in blocks (with a capacity its
+    zero weights are set by rank first, tokens whole on every shard;
+    dropless the tokens are sharded too) and agrees with one device, whose
+    op holds every expert and takes the one pass; ``train_batch`` takes
+    the one pass on the mesh too (a gradient)."""
+    rng = np.random.default_rng(11)
+    xd, yd = _data(rng, 16, 8, 32)
+    m1 = _build({"n": 1}, E=E, cf=cf)
+    m2 = _build({"n": 2, "expert": 4}, E=E, cf=cf)
+    np.testing.assert_allclose(m1.predict(xd), m2.predict(xd), rtol=2e-4,
+                               atol=2e-4)
+    (op1,), (op2,) = ([op for op in m.layers if op.name == "moe0"]
+                      for m in (m1, m2))
+    T = 16 * 8 // (1 if cf else 2)      # dropless: a token shard's
+    assert op1.dispatch == {("forward", 128): "whole"}
+    assert op2.dispatch == {("forward", 128): {
+        "rows": op2.block_rows(T, 2, E // 4, E), "of": 2 * T}}
+    np.testing.assert_allclose(float(m1.train_batch(xd, yd)),
+                               float(m2.train_batch(xd, yd)), rtol=2e-4)
+    assert op2.dispatch == {("forward", 128): "whole"}

@@ -451,22 +451,95 @@ def test_the_owner_tables_tell_the_three_parts_apart(sparse_lm):
         assert any(p == "moe_experts" for _, p in owners)
 
 
-def test_the_latent_graphs_programs_are_the_parents():
-    """The third graph that was there (latent attention, PR 41) lowers its
-    token step and a chunk program to the text it lowered to before this
-    PR (sha256 read on the parent commit under this suite's ``conftest``);
-    ``test_generation.py`` pins the other two."""
-    want = {"jit_prefill_8": LATENT_PINS[0], "jit_decode": LATENT_PINS[1]}
-    model = _build_latent_lm(weights=False)
+def _program_digests(model):
+    """sha256 of the lowered text of the model's token step and its
+    8-token chunk program."""
     dec = GraphDecoder(model, 2, model.input_tensors[0].shape[1])
     dec.decode_fn()
     dec.prefill_fn(8)
-    got = {name: hashlib.sha256(fn.lower(*args).as_text().encode()
-                                ).hexdigest()
-           for _, name, fn, args in dec._program_specs()}
-    assert got == want
+    return {name: hashlib.sha256(fn.lower(*args).as_text().encode()
+                                 ).hexdigest()
+            for _, name, fn, args in dec._program_specs()}
 
 
+def test_the_latent_graphs_programs_are_the_parents():
+    """The third graph that was there (latent attention, PR 41) lowers its
+    token step and a chunk program to the text pinned here (sha256 under
+    this suite's ``conftest``); ``test_generation.py`` pins the other two.
+    MOVED ON PURPOSE by PR 45: the graph's sparse layer holds 8 of its 16
+    experts, and an op that holds fewer experts than its router scores now
+    walks its OWN pairs in blocks under a loop (``MoE._experts``) where it
+    gathered, masked and scatter-added every pair, so both programs hold a
+    ``while`` they did not (before PR 45: ``cc389ffe..08d9be`` and
+    ``8cbf019c..69d974``); the tokens they serve are the parent's
+    (:func:`test_a_tiny_pangu_engine_serves_the_parents_tokens`)."""
+    assert _program_digests(_build_latent_lm(weights=False)) == {
+        "jit_prefill_8": LATENT_PINS[0], "jit_decode": LATENT_PINS[1]}
+
+
+def test_the_sparse_graphs_programs_are_the_parents():
+    """The fourth graph (a learned selection of keys, PR 44; its experts
+    all held) lowers its token step and a chunk program to the text it
+    lowered to before PR 45, the first to edit code they run (sha256 read
+    on the parent commit under this suite's ``conftest``).  A PR that
+    changes one of these programs on purpose says which and why, and moves
+    the pin."""
+    assert _program_digests(_build(weights=False)) == {
+        "jit_prefill_8": SPARSE_PINS[0], "jit_decode": SPARSE_PINS[1]}
+
+
+def test_a_tiny_pangu_engine_serves_the_parents_tokens():
+    """The tiny latent graph (16 experts, 8 held) serves, greedy, the
+    tokens it served before its dispatch walked its own pairs in blocks
+    (read on PR 45's parent commit, float32 on the CPU), and
+    ``stats()["moe"]`` says which form each program of the sparse layer
+    took and what the blocks ran: 8 of 16 held, so a block is all of a
+    step's pairs and no step needs a second."""
+    model = _build_latent_lm()
+    rng = np.random.default_rng(45)
+    prompts = [rng.integers(1, VOCAB, n).astype(np.int32)
+               for n in (5, 8, 13, 23, 37)]
+    with ff.fflogger.silenced("serve"):
+        with GenerationEngine(model, slots=2) as eng:
+            outs = [[int(t) for t in s.result(timeout=300)] for s in
+                    [eng.submit(p, max_new_tokens=16) for p in prompts]]
+            moe = eng.stats()["moe"]["moe_1"]
+    assert outs == PARENTS_TOKENS
+    assert moe["dispatch"] == {
+        "chunk:2": {"rows": 8, "of": 8}, "chunk:4": {"rows": 16, "of": 16},
+        "chunk:8": {"rows": 32, "of": 32}, "token:2": {"rows": 8, "of": 8}}
+    assert 0.3 < moe["own_share"] < 0.7         # 8 of 16 experts are here
+    assert 0.9 < moe["blocks_per_step"] <= 1.0
+    assert moe["past_one_block_share"] == 0.0
+
+
+def test_an_op_that_holds_every_expert_reports_the_whole_dispatch(sparse_lm):
+    """The sparse graph's ops hold all 8 of their experts: every serving
+    program of every one of them took the one pass over all pairs, and
+    nothing is said of blocks."""
+    _, snap = _serve(sparse_lm, [np.arange(1, 12, dtype=np.int32)], new=4)
+    ops = {n: m for n, m in snap["moe"].items() if n != "grouped_product"}
+    assert sorted(ops) == ["moe_0", "moe_1"]
+    for m in ops.values():
+        # (the module's model: a test before this one may have traced
+        # verify windows too)
+        assert {"chunk:2", "chunk:4", "chunk:8", "token:2"} <= set(
+            m["dispatch"])
+        assert set(m["dispatch"].values()) == {"whole"}
+        assert "own_share" not in m and "blocks_per_step" not in m
+
+
+# moved by PR 45 (the docstring above)
 LATENT_PINS = (
-    "cc389ffe9ff8e477bb79a7308828b83036f74d6e0cd4c4d2bfd1f3a94908d9be",
-    "8cbf019ca7b58924706b8bc9cc42eea11d5d7424df0ca02e72996b3ab569d974")
+    "e78eb9567407e727755842fb2e71b834f3d5c96b789542c1f3bc1c69b74232e8",
+    "197bb79058ad10655edd304360c3c948544c18bb0e4e8dbb6b41e100bf405a59")
+# read on PR 45's parent commit
+SPARSE_PINS = (
+    "60c5b2e28a2bc9fc00e6e120b80d6cfe416c19e34fe4816645092b77848a9347",
+    "88f8f8e7fdfa723e37f7e068172d2656c35e159c14a9a310b8427c6a1f4c5200")
+PARENTS_TOKENS = [
+    [29, 19, 19, 19, 19, 27, 27, 27, 9, 27, 57, 27, 9, 27, 30, 9],
+    [15, 54, 51, 48, 48, 48, 11, 5, 9, 11, 9, 11, 9, 60, 60, 60],
+    [6, 33, 11, 48, 48, 48, 9, 9, 9, 11, 52, 6, 11, 52, 60, 60],
+    [7, 31, 9, 31, 7, 52, 18, 7, 52, 9, 31, 1, 11, 18, 18, 35],
+    [49, 52, 4, 11, 27, 4, 49, 52, 4, 49, 52, 44, 42, 42, 42, 42]]
