@@ -1065,8 +1065,10 @@ class MultiHeadAttention(Op):
           applies (a TPU, one device) the chosen ROWS are copied out of the
           pools, ``topk`` a slot, and attended over densely
           (``decode_core`` ``"rows"``: the core then reads ``topk`` rows a
-          slot whatever the history); elsewhere the slot's whole view under
-          a mask (``"gathered"``), bit for bit the dense op under ``topk``;
+          slot whatever the history), the rows CHOSEN by a kernel that
+          reads ``ik`` in place (:meth:`_chosen_rows`); elsewhere the
+          slot's whole view under a mask (``"gathered"``), bit for bit the
+          dense op under ``topk``;
         * ``"window"``: each row its own set, as a mask over the views,
           bit for bit on the CPU the sequential token step's."""
         n, w = xq.shape[:2]
@@ -1103,10 +1105,8 @@ class MultiHeadAttention(Op):
             attn, chosen = self._sparse_over_blocks(
                 q, qi, wi, k_pool, v_pool, i_pool, where, scale)
         elif token and self.decode_core == "rows":
-            with jax.named_scope("dsa_index"):
-                ik = index_keys()
-            attn, chosen = self._sparse_rows(q, qi, wi, k_pool, v_pool, ik,
-                                             where, scale)
+            attn, chosen = self._sparse_rows(q, qi, wi, k_pool, v_pool,
+                                             i_pool, where, scale)
         else:
             kpos = jnp.arange(L)
             with jax.named_scope("dsa_index"):
@@ -1167,36 +1167,62 @@ class MultiHeadAttention(Op):
                 "chosen_mean": chosen / queries if queries else 0.0,
                 "live_mean": live / queries if queries else 0.0}
 
-    def _sparse_rows(self, q, qi, wi, k_pool, v_pool, ik, where, scale):
+    def _sparse_rows(self, q, qi, wi, k_pool, v_pool, i_pool, where, scale):
         """A token step that COPIES the chosen rows: each slot's one query
-        scores its slot's view of ``ik`` (n, L, di), ``jax.lax.top_k`` names
-        the ``topk`` best positions, their K and V rows are gathered out of
-        the pools where they lie (``topk`` rows a slot, whatever the
-        history) and :func:`_decode_attention` runs over them, each row at
-        the position it holds; a slot with fewer live positions than
-        ``topk`` gets dead rows, masked.  -> (attention (n, 1, h, d), the
-        count of live rows chosen by decoding slots)."""
+        scores its slot's positions, the ``topk`` best are named as a LIST
+        (:meth:`_chosen_rows`), their K and V rows are gathered out of the
+        pools where they lie (``topk`` rows a slot, whatever the history)
+        and :func:`_decode_attention` runs over them, each row at the
+        position it holds; a slot with fewer live positions than ``topk``
+        gets dead rows, masked.  -> (attention (n, 1, h, d), the count of
+        live positions chosen by decoding slots)."""
         n, page = q.shape[0], k_pool.shape[1]
-        pos = where.pos
-        with jax.named_scope("dsa_index"):
-            scores = index_scores(qi, ik, wi)[:, 0]                # (n, L)
-            scores = jnp.where(jnp.arange(scores.shape[1])[None, :]
-                               > pos[:, None], NEG_INF, scores)
+        idx, pid, alive, chosen = self._chosen_rows(qi, wi, i_pool, where)
         with jax.named_scope("dsa_select"):
-            vals, idx = jax.lax.top_k(scores, self.topk)
-            alive = vals > NEG_INF / 2
-            chosen = jnp.sum(alive & where.live(1))
             # a dead row stands at a position no query reaches
             kpos = jnp.where(alive, idx, jnp.iinfo(jnp.int32).max)
-            pid = jnp.take_along_axis(where.table, idx // page, axis=1)
         with jax.named_scope("dsa_core"):
             def rows(pool):
                 g = pool.at[pid, idx % page].get(mode="clip")
                 return g.reshape(n, self.topk, self.num_kv_heads,
                                  self.head_dim)
-            attn = _decode_attention(q, rows(k_pool), rows(v_pool), pos,
-                                     scale, kpos=kpos)
+            attn = _decode_attention(q, rows(k_pool), rows(v_pool),
+                                     where.pos, scale, kpos=kpos)
         return attn, chosen
+
+    def _chosen_rows(self, qi, wi, i_pool, where):
+        """The choice of a token step's ``"rows"`` form -> ``(idx (n, topk)
+        int32: the chosen positions in any order, pid (n, topk): the page of
+        the slot's table that holds each, alive (n, topk): which of them a
+        decoding slot's query may see, the count of live positions chosen
+        by decoding slots)``.  Scores and threshold in ONE kernel that reads
+        the live pages of ``i_pool`` in place, a slot's score row in VMEM
+        (``dsa_index``: :mod:`paged_index_kernel`, which takes every ``ik``
+        leaf beside a pool that :meth:`_decode_core` takes: the same dtype
+        and page, a row of whole lane tiles), then the chosen set as a list
+        by rank (``dsa_select``: ``rows_by_rank``, no sort and no scatter)
+        and each position's page from the table; a slot that does not
+        decode reads nothing and gets dead rows.  EXACT for the scores
+        the kernel makes, of equal scores the lower position first: the set
+        ``jax.lax.top_k`` names on them.  (The scores are
+        :func:`index_scores`' arithmetic, bit for bit under the CPU's
+        interpreter; on the chip the kernel's products sum in another order
+        than XLA's over a view, the same precision and not the same bits,
+        so of positions that all but tie another may be the 2 048-th.)"""
+        from .paged_index_kernel import paged_index_select, rows_by_rank
+        with jax.named_scope("dsa_index"):
+            scores, thr, last = paged_index_select(
+                qi[:, 0], wi[:, 0], i_pool, where.table, where.pos,
+                where.write_pages, self.topk)
+        with jax.named_scope("dsa_select"):
+            keep = selected(scores, jnp.arange(scores.shape[1]), thr, last)
+            chosen = jnp.sum(keep & (scores > NEG_INF / 2) & where.live(1))
+            idx = rows_by_rank(keep, self.topk)
+            pid = jnp.take_along_axis(where.table, idx // i_pool.shape[1],
+                                      axis=1)
+            # (a live position's score is finite: ``vals > NEG_INF / 2``)
+            alive = (idx <= where.pos[:, None]) & where.live(1)
+        return idx, pid, alive, chosen
 
     def _sparse_over_blocks(self, q, qi, wi, k_pool, v_pool, i_pool, where,
                             scale):

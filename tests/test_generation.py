@@ -3295,6 +3295,79 @@ def test_latent_chunk_kernel_compiles_for_the_chip(v5e_device, monkeypatch,
     assert "latent_chunk_attention" in text and "while" not in text
 
 
+def test_paged_index_kernel_compiles_for_the_chip(v5e_device, monkeypatch):
+    """The token step's choosing kernel (``ops/paged_index_kernel.py``) at
+    the widths ISSUE 44's configuration serves (16 index heads of 64 over
+    stored rows of 128 lanes, 24 slots of 1 568 pages of 16, bf16, 2 048 of
+    25 088 positions chosen), compiled by the TPU's compiler for a described
+    v5e: the one-row stores into the score block, the scalar searches and
+    the VMEM budget, which interpret mode cannot show
+    (``tests/test_paged_index_kernel.py`` has the arithmetic)."""
+    from flexflow_tpu.ops import paged_index_kernel as pk
+    from jax.sharding import SingleDeviceSharding
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    chip = SingleDeviceSharding(v5e_device)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, pps = 24, 1568
+    args = (sd((slots, 16, 64), jnp.bfloat16), sd((slots, 16), jnp.float32),
+            sd((slots * pps, 16, 128), jnp.bfloat16),
+            sd((slots, pps), jnp.int32), sd((slots,), jnp.int32),
+            sd((slots,), jnp.int32))
+    with _no_compilation_cache():
+        text = _within(_COMPILE_LIMIT_S, lambda: pk.paged_index_select
+                       .lower(*args, 2048).compile().as_text())
+    assert "paged_index_select" in text
+
+
+def test_the_sparse_token_step_chooses_without_a_sort_or_a_view(
+        v5e_device, monkeypatch):
+    """The WHOLE token step of a tiny graph with a learned selection
+    (bf16, heads of 128, pages of 16, 256 of 4 096 positions chosen), as
+    one TPU traces it, compiled by the TPU's compiler for a described v5e:
+    the program holds the choosing kernel, no ``sort`` anywhere
+    (``jax.lax.top_k`` is one to this compiler) and, of its gathers, none
+    under ``dsa_index`` (no view of ``ik`` is written out) and ONE under
+    ``dsa_select``, each chosen row's page from the table (4 x 256 int32):
+    the list comes by rank and one-hot products, and the only rows gathered
+    are the core's K and V."""
+    from flexflow_tpu.models import build_decoder_lm
+    from flexflow_tpu.ops import (attention as attn_mod, flash_kernel,
+                                  paged_decode_kernel, paged_index_kernel)
+
+    monkeypatch.setattr(attn_mod.jax, "default_backend", lambda: "tpu")
+    for mod in (flash_kernel, paged_decode_kernel, paged_index_kernel):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    cfg = ff.FFConfig(batch_size=2, compute_dtype="bfloat16", seed=0)
+    cfg.serve_gen_slots, cfg.serve_gen_max_seq = 4, 4096
+    cfg.serve_prefill_chunk, cfg.serve_kv_page = 64, 16
+    model = build_decoder_lm(
+        cfg, [{"attention": "full_attention", "heads": 4, "mlp": "dense"}],
+        d_model=256, head_dim=128, num_kv_heads=2, d_ff=256, vocab_size=512,
+        seq_len=4096, qk_norm=1e-6,
+        sparse={"index_heads": 4, "index_dim": 64, "topk": 256},
+        rope={"full_attention": {"rope_theta": 1e4}})[0]
+    model.compile(ff.SGDOptimizer(lr=0.01), mesh=ff.MachineMesh({"n": 1}))
+    dec = GraphDecoder(model, 4, 4096, prefill_chunk=64)
+    fn = dec.decode_fn()
+    (args,) = [a for _, _, f, a in dec._program_specs(v5e_device) if f is fn]
+    with _no_compilation_cache():
+        text = _within(_COMPILE_LIMIT_S,
+                       lambda: fn.lower(*args).compile().as_text())
+    assert dec.decode_attention()["sparse"] == {"rows": 1, "gathered": 0}
+    assert "paged_index_select" in text
+    lines = text.splitlines()
+    assert not [l for l in lines if " sort(" in l]
+    gathers = [l for l in lines if " gather(" in l]
+    assert not [l for l in gathers if "dsa_index" in l]
+    (pages,) = [l for l in gathers if "dsa_select" in l]
+    assert " s32[4,256]" in pages
+    assert len([l for l in gathers if "dsa_core" in l]) == 2, gathers
+
+
 def test_the_serving_programs_of_the_graphs_that_were_there_are_the_parents():
     """The token step and one chunk program of the tiny post-norm decoder
     (``gpt1``'s family) and of the tiny laguna graph lower to the text they

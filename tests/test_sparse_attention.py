@@ -323,8 +323,8 @@ def test_the_loop_over_key_blocks_is_the_masked_chunk(topk, monkeypatch):
 def test_a_token_step_that_copies_its_chosen_rows_is_the_masked_view(
         monkeypatch):
     """The token step's two forms on one state: ``rows`` (what a TPU takes:
-    ``jax.lax.top_k``, the chosen rows gathered out of the pools) against
-    ``gathered`` (the whole view under a mask), slots at positions under and
+    the choice in the kernel, the chosen rows gathered out of the pools)
+    against ``gathered`` (the whole view under a mask), slots at positions under and
     past ``topk``, one not decoding."""
     pages, page, slots = 40, 4, 4
     op, params = _op(SPARSE, n=slots, s=1)
@@ -354,6 +354,86 @@ def test_a_token_step_that_copies_its_chosen_rows_is_the_masked_view(
     assert got["rows"][1] == got["gathered"][1] == {
         "topk": TOPK, "queries": 3, "dense_queries": 1,
         "chosen_mean": (4 + 8 + 8) / 3, "live_mean": (4 + 18 + 40) / 3}
+
+
+def _steer_to_the_kernel(monkeypatch):
+    """What one TPU answers, on the CPU: the token step copies its chosen
+    rows and chooses them in ``ops/paged_index_kernel.py`` (the
+    interpreter's here; pages of 4 float32 rows are what the tiny graphs
+    have, not what a chip's copy takes)."""
+    monkeypatch.setattr(att.MultiHeadAttention, "_decode_core",
+                        lambda self, pool, ctx: "paged")
+
+
+@pytest.mark.parametrize("positions,idle", [
+    ((3, 17, 30, 39), (2,)),        # under and past topk, one not decoding
+    ((39, 38, 37, 36), ()),         # every slot at the table's end
+    ((0, 5, 7, 8), (0, 3)),         # nothing to leave out but in one slot
+    ((12, 12, 12, 12), (0, 1, 2)),  # one slot decodes
+])
+def test_a_token_step_that_chooses_in_the_kernel_is_the_masked_view(
+        monkeypatch, positions, idle):
+    """The token step's two forms on one state, at pages that lie out of
+    order: ``rows`` (the choice made by the kernel: scores against the
+    pages of ``ik`` where they lie, the threshold found on the slot's score
+    block, the list by rank) against ``gathered`` (the whole view under a
+    mask): the same outputs for the decoding slots, the same rows in the
+    three leaves, the same counts."""
+    pages, page, slots = 40, 4, 4
+    op, params = _op(SPARSE, n=slots, s=1)
+    rng = np.random.default_rng(sum(positions))
+    state = {"k": jnp.asarray(rng.normal(size=(pages, page, 16)), jnp.float32),
+             "v": jnp.asarray(rng.normal(size=(pages, page, 16)), jnp.float32),
+             "ik": jnp.asarray(rng.normal(size=(pages, page, 128)),
+                               jnp.float32).at[..., 8:].set(0),
+             "counts": jnp.zeros((4, 2), jnp.int32)}
+    table = jnp.asarray(rng.permutation(pages).reshape(slots, 10), jnp.int32)
+    pos = jnp.asarray(positions, jnp.int32)
+    wp = jnp.take_along_axis(table, (pos // page)[:, None], 1)[:, 0]
+    wp = wp.at[jnp.asarray(idle, jnp.int32)].set(pages)
+    x = jnp.asarray(rng.normal(size=(slots, 1, 32)), jnp.float32)
+    where = ServeStep("token", table, pos=pos, write_pages=wp,
+                      write_rows=pos % page, no_page=pages)
+    got = {}
+    for form, core in (("gathered", "gathered"), ("rows", "paged")):
+        monkeypatch.setattr(op, "_decode_core", lambda pool, ctx, c=core: c)
+        out, new = op.serve_step(params, [x], state, where, CTX)
+        assert op.decode_core == form
+        got[form] = (np.asarray(out[0]), new)
+    live = [s for s in range(slots) if s not in idle]
+    np.testing.assert_allclose(got["rows"][0][live],
+                               got["gathered"][0][live], atol=2e-5)
+    for leaf in ("k", "v", "ik"):
+        assert (np.asarray(got["rows"][1][leaf])
+                == np.asarray(got["gathered"][1][leaf])).all()
+    assert op.selection_stats(got["rows"][1]["counts"]) \
+        == op.selection_stats(got["gathered"][1]["counts"])
+    counts = op.selection_stats(got["rows"][1]["counts"])
+    assert counts["queries"] == len(live)
+    assert counts["chosen_mean"] == pytest.approx(
+        sum(min(positions[s] + 1, TOPK) for s in live) / len(live))
+
+
+def test_the_sparse_graph_serves_its_tokens_through_the_kernel(monkeypatch):
+    """The graph's engine with every token step steered to the kernel: the
+    tokens the graph's own forward gives, at histories under and past
+    ``topk`` and across pages, two streams at once; ``stats()`` says that
+    the rows were copied and that ``topk`` positions at most were chosen;
+    the chunk programs are not touched."""
+    _steer_to_the_kernel(monkeypatch)
+    model = _build(seed=11)
+    rng = np.random.default_rng(47)
+    prompts = [rng.integers(1, VOCAB, n).astype(np.int32)
+               for n in (5, 13, 23, 37)]
+    outs, snap = _serve(model, prompts)
+    for p, out in zip(prompts, outs):
+        assert out == reference_decode(model, p, 10, SEQ)
+    assert snap["decode_attention"] == {
+        "paged": 0, "gathered": 0, "sparse": {"rows": 2, "gathered": 0}}
+    assert snap["chunk_attention"]["sparse"]["mask"] > 0
+    for name in ("attention_0", "attention_1"):
+        got = snap["sparse_attention"][name]
+        assert got["chosen_mean"] <= TOPK < got["live_mean"]
 
 
 def test_serving_under_topk_is_the_dense_graph_bit_for_bit():
