@@ -3,7 +3,7 @@
 
 Default (no args) sweeps ALL BASELINE.md configs — inception first (the
 north-star headline), then alexnet / resnet50 / nmt / transformer / dlrm /
-candle_uno / serving — printing one JSON line per model as it completes,
+candle_uno — printing one JSON line per model as it completes,
 and finally a summary line whose headline fields
 (metric/value/unit/vs_baseline) are the Inception numbers and whose
 ``results`` map carries every model's row.  ``--model X`` benches a single
@@ -79,8 +79,7 @@ CONV_LAYOUT = "auto"
 
 # --steps-per-dispatch K: fuse K train steps into one dispatched lax.scan
 # window (FFConfig.steps_per_dispatch) so the sweep can record
-# dispatch-amortized rows alongside the K=1 baseline — the
-# microbenchmark isolating the effect is `flexflow-tpu train-bench`.
+# dispatch-amortized rows alongside the K=1 baseline.
 STEPS_PER_DISPATCH = 1
 
 # --flash auto|on|off -> config.flash_attention None/True/False.  The
@@ -89,12 +88,9 @@ STEPS_PER_DISPATCH = 1
 # backward pass, so the crossover for the full step may sit lower.
 FLASH = "auto"
 
-# sweep order: headline first so an interrupted sweep still records it.
-# "serving" is the inference-engine row (flexflow_tpu/serving serve-bench
-# at a fixed trace) so BENCH_*.json tracks the serving path alongside
-# training.
+# sweep order: headline first so an interrupted sweep still records it
 SWEEP = ["inception_v3", "alexnet", "resnet50", "nmt", "transformer",
-         "dlrm", "candle_uno", "serving"]
+         "dlrm", "candle_uno"]
 
 # best measured per-chip batch size per workload (v5e, BASELINE.md)
 DEFAULT_BATCH = {"inception_v3": 128, "alexnet": 512, "resnet50": 128,
@@ -247,41 +243,9 @@ def _hbm_bytes_per_step(model, batch_size, n_chips):
     return emb / max(1, n_chips) + params
 
 
-def bench_serving(batch_size):
-    """One serving row: engine rows/s at the serve-bench fixed trace
-    (seeded request mix) vs naive per-request predict — the inference
-    analogue of the training rows."""
-    from flexflow_tpu.fflogger import silenced
-    from flexflow_tpu.serving.bench import run_serve_bench
-
-    # silence the serve_stats/epoch event streams: this harness's
-    # stdout protocol is one JSON row per model (same reason
-    # serve-bench's own main() silences them)
-    with silenced("ff", "serve"):
-        payload = run_serve_bench(requests=256,
-                                  max_batch=batch_size or 64, seed=0)
-    eng, naive = payload["engine"], payload["naive"]
-    return {
-        "metric": "serving_engine_rows_per_sec",
-        "value": eng["qps_rows"],
-        "unit": "rows/s",
-        "vs_baseline": None,
-        "qps_requests": eng["qps_requests"],
-        "speedup_vs_naive": payload["speedup_rows"],
-        "naive_rows_per_sec": naive["qps_rows"],
-        "p50_ms": payload["paced"]["p50_ms"],
-        "p95_ms": payload["paced"]["p95_ms"],
-        "p99_ms": payload["paced"]["p99_ms"],
-        "batch_occupancy": eng["batch_occupancy"],
-        "batch_size": batch_size or 64,
-    }
-
-
 def bench_model(model_name, batch_size, iters):
     import jax
 
-    if model_name == "serving":
-        return bench_serving(batch_size)
     batch_size = batch_size or DEFAULT_BATCH.get(model_name, 128)
     model, xs, y = build(model_name, batch_size)
     n_chips = len(jax.devices())

@@ -17,10 +17,10 @@ from .diagnostics import (CODES, Diagnostic, DiagnosticReport, Severity,
                           VerificationError, make, validate_report_json)
 from .kv_memory import kv_cache_bytes, kv_cache_layout
 from .legality import config_diagnostics, degree_executable, per_dim_degrees
-from .sharding_passes import (comm_plan_digest, comm_plan_digest_for_model,
-                              communication_plan, explain_report,
-                              predict_fallbacks, propagate_specs,
-                              render_explain_text, validate_explain_json)
+from .sharding_passes import (comm_plan_digest, communication_plan,
+                              explain_report, predict_fallbacks,
+                              propagate_specs, render_explain_text,
+                              validate_explain_json)
 from .verifier import (drain_fallback_sites, drain_replicate_fallbacks,
                        record_replicate_fallback, verify, verify_compile)
 
@@ -30,7 +30,6 @@ __all__ = [
     "per_dim_degrees", "verify", "verify_compile",
     "record_replicate_fallback", "drain_replicate_fallbacks",
     "drain_fallback_sites", "predict_fallbacks", "propagate_specs",
-    "communication_plan", "comm_plan_digest", "comm_plan_digest_for_model",
-    "explain_report", "render_explain_text", "validate_explain_json",
+    "communication_plan", "comm_plan_digest", "explain_report", "render_explain_text", "validate_explain_json",
     "validate_report_json", "kv_cache_bytes", "kv_cache_layout",
 ]

@@ -48,8 +48,8 @@ allocation a static HBM gate must know about, so :func:`kv_page_plan`
 The default pool is sized to the dense worst case
 (``slots x ceil(max_seq / page_size)`` pages), so with ``page_size``
 dividing ``max_seq`` the GLOBAL accounting equals the pre-paging dense
-number, while the engine's *in-use* high-water mark (what the bench
-reports) drops with sharing.  One sharding caveat: the old dense cache
+number, while the engine's *in-use* high-water mark
+(``stats()["kv_pages_high_water"]``) drops with sharing.  One sharding caveat: the old dense cache
 slot-sharded over ``n`` where it divided; the pool's page dim is
 replicated (any slot must be able to borrow any page), so on a mesh
 where slot-sharding used to engage the PER-DEVICE KV bytes grow by
@@ -121,7 +121,8 @@ def default_num_pages(slots: int, max_seq: int,
     """The auto pool size (``serve_kv_pages=0``): the dense worst case
     — every slot holding a full private ``max_seq`` stream.  Sharing
     and mixed lengths keep the in-use high-water BELOW this; an
-    operator shrinks the pool once the bench shows the real mark."""
+    operator shrinks the pool once the engine's stats show the real
+    mark."""
     return int(slots) * pages_per_slot(max_seq, page_size)
 
 

@@ -19,8 +19,7 @@ cannot diverge.  On top of the propagation:
   producer/consumer spec mismatches plus per-parameter gradient
   allreduce volumes, the device-free report behind
   ``flexflow-tpu explain`` (``communication_plan`` /
-  ``explain_report``), stamped into serve-bench/train-bench rows as
-  ``comm_plan_digest``;
+  ``explain_report``), whose content digest is ``comm_plan_digest``;
 * the liveness HBM timeline consumed here lives on the Simulator
   (``Simulator.memory_timeline`` — FF121, see
   ``analysis/strategy_passes.py``).
@@ -265,28 +264,10 @@ def communication_plan(layers: List[Op],
 
 def comm_plan_digest(plan: Dict) -> str:
     """Stable content digest of a communication plan (sorted-key JSON,
-    sha256, 16 hex chars) — the provenance stamp serve-bench and
-    train-bench rows carry so rows measured under different sharding
-    plans are never compared as one population."""
+    sha256, 16 hex chars) — what an ``explain`` report carries, so two
+    reports of different sharding plans are never read as one."""
     blob = json.dumps(plan, sort_keys=True, separators=(",", ":"))
     return hashlib.sha256(blob.encode()).hexdigest()[:16]
-
-
-def comm_plan_digest_for_model(model) -> str:
-    """The digest of a compiled model's plan: resolved per-op
-    strategies on the mesh the model runs on (device-free — only the
-    mesh's shape is read).  Computed over the DENSE plan (no
-    sparse-table discount): sparse-update eligibility is a property of
-    the run's optimizer, which `flexflow-tpu explain` — the offline
-    tool that must reproduce this digest from just (model, strategy,
-    mesh) — cannot know.  The digest keys the structural plan; the
-    full sparse-aware traffic lives in the report, not the key."""
-    strategies = {op.name: op.parallel_config for op in model.layers
-                  if op.parallel_config is not None}
-    sizes = dict(model.mesh.sizes) if model.mesh is not None else {}
-    mesh = AbstractMesh(sizes)
-    return comm_plan_digest(communication_plan(
-        model.layers, strategies, mesh))
 
 
 # ---------------------------------------------------------------------

@@ -22,40 +22,11 @@ from .config import FFConfig
 def main(argv=None) -> None:
     argv = list(sys.argv[1:] if argv is None else argv)
     # built-in subcommands (no user script involved)
-    if argv and argv[0] == "search-bench":
-        # search-throughput microbenchmark: delta vs full re-simulation
-        # (JSON to stdout; see docs/strategy_search.md)
-        from .search.bench import main as bench_main
-        bench_main(argv[1:])
-        return
-    if argv and argv[0] == "train-bench":
-        # dispatch-amortization microbenchmark: fit() steps/s across
-        # steps_per_dispatch values (JSON to stdout; docs/performance.md)
-        from .train_bench import main as train_bench_main
-        train_bench_main(argv[1:])
-        return
-    if argv and argv[0] == "serve-bench":
-        # serving-engine microbenchmark: bucketed AOT + micro-batching
-        # vs naive per-request predict (JSON to stdout; docs/serving.md)
-        from .serving.bench import main as serve_bench_main
-        serve_bench_main(argv[1:])
-        return
-    if argv and argv[0] == "precision-bench":
-        # precision axis + int8 serving evidence artifact
-        # (docs/performance.md "Precision policy")
-        from .precision_bench import main as precision_bench_main
-        precision_bench_main(argv[1:])
-        return
     if argv and argv[0] == "calibrate":
         # harvest measured op/dispatch timings into a CalibrationTable,
         # or --check existing artifacts (docs/strategy_search.md)
         from .search.calibration import calibrate_main
         raise SystemExit(calibrate_main(argv[1:]))
-    if argv and argv[0] == "calibrate-bench":
-        # sim-vs-measured MAPE sweep, analytic vs calibrated estimators
-        # (docs/performance.md "Calibration")
-        from .search.calibration import calibrate_bench_main
-        raise SystemExit(calibrate_bench_main(argv[1:]))
     if argv and argv[0] == "elastic":
         # supervised multi-process training with restart-from-checkpoint
         # (docs/elastic.md)
@@ -94,15 +65,8 @@ def run_script(argv) -> dict:
         print("usage: flexflow-tpu <script.py> [FlexFlow flags]\n"
               "       flexflow-tpu elastic [supervisor flags] -- "
               "<script.py> [script args]\n"
-              "       flexflow-tpu search-bench [flags]\n"
-              "       flexflow-tpu train-bench [flags]\n"
-              "       flexflow-tpu serve-bench [--overload|--generate"
-              " [--prefix|--speculate]|--fleet|--disagg] [flags]\n"
-              "       flexflow-tpu precision-bench [--out f.json]\n"
               "       flexflow-tpu calibrate [--out table.json | "
               "--check FILE...]\n"
-              "       flexflow-tpu calibrate-bench --table table.json "
-              "[--out report.json]\n"
               "       flexflow-tpu lint --model NAME [--strategy s.pb] "
               "[--devices N] [--json]\n"
               "       flexflow-tpu lint --fleet fleet.json "
@@ -146,8 +110,8 @@ def run_script(argv) -> dict:
     from flexflow_tpu.parallel import initialize_distributed
     initialize_distributed(
         num_processes=cfg.num_nodes if cfg.num_nodes > 1 else None)
-    # a training run keeps its compiles like the engines and harnesses
-    # do (flexflow_tpu/compile_cache.py: the one placement rule)
+    # a training run keeps its compiles like the engines do
+    # (flexflow_tpu/compile_cache.py: the one placement rule)
     from .compile_cache import enable as enable_compile_cache
     enable_compile_cache()
     # the script sees the remaining argv like any __main__

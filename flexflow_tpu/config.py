@@ -67,8 +67,8 @@ def _validate_dtype_field(field: str, value: str, allowed) -> None:
 
 
 def dtype_short(dtype_name: str) -> str:
-    """The ONE dtype -> bench-tag spelling ("bfloat16" -> "bf16"), so
-    every bench's precision_policy stamp shares a vocabulary."""
+    """The ONE dtype -> short-tag spelling ("bfloat16" -> "bf16") that
+    ``precision_policy`` tags are written in."""
     return {"bfloat16": "bf16", "float32": "f32",
             "float16": "f16"}.get(dtype_name, dtype_name)
 
@@ -362,7 +362,7 @@ class FFConfig:
     # serve_kv_pages: total pool pages; 0 = auto, the dense worst case
     # slots x ceil(max_seq / page) so the accounting equals the old
     # dense preallocation (analysis/kv_memory.py) — shrink it once the
-    # bench's high-water evidence says so.  Undersized pools shed
+    # engine's kv_pages_high_water says so.  Undersized pools shed
     # streams (KVCacheExhausted) after LRU-evicting cached prefixes.
     serve_kv_pages: int = 0
     # serve_prefix_cache: "on" (default) caches full pages of prompt
@@ -374,8 +374,7 @@ class FFConfig:
     # serve_prefill_chunk: prefill long prompts in chunks of this many
     # tokens, at most one chunk per decode-step boundary, capping the
     # decode stall a joining prompt inflicts on in-flight streams
-    # (Sarathi-style).  0 = whole-prompt chunks (the monolithic
-    # baseline serve-bench --generate compares against).
+    # (Sarathi-style).  0 = whole-prompt chunks (monolithic prefill).
     serve_prefill_chunk: int = 0
     # Speculative decoding (docs/serving.md "Speculative decoding &
     # sampling").  serve_spec_gamma: draft tokens proposed per round
@@ -450,9 +449,8 @@ class FFConfig:
         return max(1, self.workers_per_node) * self.num_nodes
 
     def precision_policy(self) -> str:
-        """Short human/bench tag of the run's precision policy, stamped
-        next to device_kind/calibration_digest in bench rows: the global
-        compute dtype ("bf16"/"f32"/...), "+mixed(B/F)" when per-op
+        """Short human-readable tag of the run's precision policy: the
+        global compute dtype ("bf16"/"f32"/...), "+mixed(B/F)" when per-op
         strategy overrides are present (B ops bf16, F ops f32), and
         "+int8w" under serving weight quantization."""
         short = dtype_short(self.compute_dtype)

@@ -171,11 +171,11 @@ def get_logger(name: str) -> Category:
 @contextlib.contextmanager
 def silenced(*names: str):
     """Temporarily mute the given categories' info-level output
-    (levels restored on exit) — for harnesses whose stdout IS a JSON
-    payload and must not interleave with the event stream
-    (train-bench, serve-bench, bench.py's serving row).  Warnings and
-    errors stay visible: they go to stderr, which cannot corrupt the
-    stdout payload, and a failing bench run needs its diagnostics."""
+    (levels restored on exit) — for callers whose stdout IS a JSON
+    payload and must not interleave with the event stream (``calibrate``,
+    the benchmark's serve driver).  Warnings and errors stay visible:
+    they go to stderr, which cannot corrupt the stdout payload, and a
+    failing run needs its diagnostics."""
     logs = [get_logger(n) for n in names]
     prev = [log.level for log in logs]
     for log in logs:

@@ -616,7 +616,7 @@ double ffsim_state_simulate(void* h, int32_t overlap_backward_update) {
 
 void ffsim_destroy(void* h) { delete (SimState*)h; }
 
-// Introspection for tests and search-bench:
+// Introspection for tests:
 //   0: link-spec rebuilds   1: full replays    2: delta repairs
 //   3: repair fallbacks     4: task count      5: assemblies
 int64_t ffsim_stat(void* h, int32_t which) {

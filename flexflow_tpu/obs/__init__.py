@@ -26,8 +26,7 @@ harvesters like ``calibrate``'s ``capture_events`` hook).
 
 from .events import EVENTS, declared_events
 from .flight import FlightRecorder, flight_dump, get_flight
-from .registry import (MetricsRegistry, get_registry,
-                       render_prometheus, start_metrics_server,
+from .registry import (MetricsRegistry, get_registry, start_metrics_server,
                        validate_prometheus_text)
 from .trace import (TERMINAL_PHASES, Tracer, get_tracer, phase_of,
                     to_chrome, tracer_from_config, validate_chrome_trace,
@@ -36,8 +35,8 @@ from .trace import (TERMINAL_PHASES, Tracer, get_tracer, phase_of,
 __all__ = [
     "EVENTS", "declared_events",
     "FlightRecorder", "get_flight", "flight_dump",
-    "MetricsRegistry", "get_registry", "render_prometheus",
-    "start_metrics_server", "validate_prometheus_text",
+    "MetricsRegistry", "get_registry", "start_metrics_server",
+    "validate_prometheus_text",
     "TERMINAL_PHASES", "Tracer", "get_tracer", "phase_of", "to_chrome",
     "tracer_from_config", "validate_chrome_trace", "validate_raw_trace",
 ]

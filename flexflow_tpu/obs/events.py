@@ -4,7 +4,7 @@ contract (repo_lint RL011 pins call sites statically).
 
 Why a registry: the event stream is machine-consumed — ``flexflow-tpu
 calibrate`` harvests ``epoch``/``serve_stats`` records through
-``fflogger.capture_events``, serve-bench reconciles counters, the
+``fflogger.capture_events``, the
 flight recorder retains the stream for post-mortems.  A typo'd event
 name at an emit site used to produce a perfectly valid JSON line that
 every harvester silently ignored; declaring names here turns that rot

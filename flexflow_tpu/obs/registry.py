@@ -10,11 +10,10 @@ endpoint are two views of one set of numbers and cannot diverge.
 
 Families are label-keyed (``model`` = tenant identity, ``eng`` =
 per-process engine generation — two engines serving the same model name
-never merge counts, which is what keeps serve-bench's per-engine
-reconciliation exact).  Rendering follows the Prometheus text
-exposition format 0.0.4; :func:`validate_prometheus_text` is the
-schema gate scripts/check_trace_artifacts.py runs over the committed
-snapshot.
+never merge counts, which is what keeps each engine's
+``submitted == terminals`` reconciliation exact).  Rendering follows the
+Prometheus text exposition format 0.0.4; :func:`validate_prometheus_text`
+is the schema check the tests hold the renderer to.
 
 The optional scrape endpoint (:func:`start_metrics_server`,
 ``--metrics-port``) is a stdlib ``ThreadingHTTPServer`` on a daemon
@@ -172,8 +171,8 @@ class _Family:
             return sorted(self._children.items())
 
     def total(self) -> float:
-        """Sum over every child — the whole-process view serve-bench
-        reconciles across engine generations."""
+        """Sum over every child — the whole-process view across engine
+        generations."""
         return sum(c.value for _, c in self._series()
                    if isinstance(c, _Child))
 
@@ -220,8 +219,8 @@ class MetricsRegistry:
             return [self._families[n] for n in sorted(self._families)]
 
     def reset(self) -> None:
-        """Forget every family (tests / bench legs needing a clean
-        slate; live code never calls this)."""
+        """Forget every family (tests needing a clean slate; live code
+        never calls this)."""
         with self._lock:
             self._families.clear()
 
@@ -272,13 +271,8 @@ def get_registry() -> MetricsRegistry:
     return _registry
 
 
-def render_prometheus() -> str:
-    """The process registry's exposition — what ``/metrics`` serves."""
-    return get_registry().render()
-
-
 # ---------------------------------------------------------------------------
-# exposition validation (the artifact gate's half)
+# exposition validation
 # ---------------------------------------------------------------------------
 
 _SAMPLE_RE = re.compile(

@@ -10,7 +10,7 @@ phases (:meth:`Tracer.phase`), ``fit()``'s per-window ``train_window``,
 and exactly ONE terminal ``request`` span per logical request whose
 ``phase`` arg names its outcome (:data:`TERMINAL_PHASES`) — which is
 what lets a trace file reconcile EXACTLY against the ServingMetrics
-counters (serve-bench pins ``submitted == terminal spans``).
+counters (``submitted == terminal spans``; tests/test_obs.py).
 
 Design constraints, in order:
 
@@ -146,7 +146,7 @@ class Tracer:
             self.sample_rate = 0.0
 
     def reset(self) -> None:
-        """Drop all recorded spans and restart ids (tests, bench legs)."""
+        """Drop all recorded spans and restart ids (tests)."""
         with self._lock:
             self._spans.clear()
             self._seq = 0
@@ -243,8 +243,8 @@ class Tracer:
 
     def terminal_phase_counts(self) -> Dict[str, int]:
         """``phase -> count`` over the terminal ``request`` spans still
-        in the ring — the reconciliation half serve-bench pins against
-        the ServingMetrics counters."""
+        in the ring — the half that reconciles against the
+        ServingMetrics counters."""
         with self._lock:
             spans = list(self._spans)
         out: Dict[str, int] = {}
@@ -272,8 +272,8 @@ def get_tracer() -> Tracer:
 def tracer_from_config(cfg) -> Tracer:
     """The engines'/fit()'s entry point: returns the process tracer,
     enabling it when ``cfg.trace_sample_rate > 0`` and it is not
-    already on (an explicitly configured tracer wins — tests and
-    serve-bench set the rate directly)."""
+    already on (an explicitly configured tracer wins — tests and the
+    benchmark set the rate directly)."""
     t = get_tracer()
     rate = float(getattr(cfg, "trace_sample_rate", 0.0) or 0.0)
     if rate > 0.0 and not t.active:
@@ -360,9 +360,8 @@ def validate_raw_trace(obj) -> List[str]:
 
 
 def validate_chrome_trace(obj) -> List[str]:
-    """Schema problems of an exported Chrome-trace JSON ([] = valid) —
-    what scripts/check_trace_artifacts.py gates the committed artifact
-    with, so a format change can never rot silently."""
+    """Schema problems of an exported Chrome-trace JSON ([] = valid):
+    ``trace export`` refuses to write a file that has any."""
     probs: List[str] = []
     if not isinstance(obj, dict):
         return ["payload is not an object"]
@@ -398,9 +397,9 @@ def validate_chrome_trace(obj) -> List[str]:
 
 def trace_main(argv) -> int:
     """``flexflow-tpu trace export RAW.json [--out chrome.json]``:
-    validate a raw ``ff-trace-v1`` file (serve-bench ``--trace-out``,
-    ``Tracer.save``) and export it as Chrome-trace JSON — loadable in
-    chrome://tracing or https://ui.perfetto.dev.  ``trace summary``
+    validate a raw ``ff-trace-v1`` file (``Tracer.save``) and export it
+    as Chrome-trace JSON — loadable in chrome://tracing or
+    https://ui.perfetto.dev.  ``trace summary``
     prints span counts by name and the terminal-phase reconciliation
     counts instead.  Exit: 0 ok, 1 validation failure, 2 usage."""
     import argparse

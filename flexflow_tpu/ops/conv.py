@@ -32,8 +32,8 @@ from .common import apply_activation, cast_compute
 # ---------------------------------------------------------------------------
 # Fast max-pool: XLA lowers the autodiff backward of reduce_window(max) to
 # SelectAndScatter, which serializes badly on TPU — the round-5 on-chip
-# attribution (artifacts/INCEPTION_MFU.md) charged 27% of Inception's step
-# to pool2d, with a single stem pool's backward costing 2.9 ms and its
+# attribution charged 27% of Inception's step to pool2d,
+# with a single stem pool's backward costing 2.9 ms and its
 # forward 3-6x the bandwidth roofline.  This custom_vjp computes BOTH
 # directions from k*k strided window slices: forward = elementwise max
 # tree, backward = shifted equality-masks (first-match, cuDNN tie

@@ -107,7 +107,6 @@ class Concat(Op):
         # the boundary transposes cancel with the neighbors' — the
         # round-5 on-chip attribution charged early-block concat
         # backwards 3-4x their roofline to exactly these relayouts
-        # (artifacts/INCEPTION_MFU.md)
         if (getattr(ctx, "conv_layout", "nchw") == "nhwc"
                 and self.axis == 1 and xs[0].ndim == 4
                 and flag_enabled("FF_FAST_CONCAT", "fast_concat")):

@@ -119,8 +119,8 @@ def profile_op(op: Op, compute_dtype: str = "bfloat16", warmup: int = 2,
 
 def quantiles(samples, qs=(0.5, 0.95, 0.99)) -> Dict[float, float]:
     """Nearest-rank quantiles of a sample sequence — the p50/p95/p99
-    latency accounting shared by the serving metrics
-    (flexflow_tpu/serving/metrics.py) and serve-bench.  Nearest-rank
+    latency accounting of the serving metrics
+    (flexflow_tpu/serving/metrics.py).  Nearest-rank
     (not interpolated): every reported value is a latency that actually
     happened, which is what a tail-latency SLO compares against.
     Returns ``{q: value}``; empty input yields NaNs."""
@@ -143,8 +143,8 @@ def time_calls(fn, min_time_s: float = 0.3, max_calls: int = 1_000_000
                ) -> Tuple[float, int]:
     """(calls/sec, n_calls) of repeatedly invoking ``fn()`` until at
     least ``min_time_s`` of wall clock accumulates.  Host-side CPU
-    timing for search-throughput benchmarks (``search-bench``) — the
-    simulator runs on the host, so no device fence is involved."""
+    timing of search throughput — the simulator runs on the host, so no
+    device fence is involved."""
     import time as _time
     n = 0
     t0 = _time.perf_counter()

@@ -35,10 +35,8 @@ Three layers:
   (FLOPs, bytes in/out, fan-in/out, partition degrees — the 2008.01040
   feature set) in log space and predicts absolute times.
 
-* the ``flexflow-tpu calibrate`` / ``calibrate-bench`` CLI — harvest a
-  table from the model zoo, validate it (``--check``: schema + digest),
-  and report sim-vs-measured error (per-op and end-to-end MAPE, analytic
-  vs calibrated) as a tracked artifact (``artifacts/calib_bench_r9.json``).
+* the ``flexflow-tpu calibrate`` CLI — harvest a table from the model
+  zoo and validate it (``--check``: schema + digest).
 
 Comm-side calibration threads through :func:`calibrated_spec`: a table
 may carry ``DeviceSpec`` field overrides (measured effective bandwidths)
@@ -64,7 +62,6 @@ from .cost_model import DeviceSpec, op_compute_time, spec_for_device
 
 SCHEMA_VERSION = 1
 TABLE_KIND = "calibration_table"
-BENCH_KIND = "calib_bench"
 
 _SEED_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "calibration_seed.json")
@@ -257,8 +254,8 @@ class CalibrationTable:
 def content_digest(payload: Dict) -> str:
     """Canonical content digest (sorted-key JSON, ``digest`` excluded):
     two tables with the same measurements have the same digest on any
-    machine, and bench artifacts can cite exactly which calibration
-    state produced them."""
+    machine, so a search's ``describe()`` names exactly which calibration
+    state produced it."""
     body = {k: v for k, v in payload.items() if k != "digest"}
     blob = json.dumps(body, sort_keys=True,
                       separators=(",", ":")).encode()
@@ -362,42 +359,8 @@ def validate_table(data: Dict) -> List[str]:
     return errs
 
 
-def validate_bench(data: Dict) -> List[str]:
-    """Schema errors for a ``calibrate-bench`` report JSON."""
-    errs: List[str] = []
-    if not isinstance(data, dict):
-        return ["top level: want an object"]
-    if data.get("kind") != BENCH_KIND:
-        errs.append(f"kind: want {BENCH_KIND!r}, got {data.get('kind')!r}")
-    models = data.get("models")
-    if not isinstance(models, list) or not models:
-        errs.append("models: want a non-empty list")
-        models = []
-    for i, row in enumerate(models):
-        if not isinstance(row, dict) or "model" not in row:
-            errs.append(f"models[{i}]: want an object with 'model'")
-            continue
-        per_op = row.get("per_op", {})
-        # null MAPEs are legal only for an (explicitly recorded) empty
-        # profile — n_measured == 0, the backend-flake case the bench
-        # warns about; a null next to real measurements is corruption
-        empty = per_op.get("n_measured") == 0
-        for f in ("mape_analytic", "mape_calibrated"):
-            v = per_op.get(f)
-            if not isinstance(v, (int, float)) and not (empty and v is None):
-                errs.append(f"models[{i}].per_op.{f}: want a number")
-        e2e = row.get("end_to_end", {})
-        for f in ("measured_ms_per_step", "ape_analytic",
-                  "ape_calibrated"):
-            if not isinstance(e2e.get(f), (int, float)):
-                errs.append(f"models[{i}].end_to_end.{f}: want a number")
-    if "calibration_digest" not in data:
-        errs.append("calibration_digest: missing")
-    return errs
-
-
 def validate_file(path: str) -> List[str]:
-    """Validate either artifact kind by its ``kind`` field."""
+    """Validate a calibration table file (schema + digest)."""
     try:
         with open(path) as f:
             data = json.load(f)
@@ -406,10 +369,7 @@ def validate_file(path: str) -> List[str]:
     kind = data.get("kind") if isinstance(data, dict) else None
     if kind == TABLE_KIND:
         return validate_table(data)
-    if kind == BENCH_KIND:
-        return validate_bench(data)
-    return [f"unknown kind {kind!r} (want {TABLE_KIND!r} or "
-            f"{BENCH_KIND!r})"]
+    return [f"unknown kind {kind!r} (want {TABLE_KIND!r})"]
 
 
 def default_table() -> CalibrationTable:
@@ -459,9 +419,9 @@ def apply_step_correction(table: Optional[CalibrationTable],
                           sim_ms: float) -> float:
     """Map a simulated per-step time (ms) through the table's dispatch
     correction; identity when the table carries none.  This calibrates
-    ABSOLUTE end-to-end predictions (``calibrate-bench``); the search
-    objective never needs it — the power law is monotone, so op-level
-    rankings are unchanged by construction."""
+    ABSOLUTE end-to-end predictions; the search objective never needs
+    it — the power law is monotone, so op-level rankings are unchanged
+    by construction."""
     sc = table.step_correction if table is not None else None
     if not sc or sim_ms <= 0 or not math.isfinite(sim_ms):
         return sim_ms
@@ -759,9 +719,8 @@ def _dtype_bytes(dtype: str) -> int:
 
 def _profile_best(op, samples: int = 2, **kw) -> Dict[str, float]:
     """Best-of-N ``profile_op`` (per direction): wall-clock noise only
-    ever INFLATES a sample (the bench.py / serve-bench min-of-legs
-    philosophy), and harvest and bench both using the same estimator
-    keeps their ratio stable.  NaNs pass through (int-only ops)."""
+    ever INFLATES a sample (bench.py's min-of-legs philosophy).  NaNs
+    pass through (int-only ops)."""
     from ..profiling import profile_op
     best = {"fwd_ms": float("nan"), "bwd_ms": float("nan")}
     for _ in range(max(1, samples)):
@@ -953,7 +912,7 @@ def device_kind() -> str:
 
 
 # ---------------------------------------------------------------------------
-# CLI: flexflow-tpu calibrate / calibrate-bench
+# CLI: flexflow-tpu calibrate
 # ---------------------------------------------------------------------------
 
 def calibrate_main(argv=None) -> int:
@@ -1011,7 +970,7 @@ def calibrate_main(argv=None) -> int:
                 with open(path) as f:
                     d = json.load(f)
                 print(f"{path}: OK ({d.get('kind')}, "
-                      f"digest {d.get('digest', d.get('calibration_digest'))})")
+                      f"digest {d.get('digest')})")
         return rc
 
     names = [m.strip() for m in args.models.split(",") if m.strip()]
@@ -1124,163 +1083,3 @@ def _rows(model, x, i):
     if n_in == 1:
         return (x[i: i + size],)
     return tuple(a[i: i + size] for a in x)
-
-
-def calibrate_bench_main(argv=None) -> int:
-    """``flexflow-tpu calibrate-bench``: the sim-vs-measured error sweep.
-    For each zoo model it (a) re-measures every op fresh (independent of
-    the table's samples) and reports per-op MAPE of the analytic vs the
-    calibrated estimator against those measurements, and (b) measures
-    real ms/step through fit() and reports the end-to-end absolute
-    percentage error of the simulated step time under both estimators.
-    The JSON artifact is the tracked evidence that search wins are
-    measured, not simulated (``artifacts/calib_bench_r9.json``)."""
-    import argparse
-    ap = argparse.ArgumentParser(
-        prog="flexflow-tpu calibrate-bench",
-        description="per-op + end-to-end sim-vs-measured MAPE, analytic "
-                    "vs calibrated (docs/performance.md 'Calibration')")
-    ap.add_argument("--table", required=True,
-                    help="CalibrationTable JSON from flexflow-tpu "
-                         "calibrate")
-    ap.add_argument("--models", default="transformer,dlrm,inception")
-    ap.add_argument("--estimator", default="table",
-                    choices=["table", "ridge"],
-                    help="calibrated estimator to compare against "
-                         "analytic")
-    ap.add_argument("--iters", type=int, default=4)
-    ap.add_argument("--samples", type=int, default=2,
-                    help="best-of-N profile runs per op/direction — "
-                         "the same noise floor the harvest used")
-    ap.add_argument("--dtype", default="bfloat16")
-    ap.add_argument("--seed", type=int, default=1)
-    ap.add_argument("--out", default="")
-    args = ap.parse_args(argv)
-
-    from ..compile_cache import enable as _enable_cache
-    _enable_cache()
-
-    table = CalibrationTable.load(args.table)
-    est = make_estimator(args.estimator, table)
-    names = [m.strip() for m in args.models.split(",") if m.strip()]
-    for m in names:
-        if m not in ZOO:
-            ap.error(f"unknown model {m!r}; choose from {sorted(ZOO)}")
-
-    spec = spec_for_device()
-    dtype_bytes = _dtype_bytes(args.dtype)
-    rows = []
-    for m in names:
-        model, x, y = ZOO[m](_ZOO_BATCH[m], args.dtype)
-        rows.append(_bench_model(m, model, x, y, est, table, spec,
-                                 dtype_bytes, args))
-    payload = {
-        "kind": BENCH_KIND,
-        "version": SCHEMA_VERSION,
-        "bench": "calibrate-bench",
-        "device_kind": device_kind(),
-        "calibration_digest": table.digest,
-        "estimator": est.name,
-        "step_correction": table.step_correction,
-        "models": rows,
-    }
-    text = json.dumps(payload, indent=2)
-    print(text)
-    if args.out:
-        with open(args.out, "w") as f:
-            f.write(text + "\n")
-        import sys
-        print(f"# wrote {args.out}", file=sys.stderr)
-    return 0
-
-
-def _bench_model(name: str, model, x, y, est: CostEstimator,
-                 table: CalibrationTable, spec, dtype_bytes: int,
-                 args) -> Dict:
-    """One model's sim-vs-measured rows (per-op MAPE + end-to-end APE)."""
-    import time
-
-    import flexflow_tpu as ff
-    from ..fflogger import silenced
-    from ..op import resolve_conv_layout
-    from .simulator import Simulator
-
-    layers = model.layers
-    layout = resolve_conv_layout("auto", layers)
-    ape_ana: List[float] = []
-    ape_cal: List[float] = []
-    seen = set()
-    for op in layers:
-        nd = op.outputs[0].num_dims
-        dims = (1,) + (1,) * (nd - 1)
-        key = op_key(op, dims, args.dtype)
-        if key in seen:
-            continue
-        seen.add(key)
-        try:
-            r = _profile_best(op, samples=args.samples,
-                              compute_dtype=args.dtype, warmup=1,
-                              iters=args.iters, conv_layout=layout)
-        except Exception:  # noqa: BLE001 — skip unprofilable, keep sweep
-            continue
-        meas = r["fwd_ms"] + (r["bwd_ms"] if r["bwd_ms"] == r["bwd_ms"]
-                              else 0.0)
-        if meas != meas or meas <= 0:
-            continue
-        ana = sum(op_compute_time(op, dims, spec, dtype_bytes, b)
-                  for b in (False, True)) * 1e3
-        cal = sum(est.op_time(op, dims, spec, dtype_bytes, b,
-                              compute_dtype=args.dtype)
-                  for b in (False, True)) * 1e3
-        ape_ana.append(abs(ana - meas) / meas)
-        ape_cal.append(abs(cal - meas) / meas)
-    if not ape_ana:
-        print(f"# calibrate-bench: WARNING no op of {name!r} could be "
-              "profiled — per-op MAPEs will be null", flush=True)
-
-    # end-to-end: real ms/step through fit() vs the simulated step time
-    model.compile(ff.SGDOptimizer(lr=0.01))
-    model.init_layers(seed=args.seed)
-    steps = (len(x[0]) if isinstance(x, (list, tuple)) else len(x)) \
-        // model.config.batch_size
-    import jax
-    with silenced("ff"):
-        model.fit(x, y, epochs=1, verbose=False)  # warm (compile)
-        t0 = time.perf_counter()
-        model.fit(x, y, epochs=2, verbose=False)
-        jax.block_until_ready(model._params)
-    measured_ms = (time.perf_counter() - t0) / (2 * steps) * 1e3
-
-    sim_kw = dict(num_devices=1, use_native=False,
-                  dtype_bytes=dtype_bytes, compute_dtype=args.dtype)
-    sim_ana = Simulator(**sim_kw)
-    sim_cal = Simulator(estimator=est, **sim_kw)
-    t_ana = sim_ana.simulate(layers, {}) * 1e3
-    # the calibrated e2e prediction runs the simulated step through the
-    # table's dispatch-level power law (fusion/overhead regimes a per-op
-    # table cannot see); the analytic baseline stays raw by definition
-    t_cal = apply_step_correction(
-        table, sim_cal.simulate(layers, {}) * 1e3)
-
-    def mape(xs):
-        return round(sum(xs) / len(xs), 4) if xs else None
-
-    def ape(sim_ms):
-        return round(abs(sim_ms - measured_ms) / measured_ms, 4)
-
-    return {
-        "model": name,
-        "n_ops": len(layers),
-        "per_op": {
-            "n_measured": len(ape_ana),
-            "mape_analytic": mape(ape_ana),
-            "mape_calibrated": mape(ape_cal),
-        },
-        "end_to_end": {
-            "measured_ms_per_step": round(measured_ms, 3),
-            "sim_analytic_ms": round(t_ana, 3),
-            "sim_calibrated_ms": round(t_cal, 3),
-            "ape_analytic": ape(t_ana),
-            "ape_calibrated": ape(t_cal),
-        },
-    }

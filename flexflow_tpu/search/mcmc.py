@@ -420,7 +420,7 @@ def search(layers: List[Op], num_devices: int, budget: int = 1000,
         cur, cur_t = dict(current), cur_time
         ms_cur = dict(mesh_shape)
         b, bm, bt = dict(cur), dict(ms_cur), cur_t
-        # bench instrumentation (ISSUE 20): proposals actually evaluated,
+        # search stats (ISSUE 20): proposals actually evaluated,
         # Metropolis acceptances, the (proposal#, best-so-far) trace and
         # the wall clock of the last improvement — pure counters, no rng
         # draws, so the walk is bit-identical with or without them

@@ -299,7 +299,7 @@ class SimSession:
         self._pending: Dict[int, Tuple] = {}   # op idx -> engine row
         self._idx_of = {op.name: i for i, op in enumerate(self.layers)}
         # total evaluate() calls — the per-chain proposal-throughput
-        # denominator the bench/hybrid stats stamp (ISSUE 20)
+        # denominator the hybrid stats stamp (ISSUE 20)
         self.evaluations = 0
         self._first = True
         self._handle = None

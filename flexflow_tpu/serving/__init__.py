@@ -1,8 +1,8 @@
 """flexflow_tpu.serving — the inference-serving subsystem
 (docs/serving.md): shape-bucketed AOT executables + a dynamic
 micro-batcher over a compiled FFModel, with admission control,
-per-request deadlines/priorities, engine health states, rolling
-serving metrics and the ``flexflow-tpu serve-bench`` harness."""
+per-request deadlines/priorities, engine health states and rolling
+serving metrics."""
 
 from .batcher import (ADMISSION_POLICIES, MicroBatcher, Request, bucket_for,
                       derive_buckets, split_sizes)

@@ -399,7 +399,7 @@ class MicroBatcher:
     @property
     def peak_rows(self) -> int:
         """High-water mark of queued rows over the batcher's lifetime —
-        the bounded-queue evidence serve-bench's overload sweep records
+        the bounded-queue evidence ``stats()["peak_queue_rows"]`` reports
         (must stay <= max_queue_rows when the bound is set)."""
         with self._cv:
             return self._peak_rows

@@ -404,8 +404,8 @@ class ServingEngine:
             r.on_done(err, now)
         self._health_tick()
         # retire the live registry hooks: a stopped engine must not be
-        # retained by the process-global registry (fleet swaps, bench
-        # legs — counters stay readable, the gauge provider drops)
+        # retained by the process-global registry (fleet swaps —
+        # counters stay readable, the gauge provider drops)
         self.metrics.release()
         self._shutdown_done.set()
 
@@ -626,7 +626,7 @@ class ServingEngine:
             # and COUNT it, or record_submitted() above would leave a
             # request with no recorded outcome and break the
             # submitted == requests+rejected+shed+expired+errors
-            # reconciliation serve-bench pins
+            # reconciliation
             self.metrics.record_rejected()
             if trace_done is not None:
                 trace_done("rejected", self.clock())

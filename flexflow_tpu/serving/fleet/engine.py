@@ -21,7 +21,7 @@ ONE fleet dispatcher thread interleaves their packed dispatches under
 * isolation is therefore by construction: tenant A offered 2x its
   capacity can saturate only ITS queue (bounded, shed_oldest) and its
   weight-share of device time — tenant B's goodput is preserved
-  (``serve-bench --fleet`` pins >= 90% of solo).
+  (not measured on the chip: ROADMAP W6).
 
 **Hot load / unload / swap**: ``load()`` builds + compiles + warms the
 new model's executables on a BACKGROUND thread (the expensive part —
@@ -248,7 +248,7 @@ class FleetEngine:
         reg = get_registry()
         # eng label = this fleet's own generation id (same sequence as
         # the per-engine metrics): two FleetEngines in one process —
-        # sequential bench legs, a rebuilt fleet after drain — must
+        # a rebuilt fleet after drain — must
         # never merge their dispatch counts or overwrite each other's
         # tenant vtime gauges
         self._fleet_eng = next_engine_id()
@@ -610,8 +610,8 @@ class FleetEngine:
         # counter continuity across hot swaps: a tenant's lifetime
         # counters are the sum over every engine generation that
         # served under its name — read LIVE from the retired metrics
-        # (see _Tenant.retired) so the reconciliation serve-bench pins
-        # holds even for requests that resolved after their swap
+        # (see _Tenant.retired) so submitted == terminals holds even
+        # for requests that resolved after their swap
         for key, v in t.carried.items():
             if key in snap:
                 snap[key] += v

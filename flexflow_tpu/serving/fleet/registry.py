@@ -30,8 +30,9 @@ compiles + initializes + restores the checkpoint for actual serving
 
 ``validate_fleet_json`` is the ONE schema check, shared by
 ``ModelRegistry.from_json``, ``flexflow-tpu lint --fleet`` and the repo
-static gate (scripts/check_fleet_artifacts.py) so a committed fleet
-file can never rot silently.
+static gate (scripts/check_fleet_artifacts.py, over
+``examples/**/fleet*.json``) so a committed fleet file can never rot
+silently.
 """
 
 from __future__ import annotations

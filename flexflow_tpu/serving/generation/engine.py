@@ -643,9 +643,8 @@ class GenerationEngine:
         # (CPython deque append/popleft are atomic — no lock, no
         # cross-engine lock-order edge for the fflock gate to flag)
         self._adopt_q: deque = deque()
-        # per-migration wall costs (ms), export side and import side —
-        # the calibrated-replay bench reads these as the REAL price of
-        # a migration on this substrate
+        # per-migration wall costs (ms), export side and import side:
+        # the REAL price of a migration on this substrate
         self.migrate_export_ms: List[float] = []
         self.migrate_import_ms: List[float] = []
         self._caches = None

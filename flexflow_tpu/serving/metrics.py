@@ -33,7 +33,7 @@ from ..obs.trace import phase_of
 from ..profiling import quantiles
 
 # per-process engine-generation sequence: the ``eng`` label that keeps
-# two engines serving the SAME model name (bench legs, a fleet swap's
+# two engines serving the SAME model name (a fleet swap's
 # old/new generation) from merging their registry counters
 _ENG_SEQ = [0]
 _ENG_LOCK = lockwatch.lock("metrics._ENG_LOCK")
@@ -111,8 +111,8 @@ class ServingMetrics:
     Dispatch-side records (`record_dispatch`) come from the dispatcher
     thread, one per packed batch; request-side records
     (`record_request`) fire when a logical request's future resolves.
-    `snapshot()` reduces the rolling window to the flat dict that both
-    the ``serve_stats`` JSON event and serve-bench report.
+    `snapshot()` reduces the rolling window to the flat dict that the
+    ``serve_stats`` JSON event and ``engine.stats()`` report.
 
     ``queue_depth_fn`` (settable after construction) makes the reported
     queue depth LIVE: without it, depth freezes at the last dispatch —
@@ -459,8 +459,7 @@ class ServingMetrics:
             "cancelled": totals[8],
             # offered-load lifetime total: submitted == requests +
             # rejected + shed + expired + errors + cancelled, the exact
-            # reconciliation serve-bench (and the trace terminal-span
-            # counts) pin
+            # reconciliation the trace terminal-span counts pin
             "submitted": totals[9],
             "admission_blocked_ms": round(totals[7], 3),
         }

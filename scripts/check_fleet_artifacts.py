@@ -1,15 +1,10 @@
 #!/usr/bin/env python
-"""Repo static gate for fleet artifacts (scripts/static_checks.sh):
-
-* every shipped fleet registry JSON (``examples/serving/fleet.json``
-  and any ``examples/**/fleet*.json``) must pass
-  ``serving.fleet.validate_fleet_json`` — the SAME schema
-  ``ModelRegistry.from_json`` and ``flexflow-tpu lint --fleet``
-  enforce, so a committed registry can never rot silently;
-* every ``artifacts/fleet_bench_*.json`` must pass
-  ``serving.fleet.bench.validate_fleet_bench_json`` AND carry a
-  reconciled, zero-failed hot-swap leg — the acceptance evidence
-  stays checkable offline.
+"""Repo static gate for fleet registries (scripts/static_checks.sh):
+every shipped fleet registry JSON (``examples/serving/fleet.json`` and
+any ``examples/**/fleet*.json``) must pass
+``serving.fleet.validate_fleet_json`` — the SAME schema
+``ModelRegistry.from_json`` and ``flexflow-tpu lint --fleet`` enforce,
+so a committed registry can never rot silently.
 
 Device-free and jax-free: pure JSON + schema functions.
 """
@@ -25,7 +20,6 @@ sys.path.insert(0, REPO)
 
 def main() -> int:
     from flexflow_tpu.serving.fleet import validate_fleet_json
-    from flexflow_tpu.serving.fleet.bench import validate_fleet_bench_json
 
     failures = 0
 
@@ -48,40 +42,12 @@ def main() -> int:
         if not probs:
             print(f"ok   {rel}: {len(obj['fleet'])} tenant(s)")
 
-    benches = sorted(
-        glob.glob(os.path.join(REPO, "artifacts", "fleet_bench_*.json")))
-    for path in benches:
-        rel = os.path.relpath(path, REPO)
-        try:
-            with open(path) as f:
-                obj = json.load(f)
-        except ValueError as e:
-            print(f"FAIL {rel}: not valid JSON: {e}")
-            failures += 1
-            continue
-        probs = validate_fleet_bench_json(obj)
-        summary = obj.get("summary") or {}
-        if not probs:
-            # the acceptance evidence itself (ISSUE 12): isolation,
-            # bounded queue, lossless swap — a regenerated artifact
-            # that regressed must fail the gate, not slide in
-            for key in ("isolation_holds", "a_queue_bounded",
-                        "swap_zero_failed", "swap_reconciled"):
-                if summary.get(key) is not True:
-                    probs.append(f"summary.{key} is not true")
-        for p in probs:
-            print(f"FAIL {rel}: {p}")
-        failures += len(probs)
-        if not probs:
-            print(f"ok   {rel}: b_goodput_ratio="
-                  f"{summary.get('b_goodput_ratio')}")
-
-    if not registries and not benches:
-        print("no fleet artifacts found (nothing to check)")
+    if not registries:
+        print("no fleet registries found (nothing to check)")
     if failures:
-        print(f"fleet artifacts: {failures} problem(s)", file=sys.stderr)
+        print(f"fleet registries: {failures} problem(s)", file=sys.stderr)
         return 1
-    print("fleet artifacts: OK")
+    print("fleet registries: OK")
     return 0
 
 

@@ -5,7 +5,7 @@ committed ``artifacts/searched_*.pb`` must still (a) parse, (b) pass
 schema-valid ``lint --json`` AND ``explain --json`` report — so a
 committed strategy (or a lint/explain schema change) can never rot
 silently.  Run by ``scripts/static_checks.sh`` alongside the calibration
-artifact checks; one process, in-process CLI calls (each subprocess
+seed check; one process, in-process CLI calls (each subprocess
 would pay the jax import again).
 
 Exit 0 when every artifact passes, 1 with findings on stdout.
@@ -42,36 +42,6 @@ def _run_json(main, argv):
     except ValueError as e:
         return rc, None, [f"stdout is not JSON: {e}"]
     return rc, payload, []
-
-
-def _check_hybrid_bench(problems) -> None:
-    """ISSUE 20 CI satellite: the committed hybrid-search evidence must
-    stay schema-valid AND its acceptance booleans must hold — hybrid
-    matched/beat the pure anneal at half budget on >= 2 of 3 zoo
-    models, and the fully-decomposable control spent zero proposals."""
-    from flexflow_tpu.search.bench import validate_hybrid_bench
-
-    rel = "artifacts/search_hybrid_r20.json"
-    path = os.path.join(REPO, rel)
-    if not os.path.exists(path):
-        problems.append(f"{rel}: missing (ISSUE 20 evidence artifact)")
-        return
-    try:
-        with open(path) as f:
-            data = json.load(f)
-    except ValueError as e:
-        problems.append(f"{rel}: not JSON: {e}")
-        return
-    for p in validate_hybrid_bench(data):
-        problems.append(f"{rel}: schema: {p}")
-    acc = data.get("acceptance")
-    if isinstance(acc, dict):
-        for k in ("hybrid_le_mcmc_at_half_budget",
-                  "fully_decomposable_zero_proposals"):
-            if acc.get(k) is not True:
-                problems.append(
-                    f"{rel}: acceptance.{k} is {acc.get(k)!r}, not True "
-                    f"— the hybrid search no longer meets its gate")
 
 
 def _discover_extra_cases(problems):
@@ -111,7 +81,6 @@ def main() -> int:
     from flexflow_tpu.cli import explain_main, lint_main
 
     problems = []
-    _check_hybrid_bench(problems)
     cases = CASES + _discover_extra_cases(problems)
     for rel, model, batch in cases:
         path = os.path.join(REPO, rel)
@@ -170,7 +139,7 @@ def main() -> int:
               file=sys.stderr)
         return 1
     print(f"check_strategy_artifacts: {len(cases)} shipped strategies "
-          f"lint + explain clean, hybrid-search evidence gate holds")
+          f"lint + explain clean")
     return 0
 
 

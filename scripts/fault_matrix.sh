@@ -52,12 +52,12 @@ declare -a cases=(
   # failed dispatch and retaining its request spans; a health edge
   # into `degraded` dumps too, and the flight CLI reads both
   "$FAST_TIMEOUT tests/test_obs.py::TestFlightFaults"
-  # tier-1 serving smoke under the lockwatch gate: a full bench
-  # round-trip through the ServingEngine whose runtime
+  # tier-1 serving smoke under the lockwatch gate: six threads'
+  # round-trips through the ServingEngine whose runtime
   # acquisition-order graph must come out acyclic and a subset of the
   # static FF151 graph (asserted by the conftest session gate, which
   # the FF_LOCKWATCH export below arms for every case here)
-  "$FAST_TIMEOUT tests/test_serving.py::test_serve_bench_smoke"
+  "$FAST_TIMEOUT tests/test_serving.py::test_concurrent_submitters_resolve_correctly"
 )
 if [ "${1:-}" != "--fast-only" ]; then
   cases+=(

@@ -1,10 +1,10 @@
 """Isolated on-chip A/B of the round-5 kernel lowerings.
 
 Times each alternative lowering against XLA's stock path on the exact
-Inception-stem shapes the round-5 attribution charged
-(artifacts/INCEPTION_MFU.md): max-pool backward (SelectAndScatter vs
-the equality-mask VJP), stride-2 conv dgrad (dilated-grad conv vs the
-parity-phase decomposition), and the NHWC channel concat boundary.
+Inception-stem shapes the round-5 attribution charged: max-pool
+backward (SelectAndScatter vs the equality-mask VJP), stride-2 conv
+dgrad (dilated-grad conv vs the parity-phase decomposition), and the
+NHWC channel concat boundary.
 A full-model bench folds the input pipeline and every other op into one
 number; this isolates the kernels, completes inside ~2 min of chip time,
 and prints one JSON line per pair.  Each timed window of dispatches is

@@ -89,9 +89,7 @@ by tier-1 ``tests/test_static_checks.py``).  Rules:
   tests rot the moment one sneaks in — the code under test would mix
   fake and real time.  Default-argument position is exempt (``clock:
   Callable = time.monotonic`` and friends are the injection point
-  itself), as is ``serving/bench.py`` — the benchmark harness
-  DRIVES real wall-clock runs; it measures the engine, it is not the
-  engine.
+  itself).
 * **RL012 — dtype resolution in op code happens in ONE place**
   (ISSUE 14): inside ``flexflow_tpu/ops/`` (``ops/common.py`` — the
   resolution point — exempt), a ``jnp.dtype(...)``/``np.dtype(...)``
@@ -116,7 +114,7 @@ by tier-1 ``tests/test_static_checks.py``).  Rules:
   ``flexflow_tpu/`` must pass a string literal declared in
   ``flexflow_tpu/obs/events.py`` — a typo'd name produces a valid
   JSON line every harvester (``calibrate``'s capture_events hook,
-  serve-bench reconciliation, the flight recorder) silently ignores.
+  the flight recorder) silently ignores.
   A non-literal name needs an ``RL011-ok:`` comment naming the
   literals it can resolve to (each declared).  ``fflogger.py`` (the
   definition site) and tests/scripts are out of scope.
@@ -141,6 +139,11 @@ by tier-1 ``tests/test_static_checks.py``).  Rules:
   have to climb again.  (``serving/quantize.py`` rewrites ``Linear``
   weights and rightly knows ``Linear``; it is outside the rule.)  A
   deliberate site carries an ``RL015-ok:`` comment.
+* **RL016 — measurement lives in ``perfbench/``** (ISSUE 47): a module
+  under ``flexflow_tpu/`` whose file name contains ``bench`` is a
+  harness inside the package.  The benchmark is ``BENCHMARK.json`` +
+  ``perfbench/``; its results are ``PERF_LEDGER.jsonl``; a second place
+  that times the system is a second answer nobody reads.
 
 Exit 0 when clean, 1 with ``file:line: RLxxx message`` findings on
 stdout.  No third-party deps — must run on a bare CPython.
@@ -232,11 +235,8 @@ _RL010_FUNCS = ("_decode_loop", "_run_boundary", "_run_chunk",
 # wall-clock reads RL008 bans in flexflow_tpu/serving/ (outside
 # default-argument position): every serving class takes an injectable
 # ``clock=`` — the fake-clock overload tests depend on it being the
-# ONLY time source.  bench.py is exempt (it measures real wall-clock).
+# ONLY time source
 _RL008_BANNED = {"time.time", "time.monotonic"}
-# the benchmark harnesses measure WALL clock — that is their job
-_RL008_EXEMPT = ("flexflow_tpu/serving/bench.py",
-                 "flexflow_tpu/serving/fleet/bench.py")
 
 
 # RL012: dtype string literals banned in flexflow_tpu/ops/ outside the
@@ -394,8 +394,6 @@ class _Visitor(ast.NodeVisitor):
         self.in_layer_blind_scope = (
             self.in_generation
             or relpath == "flexflow_tpu/analysis/kv_memory.py")
-        self.in_clock_scope = (self.in_serving
-                               and relpath not in _RL008_EXEMPT)
         # RL009 engages where the concurrency-heavy classes live: the
         # serving stack (incl. generation/), the elastic supervisor and
         # the observability plane (ISSUE 18 widened it to obs/ so the
@@ -599,7 +597,7 @@ class _Visitor(ast.NodeVisitor):
                       f"resume) sees every mesh the repo constructs")
 
     def _check_clock(self, node: ast.Call, name: str) -> None:
-        if not self.in_clock_scope or name not in _RL008_BANNED:
+        if not self.in_serving or name not in _RL008_BANNED:
             return
         if id(node) in self._default_pos:
             # `def f(now=time.monotonic())` evaluates ONCE at def time —
@@ -791,6 +789,10 @@ def lint_file(path: str) -> List[str]:
     except SyntaxError as e:
         return [f"{rel}:{e.lineno or 0}: RL000 syntax error: {e.msg}"]
     v = _Visitor(rel, src.splitlines())
+    if v.in_library and "bench" in os.path.basename(rel):
+        v.findings.append((1, "RL016",
+                           "a module named *bench* under flexflow_tpu/ "
+                           "— measurement lives in perfbench/"))
     v.visit(tree)
     return [f"{rel}:{ln}: {code} {msg}"
             for ln, code, msg in sorted(v.findings)]

@@ -17,8 +17,8 @@ The contracts pinned here:
   evaluates bit-identical to one-shot ``simulate()`` under a calibrated
   estimator (the session consumes the same ``_op_plan`` rows).
 * **CLI round-trip** — harvest -> table on disk -> ``calibrate
-  --check`` validates schema/digest -> search-bench consumes it with
-  the estimator name + digest in its rows.
+  --check`` validates schema/digest -> a search given ``--calibration``
+  scores with an estimator that names its kind and the table's digest.
 """
 
 import json
@@ -334,8 +334,8 @@ def test_search_shared_sim_estimator_contradiction_warns():
 # CLI round-trip (subprocess; tiny scope to stay tier-1-fast)
 
 @pytest.mark.parametrize("estimator", ["table", "ridge"])
-def test_cli_calibrate_roundtrip_and_search_bench_consumes(tmp_path,
-                                                           estimator):
+def test_cli_calibrate_roundtrip_and_a_search_reads_the_table(tmp_path,
+                                                             estimator):
     table_path = str(tmp_path / "table.json")
     cli = [sys.executable, "-m", "flexflow_tpu.cli"]
     r = subprocess.run(
@@ -352,20 +352,17 @@ def test_cli_calibrate_roundtrip_and_search_bench_consumes(tmp_path,
                        cwd=REPO, timeout=300)
     assert r.returncode == 0, r.stdout + r.stderr
     assert "OK" in r.stdout
-    # search-bench consumes it: estimator name + digest in the rows
-    r = subprocess.run(
-        cli + ["search-bench", "--graphs", "transformer", "--devices",
-               "4", "--steps", "8", "--budget", "5", "--min-time",
-               "0.05", "--calibration", table_path, "--estimator",
-               estimator],
-        capture_output=True, text=True, env=cached_env(), cwd=REPO,
-        timeout=560)
-    assert r.returncode == 0, r.stdout + r.stderr
-    payload = json.loads(r.stdout)
-    row = payload["results"][0]
-    assert row["estimator"] == estimator
-    assert row["calibration_digest"] == wrote["digest"]
-    assert "device_kind" in row
+    # a search reads it through the flags a user passes: the estimator
+    # it scores with names the kind and the digest of THIS table
+    cfg = FFConfig.parse_args(["--calibration", table_path,
+                               "--cost-estimator", estimator])
+    est, table = estimator_from_config(cfg)
+    assert est.describe() == {"estimator": estimator,
+                              "calibration_digest": wrote["digest"]}
+    assert table.digest == wrote["digest"]
+    _best, _mesh, t = search(_transformer_layers(), 4, budget=5, seed=0,
+                             estimator=est)
+    assert math.isfinite(t) and t > 0
 
 
 def test_cli_calibrate_check_rejects_tamper(tmp_path):

@@ -4,8 +4,8 @@ role-tagged fleet hosts, KV page-chain migration bit-parity, the
 router FF_FAULT kinds (``migrate_fail_at`` / ``route_host_down`` —
 zero unaffected streams fail, pools drain to zero on both engines),
 route/migrate span reconciliation, the TenantAutoscaler fake-clock
-grow/decay cycle, cross-tenant dispatch sharing parity, the FF132
-disagg-topology gate, and the calibrated-replay estimator pins.
+grow/decay cycle, cross-tenant dispatch sharing parity and the FF132
+disagg-topology gate.
 """
 
 import os
@@ -18,13 +18,12 @@ from flexflow_tpu import faults
 from flexflow_tpu.fflogger import capture_events, silenced
 from flexflow_tpu.obs.trace import get_tracer
 from flexflow_tpu.serving.cluster import FleetRouter
-from flexflow_tpu.serving.cluster.bench import (_reconciled, _replay_colo,
-                                                _replay_disagg, build_disagg)
 from flexflow_tpu.serving.fleet import (FleetEngine, ModelRegistry,
                                         TenantAutoscaler, fleet_gate_report)
 from flexflow_tpu.serving.generation import GenerationEngine
-from flexflow_tpu.serving.generation.bench import VOCAB, _build_lm
 from flexflow_tpu.serving.generation.pages import export_pages, import_pages
+from tests.serving_fixtures import (VOCAB, _build_lm, _reconciled,
+                                    build_disagg)
 
 SLOTS, MAX_SEQ = 4, 64
 
@@ -526,31 +525,3 @@ def test_export_pages_rejects_non_page_major():
     bad = {"lstm0": {"state": jnp.zeros((3, 8), jnp.float32)}}
     with pytest.raises(ValueError, match="page-major"):
         export_pages(bad, [0], num_pages=6)
-
-
-# ----------------------------------------------------------------------
-# the calibrated-replay estimator: structural pins on the bench math
-# ----------------------------------------------------------------------
-_CAL = {"decode_step_ms": 5.0, "chunk_op_ms": {"8": 4.0},
-        "mono_prefill_ms": [15.0, 15.0], "migrate_export_ms": 2.0,
-        "migrate_import_ms": 1.0, "migrate_handoff_ms": 0.5}
-
-
-def test_replay_victim_gap_analytics():
-    """Colo's worst victim gap is chunk + decode; disagg's is
-    import + decode — the whole thesis, in closed form on a synthetic
-    price list."""
-    colo = _replay_colo(_CAL, [16, 16], 8, 2)
-    disagg = _replay_disagg(_CAL, [16, 16], 2)
-    assert colo["victim_max_gap_ms"] == pytest.approx(9.0)
-    assert disagg["victim_max_gap_ms"] == pytest.approx(6.0)
-    assert disagg["victim_max_gap_ms"] < colo["victim_max_gap_ms"]
-    # deterministic: same inputs, same row
-    assert disagg == _replay_disagg(_CAL, [16, 16], 2)
-    # disagg TTFT = the FIFO monolithic prefill completions
-    assert disagg["flood_ttft"]["p50_ms"] <= 30.0
-
-
-def test_replay_colo_chunk0_uses_mono_prefill():
-    colo = _replay_colo(_CAL, [16, 16], 0, 2)
-    assert colo["victim_max_gap_ms"] == pytest.approx(20.0)

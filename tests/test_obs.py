@@ -623,24 +623,3 @@ class TestFlightFaults:
         assert any(p.startswith("flight_health_degraded")
                    for p in os.listdir(str(tmp_path))), \
             os.listdir(str(tmp_path))
-
-
-# ----------------------------------------------------------------------
-# serve-bench --trace-out (the acceptance workflow, in-process smoke)
-# ----------------------------------------------------------------------
-@pytest.mark.slow
-def test_serve_bench_trace_out_reconciles(tmp_path, capsys):
-    from flexflow_tpu.obs.trace import trace_main
-    from flexflow_tpu.serving.bench import main as bench_main
-    raw = str(tmp_path / "trace.json")
-    bench_main(["--requests", "24", "--max-batch", "8", "--hidden", "8",
-                "--trace-out", raw])
-    payload = json.loads(capsys.readouterr().out)
-    tr = payload["trace"]
-    assert tr["reconciled"] is True
-    assert tr["terminal_phases"]["completed"] == tr["counters"]["submitted"]
-    assert tr["sample_trace_ids"]
-    out = str(tmp_path / "trace.chrome.json")
-    assert trace_main(["export", raw, "--out", out]) == 0
-    with open(out) as f:
-        assert validate_chrome_trace(json.load(f)) == []

@@ -14,7 +14,6 @@ quantized fleet tenant."""
 
 import dataclasses
 import json
-import os
 import subprocess
 import sys
 
@@ -521,34 +520,3 @@ def test_fleet_schema_rejects_bad_quantize():
     assert "quantize" in text and "dense" in text
     assert validate_fleet_json({"fleet": [
         {"name": "a", "model": "transformer", "quantize": "int8"}]}) == []
-
-
-# ---------------------------------------------------------------------
-# bench stamping + evidence artifact
-# ---------------------------------------------------------------------
-def test_train_bench_rows_stamp_precision_policy():
-    from flexflow_tpu.train_bench import bench_k
-    r = bench_k(1, steps=4, epochs=1, batch_size=8, hidden=16)
-    assert r["precision_policy"] == "f32"
-    r = bench_k(1, steps=4, epochs=1, batch_size=8, hidden=16,
-                compute_dtype="bfloat16")
-    assert r["precision_policy"] == "bf16"
-
-
-def test_shipped_precision_bench_artifact_passes_acceptance():
-    path = os.path.join(REPO, "artifacts", "precision_bench_r15.json")
-    with open(path) as f:
-        payload = json.load(f)
-    assert payload["bench"] == "precision-bench"
-    s = payload["search"]
-    assert s["mixed_beats_baseline"] is True
-    assert s["mixed_precision_ms"] < s["baseline_all_f32_ms"]
-    assert s["bf16_ops"] >= 1
-    q = payload["serve"]["quality"]
-    assert q["bound_ok"] is True
-    assert q["max_abs_err"] <= q["error_bound"]
-    assert q["bytes_after"] < q["bytes_before"]
-    for section in ("train", "serve"):
-        assert section in payload
-    assert payload["train"]["float32"]["steps_per_sec"] > 0
-    assert payload["serve"]["baseline_rows_per_s"] > 0

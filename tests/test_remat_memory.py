@@ -165,8 +165,7 @@ def test_remat_multichip_mesh_executes():
 
 def test_fast_max_pool_matches_autodiff():
     """The custom max-pool VJP (equality-mask scatter; SelectAndScatter
-    replacement — see artifacts/INCEPTION_MFU.md round-5 attribution)
-    must match jax's autodiff gradient bit-for-bit on ties and to float
+    replacement) must match jax's autodiff gradient bit-for-bit on ties and to float
     rounding elsewhere, across layouts / strides / paddings."""
     import jax
     import jax.numpy as jnp

@@ -260,10 +260,17 @@ def test_hybrid_seeded_determinism_across_chain_counts():
 
 def test_hybrid_run_to_run_deterministic_with_residual():
     m = _branchy_model()
-    runs = [search(m.layers, 8, budget=40, seed=3, mode="hybrid")
+    stats = {}
+    runs = [search(m.layers, 8, budget=40, seed=3, mode="hybrid",
+                   stats=stats)
             for _ in range(2)]
     assert strategy_digest(runs[0][0]) == strategy_digest(runs[1][0])
     assert runs[0][2] == runs[1][2]
+    # what a residual run counts: every op is exact or annealed, the
+    # anneal's proposals and how long the best took to appear
+    assert stats["exact_ops"] + stats["residual_ops"] == len(m.layers)
+    assert 0 <= stats["accepted"] <= stats["proposals"] <= 40
+    assert stats["time_to_best_ms"] >= 0
 
 
 # ---------------------------------------------------------------------------

@@ -468,15 +468,3 @@ def test_searched_strategies_predict_zero_fallbacks():
                                  seed=0)
     amesh = AbstractMesh(best_mesh)
     assert predict_fallbacks(model.layers, best, amesh) == {}
-
-
-def test_train_bench_rows_carry_comm_plan_digest(tmp_path, capsys):
-    from flexflow_tpu.train_bench import main as tb_main
-    out = tmp_path / "tb.json"
-    tb_main(["--ks", "1", "--steps", "2", "--epochs", "1",
-             "--batch", "8", "--out", str(out)])
-    payload = json.loads(out.read_text())
-    assert payload["comm_plan_digest"]
-    for r in payload["results"]:
-        assert len(r["comm_plan_digest"]) == 16
-    capsys.readouterr()

@@ -473,7 +473,7 @@ def test_the_third_leaf_is_lent_rolled_back_and_shipped(sparse_lm):
     divergent draft whose windows are partly REJECTED (each window row its
     own chosen set), and a stream that prefills on one engine and decodes on
     another each serve the tokens the graph's own forward gives (float32)."""
-    from flexflow_tpu.serving.cluster.bench import build_disagg
+    from tests.serving_fixtures import build_disagg
 
     model = sparse_lm
     rng = np.random.default_rng(45)
