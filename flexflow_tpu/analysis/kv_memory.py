@@ -163,6 +163,14 @@ def kv_cache_layout(layers: List[Op],
     ``(slots, rows // page_size, page_size, ..)`` with ``"rows"``
     (:func:`window_rows`) noted on the entry — instead of pages of the
     shared pool: the pool's page ids then index the other layers only.
+    An op that a looped stack calls ``loop_passes`` times a token
+    (``Op.loop_passes``; ``models/decoder_lm.py``) keeps a cache of its own
+    for every pass: its page-major leaves get ``loop_passes`` REGIONS of the
+    pool's pages (``(loop_passes * num_pages, page_size, ..)``, ``"passes"``
+    noted on the entry; pass ``t`` of page ``p`` is row ``t * num_pages +
+    p``), so a page id means one page in every pass and a token costs
+    ``loop_passes`` rows; the later passes' ops (``Op.loop_source``) declare
+    nothing of their own.
     A ``"kv"`` entry that also declares ``"counters"`` (``{"shapes",
     "entries"}``: what the op counts on the device beside its pages) gets
     them as an entry of their own, kind ``"counter"``, named ``<op> +
@@ -177,9 +185,21 @@ def kv_cache_layout(layers: List[Op],
     pool = int(num_pages) or default_num_pages(slots, max_seq, page_size)
     out: Dict[str, Dict] = {}
     for op in layers:
+        if op.loop_source is not None:
+            continue    # a later pass's call: its state is its source's
         entry = op.serve_state(int(slots), pool, page_size, mesh_sizes)
         if entry is None:
             continue
+        if op.loop_passes > 1 and entry["kind"] != "counter":
+            if entry["kind"] != "kv" or entry.get("window"):
+                raise ValueError(
+                    f"{op.name} is run {op.loop_passes} times a token and "
+                    f"keeps state that is not pages of the shared pool: a "
+                    f"pass's state of its own needs page-major leaves")
+            # a region of the pool's pages for each pass, in ONE leaf
+            entry = dict(entry, passes=op.loop_passes, shapes={
+                leaf: (op.loop_passes * shape[0],) + tuple(shape[1:])
+                for leaf, shape in entry["shapes"].items()})
         if entry.get("window"):
             rows = window_rows(entry["window"], max_seq, page_size,
                                prefill_chunk)

@@ -46,7 +46,7 @@ MeshShape = Dict[str, int]
 # the walk can never propose a precision the verifier rejects.
 F32_PINNED_OPS = frozenset({
     OpType.MSELOSS, OpType.SOFTMAX, OpType.BATCHNORM,
-    OpType.LAYERNORM, OpType.RMSNORM,
+    OpType.LAYERNORM, OpType.RMSNORM, OpType.EXIT_GATE,
 })
 
 

@@ -228,7 +228,7 @@ def communication_plan(layers: List[Op],
                 c_deg *= deg
             else:
                 repl *= deg
-        for w in op.weights:
+        for w in op.own_weights():     # a shared one syncs once
             if not w.trainable:
                 continue
             wb = w.volume * 4

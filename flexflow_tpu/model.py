@@ -114,6 +114,10 @@ class FFModel:
         self.loss_type: Optional[str] = None
         self.metrics: List[str] = []
         self._name_counts: Dict[str, int] = {}
+        # ``(first op, ops a pass, passes)`` where a stretch of the layer
+        # list is one stack laid several times with the same parameters
+        # (``models/decoder_lm.py``, ``loops``), else None
+        self.loop: Optional[Tuple[int, int, int]] = None
         self._compiled = False
         # runtime state
         self._params: Dict[str, jax.Array] = {}
@@ -359,6 +363,17 @@ class FFModel:
     def rms_norm(self, input_tensor, eps=1e-6, name=None) -> Tensor:
         return self._register(
             RMSNorm(self._uname("rmsnorm", name), input_tensor, eps)).outputs[0]
+
+    def exit_gate(self, states, threshold=1.0, kernel_initializer=None,
+                  name=None) -> Tensor:
+        """The state of the pass a token leaves a looped stack by
+        (``ops/exit_gate.py``): ``states`` are the passes' normed ends, in
+        order; the first whose cumulative exit mass reaches ``threshold``
+        is the output."""
+        from .ops.exit_gate import ExitGate
+        return self._register(ExitGate(
+            self._uname("exit_gate", name), states, threshold,
+            kernel_initializer)).outputs[0]
 
     # element unary/binary builders (reference model.h: exp/relu/... adders)
     def _unary(self, fn, x, name=None, scalar=None) -> Tensor:

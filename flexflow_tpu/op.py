@@ -52,6 +52,7 @@ class OpType(enum.Enum):
     LSTM = "lstm"
     PIPELINE = "pipeline"
     MOE = "moe"
+    EXIT_GATE = "exit_gate"
     INPUT = "input"
 
 
@@ -195,6 +196,13 @@ class Op:
     # programs open round it): the parts an owner table tells apart
     # (``obs/device_ops.table_from_hlo``)
     scopes: Tuple[str, ...] = ()
+    # a stack run several times with the same parameters (``models/
+    # decoder_lm.py``, ``loops``): a later pass's op is ``loop_source``'s
+    # call again, and what it keeps between tokens lives in THAT op's
+    # leaves, which hold ``loop_passes`` regions of the pool's pages, one a
+    # pass (``analysis/kv_memory.kv_cache_layout``, ``GraphDecoder._walk``)
+    loop_passes: int = 1
+    loop_source: Optional["Op"] = None
 
     def __init__(self, name: str, inputs: Sequence[Tensor]):
         self.name = name
@@ -220,6 +228,15 @@ class Op:
                       trainable=trainable)
         self.weights.append(p)
         return p
+
+    def own_weights(self) -> List[Parameter]:
+        """The parameters this op is the FIRST owner of: all of them, but for
+        an op that reads another's (``FFModel.share_weights``), whose
+        parameters are held, updated and all-reduced once, at the op that
+        made them (``Parameter.pcname``).  What the memory and the
+        weight-sync accounting go over; a call site still READS all of
+        ``weights``."""
+        return [w for w in self.weights if w.pcname == self.name]
 
     # --- execution ------------------------------------------------------
     def forward(self, params: Dict[str, jax.Array], inputs: List[jax.Array],

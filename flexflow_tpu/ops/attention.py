@@ -31,13 +31,12 @@ from jax.sharding import PartitionSpec
 from ..initializers import (ConstantInitializer, GlorotUniform,
                             ZeroInitializer)
 from ..op import Op, OpContext, OpType
-from .common import cast_compute
+from .common import add_wide, cast_compute, read_wide
 from .norm import rms_normalize
 
 NEG_INF = -1e30  # finite mask value: keeps online-softmax exp() NaN-free
 _LANES = 128
 _KEY_BLOCK = 512    # keys a block of a sparse op's long chunk history
-_WIDE = 24          # bits of a wide counter's low word (``_add_wide``)
 
 
 def _use_flash(q, k, ctx_flag, training_dropout: bool,
@@ -334,15 +333,6 @@ def selected(scores, kpos, thr, last):
     :func:`select_threshold`'s."""
     thr, last = thr[..., None], last[..., None]
     return (scores > thr) | ((scores == thr) & (kpos <= last))
-
-
-def _add_wide(counts, x):
-    """``counts`` (.., 2) int32, each a ``[high, low]`` pair in base ``2 **
-    _WIDE``, plus ``x`` (..,) int32 < 2 ** 30: sums of live positions pass
-    2 ** 31 within minutes of serving, and x64 is off."""
-    low = counts[..., 1] + x
-    return jnp.stack([counts[..., 0] + (low >> _WIDE),
-                      low & ((1 << _WIDE) - 1)], axis=-1)
 
 
 def _decode_attention(q, k_cache, v_cache, pos, scale: float,
@@ -847,7 +837,7 @@ class MultiHeadAttention(Op):
             # wide; and what the op counts of its choosing, on the device
             # (``counts`` rows: queries, of them with a history no longer
             # than ``topk``, positions chosen, positions live; each a
-            # ``[high, low]`` pair, :func:`_add_wide`)
+            # ``[high, low]`` pair, :func:`~.common.add_wide`)
             out["shapes"]["ik"] = (num_pages, page_size, self.index_width)
             out["entries"]["ik"] = (None, None, None)
             out["values"] = {"ik": self.index_dim}
@@ -1151,17 +1141,14 @@ class MultiHeadAttention(Op):
         step = jnp.stack([
             jnp.sum(live), jnp.sum(live & (seen <= self.topk)),
             jnp.sum(seen) if chosen is None else chosen, jnp.sum(seen)])
-        return dict(state, counts=_add_wide(state["counts"],
-                                            step.astype(jnp.int32)))
+        return dict(state, counts=add_wide(state["counts"],
+                                           step.astype(jnp.int32)))
 
     def selection_stats(self, counts):
         """The op's counters as fetched (``counts`` (4, 2), :meth:`_counted`)
         -> ``{"topk", "queries", "dense_queries", "chosen_mean",
         "live_mean"}``: a mean is over the live queries."""
-        import numpy as np
-        wide = np.asarray(counts, np.int64)
-        queries, dense, chosen, live = (
-            int(v) for v in (wide[:, 0] << _WIDE) + wide[:, 1])
+        queries, dense, chosen, live = read_wide(counts)
         return {"topk": self.topk, "queries": queries,
                 "dense_queries": dense,
                 "chosen_mean": chosen / queries if queries else 0.0,

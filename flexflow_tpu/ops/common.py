@@ -41,6 +41,26 @@ def dtype_itemsize(dtype) -> int:
     return jnp.dtype(dtype).itemsize
 
 
+WIDE = 24           # bits of a wide counter's low word (``add_wide``)
+
+
+def add_wide(counts, x):
+    """``counts`` (.., 2) int32, each a ``[high, low]`` pair in base ``2 **
+    WIDE``, plus ``x`` (..,) int32 < 2 ** 30: what an op counts on the
+    device for ``stats()`` (sums of live positions) passes 2 ** 31 within
+    minutes of serving, and x64 is off."""
+    low = counts[..., 1] + x
+    return jnp.stack([counts[..., 0] + (low >> WIDE),
+                      low & ((1 << WIDE) - 1)], axis=-1)
+
+
+def read_wide(counts):
+    """Wide counters as fetched, ``(.., 2)``, as a list of Python ints."""
+    import numpy as np
+    wide = np.asarray(counts, np.int64)
+    return [int(v) for v in (wide[..., 0] << WIDE) + wide[..., 1]]
+
+
 def cast_compute(x: jax.Array, ctx) -> jax.Array:
     dt = jnp.dtype(ctx.compute_dtype)
     if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype != dt:

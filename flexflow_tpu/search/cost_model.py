@@ -82,7 +82,7 @@ _VPU_OPS = {
     OpType.ELEMENT_UNARY, OpType.ELEMENT_BINARY, OpType.SOFTMAX,
     OpType.BATCHNORM, OpType.LAYERNORM, OpType.RMSNORM, OpType.DROPOUT,
     OpType.POOL2D, OpType.EMBEDDING, OpType.CONCAT, OpType.SPLIT,
-    OpType.FLAT, OpType.RESHAPE, OpType.TRANSPOSE,
+    OpType.FLAT, OpType.RESHAPE, OpType.TRANSPOSE, OpType.EXIT_GATE,
 }
 
 
@@ -252,7 +252,7 @@ def op_memory_components(op: Op, part_degrees: Tuple[int, ...],
     for d in part_degrees:
         nparts *= d
     state = 0.0
-    for w in op.weights:
+    for w in op.own_weights():     # a shared parameter resides once
         if w.name in sparse_tables:
             # sparse-update table (FFModel._sparse_embedding_specs): no
             # table-shaped gradient ever materializes (row grads are

@@ -267,7 +267,9 @@ class Simulator:
             # dps // c_deg members of a DP replica group — groups larger
             # than that ride DCN for the cross-slice ring.
             dps = self.devices_per_slice
-            for w in op.weights:
+            # a parameter several ops read has ONE gradient (autodiff sums
+            # the call sites'), reduced once: at its first owner
+            for w in op.own_weights():
                 if not w.trainable:
                     continue
                 wb = w.volume * 4

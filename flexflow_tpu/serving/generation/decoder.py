@@ -65,11 +65,15 @@ embeddings, and whatever else writes the contract.  Anything else
 validation loudly at construction — a generation engine must never
 silently produce wrong tokens for an unsupported graph.  What a graph
 with a WINDOWED entry cannot do yet is refused in one place,
-:meth:`GraphDecoder.refusal`.
+:meth:`GraphDecoder.refusal`.  A stack the graph lays several times with
+the same parameters (``FFModel.loop``) is walked once, under a loop over
+its passes, each pass in a region of its own of the ops' leaves
+(:meth:`GraphDecoder._walk`).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import re
 from typing import Dict, List, Tuple
 
@@ -215,6 +219,12 @@ class GraphDecoder:
         # what ops count on the device for stats(), by entry name
         self.counters = tuple(name for name, ent in self.layout.items()
                               if ent["kind"] == "counter")
+        if self._loop is not None:      # the entries the loop carries
+            mine = {op.name for op in model.layers[
+                self._loop[0]:self._loop[0] + self._loop[1]]}
+            self._loop_state = tuple(
+                name for name in self.layout
+                if name in mine or name[:-len(COUNTERS)] in mine)
         kinds = {ent["kind"] for ent in self.layout.values()} - {"counter"}
         # a fixed per-slot "state" leaf cannot page: a chunk at offset k
         # would need the carry from chunk k-1 as a program input —
@@ -289,6 +299,23 @@ class GraphDecoder:
         self._vocab = int(final.shape[-1])
         for op in model.layers:
             op.serve_check(self.max_seq)
+        # a stack laid several times: ``(first op, ops a pass, passes)``,
+        # the ONE tensor a pass reads from outside itself, and each pass's
+        # last output (what the next starts from, and what reads them all)
+        self._loop = model.loop
+        if self._loop is not None:
+            first, body, passes = self._loop
+            ops = model.layers[first:first + passes * body]
+            made = {t.uid for op in ops[:body] for t in op.outputs}
+            outside = {t.uid for op in ops[:body] for t in op.inputs
+                       if t.uid not in made}
+            if len(outside) != 1:
+                raise ValueError(
+                    f"a looped stack must read ONE tensor from outside "
+                    f"itself, pass 1 reads {len(outside)}")
+            self._loop_io = (outside.pop(), [
+                ops[t * body + body - 1].outputs[0].uid
+                for t in range(passes)])
 
     # ---- shared context ------------------------------------------------
     def _ctx(self) -> OpContext:
@@ -422,11 +449,55 @@ class GraphDecoder:
         with the op's own leaves of ``caches``.  Returns the final
         tensor's value (.., V) and the caches as the ops left them: the
         same structure and leaf names, since the programs donate them
-        and hand them on."""
+        and hand them on.
+
+        A stack the graph lays several times with the same parameters
+        (``model.loop``) is walked ONCE, under a loop over its passes:
+        the ops of pass 1 run each time (they ARE the later passes' ops,
+        parameter for parameter), pass ``t`` reading and writing region
+        ``t`` of their leaves (:meth:`_in_pass`), so a program holds one
+        pass's instructions whatever the number of passes (walked op by
+        op, the 192 call sites of a 48-layer stack run four times took
+        the TPU's compiler 46-57 s a program, nine programs an engine:
+        PERF.md, PR 49)."""
         ctx = self._ctx()
         values: Dict[int, jax.Array] = {self._input_uid: x}
         new: Dict[str, Dict[str, jax.Array]] = {}
-        for op in self.model.layers:
+        ops = self.model.layers
+        if self._loop is None:
+            self._run(ops, params, caches, new, values, where, ctx)
+            return values[self._final_uid], new
+        first, body, passes = self._loop
+        self._run(ops[:first], params, caches, new, values, where, ctx)
+        one_pass = ops[first:first + body]
+        enters, leaves = self._loop_io
+
+        def run_pass(t, carry):
+            h, state, states = carry
+            vals, kept = {enters: h}, {}
+            self._run(one_pass, params, state, kept, vals,
+                      self._in_pass(where, t), ctx)
+            h = vals[leaves[0]]
+            with jax.named_scope("step_io"):
+                return h, kept, jax.lax.dynamic_update_index_in_dim(
+                    states, h, t, 0)
+
+        h = values[enters]
+        _, kept, states = jax.lax.fori_loop(
+            0, passes, run_pass,
+            (h, {name: caches[name] for name in self._loop_state},
+             jnp.zeros((passes,) + h.shape, h.dtype)))
+        new.update(kept)
+        values.update((uid, states[t]) for t, uid in enumerate(leaves))
+        self._run(ops[first + passes * body:], params, caches, new, values,
+                  where, ctx)
+        return values[self._final_uid], new
+
+    def _run(self, ops, params, caches, new, values, where, ctx) -> None:
+        """``ops`` in order, each under its name: inputs out of ``values``
+        and outputs into it, its leaves out of ``caches`` and, as it left
+        them, into ``new``."""
+        for op in ops:
             ins = [values[t.uid] for t in op.inputs]
             state = caches.get(op.name)
             # an op that pages AND counts is handed both entries' leaves
@@ -446,7 +517,22 @@ class GraphDecoder:
                 new[op.name] = state
             for t, val in zip(op.outputs, outs):
                 values[t.uid] = val
-        return values[self._final_uid], new
+
+    def _in_pass(self, where: ServeStep, t) -> ServeStep:
+        """``where`` as pass ``t`` of a looped stack sees it: every page id
+        moved into region ``t`` of the leaves (``t * num_pages`` on), the
+        pool's sentinel to the end of ALL regions, which is what an op
+        reads off its leaves as the sentinel (``leaf.shape[0]``)."""
+        end = self._loop[2] * self.num_pages
+
+        def region(pages):
+            return None if pages is None else jnp.where(
+                pages >= self.num_pages, end, pages + t * self.num_pages)
+
+        with jax.named_scope("step_io"):
+            return dataclasses.replace(
+                where, table=region(where.table),
+                write_pages=region(where.write_pages), no_page=end)
 
     def _walk_decode(self, params, caches, tokens, pos, table,
                      write_pages, write_rows):
@@ -911,6 +997,29 @@ class GraphDecoder:
                                         * int(np.size(c["load"]))
                                         for c in moe),
                 "moe_untouched": sum(int(c["untouched"]) for c in moe)}
+
+    def loop_stats(self, host) -> Dict:
+        """``{"passes", "tokens", "loop_passes", "exits_by_pass",
+        "exit_mass_by_pass"}`` of a stack the graph runs several times a
+        token, from ``host``, a token step's counters fetched: what the exit
+        gate counted of the live rows it served (``ExitGate.loop_stats``);
+        ``{}`` for a graph without one."""
+        for op in self.model.layers:
+            counted = (host or {}).get(op.name)
+            if counted is not None and hasattr(op, "loop_stats"):
+                return {"passes": op.passes,
+                        **op.loop_stats(counted["counts"])}
+        return {}
+
+    def span_totals(self, host) -> Dict[str, int]:
+        """What a ``decode_step`` span carries of the ops' counters as they
+        stood behind that step: :meth:`moe_totals`, and for a looped stack
+        ``loop_tokens`` and ``loop_passes`` (the live rows the exit gate
+        served, and the passes they were run through)."""
+        loop = self.loop_stats(host)
+        return {**self.moe_totals(host),
+                **({"loop_tokens": loop["tokens"],
+                    "loop_passes": loop["loop_passes"]} if loop else {})}
 
     # ---- shared-instance registry --------------------------------------
     @classmethod

@@ -1220,10 +1220,13 @@ class GenerationEngine:
                  if ent["kind"] == "kv" and not ent.get("window")}
         out = {"full": {"entries": len(paged),
                         # a token's bytes by entry, as STORED (every leaf's
-                        # declared width, lane padding included)
+                        # declared width, lane padding included; a row in
+                        # every pass's region where a looped stack calls
+                        # the op several times a token)
                         "bytes_per_token": {
-                            name: itemsize * sum(int(shape[-1]) for shape
-                                                 in ent["shapes"].values())
+                            name: itemsize * ent.get("passes", 1)
+                            * sum(int(shape[-1]) for shape
+                                  in ent["shapes"].values())
                             for name, ent in paged.items()},
                         "num_pages": self.num_pages,
                         "in_use": pool.pages_in_use,
@@ -1310,6 +1313,10 @@ class GenerationEngine:
                    "ahead": self._pipe_ahead,
                    "drained": dict(self._pipe_drained),
                    "dropped_tokens": self._pipe_dropped}}
+        # what a token leaves in the shared pool, all entries (and, for a
+        # looped stack, all passes) together
+        out["kv_bytes_per_token"] = sum(
+            out["kv_pages"]["full"]["bytes_per_token"].values())
         chunk_attention = self._decoder.chunk_attention()
         if chunk_attention:     # latent attention, or a learned selection
             out["chunk_attention"] = chunk_attention
@@ -1328,6 +1335,13 @@ class GenerationEngine:
         sparse = self._decoder.sparse_stats(self._counters_host)
         if sparse:
             out["sparse_attention"] = sparse
+        # a stack run several times a token: the passes the served rows
+        # went through and the exit gate's mass by pass, the same way
+        loop = self._decoder.loop_stats(self._counters_host)
+        if loop:
+            out.update(loop_passes=loop["loop_passes"],
+                       exit_mass_by_pass=loop["exit_mass_by_pass"],
+                       loop=loop)
         return out
 
     # ---- dispatcher thread ---------------------------------------------
@@ -1722,9 +1736,9 @@ class GenerationEngine:
                 (time.perf_counter() - e0) * 1e3)
             # charge only the REAL chain (the pad rows are a fixed-
             # shape compile-cache artifact, not shipped state)
-            nbytes = sum(int(a.nbytes) // int(a.shape[0])
-                         for sub in host.values()
-                         for a in sub.values()) * len(st.pages)
+            nbytes = sum(int(a.nbytes) for sub in host.values()
+                         for a in sub.values()) * len(st.pages) // max(
+                             len(st.pages), self._decoder.pages_per_slot)
             payload = {
                 "stream": stream,
                 "prompt": st.prompt,
@@ -1853,7 +1867,8 @@ class GenerationEngine:
             if why is not None:
                 raise ValueError(why)
             self._caches = {**self._caches, **import_pages(
-                self._paged_caches(), payload["pages"], pages)}
+                self._paged_caches(), payload["pages"], pages,
+                self.num_pages)}
             self.migrate_import_ms.append(
                 (time.perf_counter() - i0) * 1e3)
         except BaseException as e:  # noqa: BLE001 — a poisoned import
@@ -2114,7 +2129,7 @@ class GenerationEngine:
                               step=f.step, active=len(f.rows),
                               phase="decode",
                               program=_program_name(f.fn),
-                              **self._decoder.moe_totals(f.counters))
+                              **self._decoder.span_totals(f.counters))
         self.metrics.record_decode_step(emitted, now - f.t0)
         self._fire_cancel_at_token(f.rows, now)
         if self.stats_every and (f.step + 1) % self.stats_every == 0:

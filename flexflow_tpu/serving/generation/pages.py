@@ -355,7 +355,10 @@ def export_pages(caches, pages: List[int], num_pages: int,
     to host in ONE ``device_get`` — the export half of disaggregated
     prefill/decode migration (docs/serving.md "Disaggregated
     prefill/decode").  ``pages`` is the slot's page-id chain IN ORDER;
-    every leaf must be page-major (``shape[0] == num_pages``), which is
+    every leaf must be page-major (``shape[0]`` the pool's ``num_pages``,
+    or a whole number of REGIONS of them: an op a looped stack calls
+    several times a token keeps a region a pass, and a page id names one
+    page in each, :func:`region_rows`), which is
     true exactly for the attention K/V pools — LSTM ``state`` leaves
     are slot-major and cannot migrate (the engine gates migration on
     chunkable attention graphs for the same reason).  Returns a host
@@ -378,21 +381,34 @@ def export_pages(caches, pages: List[int], num_pages: int,
     for name, sub in caches.items():
         rows = {}
         for leaf, arr in sub.items():
-            if arr.shape[0] != num_pages:
+            if arr.shape[0] % num_pages:
                 raise ValueError(
                     f"cache leaf {name}.{leaf} is not page-major "
                     f"(shape {tuple(arr.shape)}, pool has {num_pages} "
                     f"pages): this graph's state cannot migrate")
-            rows[leaf] = arr[idx]
+            rows[leaf] = arr[region_rows(idx, arr.shape[0], num_pages)]
         gathered[name] = rows
     # one transfer for the whole pytree (RL010-class budget: migration
     # costs one sync on the source, one put on the destination)
     return jax.device_get(gathered)
 
 
-def import_pages(caches, payload, pages: List[int]):
+def region_rows(idx, rows: int, num_pages: int):
+    """The rows of a page-major leaf of ``rows`` rows that the page ids
+    ``idx`` name: the ids themselves, and for a leaf that holds several
+    REGIONS of the pool's ``num_pages`` pages (one a pass of a looped
+    stack) the same pages of every region, region by region."""
+    import numpy as np
+
+    return np.concatenate([idx + r * num_pages
+                           for r in range(rows // num_pages)])
+
+
+def import_pages(caches, payload, pages: List[int], num_pages: int = 0):
     """Scatter an :func:`export_pages` payload into ``pages`` of the
-    DESTINATION pool with ONE ``device_put`` of the payload pytree —
+    DESTINATION pool (of ``num_pages`` pages; 0: as many as a leaf has
+    rows, no leaf holds regions) with ONE ``device_put`` of
+    the payload pytree —
     the import half of KV page migration.  ``pages`` are freshly
     allocated destination page ids (one per exported page, same order).
     Returns the updated caches pytree (functional ``.at[].set`` — the
@@ -414,8 +430,17 @@ def import_pages(caches, payload, pages: List[int]):
 
     idx = np.asarray(list(pages), np.int32)
     dev = jax.device_put(payload)
-    rows0 = next(iter(next(iter(dev.values())).values())).shape[0] \
-        if isinstance(dev, dict) and dev else idx.size
+
+    def regions(arr):
+        return arr.shape[0] // num_pages if num_pages else 1
+
+    rows0 = idx.size
+    if isinstance(dev, dict) and dev:
+        name0, sub0 = next(iter(dev.items()))
+        leaf0, val0 = next(iter(sub0.items()))
+        # a leaf of several regions ships its pages once a region
+        rows0 = val0.shape[0] // regions(
+            caches.get(name0, {}).get(leaf0, val0))
     if rows0 > idx.size:
         idx = np.concatenate(
             [idx, np.full(rows0 - idx.size, idx[-1], np.int32)])
@@ -432,7 +457,7 @@ def import_pages(caches, payload, pages: List[int]):
         for leaf, arr in sub.items():
             val = rows[leaf]
             if tuple(val.shape[1:]) != tuple(arr.shape[1:]) \
-                    or val.shape[0] != idx.size:
+                    or val.shape[0] != idx.size * regions(arr):
                 raise ValueError(
                     f"migration payload {name}.{leaf} shape "
                     f"{tuple(val.shape)} does not fit destination pool "
@@ -442,7 +467,10 @@ def import_pages(caches, payload, pages: List[int]):
     for name, sub in caches.items():
         rows = dev[name]
         out[name] = {
-            leaf: _scatter_rows(arr, idx, rows[leaf].astype(arr.dtype))
+            leaf: _scatter_rows(
+                arr, region_rows(idx, arr.shape[0],
+                                 num_pages or arr.shape[0]),
+                rows[leaf].astype(arr.dtype))
             for leaf, arr in sub.items()}
     return out
 

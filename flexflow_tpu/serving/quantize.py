@@ -43,7 +43,9 @@ QUANT_MODES = ("", "int8")
 
 def eligible_weights(layers) -> List[Tuple[Any, Any]]:
     """``[(op, weight), ...]`` of the kernels int8 quantization applies
-    to: 2-D Linear matmul kernels.  Device-free (type/shape checks
+    to: 2-D Linear matmul kernels, each ONCE, at its first owner (a
+    kernel several ops read, ``FFModel.share_weights``, is one array to
+    quantize and one to charge).  Device-free (type/shape checks
     only), so the fleet gate sizes an uncompiled graph with the exact
     predicate the runtime quantizes by."""
     from ..ops.linear import Linear, host_placed
@@ -56,7 +58,7 @@ def eligible_weights(layers) -> List[Tuple[Any, Any]]:
             # them would change that contract for negligible HBM win
             continue
         w = getattr(op, "w_kernel", None)
-        if w is not None and len(w.shape) == 2:
+        if w is not None and len(w.shape) == 2 and w in op.own_weights():
             out.append((op, w))
     return out
 

@@ -3294,6 +3294,48 @@ def test_the_sparse_token_step_chooses_without_a_sort_or_a_view(
     assert len([l for l in gathers if "dsa_core" in l]) == 2, gathers
 
 
+def test_a_looped_token_step_reads_every_passs_region_in_place(
+        v5e_device, monkeypatch):
+    """The WHOLE token step of a stack run three times (two layers at the
+    widths of the cell that serves one: 16 heads of 128 with as many
+    key/value heads, pages of 16, bfloat16), as one TPU traces it, compiled
+    by the TPU's compiler for a described v5e: the passes are ONE ``while``
+    whose body holds the paged decode kernel (each layer's call reads its
+    pass's region of the leaves where it lies), and no pool-sized array is
+    copied, though every leaf is carried round the loop."""
+    from flexflow_tpu.models import build_decoder_lm
+    from flexflow_tpu.ops import (attention as attn_mod, flash_kernel,
+                                  paged_decode_kernel)
+
+    monkeypatch.setattr(attn_mod.jax, "default_backend", lambda: "tpu")
+    for mod in (flash_kernel, paged_decode_kernel):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+    cfg = ff.FFConfig(batch_size=2, compute_dtype="bfloat16", seed=0)
+    cfg.param_dtype = "bfloat16"
+    cfg.serve_gen_slots, cfg.serve_gen_max_seq = 4, 448
+    cfg.serve_prefill_chunk, cfg.serve_kv_page = 64, 16
+    model = build_decoder_lm(
+        cfg, [{"attention": "full_attention", "heads": 16,
+               "mlp": "dense"}] * 2,
+        d_model=2048, head_dim=128, num_kv_heads=16, d_ff=512,
+        vocab_size=512, seq_len=448, sandwich=True, loops=3, exit_gate=1.0,
+        rope={"full_attention": {"rope_theta": 1e6}})[0]
+    model.compile(ff.SGDOptimizer(lr=0.01), mesh=ff.MachineMesh({"n": 1}))
+    dec = GraphDecoder(model, 4, 448, prefill_chunk=64)
+    assert dec.layout["attention_1"]["shapes"]["v"] == (3 * 4 * 28, 16, 2048)
+    fn = dec.decode_fn()
+    with _no_compilation_cache():
+        copies = _within(_COMPILE_LIMIT_S,
+                         lambda: dec.pool_copies(v5e_device))
+        (args,) = [a for _, _, f, a in dec._program_specs(v5e_device)
+                   if f is fn]
+        text = fn.lower(*args).compile().as_text()
+    assert copies == {"jit_decode": {"count": 0, "bytes": 0}}
+    assert dec.decode_attention() == {"paged": 2, "gathered": 0}
+    assert "paged_decode_attention" in text
+    assert len([l for l in text.splitlines() if " while(" in l]) == 1
+
+
 def test_the_serving_programs_of_the_graphs_that_were_there_are_the_parents():
     """The token step and one chunk program of the tiny post-norm decoder
     (``gpt1``'s family) and of the tiny laguna graph lower to the text they
