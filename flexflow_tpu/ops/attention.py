@@ -1052,13 +1052,18 @@ class MultiHeadAttention(Op):
           :meth:`_sparse_over_blocks` (``"loop"``): scores and core a block
           of keys at a time, never ``(heads, chunk, max_seq)`` at once;
         * ``"token"``: where :meth:`_decode_core` says the in-place read
-          applies (a TPU, one device) the chosen ROWS are copied out of the
-          pools, ``topk`` a slot, and attended over densely
-          (``decode_core`` ``"rows"``: the core then reads ``topk`` rows a
-          slot whatever the history), the rows CHOSEN by a kernel that
-          reads ``ik`` in place (:meth:`_chosen_rows`); elsewhere the
-          slot's whole view under a mask (``"gathered"``), bit for bit the
-          dense op under ``topk``;
+          applies (a TPU, one device) the set is CHOSEN by a kernel that
+          reads ``ik`` in place (:meth:`_chosen_set`) and the core is one of
+          two (:meth:`_token_form`, on the table's shape).  A table of no
+          more pages than the op chooses rows (``pages_per_slot <= topk``):
+          the paged decode kernel reads every live page where it lies,
+          once, under the chosen set as a MASK (``decode_core``
+          ``"paged"``, :meth:`_sparse_paged`: no list of rows, nothing
+          gathered).  A longer table: the chosen ROWS are copied out of the
+          pools, ``topk`` a slot, and attended over densely (``"rows"``,
+          :meth:`_sparse_rows`: the core then reads ``topk`` rows a slot
+          whatever the history).  Elsewhere the slot's whole view under a
+          mask (``"gathered"``), bit for bit the dense op under ``topk``;
         * ``"window"``: each row its own set, as a mask over the views,
           bit for bit on the CPU the sequential token step's."""
         n, w = xq.shape[:2]
@@ -1087,13 +1092,15 @@ class MultiHeadAttention(Op):
             return g.reshape(n, L, -1)[..., :self.index_dim]
 
         if token:
-            self.decode_core = ("rows" if self._decode_core(k_pool, ctx)
-                                == "paged" else "gathered")
+            self.decode_core = self._token_form(k_pool, table, ctx)
         if chunk:
             self.chunk_core[w] = "loop" if L > _KEY_BLOCK else "mask"
         if chunk and L > _KEY_BLOCK:
             attn, chosen = self._sparse_over_blocks(
                 q, qi, wi, k_pool, v_pool, i_pool, where, scale)
+        elif token and self.decode_core == "paged":
+            attn, chosen = self._sparse_paged(q, qi, wi, k_pool, v_pool,
+                                              i_pool, where, scale)
         elif token and self.decode_core == "rows":
             attn, chosen = self._sparse_rows(q, qi, wi, k_pool, v_pool,
                                              i_pool, where, scale)
@@ -1154,6 +1161,38 @@ class MultiHeadAttention(Op):
                 "chosen_mean": chosen / queries if queries else 0.0,
                 "live_mean": live / queries if queries else 0.0}
 
+    def _token_form(self, pool, table, ctx: OpContext) -> str:
+        """The form of a sparse op's token step, from what the code can see
+        (never a flag): ``"gathered"`` where the in-place read does not
+        apply (:meth:`_decode_core`); else ``"paged"`` where a slot's
+        ``table`` has no more pages than the op chooses rows, ``"rows"``
+        beyond.  Reading pages costs by the HISTORY, reading rows by
+        ``topk``, and on this chip a copy costs by its count, a 16 KB page
+        about what a 1 KB row does: with ``pages_per_slot <= topk`` the page
+        reader can never issue more copies a slot and pool than the row form
+        gathers rows."""
+        if self._decode_core(pool, ctx) != "paged":
+            return "gathered"
+        return "paged" if table.shape[1] <= self.topk else "rows"
+
+    def _sparse_paged(self, q, qi, wi, k_pool, v_pool, i_pool, where, scale):
+        """A token step that reads its slot's live PAGES once, where they
+        lie, under the chosen set as a mask
+        (``paged_decode_kernel.paged_sparse_attention``): the set is never
+        turned into a list and no row is copied out of a pool.  The softmax
+        runs over exactly the chosen positions at or under ``pos``, float32
+        statistics, as the paged kernel keeps them everywhere.  -> (attention
+        (n, h * hd) f32 folded, zero for a slot that is not decoding; the
+        count of live positions chosen by decoding slots)."""
+        from .paged_decode_kernel import paged_sparse_attention
+        keep, chosen = self._chosen_set(qi, wi, i_pool, where)
+        with jax.named_scope("dsa_core"):
+            attn = paged_sparse_attention(
+                self._fold_rows(q[:, 0]), k_pool, v_pool, where.table,
+                where.pos, where.write_pages, keep, self.num_heads, scale,
+                self.num_kv_heads)
+        return attn, chosen
+
     def _sparse_rows(self, q, qi, wi, k_pool, v_pool, i_pool, where, scale):
         """A token step that COPIES the chosen rows: each slot's one query
         scores its slot's positions, the ``topk`` best are named as a LIST
@@ -1177,26 +1216,23 @@ class MultiHeadAttention(Op):
                                      where.pos, scale, kpos=kpos)
         return attn, chosen
 
-    def _chosen_rows(self, qi, wi, i_pool, where):
-        """The choice of a token step's ``"rows"`` form -> ``(idx (n, topk)
-        int32: the chosen positions in any order, pid (n, topk): the page of
-        the slot's table that holds each, alive (n, topk): which of them a
-        decoding slot's query may see, the count of live positions chosen
-        by decoding slots)``.  Scores and threshold in ONE kernel that reads
-        the live pages of ``i_pool`` in place, a slot's score row in VMEM
-        (``dsa_index``: :mod:`paged_index_kernel`, which takes every ``ik``
-        leaf beside a pool that :meth:`_decode_core` takes: the same dtype
-        and page, a row of whole lane tiles), then the chosen set as a list
-        by rank (``dsa_select``: ``rows_by_rank``, no sort and no scatter)
-        and each position's page from the table; a slot that does not
-        decode reads nothing and gets dead rows.  EXACT for the scores
-        the kernel makes, of equal scores the lower position first: the set
+    def _chosen_set(self, qi, wi, i_pool, where):
+        """The choice of a token step on one TPU -> ``(keep (n, L) bool: the
+        chosen positions of each slot's table, the count of live positions
+        chosen by decoding slots)``.  Scores and threshold in ONE kernel that
+        reads the live pages of ``i_pool`` in place, a slot's score row in
+        VMEM (``dsa_index``: :mod:`paged_index_kernel`, which takes every
+        ``ik`` leaf beside a pool that :meth:`_decode_core` takes: the same
+        dtype and page, a row of whole lane tiles), then the set as a mask
+        (``dsa_select``: :func:`selected`); a slot that does not decode reads
+        nothing and keeps dead positions.  EXACT for the scores the kernel
+        makes, of equal scores the lower position first: the set
         ``jax.lax.top_k`` names on them.  (The scores are
         :func:`index_scores`' arithmetic, bit for bit under the CPU's
         interpreter; on the chip the kernel's products sum in another order
         than XLA's over a view, the same precision and not the same bits,
         so of positions that all but tie another may be the 2 048-th.)"""
-        from .paged_index_kernel import paged_index_select, rows_by_rank
+        from .paged_index_kernel import paged_index_select
         with jax.named_scope("dsa_index"):
             scores, thr, last = paged_index_select(
                 qi[:, 0], wi[:, 0], i_pool, where.table, where.pos,
@@ -1204,6 +1240,20 @@ class MultiHeadAttention(Op):
         with jax.named_scope("dsa_select"):
             keep = selected(scores, jnp.arange(scores.shape[1]), thr, last)
             chosen = jnp.sum(keep & (scores > NEG_INF / 2) & where.live(1))
+        return keep, chosen
+
+    def _chosen_rows(self, qi, wi, i_pool, where):
+        """The choice of a token step's ``"rows"`` form -> ``(idx (n, topk)
+        int32: the chosen positions in any order, pid (n, topk): the page of
+        the slot's table that holds each, alive (n, topk): which of them a
+        decoding slot's query may see, the count of live positions chosen
+        by decoding slots)``: :meth:`_chosen_set`, then the set as a LIST by
+        rank (``dsa_select``: ``rows_by_rank``, no sort and no scatter) and
+        each position's page from the table; a slot that does not decode
+        gets dead rows."""
+        from .paged_index_kernel import rows_by_rank
+        keep, chosen = self._chosen_set(qi, wi, i_pool, where)
+        with jax.named_scope("dsa_select"):
             idx = rows_by_rank(keep, self.topk)
             pid = jnp.take_along_axis(where.table, idx // i_pool.shape[1],
                                       axis=1)

@@ -61,6 +61,22 @@ stored as 640), scores contract the whole row and the VALUES are the first
 products.  It is the grouped form with one key/value head whose group is
 all the query heads (rounded up to sublane tiles), one product a chunk.
 
+**A chosen set.**  With ``keep`` given (``masked``: an operand STATIC in its
+presence; absent, the kernel traces to what it always traced to) a slot
+attends over a SET of its positions (``MultiHeadAttention(sparse=)``'s
+``topk`` best by the indexer): ``keep`` is a per-slot ``(1, positions / 128,
+128)`` int32 block by an ordinary ``BlockSpec``, position ``p`` lane ``p %
+128`` of row ``p // 128`` (the layout ``paged_index_select`` writes its
+scores in), non-zero where chosen.  Every live page is still copied, once:
+a page is what this chip copies cheaply (a 1 KB row costs an asynchronous
+copy as much as a 16 KB page does, and XLA's gather of the chosen rows
+more than the pages' copies), and the set needs no list.  A position is
+dead where it is past ``pos`` OR not kept.  A chunk, the first one too, may
+keep nothing, and ``exp(NEG_INF - NEG_INF)`` is 1: the dead entries of the
+probabilities are zeroed explicitly, as ``_sparse_over_blocks`` does.  The
+caller keeps at least one live position of every decoding slot (a query's
+``topk`` is never empty), so the sum is never 0.
+
 The kernel's ``name=`` is ``paged_decode_attention`` in the device trace
 (not ``flash_..``: ``perfbench/flops``' ``FLASH_KERNELS`` matches on that
 prefix and the train cells' ``flash_share`` must not learn of it).
@@ -69,6 +85,7 @@ prefix and the train cells' ``flash_share`` must not learn of it).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -86,6 +103,8 @@ _VMEM_LIMIT = 32 << 20
 
 _LATENT_ROWS = 256          # query heads over one latent row, at most
 _LATENT_CHUNK = 512         # key rows of one product over latent rows
+_SPARSE_CHUNK = 1024        # key rows of one product under a chosen set
+_SPARSE_GROUP_ROWS = 2048   # and of one copy group there, at most
 
 
 def supported(backend: str, dtype, num_heads: int, head_dim: int,
@@ -129,20 +148,24 @@ def supported(backend: str, dtype, num_heads: int, head_dim: int,
 
 
 def _geometry(page: int, pages_per_slot: int, e: int, itemsize: int,
-              window: int = 0):
+              window: int = 0, group_rows: int = _GROUP_ROWS):
     """``(chunk, group)``: key rows of one product (whole pages, 128 or
     one larger page) and of one copy group (whole chunks: no more than a
-    slot can hold, than ``_GROUP_ROWS`` or one window and the page it
+    slot can hold, than ``group_rows`` or one window and the page it
     straddles, than the buffers' budget)."""
     chunk = max(page, LANES)
     whole = -(-pages_per_slot * page // chunk) * chunk
     fits = _BUFFER_BYTES // (4 * e * itemsize) // chunk * chunk
-    want = _GROUP_ROWS if not window else max(_GROUP_ROWS, window + page)
+    want = group_rows if not window else max(group_rows, window + page)
     return chunk, max(chunk, min(whole, -(-want // chunk) * chunk, fits))
 
 
 def _kernel(table_ref, pos_ref, wp_ref, q_ref, *refs, num_heads, kv_heads,
-            scale, page, pages_per_slot, chunk, window, value_lanes=0):
+            scale, page, pages_per_slot, chunk, window, value_lanes=0,
+            masked=False):
+    keep_ref = None
+    if masked:          # the chosen set, a (1, positions / 128, 128) block
+        keep_ref, *refs = refs
     if value_lanes:     # one pool: the values are lanes of the key rows
         k_hbm, o_ref, k_buf, sems, turn = refs
         v_hbm = v_buf = None
@@ -252,15 +275,19 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, *refs, num_heads, kv_heads,
             q_heads = jnp.where(diagonal, q_ref[0].astype(jnp.float32),
                                 0.0).astype(q_ref.dtype)
 
-        def softmax_step(carry, q, k, v, kpos):
+        def softmax_step(carry, q, k, v, kpos, dropped=None):
             m, l, acc = carry
             s = _dot(q, k, _NT) * scale                        # (rows, T)
             dead = kpos > pos
             if window:
                 dead = jnp.logical_or(dead, kpos <= pos - window)
+            if dropped is not None:
+                dead = jnp.logical_or(dead, dropped)
             s = jnp.where(dead, NEG_INF, s)
             m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
             p = jnp.exp(s - m_new)
+            if dropped is not None:     # a chunk may keep nothing, and
+                p = jnp.where(dead, 0.0, p)     # exp(NEG_INF - NEG_INF) is 1
             alpha = jnp.exp(m - m_new)
             return (m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
                     alpha * acc + _dot(p.astype(v.dtype), v))
@@ -282,11 +309,19 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, *refs, num_heads, kv_heads,
 
             def one_chunk(c, carry):
                 at = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+                q_rows = head_rows if grouped else rows
                 kpos = base + c * chunk + jax.lax.broadcasted_iota(
-                    jnp.int32, (head_rows if grouped else rows, chunk), 1)
+                    jnp.int32, (q_rows, chunk), 1)
+                dropped = None
+                if masked:  # the chunk's rows of the block, side by side
+                    r0 = (base + c * chunk) // LANES
+                    dropped = jnp.broadcast_to(jnp.concatenate(
+                        [keep_ref[0, pl.ds(r0 + j, 1), :]
+                         for j in range(chunk // LANES)], axis=1),
+                        (q_rows, chunk)) == 0
                 if not grouped:
                     return softmax_step(carry, q_heads, k_buf[buf, at, :],
-                                        v_buf[buf, at, :], kpos)
+                                        v_buf[buf, at, :], kpos, dropped)
                 out = []
                 for j in heads:     # a key/value head: lanes j * head_dim ..
                     lanes = pl.ds(j * head_dim, head_dim)
@@ -294,7 +329,8 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, *refs, num_heads, kv_heads,
                     kj = k_buf[buf, at, lanes]
                     vj = (kj[:, :value_lanes] if value_lanes
                           else v_buf[buf, at, lanes])
-                    out.append(softmax_step(carry[j], qj, kj, vj, kpos))
+                    out.append(softmax_step(carry[j], qj, kj, vj, kpos,
+                                            dropped))
                 return tuple(out)
 
             return jax.lax.fori_loop(0, chunks, one_chunk, carry)
@@ -318,14 +354,21 @@ def _kernel(table_ref, pos_ref, wp_ref, q_ref, *refs, num_heads, kv_heads,
 
 
 def _paged_call(q, pools, table, pos, write_pages, static, group: int,
-                out_width: int, name: str):
+                out_width: int, name: str, keep=None):
     """The one ``pallas_call``: ``q`` (slots, query rows, width) against
     ``pools`` (K and V, or the one latent pool), the scalars prefetched,
-    the pools left in HBM, two ``group``-row buffers a pool; -> (slots,
-    query rows, ``out_width``) f32."""
+    the pools left in HBM, two ``group``-row buffers a pool; ``keep``
+    (slots, rows, 128) int32, a slot's block in VMEM, where the step
+    attends over a chosen set; -> (slots, query rows, ``out_width``) f32."""
     slots, q_rows, width = q.shape
     page, e = pools[0].shape[1], pools[0].shape[2]
     row = pl.BlockSpec((1, q_rows, width), lambda i, *_: (i, 0, 0))
+    blocks, masks = [row], ()
+    if keep is not None:
+        static = dict(static, masked=True)
+        blocks.append(pl.BlockSpec((1,) + keep.shape[1:],
+                                   lambda i, *_: (i, 0, 0)))
+        masks = (keep,)
     out_row = pl.BlockSpec((1, q_rows, out_width), lambda i, *_: (i, 0, 0))
     pool = pl.BlockSpec(memory_space=pl.ANY)
     params = None if _interpret() else pltpu.CompilerParams(
@@ -335,7 +378,7 @@ def _paged_call(q, pools, table, pos, write_pages, static, group: int,
                           pages_per_slot=table.shape[1], **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(slots,),
-            in_specs=[row] + [pool] * len(pools), out_specs=out_row,
+            in_specs=blocks + [pool] * len(pools), out_specs=out_row,
             scratch_shapes=[pltpu.VMEM((2, group, e), p.dtype)
                             for p in pools]
             + [pltpu.SemaphoreType.DMA((2, 2)),
@@ -343,7 +386,7 @@ def _paged_call(q, pools, table, pos, write_pages, static, group: int,
         out_shape=jax.ShapeDtypeStruct((slots, q_rows, out_width),
                                        jnp.float32),
         compiler_params=params, interpret=_interpret(), name=name,
-    )(table.reshape(-1), pos, write_pages, q, *pools)
+    )(table.reshape(-1), pos, write_pages, q, *masks, *pools)
 
 
 @functools.partial(jax.jit, static_argnums=(5, 6))
@@ -370,6 +413,49 @@ def paged_latent_attention(q, pool, table, pos, write_pages, scale: float,
     return out[:, :heads]
 
 
+def _over_heads(q, k_pool, v_pool, table, pos, write_pages, num_heads: int,
+                scale: float, num_kv_heads: int, window: int, name: str,
+                keep=None):
+    """:func:`paged_decode_attention` and :func:`paged_sparse_attention`:
+    the folded queries laid out as the kernel wants them (one row, or a
+    key/value head's group as ``_HEAD_ROWS`` rows), the call, the output
+    folded back.  ``keep`` (slots, positions) bool: the chosen set."""
+    slots = q.shape[0]
+    kv_heads = num_kv_heads or num_heads
+    page, pages_per_slot, e = k_pool.shape[1], table.shape[1], k_pool.shape[2]
+    chunk, group = _geometry(
+        page, pages_per_slot, e, k_pool.dtype.itemsize, window,
+        _GROUP_ROWS if keep is None else _SPARSE_GROUP_ROWS)
+    if keep is not None:
+        # longer groups and products than the dense read's 512 and 128
+        # rows (timed on the chip: PERF.md section 6): the set costs a
+        # chunk's compares whatever it keeps, and the loops' turns cost more
+        # than the rows a slot's last group reads in vain; a chunk is whole
+        # rows of the block and divides the group
+        chunk = math.gcd(group, max(chunk, _SPARSE_CHUNK))
+        positions = -(-pages_per_slot * page // chunk) * chunk
+        keep = jnp.pad(keep.astype(jnp.int32),
+                       ((0, 0), (0, positions - keep.shape[1]))
+                       ).reshape(slots, positions // LANES, LANES)
+    if kv_heads == num_heads:
+        q_rows, width = 1, e
+        q = q[:, None, :]
+    else:       # a key/value head's group of queries as _HEAD_ROWS rows
+        per, width = num_heads // kv_heads, e // kv_heads
+        q_rows = kv_heads * _HEAD_ROWS
+        q = jnp.pad(q.reshape(slots, kv_heads, per, width),
+                    ((0, 0), (0, 0), (0, _HEAD_ROWS - per), (0, 0))
+                    ).reshape(slots, q_rows, width)
+    out = _paged_call(q, (k_pool, v_pool), table, pos, write_pages,
+                      dict(num_heads=num_heads, kv_heads=kv_heads,
+                           scale=scale, chunk=chunk, window=window),
+                      group, width, name, keep)
+    if kv_heads == num_heads:
+        return out[:, 0, :]
+    return out.reshape(slots, kv_heads, _HEAD_ROWS, width)[:, :, :per].reshape(
+        slots, num_heads * width)
+
+
 # jitted so that the equal-shaped layers of a model share ONE traced and
 # lowered kernel (flash_kernel.py: tracing it per layer cost 3.5 s of set-up)
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
@@ -385,25 +471,21 @@ def paged_decode_attention(q, k_pool, v_pool, table, pos, write_pages,
     folded, zero for such a slot.  ``window``: read positions ``pos -
     window + 1 .. pos`` only, ``table`` a ring (the module's docstring).
     The caller checks :func:`supported`."""
-    slots = q.shape[0]
-    kv_heads = num_kv_heads or num_heads
-    page, pages_per_slot, e = k_pool.shape[1], table.shape[1], k_pool.shape[2]
-    chunk, group = _geometry(page, pages_per_slot, e, k_pool.dtype.itemsize,
-                             window)
-    if kv_heads == num_heads:
-        q_rows, width = 1, e
-        q = q[:, None, :]
-    else:       # a key/value head's group of queries as _HEAD_ROWS rows
-        per, width = num_heads // kv_heads, e // kv_heads
-        q_rows = kv_heads * _HEAD_ROWS
-        q = jnp.pad(q.reshape(slots, kv_heads, per, width),
-                    ((0, 0), (0, 0), (0, _HEAD_ROWS - per), (0, 0))
-                    ).reshape(slots, q_rows, width)
-    out = _paged_call(q, (k_pool, v_pool), table, pos, write_pages,
-                      dict(num_heads=num_heads, kv_heads=kv_heads,
-                           scale=scale, chunk=chunk, window=window),
-                      group, width, "paged_decode_attention")
-    if kv_heads == num_heads:
-        return out[:, 0, :]
-    return out.reshape(slots, kv_heads, _HEAD_ROWS, width)[:, :, :per].reshape(
-        slots, num_heads * width)
+    return _over_heads(q, k_pool, v_pool, table, pos, write_pages, num_heads,
+                       scale, num_kv_heads, window, "paged_decode_attention")
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def paged_sparse_attention(q, k_pool, v_pool, table, pos, write_pages, keep,
+                           num_heads: int, scale: float,
+                           num_kv_heads: int = 0):
+    """:func:`paged_decode_attention` over a CHOSEN SET: ``keep`` (slots,
+    pages_per_slot * page) bool, the positions of its table each slot's
+    query attends over (past ``pos`` none is read whatever ``keep`` says; a
+    decoding slot keeps at least one at or under ``pos``); every live page
+    is read once, where it lies -> (slots, h * hd) f32, zero for a slot that
+    is not decoding.  ``paged_sparse_attention`` in a device trace (not
+    ``paged_decode_attention``: the benchmark finds that kernel by its name
+    in other cells).  The caller checks :func:`supported`."""
+    return _over_heads(q, k_pool, v_pool, table, pos, write_pages, num_heads,
+                       scale, num_kv_heads, 0, "paged_sparse_attention", keep)

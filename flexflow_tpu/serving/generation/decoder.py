@@ -876,8 +876,9 @@ class GraphDecoder:
             out["latent"] = count(latent)
         sparse = self._of_kind("sparse")
         if sparse:          # a learned selection: ``"rows"`` copies the
-            # chosen rows out of the pools, ``"gathered"`` masks the view
-            out["sparse"] = count(sparse, ("rows", "gathered"))
+            # chosen rows out of the pools, ``"paged"`` reads the live pages
+            # in place under the set as a mask, ``"gathered"`` masks the view
+            out["sparse"] = count(sparse, ("rows", "paged", "gathered"))
         return out
 
     def _of_kind(self, kind):

@@ -17,18 +17,27 @@ core, output) against a 25 088-position table, pages of 16:
   set; the tree keeps one as ``ops/attention.select_threshold`` and the
   other is written HERE only;
 * a TOKEN step of 24 slots of which 13 decode, at positions 8 191-24 575.
-  The core two ways: ``rows`` (the 2 048 chosen rows a slot copied out of
-  the pools, then dense attention over them: what the op does on a TPU)
-  and ``gathered`` (each slot's whole page table written out as a view and
-  masked: what it does elsewhere).  And under ``rows`` the CHOICE five
-  ways: ``view + sort`` (every slot's view of ``ik`` written out, its
-  scores sorted by ``jax.lax.top_k``: the op before PR 46, written HERE
-  only), ``paged + sort`` (``ops/paged_index_kernel.py``'s scores, read
-  from the pool in place, then ``top_k``), and ``paged + threshold`` (the
-  kernel's scores AND its threshold search) with the list made in XLA
-  (``rows_by_rank``, the pages by a gather from the table: what the op
-  does; or the pages by a one-hot product too, written HERE only) or in a
-  second kernel that is written HERE only;
+  The core three ways: ``paged`` (every live page read once, where it
+  lies, by the paged decode kernel under the chosen set as a MASK: what the
+  op does on a TPU for a table of no more pages than it chooses rows, so
+  here), ``rows`` (the 2 048 chosen rows a slot copied out of the pools,
+  then dense attention over them: what it does for a longer table, held to
+  it HERE by a subclass) and ``gathered`` (each slot's whole page table
+  written out as a view and masked: what it does elsewhere).  And under
+  ``rows`` the CHOICE five ways: ``view + sort`` (every slot's view of
+  ``ik`` written out, its scores sorted by ``jax.lax.top_k``: the op before
+  PR 46, written HERE only), ``paged + sort``
+  (``ops/paged_index_kernel.py``'s scores, read from the pool in place,
+  then ``top_k``), and ``paged + threshold`` (the kernel's scores AND its
+  threshold search) with the list made in XLA (``rows_by_rank``, the pages
+  by a gather from the table: the op's ``rows``; or the pages by a one-hot
+  product too, written HERE only) or in a second kernel that is written
+  HERE only;
+* the three cores again where reading pages costs most and least: 13 of 24
+  AND 24 of 24 slots decoding, every slot at position 4 095, spread over
+  8 191-24 575, and every slot at 24 575 (all slots at the full history is
+  the ``paged`` form's WORST case in this table: about 37 600 live pages a
+  layer, where ``rows`` reads 49 152 rows whatever the history);
 * the CHOICE alone at those slots and positions, on scores that seldom tie
   and on scores that often do: the kernel's threshold and ``rows_by_rank``
   must name the SET ``jax.lax.top_k`` names on the kernel's own scores
@@ -78,7 +87,16 @@ class Gathered(att.MultiHeadAttention):
         return "gathered"
 
 
-class ViewSort(att.MultiHeadAttention):
+class Rows(att.MultiHeadAttention):
+    """The same op held to the chosen rows' copies in its token step, where
+    the table's shape would have it read pages."""
+
+    def _token_form(self, pool, table, ctx):
+        form = super()._token_form(pool, table, ctx)
+        return form if form == "gathered" else "rows"
+
+
+class ViewSort(Rows):
     """The parent's choice: every slot's view of ``ik`` written out, the
     scores from the view, ``jax.lax.top_k``, the pages by a gather."""
 
@@ -97,7 +115,7 @@ class ViewSort(att.MultiHeadAttention):
             return idx, pid, alive, jnp.sum(alive & where.live(1))
 
 
-class _KernelsScores(att.MultiHeadAttention):
+class _KernelsScores(Rows):
     """The kernel's scores and threshold (``ik`` read in place), and a list
     made of them by ``_listed`` in place of ``rows_by_rank``; the pages by a
     gather from the table."""
@@ -155,7 +173,7 @@ def pages_by_one_hot(keep, idx, table, page):
         axis=-1).astype(jnp.int32)
 
 
-class PagesByOneHot(att.MultiHeadAttention):
+class PagesByOneHot(Rows):
     """The op's choice with each row's page by :func:`pages_by_one_hot` in
     place of the gather from the table (written here only)."""
 
@@ -245,11 +263,13 @@ class PagedKernelList(_KernelsScores):
 TOKEN_FORMS = {
     "view + sort (the parent's)": ViewSort,
     "paged + sort": PagedSort,
-    "paged + threshold, list in XLA": att.MultiHeadAttention,
+    "paged + threshold, list in XLA": Rows,
     "paged + threshold, pages by a one-hot": PagesByOneHot,
     "paged + threshold, list in a kernel": PagedKernelList,
     "gathered (the CPU's)": Gathered,
+    "pages under the set as a mask": att.MultiHeadAttention,
 }
+CORES = {"rows": Rows, "gathered": Gathered, "paged": att.MultiHeadAttention}
 
 
 def _timed(fn, *args):
@@ -352,8 +372,8 @@ def main():
         tab, (pos // page)[:, None], axis=1)[:, 0], pages)
     tok = (0.5 * jax.random.normal(key, (slots, 1, d), jnp.float32)
            ).astype(jnp.bfloat16)
-    outs = {}
-    for name, cls in TOKEN_FORMS.items():
+    def token_step(cls, pos, wp):
+        """``(ms, outputs, the op)`` of one form's whole token step."""
         op, params = make(cls, slots, 1)
         if small and cls is not Gathered:   # a rehearsal: the interpreter's
             op._decode_core = lambda pool, ctx: "paged"
@@ -367,16 +387,43 @@ def main():
             return out[0]
 
         ms, out = _timed(step, params, tok, state)
-        outs[name] = np.asarray(out, np.float32)[decoding]
+        return ms, np.asarray(out, np.float32), op
+
+    outs = {}
+    for name, cls in TOKEN_FORMS.items():
+        ms, out, op = token_step(cls, pos, wp)
+        outs[name] = out[decoding]
         print(f"token {name:36s} ({op.decode_core}): {ms:8.3f} ms",
               flush=True)
-        if not small and cls is att.MultiHeadAttention:
-            assert op.decode_core == "rows"
+        if not small and cls in (att.MultiHeadAttention, Rows):
+            assert op.decode_core == ("rows" if cls is Rows else "paged")
     for against in ("view + sort (the parent's)", "paged + sort"):
         for name, out in outs.items():
             print(f"token {name:36s}: largest difference from {against!r} "
                   f"{float(np.abs(out - outs[against]).max()):.3g} (outputs "
                   f"up to {float(np.abs(out).max()):.3g})", flush=True)
+    # ---- the three cores, where pages cost most and least ----------------
+    short, last = (127 if small else 4095), max_seq - chunk - 1
+    for count in (int(decoding.sum()), slots):
+        busy = np.ones(slots, bool) if count == slots else decoding
+        for label, at in ((f"all at {short}", np.full(slots, short)),
+                          (f"{max_seq // 3}-{last}", np.asarray(pos)),
+                          (f"all at {last}", np.full(slots, last))):
+            at = jnp.asarray(at.astype(np.int32))
+            to = jnp.where(jnp.asarray(busy), jnp.take_along_axis(
+                tab, (at // page)[:, None], axis=1)[:, 0], pages)
+            got = {name: token_step(cls, at, to)
+                   for name, cls in CORES.items()}
+            live_pages = int(np.sum((np.asarray(at) // page + 1)[busy]))
+            print(f"cores, {count:2d} of {slots} decode, positions "
+                  f"{label:12s} ({live_pages:6d} live pages): " + ", ".join(
+                      f"{name} {ms:7.3f} ms" for name, (ms, _, _)
+                      in got.items()) + "; largest difference from rows: "
+                  + ", ".join(
+                      f"{name} "
+                      f"{float(np.abs(out - got['rows'][1])[busy].max()):.3g}"
+                      for name, (_, out, _) in got.items()
+                      if name != "rows"), flush=True)
     # ---- the choice alone: one row of scores, one set --------------------
     di, width = op.index_dim, op.index_width
     wi = jax.random.normal(jax.random.fold_in(key, 7), (slots, op.index_heads),

@@ -2739,6 +2739,131 @@ def test_paged_decode_kernel_takes_groups_and_a_window(window, dtype, tol):
     assert np.all(np.asarray(got)[idle] == 0.0)
 
 
+@pytest.mark.parametrize("heads,kv_heads,head_dim,dtype,tol", [
+    (8, 2, 128, "float32", 1e-5), (8, 2, 128, "bfloat16", 2e-2),
+    (2, 2, 64, "float32", 1e-5), (2, 2, 64, "bfloat16", 2e-2)],
+    ids=["groups-f32", "groups-bf16", "folded-f32", "folded-bf16"])
+def test_paged_sparse_kernel_attends_over_the_chosen_set(heads, kv_heads,
+                                                         head_dim, dtype,
+                                                         tol):
+    """The kernel with ``keep`` (interpret mode; 8 query heads over 2
+    key/value heads of 128, and 2 heads of 64 on the folded row) against
+    ``_decode_attention(.., keep=)`` over the gathered view, pages out of
+    order, 5 120 positions a slot (two and a half copy groups of 2 048):
+    slots at position 0, at a page's last row, one that keeps a tenth of its
+    history, one whose FIRST 128 positions and one whole copy group
+    (2 048-4 095) hold no chosen row (an empty chunk, the first one too, must
+    weigh nothing: ``exp(NEG_INF - NEG_INF)`` is 1), one that keeps only
+    positions past ``pos`` and its own, one that is not decoding (zero),
+    and one that keeps EVERYTHING, which must be the dense paged kernel's
+    output (float32: to the order of the sums over longer chunks; bfloat16:
+    to the rounding of a weight).  Every page past a slot's position is NaN
+    in the pools the kernel gets."""
+    from flexflow_tpu.ops.attention import _decode_attention
+    from flexflow_tpu.ops.paged_decode_kernel import (paged_decode_attention,
+                                                      paged_sparse_attention)
+
+    page, pps = 16, 320
+    e, L = kv_heads * head_dim, 16 * 320
+    pos = np.array([0, page - 1, 2700, 5119, 77, 3000, 4500], np.int32)
+    slots, idle, empty, future, whole = len(pos), 4, 3, 5, 6
+    rng = np.random.default_rng(heads)
+    num_pages = slots * pps + 3
+    table = rng.permutation(num_pages)[:slots * pps].reshape(
+        slots, pps).astype(np.int32)
+    wp = table[np.arange(slots), pos // page]
+    wp[idle] = num_pages
+    keep = rng.random((slots, L)) < 0.1
+    keep[empty, :128] = keep[empty, 2048:4096] = False
+    keep[future] = np.arange(L) > pos[future]
+    keep[whole] = True
+    keep[np.arange(slots), pos] = True
+    q, k, v = (jnp.asarray(rng.standard_normal(shape), dtype)
+               for shape in ((slots, heads * head_dim), (num_pages, page, e),
+                             (num_pages, page, e)))
+
+    def view(pool):
+        return jnp.take(pool, table, axis=0).reshape(slots, L, kv_heads,
+                                                     head_dim)
+
+    scale = 1.0 / np.sqrt(head_dim)
+    want = _decode_attention(
+        q.reshape(slots, 1, heads, head_dim), view(k), view(v),
+        jnp.asarray(pos), scale, keep=jnp.asarray(keep)[:, None, None, :])
+    stale = np.ones(num_pages, bool)
+    for i in range(slots):
+        if i != idle:
+            stale[table[i, :pos[i] // page + 1]] = False
+    poison = jnp.asarray(stale)[:, None, None]
+    k, v = jnp.where(poison, jnp.nan, k), jnp.where(poison, jnp.nan, v)
+    args = (q, k, v, jnp.asarray(table), jnp.asarray(pos), jnp.asarray(wp))
+    got = np.asarray(paged_sparse_attention(*args, jnp.asarray(keep), heads,
+                                            scale, kv_heads))
+    assert got.dtype == np.float32 and got.shape == (slots, heads * head_dim)
+    decoding = np.arange(slots) != idle
+    np.testing.assert_allclose(
+        got[decoding],
+        np.asarray(want, np.float32).reshape(slots, -1)[decoding], rtol=tol,
+        atol=tol)
+    assert np.all(got[idle] == 0.0)
+    dense = np.asarray(paged_decode_attention(*args, heads, scale, kv_heads))
+    np.testing.assert_allclose(got[whole], dense[whole], rtol=tol, atol=tol)
+
+
+def _kernel_jaxpr_digest(fn, static, *args):
+    import hashlib
+    import re
+    text = str(jax.make_jaxpr(fn, static_argnums=static)(*args))
+    return hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", text).encode()
+                          ).hexdigest()
+
+
+@pytest.mark.parametrize("form,want", [
+    ("one head a key/value head",
+     "de33fceba70b833f0d694c38d1a8770d84e2881954687367ad084356b21b3025"),
+    ("groups",
+     "4dbfac730c78f59d3fbc808a9be227c576424bc7d3c5e5751b6a7ce7392f6105"),
+    ("groups and a window",
+     "13d0358ddebd37d42a3e01f9e8e18c7798812d1c389a8a8995e46f2b70970ae2"),
+    ("a latent row",
+     "9c82e5c575b1e9236298bcfcd9725d451702c2bc5f609f013ca6ab0a0ba73897")],
+    ids=["folded", "groups", "window", "latent"])
+def test_the_paged_kernel_without_a_chosen_set_is_the_parents(form, want,
+                                                              monkeypatch):
+    """``keep`` is STATIC in its presence: without it the wrappers that were
+    there trace the kernel, body and all, to the jaxpr they traced to before
+    the kernel learned of a chosen set (sha256 of the printed jaxpr as one
+    TPU traces it, bfloat16, pages of 16, read on the parent commit under
+    this suite's ``conftest.py``): ``gpt1``'s folded heads, laguna's groups
+    without and with a window, pangu's latent row; ouro's is the first with
+    other widths.  A PR that changes the kernel for them on purpose says so
+    and moves the pins."""
+    from flexflow_tpu.ops import flash_kernel, paged_decode_kernel as pk
+
+    monkeypatch.setattr(flash_kernel, "_interpret", lambda: False)
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+
+    def sd(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype))
+
+    def decode(slots, heads, kv, hd, pps, window):
+        pool = sd((slots * pps, 16, kv * hd))
+        return (pk.paged_decode_attention, (6, 7, 8, 9),
+                sd((slots, heads * hd)), pool, pool, sd((slots, pps), "int32"),
+                sd((slots,), "int32"), sd((slots,), "int32"), heads, 0.125,
+                0 if kv == heads else kv, window)
+
+    fn, static, *args = {
+        "one head a key/value head": decode(8, 12, 12, 64, 24, 0),
+        "groups": decode(8, 48, 8, 128, 64, 0),
+        "groups and a window": decode(8, 64, 8, 128, 64, 512),
+        "a latent row": (pk.paged_latent_attention, (5, 6),
+                         sd((4, 128, 640)), sd((4 * 50, 16, 640)),
+                         sd((4, 50), "int32"), sd((4,), "int32"),
+                         sd((4,), "int32"), 0.0722, 512)}[form]
+    assert _kernel_jaxpr_digest(fn, static, *args) == want
+
+
 @pytest.mark.parametrize("why,args,ok", [
     ("6 query heads a key/value head of 128", ("tpu", "bfloat16", 48, 128,
                                                16, False, 8), True),
@@ -3249,17 +3374,11 @@ def test_paged_index_kernel_compiles_for_the_chip(v5e_device, monkeypatch):
     assert "paged_index_select" in text
 
 
-def test_the_sparse_token_step_chooses_without_a_sort_or_a_view(
-        v5e_device, monkeypatch):
-    """The WHOLE token step of a tiny graph with a learned selection
-    (bf16, heads of 128, pages of 16, 256 of 4 096 positions chosen), as
-    one TPU traces it, compiled by the TPU's compiler for a described v5e:
-    the program holds the choosing kernel, no ``sort`` anywhere
-    (``jax.lax.top_k`` is one to this compiler) and, of its gathers, none
-    under ``dsa_index`` (no view of ``ik`` is written out) and ONE under
-    ``dsa_select``, each chosen row's page from the table (4 x 256 int32):
-    the list comes by rank and one-hot products, and the only rows gathered
-    are the core's K and V."""
+def _sparse_token_step_for_the_chip(v5e_device, monkeypatch, slots, seq,
+                                    d_model, heads, kv_heads, sparse):
+    """``(decoder, compiled text)`` of the WHOLE token step of a one-layer
+    graph with a learned selection (bf16, heads of 128, pages of 16), as one
+    TPU traces it, compiled by the TPU's compiler for a described v5e."""
     from flexflow_tpu.models import build_decoder_lm
     from flexflow_tpu.ops import (attention as attn_mod, flash_kernel,
                                   paged_decode_kernel, paged_index_kernel)
@@ -3268,30 +3387,99 @@ def test_the_sparse_token_step_chooses_without_a_sort_or_a_view(
     for mod in (flash_kernel, paged_decode_kernel, paged_index_kernel):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
     cfg = ff.FFConfig(batch_size=2, compute_dtype="bfloat16", seed=0)
-    cfg.serve_gen_slots, cfg.serve_gen_max_seq = 4, 4096
+    cfg.serve_gen_slots, cfg.serve_gen_max_seq = slots, seq
     cfg.serve_prefill_chunk, cfg.serve_kv_page = 64, 16
     model = build_decoder_lm(
-        cfg, [{"attention": "full_attention", "heads": 4, "mlp": "dense"}],
-        d_model=256, head_dim=128, num_kv_heads=2, d_ff=256, vocab_size=512,
-        seq_len=4096, qk_norm=1e-6,
-        sparse={"index_heads": 4, "index_dim": 64, "topk": 256},
+        cfg, [{"attention": "full_attention", "heads": heads,
+               "mlp": "dense"}],
+        d_model=d_model, head_dim=128, num_kv_heads=kv_heads, d_ff=256,
+        vocab_size=512, seq_len=seq, qk_norm=1e-6, sparse=sparse,
         rope={"full_attention": {"rope_theta": 1e4}})[0]
     model.compile(ff.SGDOptimizer(lr=0.01), mesh=ff.MachineMesh({"n": 1}))
-    dec = GraphDecoder(model, 4, 4096, prefill_chunk=64)
+    dec = GraphDecoder(model, slots, seq, prefill_chunk=64)
     fn = dec.decode_fn()
     (args,) = [a for _, _, f, a in dec._program_specs(v5e_device) if f is fn]
     with _no_compilation_cache():
         text = _within(_COMPILE_LIMIT_S,
                        lambda: fn.lower(*args).compile().as_text())
-    assert dec.decode_attention()["sparse"] == {"rows": 1, "gathered": 0}
+    return dec, text
+
+
+def test_the_sparse_token_step_chooses_without_a_sort_or_a_view(
+        v5e_device, monkeypatch):
+    """A table of MORE pages than the op chooses rows (128 of 4 096
+    positions, 256 pages a slot): the ``"rows"`` form.  The program holds
+    the choosing kernel, no ``sort`` anywhere (``jax.lax.top_k`` is one to
+    this compiler) and, of its gathers, none under ``dsa_index`` (no view of
+    ``ik`` is written out) and ONE under ``dsa_select``, each chosen row's
+    page from the table (4 x 128 int32): the list comes by rank and one-hot
+    products, and the only rows gathered are the core's K and V."""
+    dec, text = _sparse_token_step_for_the_chip(
+        v5e_device, monkeypatch, 4, 4096, 256, 4, 2,
+        {"index_heads": 4, "index_dim": 64, "topk": 128})
+    assert dec.decode_attention()["sparse"] == {"rows": 1, "paged": 0,
+                                                "gathered": 0}
     assert "paged_index_select" in text
+    assert "paged_sparse_attention" not in text
     lines = text.splitlines()
     assert not [l for l in lines if " sort(" in l]
     gathers = [l for l in lines if " gather(" in l]
     assert not [l for l in gathers if "dsa_index" in l]
     (pages,) = [l for l in gathers if "dsa_select" in l]
-    assert " s32[4,256]" in pages
+    assert " s32[4,128]" in pages
     assert len([l for l in gathers if "dsa_core" in l]) == 2, gathers
+
+
+def test_the_sparse_token_step_reads_its_pages_under_the_chosen_set(
+        v5e_device, monkeypatch):
+    """A table of no more pages than the op chooses rows, at the widths
+    ISSUE 44's configuration serves (24 slots of 1 568 pages, 2 048 of
+    25 088 positions chosen by 16 index heads of 64, 32 query heads over 4
+    key/value heads of 128): the ``"paged"`` form.  The program holds both
+    kernels (the choice, and the paged decode kernel under the set as a
+    mask), no ``sort``, and NO gather under any of the op's three scopes:
+    the set is never a list, and no row and no page id is looked up."""
+    dec, text = _sparse_token_step_for_the_chip(
+        v5e_device, monkeypatch, 24, 25088, 2048, 32, 4,
+        {"index_heads": 16, "index_dim": 64, "topk": 2048})
+    assert dec.decode_attention() == {
+        "paged": 1, "gathered": 0,
+        "sparse": {"rows": 0, "paged": 1, "gathered": 0}}
+    assert "paged_index_select" in text and "paged_sparse_attention" in text
+    assert "paged_decode_attention" not in text
+    lines = text.splitlines()
+    assert not [l for l in lines if " sort(" in l]
+    assert not [l for l in lines if " gather(" in l and "dsa_" in l]
+    for part in ("dsa_index", "dsa_select", "dsa_core"):
+        assert [l for l in lines if part in l], part
+
+
+def test_paged_sparse_kernel_compiles_for_the_chip(v5e_device, monkeypatch):
+    """The paged decode kernel with a chosen set at those widths (32 query
+    heads over 4 key/value heads of 128, bf16 pools, 24 slots of 1 568 pages
+    of 16, the set a ``(196, 128)`` int32 block a slot), compiled by the
+    TPU's compiler for a described v5e: the block's one-row loads side by
+    side, the lane slices and the VMEM budget, which interpret mode cannot
+    show."""
+    from flexflow_tpu.ops import flash_kernel, paged_decode_kernel as pk
+    from jax.sharding import SingleDeviceSharding
+
+    monkeypatch.setattr(flash_kernel, "_interpret", lambda: False)
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    chip = SingleDeviceSharding(v5e_device)
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    slots, pps = 24, 1568
+    pool = sd((slots * pps, 16, 4 * 128), jnp.bfloat16)
+    args = (sd((slots, 32 * 128), jnp.bfloat16), pool, pool,
+            sd((slots, pps), jnp.int32), sd((slots,), jnp.int32),
+            sd((slots,), jnp.int32), sd((slots, pps * 16), jnp.bool_))
+    with _no_compilation_cache():
+        text = _within(_COMPILE_LIMIT_S, lambda: pk.paged_sparse_attention
+                       .lower(*args, 32, 0.088, 4).compile().as_text())
+    assert "paged_sparse_attention" in text
 
 
 def test_a_looped_token_step_reads_every_passs_region_in_place(

@@ -253,7 +253,8 @@ def test_sparse_graph_serves_what_its_forward_computes(sparse_lm):
     for p, out in zip(prompts, outs):
         assert out == reference_decode(model, p, 10, SEQ)
     assert snap["decode_attention"] == {
-        "paged": 0, "gathered": 2, "sparse": {"rows": 0, "gathered": 2}}
+        "paged": 0, "gathered": 2,
+        "sparse": {"rows": 0, "paged": 0, "gathered": 2}}
     assert snap["chunk_attention"] == {"sparse": {
         "mask": 6, "gather": 0, "loop": 0, "dense": 0}}
     assert snap["kv_pages"]["full"]["bytes_per_token"] == {
@@ -429,11 +430,121 @@ def test_the_sparse_graph_serves_its_tokens_through_the_kernel(monkeypatch):
     for p, out in zip(prompts, outs):
         assert out == reference_decode(model, p, 10, SEQ)
     assert snap["decode_attention"] == {
-        "paged": 0, "gathered": 0, "sparse": {"rows": 2, "gathered": 0}}
+        "paged": 0, "gathered": 0,
+        "sparse": {"rows": 2, "paged": 0, "gathered": 0}}
     assert snap["chunk_attention"]["sparse"]["mask"] > 0
     for name in ("attention_0", "attention_1"):
         got = snap["sparse_attention"][name]
         assert got["chosen_mean"] <= TOPK < got["live_mean"]
+
+
+@pytest.mark.parametrize("pages_per_slot,core,form", [
+    (TOPK - 1, "paged", "paged"), (TOPK, "paged", "paged"),
+    (TOPK + 1, "paged", "rows"), (4 * TOPK, "paged", "rows"),
+    (TOPK, "gathered", "gathered"), (TOPK + 1, "gathered", "gathered")])
+def test_the_token_form_is_read_off_the_tables_shape(monkeypatch,
+                                                     pages_per_slot, core,
+                                                     form):
+    """Where the in-place read applies, a table of no more pages than the
+    op chooses rows reads its pages under the set as a mask and a longer one
+    copies the chosen rows; elsewhere the masked view.  Shapes at trace
+    time, nothing else."""
+    op, _ = _op(SPARSE, n=3, s=1)
+    monkeypatch.setattr(op, "_decode_core", lambda pool, ctx: core)
+    table = jax.ShapeDtypeStruct((3, pages_per_slot), jnp.int32)
+    pool = jax.ShapeDtypeStruct((3 * pages_per_slot, 4, 16), jnp.float32)
+    assert op._token_form(pool, table, CTX) == form
+
+
+@pytest.mark.parametrize("ties", [False, True], ids=["", "ties"])
+@pytest.mark.parametrize("positions,idle", [
+    ((3, 17, 30, 39), (2,)),        # under and past topk, one not decoding
+    ((39, 38, 37, 36), ()),         # every slot at the table's end
+    ((0, 5, 11, 12), (0, 3)),       # nothing to leave out but in one slot
+    ((20, 20, 20, 20), (0, 1, 2)),  # one slot decodes
+])
+def test_a_token_step_that_reads_its_pages_under_the_set_is_the_masked_view(
+        monkeypatch, positions, idle, ties):
+    """The token step's three forms on one state, at pages that lie out of
+    order, an op that keeps 12 of a table of 10 pages of 4: ``paged`` (the
+    choice by the kernel, then the paged decode kernel over every live page
+    under the set as a mask: no list, nothing gathered) against ``rows``
+    (the set as a list, its rows gathered) and ``gathered`` (the whole view
+    under a mask): the same outputs for the decoding slots, the same rows in
+    the three leaves, the same counts.  With ``ties`` every cached position
+    holds one of THREE indexer keys, so a third of a slot's positions score
+    the same to the bit, many tie AT the threshold and the lower ones must be
+    the ones kept."""
+    pages, page, slots, topk = 40, 4, 4, 12
+    op, params = _op(dict(SPARSE, topk=topk), n=slots, s=1)
+    rng = np.random.default_rng(sum(positions))
+    ik = rng.normal(size=(pages, page, 128))
+    if ties:
+        ik = rng.normal(size=(3, 128))[rng.integers(0, 3, (pages, page))]
+    state = {"k": jnp.asarray(rng.normal(size=(pages, page, 16)), jnp.float32),
+             "v": jnp.asarray(rng.normal(size=(pages, page, 16)), jnp.float32),
+             "ik": jnp.asarray(ik, jnp.float32).at[..., 8:].set(0),
+             "counts": jnp.zeros((4, 2), jnp.int32)}
+    table = jnp.asarray(rng.permutation(pages).reshape(slots, 10), jnp.int32)
+    pos = jnp.asarray(positions, jnp.int32)
+    wp = jnp.take_along_axis(table, (pos // page)[:, None], 1)[:, 0]
+    wp = wp.at[jnp.asarray(idle, jnp.int32)].set(pages)
+    x = jnp.asarray(rng.normal(size=(slots, 1, 32)), jnp.float32)
+    where = ServeStep("token", table, pos=pos, write_pages=wp,
+                      write_rows=pos % page, no_page=pages)
+    monkeypatch.setattr(op, "_decode_core", lambda pool, ctx: "paged")
+    got = {}
+    for form in ("paged", "gathered", "rows"):
+        if form != "paged":     # held to the other forms: the rule says paged
+            monkeypatch.setattr(op, "_token_form",
+                                lambda pool, table, ctx, f=form: f)
+        out, new = op.serve_step(params, [x], state, where, CTX)
+        assert op.decode_core == form
+        got[form] = (np.asarray(out[0]), new)
+    live = [s for s in range(slots) if s not in idle]
+    for other in ("gathered", "rows"):
+        np.testing.assert_allclose(got["paged"][0][live],
+                                   got[other][0][live], atol=2e-5)
+        for leaf in ("k", "v", "ik"):
+            assert (np.asarray(got["paged"][1][leaf])
+                    == np.asarray(got[other][1][leaf])).all()
+        assert op.selection_stats(got["paged"][1]["counts"]) \
+            == op.selection_stats(got[other][1]["counts"])
+    counts = op.selection_stats(got["paged"][1]["counts"])
+    assert counts["queries"] == len(live)
+    assert counts["chosen_mean"] == pytest.approx(
+        sum(min(positions[s] + 1, topk) for s in live) / len(live))
+
+
+def test_the_sparse_graph_serves_the_same_tokens_from_pages_and_from_rows(
+        monkeypatch):
+    """A graph that keeps 16 of a table of 12 pages, every token step
+    steered to the kernels: by the rule it reads its pages under the set as
+    a mask (``"paged"``: counted under ``"sparse"`` and, reading the pool in
+    place, in the graph's ``"paged"`` total); held to ``"rows"`` it serves
+    the same tokens, which are the ones the graph's own forward gives, at
+    histories under and past ``topk``, two streams at once."""
+    _steer_to_the_kernel(monkeypatch)
+    model = _build(dict(SPARSE, topk=16), seed=11)
+    rng = np.random.default_rng(48)
+    prompts = [rng.integers(1, VOCAB, n).astype(np.int32)
+               for n in (5, 13, 23, 37)]
+    paged, snap = _serve(model, prompts)
+    assert snap["decode_attention"] == {
+        "paged": 2, "gathered": 0,
+        "sparse": {"rows": 0, "paged": 2, "gathered": 0}}
+    for name in ("attention_0", "attention_1"):
+        got = snap["sparse_attention"][name]
+        assert got["chosen_mean"] <= 16 < got["live_mean"]
+    monkeypatch.setattr(att.MultiHeadAttention, "_token_form",
+                        lambda self, pool, table, ctx: "rows")
+    # (a graph of its own: a model keeps the programs it traced)
+    rows, snap = _serve(_build(dict(SPARSE, topk=16), seed=11), prompts)
+    assert snap["decode_attention"]["sparse"] == {
+        "rows": 2, "paged": 0, "gathered": 0}
+    assert paged == rows
+    for p, out in zip(prompts, paged):
+        assert out == reference_decode(model, p, 10, SEQ)
 
 
 def test_serving_under_topk_is_the_dense_graph_bit_for_bit():
